@@ -21,8 +21,6 @@ Subpackages (lazily importable):
 
 import logging as _logging
 
-from apex_tpu import _compat as _compat  # installs jax version shims
-
 __version__ = "0.1.0"
 
 

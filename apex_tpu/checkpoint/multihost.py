@@ -39,8 +39,8 @@ carry a bumped `attempt` — the barrier refuses to mix a surviving
 host's fresh files with a dead attempt's stale sub-manifest (the crc
 sweep alone cannot distinguish two internally-consistent attempts).
 
-CPU-emulation note: jax 0.4.x cannot run cross-process collectives on
-the CPU backend, so `scripts/fleet_probe.py` exercises this protocol
+CPU-emulation note: `scripts/fleet_probe.py` runs no cross-process
+collective on the CPU backend; it exercises this protocol
 with per-process deterministic replicas of the compute and genuinely
 distributed writes + real process kills — the commit/barrier layer
 under test here is exactly the code path a real TPU pod runs.

@@ -3,6 +3,8 @@
 ≡ the reference's pybind11 extension loading (`import apex_C` etc.) —
 here a plain ctypes binding with automatic build-on-first-use and pure
 Python fallbacks, so the package works with or without a toolchain.
+The fallbacks compute the SAME answers (the shuffle included), and a
+build or load failure is reported once with its reason, never silent.
 """
 
 from __future__ import annotations
@@ -10,6 +12,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 from typing import Optional
 
 import numpy as np
@@ -25,15 +28,18 @@ def _load() -> Optional[ctypes.CDLL]:
     if _LIB is not None or _TRIED:
         return _LIB
     _TRIED = True
-    if not os.path.exists(_SO):
-        try:
+    try:
+        if not os.path.exists(_SO):
             subprocess.run(["sh", os.path.join(_DIR, "build_host_runtime.sh")],
                            check=True, capture_output=True, timeout=120)
-        except Exception:
-            return None
-    try:
         lib = ctypes.CDLL(_SO)
-    except OSError:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = (getattr(e, "stderr", None) or b"").decode(
+            errors="replace").strip()[-400:]
+        warnings.warn(
+            f"apex_tpu.csrc: native host runtime unavailable ({e!r}"
+            f"{': ' + detail if detail else ''}); using the pure-Python "
+            "paths", RuntimeWarning, stacklevel=3)
         return None
     i64p = ctypes.POINTER(ctypes.c_int64)
     lib.flat_layout.restype = ctypes.c_int64
@@ -109,12 +115,36 @@ def shuffle_indices(n: int, seed: int):
     """Deterministic Fisher-Yates permutation of [0, n)."""
     lib = _load()
     if lib is None:
-        rng = np.random.RandomState(seed & 0x7FFFFFFF)
-        return rng.permutation(n).astype(np.int64)
+        return _shuffle_indices_py(n, seed)
     out = np.empty(n, np.int64)
     lib.shuffle_indices(n, seed,
                         out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
     return out
+
+
+def _shuffle_indices_py(n: int, seed: int):
+    """host_runtime.cpp's shuffle_indices, statement for statement
+    (xorshift128+ Fisher-Yates on uint64), so an epoch's order does not
+    depend on whether a compiler was present."""
+    mask = (1 << 64) - 1
+    s0 = (seed ^ 0x9E3779B97F4A7C15) & mask
+    s1 = ((seed << 1) | 0x243F6A8885A308D3) & mask
+
+    def nxt():
+        nonlocal s0, s1
+        x, y = s0, s1
+        s0 = y
+        x ^= (x << 23) & mask
+        s1 = x ^ y ^ (x >> 17) ^ (y >> 26)
+        return (s1 + y) & mask
+
+    for _ in range(8):
+        nxt()
+    out = list(range(n))
+    for i in range(n - 1, 0, -1):
+        j = nxt() % (i + 1)
+        out[i], out[j] = out[j], out[i]
+    return np.asarray(out, np.int64)
 
 
 def gather_rows(dataset: np.ndarray, indices, num_threads: int = 4):

@@ -26,10 +26,7 @@ from typing import Any, List, Optional, Sequence
 
 import jax
 
-try:  # jax >= 0.5 moves the core IR types to jax.extend.core
-    from jax.extend.core import Literal as _Literal
-except ImportError:  # pragma: no cover — 0.4.x
-    _Literal = jax.core.Literal
+from jax.extend.core import Literal as _Literal
 
 from apex_tpu.lint.findings import Finding
 

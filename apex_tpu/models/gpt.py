@@ -329,10 +329,16 @@ class GPT:
         """LM head with tied embedding weight → vocab-sharded logits
         (S, B, V/tp).  With SP the hidden is re-gathered first."""
         c = self.c
-        if c.sequence_parallel:
-            h = gather_from_sequence_parallel_region(h, c.axis_name)
         w = params["embed"]["weight"]  # local (V/tp, H)
-        x = copy_to_tensor_model_parallel_region(h, c.axis_name)
+        # each rank's d(hidden) is partial (its vocab shard only) and
+        # must be summed over tp exactly once on the way back: by the
+        # gather's reduce-scatter under SP, by the copy's psum otherwise
+        # (≡ ColumnParallelLinear; both together scale every gradient
+        # upstream of the head by tp)
+        if c.sequence_parallel:
+            x = gather_from_sequence_parallel_region(h, c.axis_name)
+        else:
+            x = copy_to_tensor_model_parallel_region(h, c.axis_name)
         out_dtype = c.logits_dtype or jnp.float32
         return jnp.einsum("sbh,vh->sbv", x, w,
                           preferred_element_type=jnp.float32
@@ -468,6 +474,29 @@ class GPTPipelined(GPT):
                               checkpoint_window=self.checkpoint_window,
                               loss_fn=head_one, loss_args=lbl)
         return total / m
+
+
+def qkv_as_tp1(tree, config: GPTConfig, tp: int):
+    """The tp=1 spelling of a GPT param (or grad) pytree laid out for
+    tensor parallelism `tp`.
+
+    Each rank views ITS columns of the packed QKV projection as
+    (3, heads/tp, head_dim), so the global column order is rank-major,
+    (tp, 3, heads/tp, d), where tp=1 reads (3, heads, d): the same
+    global arrays are a different network under a different tp.  Every
+    other leaf means the same at any tp.  Regrouped by this function, a
+    tp=`tp` tree computes under tp=1 exactly what it computed under tp."""
+    def regroup(x):
+        lead = x.shape[:-1]
+        x = x.reshape(*lead, tp, 3, config.num_heads // tp, config.head_dim)
+        return jnp.moveaxis(x, -4, -3).reshape(*lead, -1)
+
+    out = dict(tree)
+    for i in range(config.num_layers):
+        block = dict(tree[f"block{i}"])
+        block["qkv"] = jax.tree_util.tree_map(regroup, block["qkv"])
+        out[f"block{i}"] = block
+    return out
 
 
 def gpt_350m(**overrides) -> GPT:

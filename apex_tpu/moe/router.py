@@ -126,12 +126,10 @@ def topk_gates(x, wg, top_k: int,
     work) and a miss falls back to the dense reference — byte-identical
     on every path, per the tune/ contract."""
     if block_rows is None:
-        try:
-            from apex_tpu import tune
-            cfg = tune.tuned("moe_router", tune.moe_router_attrs(
-                x.shape[0], wg.shape[1], top_k, x.dtype))
-        except Exception:  # pragma: no cover — tuner must never break ops
-            cfg = None
+        from apex_tpu import tune
+
+        cfg = tune.tuned("moe_router", tune.moe_router_attrs(
+            x.shape[0], wg.shape[1], top_k, x.dtype))
         if cfg:
             blk = cfg.get("block_rows")
             if isinstance(blk, int) and 8 <= blk <= 1 << 16 \

@@ -149,15 +149,12 @@ def _classify_budget(args: Sequence[Any], names: Sequence[str]) -> dict:
 
 
 def _cost_entry(compiled) -> Optional[dict]:
-    """cost_analysis() is a list of per-program dicts on jax 0.4.x and
-    a single dict on newer releases; normalize to the first program's
-    dict (the train step is one program)."""
+    """The program's cost_analysis() dict, or None where the backend
+    withholds it."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     return ca if isinstance(ca, dict) else None
 
 

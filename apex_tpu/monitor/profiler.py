@@ -94,10 +94,9 @@ class ProfileCapture:
             self._fired = True
         if self._active:
             # StepTraceAnnotation groups the step in the trace viewer's
-            # step axis; older jax falls back to a plain annotation
-            mk = getattr(jax.profiler, "StepTraceAnnotation", None)
-            ann = (mk(self.annotation, step_num=i) if mk is not None
-                   else jax.profiler.TraceAnnotation(self.annotation))
+            # step axis
+            ann = jax.profiler.StepTraceAnnotation(self.annotation,
+                                                   step_num=i)
         else:
             ann = contextlib.nullcontext()
         self._step_depth += 1

@@ -20,6 +20,13 @@ import jax.numpy as jnp
 _FORCE = os.environ.get("APEX_TPU_FORCE_PALLAS", "")
 
 
+def on_chip() -> bool:
+    """The one answer to "am I on the chip": the default backend's
+    devices are TPUs.  Kernel dispatch, interpret mode, the tuner's
+    device kind, bench.py, the probes and chip_smoke.py all ask here."""
+    return jax.devices()[0].platform == "tpu"
+
+
 def use_pallas(override=None) -> bool:
     """Decide kernel path: Pallas on TPU, jnp reference elsewhere.
 
@@ -32,7 +39,7 @@ def use_pallas(override=None) -> bool:
         return True
     if _FORCE == "0":
         return False
-    return jax.default_backend() == "tpu"
+    return on_chip()
 
 
 def use_pallas_fusable(override=None) -> bool:
@@ -54,7 +61,7 @@ def use_pallas_fusable(override=None) -> bool:
 
 def pallas_interpret() -> bool:
     """Pallas kernels run in interpret mode off-TPU (for CPU CI parity)."""
-    return jax.default_backend() != "tpu"
+    return not on_chip()
 
 
 def round_up(n: int, m: int) -> int:
@@ -77,13 +84,10 @@ def tuned_row_block(op: str, rows: int, hidden: int, **kw) -> int:
     block_rows is a sane sublane multiple wins, anything else falls
     back to the deterministic heuristic.  Trace-time host-side lookup
     only — no device work (tune package docstring)."""
+    from apex_tpu import tune
+
     base = row_block(rows, hidden, **kw)
-    try:
-        from apex_tpu import tune
-        cfg = tune.tuned(op, dict(rows=tune.pow2_bucket(rows),
-                                  hidden=hidden))
-    except Exception:  # pragma: no cover — tuner must never break ops
-        return base
+    cfg = tune.tuned(op, dict(rows=tune.pow2_bucket(rows), hidden=hidden))
     if cfg:
         blk = cfg.get("block_rows")
         if (isinstance(blk, int) and 8 <= blk <= 4096 and blk % 8 == 0):

@@ -206,8 +206,7 @@ def _dropout_keep(seed_ref, i, j, t, shape, rate):
     dropout composes across ring steps (fwd and bwd see one mask).
     The hardware PRNG (pltpu.prng_random_bits) is NOT usable here: its
     stream→element mapping follows each kernel's codegen, so forward
-    and backward kernels with different structure silently disagree
-    (caught by the examples/tpu_kernel_smoke.py dropout gate)."""
+    and backward kernels with different structure silently disagree."""
     bk, bq = shape
     krow = (seed_ref[2, 0] + t * bk
             + lax.broadcasted_iota(jnp.int32, shape, 0))  # k global
@@ -1144,10 +1143,8 @@ def _tuned_flash_config(b, h, sq, sk, d, dtype, causal, bias_kind,
     tile must fit VMEM; anything off warns once and is ignored
     (divisibility fixups happen later in _resolve_blocks /
     _resolve_heads_per_step)."""
-    try:
-        from apex_tpu import tune
-    except Exception:  # pragma: no cover — tune must never break attn
-        return None
+    from apex_tpu import tune
+
     if sq != sk:
         return None   # tuned entries are swept at self-attention shapes
     cfg = tune.tuned("flash_sdpa",

@@ -135,10 +135,8 @@ def _tuned_decode_config(n_slots, q_len, hq, hkv, d, page_size, dtype):
     dict access, None on a miss so an empty cache keeps the
     heuristics.  A hit is sanity-validated (hand-edited caches degrade,
     never crash a serving step)."""
-    try:
-        from apex_tpu import tune
-    except Exception:  # pragma: no cover — tune must never break decode
-        return None
+    from apex_tpu import tune
+
     cfg = tune.tuned("flash_decode",
                      tune.decode_attrs(n_slots, q_len, hq, hkv, d,
                                        page_size, dtype))

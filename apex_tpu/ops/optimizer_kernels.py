@@ -53,12 +53,10 @@ def _block_rows(rows: int, kernel: str) -> int:
     row count, else the swept default _BLOCK_ROWS.  Trace-time lookup
     only (apex_tpu.tune) — an empty cache is byte-identical to the
     constant."""
-    try:
-        from apex_tpu import tune
-        cfg = tune.tuned("opt_flat", dict(kernel=kernel,
-                                          rows=tune.pow2_bucket(rows)))
-    except Exception:  # pragma: no cover — tuner must never break opts
-        return _BLOCK_ROWS
+    from apex_tpu import tune
+
+    cfg = tune.tuned("opt_flat", dict(kernel=kernel,
+                                      rows=tune.pow2_bucket(rows)))
     if cfg:
         br = cfg.get("block_rows")
         if isinstance(br, int) and 8 <= br <= 4096 and rows % br == 0:

@@ -74,13 +74,10 @@ def default_page_size(n_kv_heads: int, head_dim: int, dtype=None) -> int:
     of the decode kernel fills whole vregs, and at d=64/bf16 a page is
     16 KiB per kv head, a comfortable DMA unit.  Pure host-side lookup,
     safe at trace time (tune package docstring)."""
-    try:
-        from apex_tpu import tune
-        cfg = tune.tuned("serve_page",
-                         tune.serve_page_attrs(n_kv_heads, head_dim,
-                                               dtype))
-    except Exception:  # pragma: no cover — tune must never break serve
-        cfg = None
+    from apex_tpu import tune
+
+    cfg = tune.tuned("serve_page",
+                     tune.serve_page_attrs(n_kv_heads, head_dim, dtype))
     if cfg:
         ps = cfg.get("page_size")
         if isinstance(ps, int) and 8 <= ps <= 2048 and ps % 8 == 0:

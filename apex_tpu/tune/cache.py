@@ -83,12 +83,10 @@ def device_kind() -> str:
     """
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover — backend init failure
-        return "unknown"
-    if backend != "tpu":
-        return backend
+    from apex_tpu.ops._common import on_chip
+
+    if not on_chip():
+        return jax.default_backend()
     kind = jax.devices()[0].device_kind.lower()
     for sub, canon in _DEVICE_ALIASES:
         if sub in kind:

@@ -1,0 +1,706 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+A smoke, not a benchmark: it drives the main path once on one TPU chip
+through the entry points a user calls, at the full width of GPT-350M,
+and checks what comes out by the repo's own means.
+
+    python chip_smoke.py               # one chip: kernels, train, serve
+    python chip_smoke.py --multichip   # four chips: tp=2 x dp=2 vs tp=1
+
+One process, no child, no CPU fallback.  Every line on stdout is one
+JSON object; the last is `{"ok": true, "device": {...}}`, or carries
+`"ok": false` when JAX finds no TPU or any phase raised (the exception
+then propagates and the exit code is non-zero).  The seconds printed
+are host wall time around `jax.block_until_ready` and say whether the
+program ran, not how fast the system is.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import math
+import os
+import sys
+import time
+from typing import Callable, Optional, Sequence
+
+# GPT-350M as bench.py trains it: the widths are the model's, nothing cut
+FLAGSHIP = dict(vocab_size=50304, seq_len=1024, hidden=1024, num_layers=24,
+                num_heads=16)
+BATCH, SEQ = 12, 1024
+LN_VOCAB_TOL = 0.5      # tests/test_gpt_minimal.py::test_init_loss_near_uniform
+# Full-size Adam steps from step 1, with no warm-up, overshoot on one fixed
+# batch: at lr 1e-4 the 350M loss wobbles (11.02, 10.81, 10.91, 10.66,
+# 10.40, 11.07 on the chip, kernels and jnp references alike), at 1e-5 it
+# falls on every step.  A smoke wants the second.
+LR = 1e-5
+MULTICHIP_RTOL = 2e-3   # per-step loss, tp=2 x dp=2 (bf16) against tp=1
+
+
+def emit(**record) -> None:
+    print(json.dumps(record), flush=True)
+
+
+class CacheEvents:
+    """Counts JAX's persistent-compilation-cache hits and misses."""
+
+    _PREFIX = "/jax/compilation_cache/cache_"
+
+    def __init__(self):
+        import jax
+
+        self.hits = self.misses = 0
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event, **_):
+        if event == self._PREFIX + "hits":
+            self.hits += 1
+        elif event == self._PREFIX + "misses":
+            self.misses += 1
+
+    def snapshot(self) -> dict:
+        return {"persistent_cache_hits": self.hits,
+                "persistent_cache_misses": self.misses}
+
+
+# ------------------------------- kernels -------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class KernelCase:
+    """One main-path kernel at one real-width shape: the dispatching op
+    as callers reach it, its own jnp reference, and the tolerance
+    `|got - want| <= atol + rtol * |want|` held elementwise."""
+
+    name: str
+    make_args: Callable        # PRNG key -> tuple of arrays
+    kernel: Callable           # *args -> tuple of arrays
+    reference: Callable        # *args -> tuple of arrays
+    rtol: float
+    atol: float
+    mosaic: bool = True        # a tpu_custom_call must be in the program
+
+
+def flash_case(name, shape, config: Optional[dict] = None) -> KernelCase:
+    """Causal bf16 flash attention, forward and backward, against the
+    dense reference.  config None leaves the kernel shape to the tuner
+    and the heuristics, as the models do.  The reference walks the
+    batch one row at a time so its (S, S) scores stay small."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.flash_attention import (
+        attention_reference,
+        flash_attention,
+    )
+
+    def make_args(key):
+        ks = jax.random.split(key, 4)
+        return tuple(jax.random.normal(k, shape, jnp.bfloat16) for k in ks)
+
+    def fwd_bwd(attn, q, k, v, do):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(do)
+
+    def kernel(q, k, v, do):
+        return fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, **(config or {})), q, k, v, do)
+
+    def reference(q, k, v, do):
+        def one(row):
+            return fwd_bwd(lambda q, k, v: attention_reference(
+                q, k, v, causal=True), *(a[None] for a in row))
+        outs = jax.lax.map(one, (q, k, v, do))
+        return tuple(o[:, 0] for o in outs)
+
+    # bf16 in and out: a few bf16 ulps at the O(1) magnitudes attention
+    # produces, the bound tests/test_flash_attention.py holds bf16 to
+    return KernelCase(name, make_args, kernel, reference, 5e-2, 5e-2)
+
+
+def adam_case(name, n, state_dtype) -> KernelCase:
+    """adam_flat over n elements with bf16 grads against its jnp
+    reference (the same op with the kernel switched off)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops import optimizer_kernels as K
+
+    n = -(-n // K.FLAT_TILE) * K.FLAT_TILE
+
+    def make_args(key):
+        kp, km, kv, kg = jax.random.split(key, 4)
+        return ((jax.random.normal(kp, (n,), jnp.float32) * 0.02
+                 ).astype(state_dtype),
+                (jax.random.normal(km, (n,), jnp.float32) * 1e-3
+                 ).astype(state_dtype),
+                (jax.random.uniform(kv, (n,), jnp.float32) * 1e-6
+                 ).astype(state_dtype),
+                (jax.random.normal(kg, (n,), jnp.float32) * 1e-3
+                 ).astype(jnp.bfloat16))
+
+    step = functools.partial(K.adam_flat, lr=1e-4, step=10,
+                             weight_decay=0.01)
+    # both sides do the update in fp32 and round once to the state
+    # dtype: a few ulps of that dtype, plus what cancellation between
+    # 1e-3-sized moments and grads leaves in fp32
+    rtol = 4 * float(jnp.finfo(state_dtype).eps)
+    return KernelCase(
+        name, make_args, step,
+        functools.partial(step, use_pallas_override=False), rtol, 1e-9)
+
+
+def _loss_and_dlogits(loss_fn, logits, labels, g):
+    import jax
+
+    loss, vjp = jax.vjp(lambda x: loss_fn(x, labels), logits)
+    return loss, vjp(g)[0]
+
+
+def xent_case(name, rows, vocab) -> KernelCase:
+    """The Pallas softmax cross entropy on bf16 logits, loss and
+    d(logits), against its jnp reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.xentropy import (
+        softmax_cross_entropy_loss,
+        softmax_cross_entropy_reference,
+    )
+
+    def make_args(key):
+        kl, kt, kg = jax.random.split(key, 3)
+        return (jax.random.normal(kl, (rows, vocab), jnp.bfloat16),
+                jax.random.randint(kt, (rows,), 0, vocab),
+                jax.random.uniform(kg, (rows,), jnp.float32))
+
+    # fp32 math on both sides; d(logits) is rounded to bf16 once
+    return KernelCase(
+        name, make_args,
+        lambda *a: _loss_and_dlogits(softmax_cross_entropy_loss, *a),
+        lambda *a: _loss_and_dlogits(softmax_cross_entropy_reference, *a),
+        2e-2, 1e-6)
+
+
+def vocab_parallel_xent_case(name, seq, batch, vocab, device) -> KernelCase:
+    """The loss the GPT step really calls: the fused custom_vjp
+    vocab-parallel cross entropy on bf16 logits against the unfused AD
+    spelling.  Plain XLA, no Mosaic kernel; it runs under a tp axis of
+    `device` alone, as it does inside the one-chip step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from apex_tpu.transformer.tensor_parallel.cross_entropy import (
+        vocab_parallel_cross_entropy,
+    )
+
+    def make_args(key):
+        kl, kt, kg = jax.random.split(key, 3)
+        return (jax.random.normal(kl, (seq, batch, vocab), jnp.bfloat16),
+                jax.random.randint(kt, (seq, batch), 0, vocab),
+                jax.random.uniform(kg, (seq, batch), jnp.float32))
+
+    mesh = Mesh(np.asarray([device]), ("tp",))
+
+    def run(fused, *args):
+        def loss_fn(logits, labels):
+            return vocab_parallel_cross_entropy(logits, labels,
+                                                axis_name="tp", fused=fused)
+
+        return shard_map(
+            lambda *a: _loss_and_dlogits(loss_fn, *a), mesh=mesh,
+            in_specs=(P(), P(), P()), out_specs=(P(), P()),
+            check_vma=False)(*args)
+
+    return KernelCase(
+        name, make_args, lambda *a: run(True, *a),
+        lambda *a: run(False, *a), 2e-2, 1e-6, mosaic=False)
+
+
+def decode_case(name, n_slots, heads, head_dim, page, pages_per_slot
+                ) -> KernelCase:
+    """flash_decode at a serving engine's shape: one query token per
+    slot against a paged bf16 cache, slots at every fill level
+    (empty included), against the dense gathered reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.flash_decode import (
+        flash_decode,
+        paged_attention_reference,
+    )
+
+    n_pages = 1 + n_slots * pages_per_slot
+
+    def make_args(key):
+        kq, kk, kv, kl = jax.random.split(key, 4)
+        pool = (heads, n_pages, page, head_dim)
+        table = 1 + jnp.arange(n_slots * pages_per_slot, dtype=jnp.int32
+                               ).reshape(n_slots, pages_per_slot)
+        return (jax.random.normal(kq, (n_slots, 1, heads, head_dim),
+                                  jnp.bfloat16),
+                jax.random.normal(kk, pool, jnp.bfloat16),
+                jax.random.normal(kv, pool, jnp.bfloat16),
+                table,
+                jax.random.randint(kl, (n_slots,), 0,
+                                   page * pages_per_slot + 1))
+
+    return KernelCase(
+        name, make_args,
+        lambda *a: (flash_decode(*a),),
+        lambda *a: (paged_attention_reference(*a),), 5e-2, 5e-2)
+
+
+PACKED_FLASH_SHAPE = (8, 16, 2048, 64)   # a key of tune/defaults.py on v5e
+
+
+def packed_flash_attrs() -> dict:
+    """The tuner's lookup attrs for flash attention at PACKED_FLASH_SHAPE."""
+    from apex_tpu import tune
+
+    b, h, s, d = PACKED_FLASH_SHAPE
+    return tune.flash_attrs(b, h, s, s, d, "bfloat16", True)
+
+
+def flagship_config(**overrides):
+    """GPT-350M as bench.py trains it."""
+    import jax.numpy as jnp
+
+    from apex_tpu.models.gpt import GPTConfig
+
+    return GPTConfig(dropout=0.0, dtype=jnp.bfloat16,
+                     logits_dtype=jnp.bfloat16, remat=False,
+                     use_flash_attention=True, **FLAGSHIP, **overrides)
+
+
+def kernel_cases(device) -> list:
+    """Every kernel of the GPT-350M train and serve path, at the shape
+    that path gives it, for the chip `device`."""
+    import jax.numpy as jnp
+
+    h = FLAGSHIP["num_heads"]
+    d = FLAGSHIP["hidden"] // h
+    n_params = 354_000_000
+    return [
+        flash_case("flash_350m", (BATCH, h, SEQ, d)),
+        flash_case("flash_packed", PACKED_FLASH_SHAPE),
+        adam_case("adam_flat_fp32", n_params, jnp.float32),
+        adam_case("adam_flat_bf16", n_params, jnp.bfloat16),
+        xent_case("xent_pallas", BATCH * SEQ, FLAGSHIP["vocab_size"]),
+        vocab_parallel_xent_case("xent_vocab_parallel", SEQ, BATCH,
+                                 FLAGSHIP["vocab_size"], device),
+        # build_flagship_engine(True): 64 slots, 128 + 128 tokens a
+        # slot in 128-token pages
+        decode_case("flash_decode", 64, h, d, 128, 2),
+    ]
+
+
+def kernel_check(case: KernelCase):
+    """jit(args -> per-output [worst tolerance excess, worst abs error,
+    all finite]): kernel, reference and comparison in one program, so
+    only scalars outlive it on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    def check(*args):
+        rows = []
+        for got, want in zip(case.kernel(*args), case.reference(*args),
+                             strict=True):
+            got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+            err = jnp.abs(got - want)
+            rows.append(jnp.stack([
+                jnp.max(err - case.rtol * jnp.abs(want)), jnp.max(err),
+                jnp.all(jnp.isfinite(got)).astype(jnp.float32)]))
+        return jnp.stack(rows)
+
+    return jax.jit(check)
+
+
+def run_kernel_case(case: KernelCase, seed: int, require_chip: bool) -> dict:
+    """Compile, execute and compare one case; raises on a mismatch, a
+    non-finite value, or (on the chip) a program without its kernel."""
+    import jax
+    import numpy as np
+
+    args = case.make_args(jax.random.PRNGKey(seed))
+    t0 = time.perf_counter()
+    compiled = kernel_check(case).lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    n_calls = compiled.as_text().count("tpu_custom_call")
+    if require_chip and case.mosaic and n_calls == 0:
+        raise RuntimeError(
+            f"{case.name}: no tpu_custom_call in the compiled program — "
+            "the op took its jnp reference instead of the kernel")
+    stats = np.asarray(jax.block_until_ready(compiled(*args)))
+    excess, max_err, finite = stats[:, 0], stats[:, 1], stats[:, 2]
+    if not (finite.all() and (excess <= case.atol).all()):
+        raise RuntimeError(
+            f"{case.name}: kernel and reference disagree beyond rtol="
+            f"{case.rtol} atol={case.atol}: excess={excess.tolist()} "
+            f"max_abs_err={max_err.tolist()} finite={finite.tolist()}")
+    return {"kernel": case.name, "compile_s": round(compile_s, 2),
+            "tpu_custom_calls": n_calls, "rtol": case.rtol,
+            "atol": case.atol, "max_abs_err": max_err.tolist()}
+
+
+def phase_kernels(device, seed: int) -> None:
+    from apex_tpu import tune
+    from apex_tpu.ops._common import pallas_interpret
+
+    if pallas_interpret():
+        raise RuntimeError("pallas_interpret() is true on the chip: the "
+                           "kernels would run interpreted, not compiled")
+    packed = tune.tuned("flash_sdpa", packed_flash_attrs())
+    if not packed or packed.get("heads_per_step", 1) < 2:
+        raise RuntimeError(
+            f"the tuner gives {packed!r} at {PACKED_FLASH_SHAPE}, not the "
+            "head-packed config tune/defaults.py commits for v5e")
+    for case in kernel_cases(device):
+        emit(phase="kernels", **run_kernel_case(case, seed,
+                                                require_chip=True))
+        gc.collect()
+
+
+# ----------------------------- train steps -----------------------------
+
+def _collectives(text: str) -> dict:
+    kinds = ("all-gather", "all-reduce", "reduce-scatter",
+             "collective-permute", "all-to-all")
+    return {k: text.count(f" {k}(") + text.count(f" {k}-start(")
+            for k in kinds}
+
+
+def train_run(cfg, devices: Sequence, tp: int, batch: int, steps: int,
+              seed: int, require_chip: bool, weights_of_tp: int = 1) -> dict:
+    """The README quick-start path on `devices`: mesh -> GPT ->
+    FusedAdam(bf16 state) -> init_sharded_optimizer ->
+    make_tp_dp_train_step(donate=True), then `steps` steps on one fixed
+    seeded batch under the RecompileSentry (the first two are warm-up).
+    weights_of_tp > 1 (with tp=1) gives the one-chip model the network
+    the same seed makes under that tensor parallelism.
+    Its record goes to stdout before any of its checks can raise, and
+    everything it put on the devices is dropped before it returns."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models.gpt import GPT, qkv_as_tp1
+    from apex_tpu.monitor.compile import RecompileSentry
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.parallel import mesh as M
+    from apex_tpu.transformer.training import (
+        init_sharded_optimizer,
+        make_tp_dp_train_step,
+    )
+
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(tensor_model_parallel_size=tp,
+                                       devices=list(devices))
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(seed))
+    if weights_of_tp > 1:
+        params = qkv_as_tp1(params, cfg, weights_of_tp)
+    opt = FusedAdam(lr=LR, master_dtype=jnp.bfloat16)
+    state = init_sharded_optimizer(opt, model, params, mesh)
+    step = make_tp_dp_train_step(model, opt, mesh, donate=True)
+    del params  # the donated state owns the only copy from here on
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 1),
+                                (batch, cfg.seq_len), 0, cfg.vocab_size)
+    labels = jnp.roll(tokens, -1, axis=1)
+
+    # the jitted step reuses this executable: one compile, not two
+    t0 = time.perf_counter()
+    lowered = step.lower(state, tokens, labels)
+    t1 = time.perf_counter()
+    compiled = lowered.compile()   # XLA, or a read of the persistent cache
+    compile_s = time.perf_counter() - t1
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    rec = {"devices": len(devices), "tp": tp, "dp": len(devices) // tp,
+           "trace_lower_s": round(t1 - t0, 2),
+           "compile_s": round(compile_s, 2),
+           "tpu_custom_calls": text.count("tpu_custom_call"),
+           "collectives": _collectives(text),
+           "argument_bytes": int(mem.argument_size_in_bytes),
+           "temp_bytes": int(mem.temp_size_in_bytes),
+           "generated_code_bytes": int(mem.generated_code_size_in_bytes)}
+    del lowered, compiled, text
+    if require_chip and rec["tpu_custom_calls"] < 2:
+        raise RuntimeError(
+            f"{rec['tpu_custom_calls']} tpu_custom_call(s) in the step: "
+            "flash attention or fused Adam silently took its jnp "
+            "reference")
+
+    sentry = RecompileSentry(step, name="chip_smoke_train", warn=False)
+    losses, step_s = [], []
+    for i in range(steps):
+        if i == 2:
+            sentry.mark_steady()
+        t0 = time.perf_counter()
+        state, loss = sentry(state, tokens, labels)
+        loss = jax.block_until_ready(loss)
+        step_s.append(round(time.perf_counter() - t0, 4))
+        losses.append(float(loss))
+    rec.update(losses=losses, step_s=step_s, sentry=sentry.summary())
+    emit(phase="train_run", **rec)   # before any check can raise
+    if not all(math.isfinite(v) for v in losses):
+        raise RuntimeError(f"non-finite loss: {losses}")
+    if sentry.steady_recompiles:
+        raise RuntimeError(f"steady-state recompile: {sentry.events}")
+
+    if len(devices) > 1:
+        rec.update(_placement(state, list(devices), tp, require_chip))
+        emit(phase="placement", tp=tp, bytes_in_use=rec["bytes_in_use"])
+    del state, tokens, labels, step, sentry
+    M.destroy_model_parallel()
+    gc.collect()
+    return rec
+
+
+def _placement(state, devices, tp, require_chip) -> dict:
+    """Are the shards really spread?  Every device holds 1/tp of the
+    rows of each flat optimizer buffer, and no device holds more than
+    twice the bytes of another."""
+    for name in ("params", "exp_avg", "exp_avg_sq"):
+        buf = getattr(state, name)
+        rows = {s.device: s.data.shape[0] for s in buf.addressable_shards}
+        if set(rows) != set(devices) or set(rows.values()) != {
+                buf.shape[0] // tp}:
+            raise RuntimeError(
+                f"{name}: shards {rows} are not {buf.shape[0] // tp} rows "
+                f"on each of {devices}")
+    stats = [d.memory_stats() for d in devices]
+    if not all(stats):
+        if require_chip:
+            raise RuntimeError(f"no memory_stats() from {devices}")
+        return {"bytes_in_use": None}
+    in_use = [int(s["bytes_in_use"]) for s in stats]
+    if max(in_use) > 2 * min(in_use):
+        raise RuntimeError(f"bytes_in_use is lopsided: {in_use}")
+    return {"bytes_in_use": in_use}
+
+
+def phase_train(cfg, devices, batch: int, steps: int, seed: int,
+                require_chip: bool = True) -> dict:
+    """The one-chip step: the first loss sits at ln(vocab), where a
+    freshly initialised model's must, and the last is below it."""
+    rec = train_run(cfg, devices[:1], 1, batch, steps, seed, require_chip)
+    losses = rec["losses"]
+    if abs(losses[0] - math.log(cfg.vocab_size)) >= LN_VOCAB_TOL:
+        raise RuntimeError(
+            f"first loss {losses[0]} is not within {LN_VOCAB_TOL} of "
+            f"ln({cfg.vocab_size}) = {math.log(cfg.vocab_size):.3f}")
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"the loss did not fall: {losses}")
+    return rec
+
+
+def phase_multichip(cfg, devices, batch: int, steps: int, seed: int,
+                    require_chip: bool = True) -> dict:
+    """tp=2 x dp=2 with sequence parallelism over four devices, in the
+    monolithic and the chunked collective spelling, against the same
+    network and batch through the tp=1 step on the first device (the
+    same seed, its packed QKV columns regrouped: models.gpt.qkv_as_tp1).
+    The reference runs first: it needs its whole chip."""
+    if len(devices) != 4:
+        raise RuntimeError(f"needs four devices, got {len(devices)}")
+    base = dataclasses.replace(cfg, sequence_parallel=False,
+                               overlap_chunks=None)
+    runs = {"tp1": train_run(base, devices[:1], 1, batch, steps, seed,
+                             require_chip, weights_of_tp=2)}
+    for name, chunks in (("monolithic", 1), ("chunked", 2)):
+        sp = dataclasses.replace(cfg, sequence_parallel=True,
+                                 overlap_chunks=chunks)
+        runs[name] = train_run(sp, devices, 2, batch, steps, seed,
+                               require_chip)
+        if not sum(runs[name]["collectives"].values()):
+            raise RuntimeError(f"{name}: no collective in the program")
+    for a, b in (("monolithic", "tp1"), ("chunked", "tp1"),
+                 ("chunked", "monolithic")):
+        for i, (x, y) in enumerate(zip(runs[a]["losses"],
+                                       runs[b]["losses"], strict=True)):
+            if abs(x - y) > MULTICHIP_RTOL * abs(y):
+                raise RuntimeError(
+                    f"step {i}: {a} loss {x} and {b} loss {y} differ by "
+                    f"more than rtol {MULTICHIP_RTOL}")
+    return runs
+
+
+# -------------------------------- serve --------------------------------
+
+def phase_serve(eng, n_requests: int, min_prompt: int, max_new: int,
+                seed: int) -> dict:
+    """submit -> run on a built DecodeEngine, then hold every request's
+    first greedy token to a plain full forward of the same weights over
+    the prompt on the same device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from apex_tpu.models.gpt import GPT
+
+    cfg, sc = eng.model_cfg, eng.serve_cfg
+    rng = np.random.RandomState(seed)
+    lens = rng.randint(min_prompt, sc.max_prompt_len + 1, n_requests)
+    prompts = [rng.randint(0, cfg.vocab_size, n).tolist() for n in lens]
+    rids = [eng.submit(p, max_new) for p in prompts]
+    t0 = time.perf_counter()
+    finished = {f.request_id: f for f in eng.run()}
+    run_s = time.perf_counter() - t0
+    bad = [r for r in rids if r not in finished
+           or finished[r].status != "ok"
+           or len(finished[r].tokens) != max_new]
+    if bad:
+        raise RuntimeError(f"requests {bad} did not return {max_new} "
+                           f"tokens with status ok: {finished}")
+    if not eng.recompile_ok:
+        raise RuntimeError("the decode step recompiled in steady state: "
+                           f"{eng.sentry.events}")
+
+    model = GPT(cfg)
+    padded = np.zeros((n_requests, sc.max_prompt_len), np.int32)
+    for row, p in zip(padded, prompts):
+        row[:len(p)] = p   # causal: what follows a prompt cannot reach it
+    mesh = Mesh(np.asarray(list(eng.params["pos_embed"].devices())),
+                (cfg.axis_name,))
+    forward = jax.jit(shard_map(
+        lambda p, t: model.logits_local(p, model.apply(p, t)),
+        mesh=mesh, in_specs=(model.partition_specs(), P()),
+        out_specs=P(), check_vma=False))
+    logits = forward(eng.params, jnp.asarray(padded))   # (S, B, V) fp32
+    last = logits[jnp.asarray(lens) - 1, jnp.arange(n_requests)]
+    top_v, top_i = (np.asarray(a) for a in jax.lax.top_k(last, 2))
+    # two logits closer than four ulps of the compute dtype are a tie
+    # the two spellings of the forward may break either way
+    tie = 4 * float(jnp.finfo(cfg.dtype).eps) * np.abs(top_v[:, 0])
+    first = np.asarray([finished[r].tokens[0] for r in rids])
+    ok = (first == top_i[:, 0]) | ((first == top_i[:, 1])
+                                   & (top_v[:, 0] - top_v[:, 1] < tie))
+    rec = {"n_requests": n_requests, "prompt_lens": lens.tolist(),
+           "max_new": max_new, "run_s": round(run_s, 2),
+           "engine_steps": eng.steps_completed,
+           "first_tokens": first.tolist(),
+           "forward_top2": top_i.tolist(),
+           "forward_top2_logits": top_v.round(4).tolist(),
+           "top1_matches": int((first == top_i[:, 0]).sum()),
+           "sentry": eng.sentry.summary()}
+    emit(phase="serve_run", **rec)
+    if not ok.all():
+        raise RuntimeError(
+            f"first tokens {first.tolist()} disagree with the full "
+            f"forward's top-2 {top_i.tolist()}")
+    return rec
+
+
+# --------------------------------- main ---------------------------------
+
+def _env_record(devices, cache_dir) -> dict:
+    import importlib.metadata as md
+
+    import jax
+
+    from apex_tpu import csrc, tune
+
+    so_found = os.path.exists(csrc._SO)
+    native = csrc.available()
+    stats = devices[0].memory_stats() or {}
+    return {
+        "note": "a smoke, not a benchmark",
+        "jax": jax.__version__, "jaxlib": md.version("jaxlib"),
+        "libtpu": md.version("libtpu"),
+        "default_backend": jax.default_backend(),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "n_devices": len(devices),
+        "compile_cache_dir": cache_dir,
+        "compile_cache_dir_from_env": bool(
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+        "csrc": ("found" if so_found else "built") if native else "python",
+        "tune_device_kind": tune.device_kind(),
+        "tune_cache_path": tune.cache_path(),
+        "tune_cache_file_present": os.path.exists(tune.cache_path()),
+        "tune_fingerprint": tune.fingerprint(),
+        "memory_stats_keys": sorted(stats),
+        "bytes_limit": stats.get("bytes_limit"),
+    }
+
+
+def _after(phase: str, device, events: CacheEvents, t0: float, **rec) -> None:
+    from apex_tpu import tune
+
+    stats = device.memory_stats() or {}
+    emit(phase=phase, wall_s=round(time.perf_counter() - t0, 1),
+         peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+         bytes_in_use=stats.get("bytes_in_use"),
+         tune=tune.stats(), **events.snapshot(), **rec)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="run only the four-chip tp=2 x dp=2 step and "
+                         "the one-chip step it is compared with")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    n_used = 4 if args.multichip else 1
+    device = {"platform": devices[0].platform,
+              "kind": devices[0].device_kind, "count": n_used}
+    if devices[0].platform != "tpu" or len(devices) < n_used:
+        emit(ok=False, device=dict(device, count=len(devices)),
+             error=f"needs {n_used} TPU device(s); jax.devices() is "
+                   f"{devices}")
+        return 1
+    try:
+        _run(args, devices[:n_used])
+    except BaseException as e:
+        emit(ok=False, device=device, error=repr(e)[:2000])
+        raise
+    emit(ok=True, device=device)
+    return 0
+
+
+def _run(args, devices) -> None:
+    from apex_tpu.ops._common import on_chip
+    from apex_tpu.utils.compile_cache import enable_compile_cache
+
+    events = CacheEvents()
+    cache_dir = enable_compile_cache()
+    if not on_chip():
+        raise RuntimeError("apex_tpu's on_chip() is false on a TPU")
+    emit(phase="env", **_env_record(devices, cache_dir))
+    cfg = flagship_config()
+    t0 = time.perf_counter()
+    if args.multichip:
+        phase_multichip(cfg, devices, BATCH, 3, args.seed)
+        _after("multichip", devices[0], events, t0, rtol=MULTICHIP_RTOL)
+        return
+    phase_kernels(devices[0], args.seed)
+    _after("kernels", devices[0], events, t0)
+
+    t0 = time.perf_counter()
+    phase_train(cfg, devices, BATCH, 5, args.seed)
+    _after("train", devices[0], events, t0)
+
+    from apex_tpu.serve import build_flagship_engine
+
+    t0 = time.perf_counter()
+    eng = build_flagship_engine(True, seed=args.seed)
+    phase_serve(eng, n_requests=8, min_prompt=16, max_new=64,
+                seed=args.seed)
+    _after("serve", devices[0], events, t0, n_slots=eng.serve_cfg.n_slots)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

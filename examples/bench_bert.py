@@ -19,7 +19,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from apex_tpu.models.bert import Bert, BertConfig
 from apex_tpu.optimizers.fused_lamb import FusedLAMB
@@ -42,44 +41,46 @@ def main():
                     "e.g. '16,32,40,48')")
     args = ap.parse_args()
 
-    on_tpu = jax.default_backend() not in ("cpu",)
-    if not on_tpu:
-        args.batch, args.seq, args.iters = 2, 64, 2
-
     if args.batch_sweep:
-        if not on_tpu:
-            # the child self-clamps to b2/s64 off-TPU, so every point
-            # would be the same measurement wearing different labels
-            print("--batch-sweep needs a TPU backend; got "
-                  f"{jax.default_backend()}", file=_sys.stderr)
-            _sys.exit(2)
+        # one process per chip: this parent never initialises a JAX
+        # backend, so each point's child has the chip to itself (and a
+        # failed compile cannot poison the next point's allocator)
         import subprocess
         for b in (int(x) for x in args.batch_sweep.split(",") if x):
             cmd = [_sys.executable, _os.path.abspath(__file__),
                    "--batch", str(b), "--seq", str(args.seq),
                    "--iters", str(args.iters)]
-            # fresh process per point: a failed compile (b64 round 4)
-            # must not poison the later points' allocator
             try:
                 r = subprocess.run(cmd, capture_output=True, text=True,
                                    timeout=1800)
             except subprocess.TimeoutExpired:
                 print(f"b{b}: FAIL timeout (1800s)", flush=True)
                 continue
-            # reverse-scan for the JSON line (≡ bench._run_isolated): a
-            # plugin log line after the JSON must not eat the result
-            line = "<no json output>"
+            # reverse-scan for the JSON line: a log line after the
+            # JSON must not eat the result
+            line, platform = "<no json output>", None
             for cand in reversed(r.stdout.strip().splitlines()):
                 try:
                     d = json.loads(cand)
                 except ValueError:
                     continue
                 if isinstance(d, dict) and "metric" in d:
-                    line = cand
+                    line, platform = cand, d.get("platform")
                     break
-            print(f"b{b}: {line if r.returncode == 0 else 'FAIL ' + r.stderr.strip()[-120:]}",
-                  flush=True)
+            if r.returncode != 0:
+                line = "FAIL " + r.stderr.strip()[-120:]
+            elif platform != "tpu":
+                # off-TPU the child clamps itself to b2/s64: every point
+                # would be the same measurement wearing different labels
+                line = f"FAIL ran on {platform}, not a TPU"
+            print(f"b{b}: {line}", flush=True)
         return
+
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
+    if not on_tpu:
+        args.batch, args.seq, args.iters = 2, 64, 2
 
     M.destroy_model_parallel()
     mesh = M.initialize_model_parallel(devices=jax.devices()[:1])
@@ -111,11 +112,11 @@ def main():
 
     for _ in range(2):
         opt_state, loss = step(opt_state, tokens, mlm_labels)
-    np.asarray(loss)
+    jax.block_until_ready(loss)
     t0 = time.perf_counter()
     for _ in range(args.iters):
         opt_state, loss = step(opt_state, tokens, mlm_labels)
-    np.asarray(loss)
+    jax.block_until_ready(loss)
     dt = (time.perf_counter() - t0) / args.iters
     print(json.dumps({
         "metric": "bert_large_lamb_seqs_per_sec_per_chip",
@@ -123,6 +124,7 @@ def main():
         "unit": "sequences/s",
         "s_per_iter": round(dt, 4),
         "vs_baseline": 1.0,
+        "platform": jax.devices()[0].platform,
     }))
 
 

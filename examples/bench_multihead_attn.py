@@ -42,7 +42,8 @@ def main():
     ap.add_argument("--hidden", type=int, default=1024)
     ap.add_argument("--heads", type=int, default=16)
     args = ap.parse_args()
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+    on_tpu = on_chip()
     if not on_tpu:
         args.seq, args.batch = 128, 2
 

@@ -25,7 +25,9 @@ def bench_adam(n: int, param_dtype=jnp.float32, iters: int = 20,
                warmup: int = 3) -> dict:
     from apex_tpu.ops import optimizer_kernels as K
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
 
     # tile-aligned, as FusedAdam.init allocates (flatten(pad_to=FLAT_TILE)):
     # unaligned buffers force a pad copy that breaks in-place aliasing

@@ -38,8 +38,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu import _compat as _compat  # jax 0.4.x shims (jax.shard_map)
-
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 

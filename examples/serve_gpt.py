@@ -110,13 +110,14 @@ def main():
     if args.streams < 1:
         ap.error("--streams must be >= 1")
 
-    import jax
     import numpy as np
 
     from apex_tpu.serve import (ServeSLO, build_flagship_engine,
                                 measure_decode)
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     n_slots = args.slots or min(args.streams, 64)
     max_new = args.max_new or (64 if on_tpu else 16)
     eng = build_flagship_engine(on_tpu, n_slots=n_slots)

@@ -650,7 +650,7 @@ def probe(steps: int, save_at: int, as_json: bool, smoke: bool) -> int:
 
     result["ok"] = not failures
     if as_json:
-        # ONE line so callers can reverse-scan stdout past plugin noise
+        # ONE line so callers can reverse-scan stdout past log noise
         print(json.dumps(result, sort_keys=True))
     else:
         for k in sorted(result):

@@ -551,7 +551,9 @@ def mem_mode(targets):
     from apex_tpu import monitor
     from apex_tpu.parallel import mesh as M
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     rc = 0
     for t in targets:
         label, step, args, analytic = _build_bench_step(t, on_tpu)
@@ -590,7 +592,8 @@ def lint_mode(targets):
         "lint_allowlist.txt")
     allowlist = (lint.load_allowlist(allowlist_path)
                  if _os.path.exists(allowlist_path) else [])
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+    on_tpu = on_chip()
     rc = 0
     for t in targets:
         label, step, args, _ = _build_bench_step(t, on_tpu, mode="lint")
@@ -618,7 +621,9 @@ def comms_mode(targets):
     from apex_tpu import monitor
     from apex_tpu.parallel import mesh as M
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     rc = 0
     for t in targets:
         label, step, args, _ = _build_bench_step(t, on_tpu, mode="comms")
@@ -649,7 +654,9 @@ def timeline_mode(targets, n_steps=3):
     from apex_tpu import monitor
     from apex_tpu.parallel import mesh as M
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     rc = 0
     for t in targets:
         label, step, (opt_state, tokens, labels), _ = \
@@ -781,7 +788,9 @@ def overlap_mode(targets, n_steps=3):
     from apex_tpu.monitor import timeline
     from apex_tpu.parallel import mesh as M
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     rc = 0
     for t in targets:
         summaries = {}

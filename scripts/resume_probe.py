@@ -245,7 +245,9 @@ def probe(steps: int, save_at: int, as_json: bool) -> int:
     from apex_tpu.models.gpt import GPTConfig
     from jax.sharding import PartitionSpec as P
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     n_dev = len(jax.devices())
     if n_dev < 2:
         print("resume_probe: needs >= 2 devices for the dp=2 baseline",
@@ -347,8 +349,7 @@ def probe(steps: int, save_at: int, as_json: bool) -> int:
         shutil.rmtree(tmp, ignore_errors=True)
     result["ok"] = not failures
     if as_json:
-        # ONE line so callers can reverse-scan stdout past plugin noise
-        # (the bench _run_isolated convention)
+        # ONE line so callers can reverse-scan stdout past log noise
         print(json.dumps(result, sort_keys=True))
     else:
         for k in sorted(result):

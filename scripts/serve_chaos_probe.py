@@ -277,15 +277,15 @@ def _leg_checks(name, eng, fins, ref, failures):
 def probe(args) -> int:
     import time
 
-    import jax
-
     from apex_tpu.checkpoint import chaos
     from apex_tpu.serve import (EngineStalledError, EngineWatchdog,
                                 PoisonedOutputError, ServeSLO,
                                 build_flagship_engine,
                                 validate_serve_report)
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     chaos.disarm_all()
     failures = []
     result = {"backend": "tpu" if on_tpu else "cpu"}

@@ -172,13 +172,14 @@ def _churn_workload(eng, n_requests, max_new_cap, seed=0):
 
 
 def probe(args) -> int:
-    import jax
     import numpy as np
 
     from apex_tpu.serve import (ServeSLO, build_flagship_engine,
                                 measure_decode, validate_serve_report)
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     slo = ServeSLO(ttft_p99_ms=args.slo_ttft_p99_ms,
                    per_token_p99_ms=args.slo_token_p99_ms,
                    max_queue_wait_ms=args.slo_queue_wait_ms)
@@ -327,8 +328,7 @@ def probe(args) -> int:
 
     result["ok"] = not failures
     if args.json:
-        # ONE line so callers can reverse-scan stdout past plugin
-        # noise (the bench _run_isolated convention)
+        # ONE line so callers can reverse-scan stdout past log noise
         print(json.dumps(result, sort_keys=True))
     else:
         for k in sorted(result):

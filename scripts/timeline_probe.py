@@ -236,8 +236,6 @@ def _build(target, on_tpu):
         return step, (opt_state, tokens, labels), run
     import gpt_anatomy
 
-    import jax
-
     key = {"gpt": "350m", "bert": "bert"}[target]
     _, step, (opt_state, tokens, labels), _ = \
         gpt_anatomy._build_bench_step(key, on_tpu, mode="comms")
@@ -262,7 +260,9 @@ def _probe_target(target, n_steps, logdir, as_json) -> int:
     from apex_tpu.monitor import comms as comms_lib
     from apex_tpu.monitor import timeline
 
-    on_tpu = jax.default_backend() not in ("cpu",)
+    from apex_tpu.ops._common import on_chip
+
+    on_tpu = on_chip()
     step, abstract_args, run = _build(target, on_tpu)
 
     # two warmups absorb the compile (+ the donated-layout second

@@ -423,17 +423,6 @@ def test_ring_dropout_matches_single_chip_flash(layout, use_pallas):
                                    err_msg=f"d{nm} {layout}")
 
 
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 6),
-    reason="quarantined on jax<0.6 (this image: 0.4.x): the ring-dropout "
-           "path derives its per-chunk key from lax.axis_index, which "
-           "this jaxlib's CPU SPMD partitioner lowers to a bare "
-           "PartitionId instruction and then rejects with "
-           "'UNIMPLEMENTED: PartitionId instruction is not supported "
-           "for SPMD partitioning' (jax-ml/jax#14910-class "
-           "partition-id-under-jit gap, fixed on newer jaxlibs).  "
-           "Pre-dates PR 8 — fails identically at the PR-7 HEAD; "
-           "re-enable when the image's jax moves past 0.6.")
 def test_ring_dropout_distribution_and_jnp_path():
     """jnp (non-pallas) ring path: dropout drops ~rate of attention
     mass and is deterministic per key; fwd is reproducible."""

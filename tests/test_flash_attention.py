@@ -79,8 +79,8 @@ def test_flash_long_seq_blocks():
 def test_flash_dropout_fallback_api():
     """dropout on the non-kernel path: masks attention weights, scales
     by 1/keep, deterministic per key, E[out] tracks the no-dropout
-    output (the in-kernel philox path is validated on hardware by
-    examples/tpu_kernel_smoke.py)."""
+    output (the in-kernel coordinate-hash mask has its own test,
+    test_flash_in_kernel_dropout_mask_consistency)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -5,10 +5,11 @@ loss sanity, and training convergence with FusedAdam on a tp×dp mesh."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from apex_tpu.models.gpt import GPT, GPTConfig
+from apex_tpu.models.gpt import GPT, GPTConfig, qkv_as_tp1
 from apex_tpu.optimizers.fused_adam import FusedAdam
 from apex_tpu.parallel import mesh as M
 
@@ -62,6 +63,54 @@ def test_sequence_parallel_matches():
     base = _run_loss(tp=4, sequence_parallel=False)
     sp = _run_loss(tp=4, sequence_parallel=True)
     np.testing.assert_allclose(base, sp, rtol=2e-3)
+
+
+def _loss_and_grads(cfg, tp, devices, reparam=None):
+    """Loss and dp-averaged grads as make_tp_dp_train_step's local step
+    computes them, gathered to global arrays."""
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(tensor_model_parallel_size=tp,
+                                       devices=devices)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(7))
+    if reparam is not None:
+        params = reparam(params)
+    specs = model.partition_specs()
+
+    def local(p, tokens, labels):
+        loss, g = jax.value_and_grad(
+            lambda p: model.loss(p, tokens, labels))(p)
+        g = jax.tree_util.tree_map(lambda x: jax.lax.pmean(x, "dp"), g)
+        return jax.lax.pmean(loss, "dp"), g
+
+    out = jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(specs, P("dp"), P("dp")),
+        out_specs=(P(), specs), check_vma=False))(params, *_data())
+    M.destroy_model_parallel()
+    return out
+
+
+@pytest.mark.parametrize("sp,chunks", [(False, None), (True, None),
+                                       (True, 2)],
+                         ids=["tp", "tp_sp", "tp_sp_chunked"])
+def test_grads_match_tp1(sp, chunks):
+    """tp=2 x dp=2 computes the tp=1 loss AND the tp=1 gradient of the
+    same network, leaf for leaf (fp32, so to rounding) — with sequence
+    parallelism and the chunked collectives too.  The loss alone cannot
+    tell: the LM head once summed d(hidden) over tp twice under SP, the
+    loss was right, and every gradient upstream of the head was tp
+    times too large (the tied embedding's a mix of both scales)."""
+    cfg = _cfg(sequence_parallel=sp, overlap_chunks=chunks)
+    loss, grads = _loss_and_grads(cfg, 2, jax.devices()[:4])
+    want_loss, want = _loss_and_grads(
+        _cfg(), 1, jax.devices()[:1], reparam=lambda p: qkv_as_tp1(p, cfg, 2))
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    got = jax.tree_util.tree_leaves_with_path(qkv_as_tp1(grads, cfg, 2))
+    for (path, g), w in zip(got, jax.tree_util.tree_leaves(want),
+                            strict=True):
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=1e-5 * float(jnp.abs(w).max()),
+            err_msg=jax.tree_util.keystr(path))
 
 
 def test_gpt_trains_tp_dp():

@@ -3,6 +3,7 @@
 metadata behavior."""
 
 import numpy as np
+import pytest
 
 from apex_tpu import csrc
 
@@ -44,3 +45,26 @@ def test_gather_rows():
     ds_i = np.arange(30, dtype=np.int32).reshape(10, 3)
     out_i = csrc.gather_rows(ds_i, [9, 1])
     np.testing.assert_array_equal(out_i, ds_i[[9, 1]])
+
+
+@pytest.mark.parametrize("n,seed", [(0, 3), (1, 7), (1000, 42),
+                                    (257, 2 ** 40 + 5)])
+def test_shuffle_fallback_is_the_same_permutation(n, seed):
+    """The pure-Python path must give the native permutation: an epoch's
+    data order may not depend on whether a compiler was present."""
+    np.testing.assert_array_equal(csrc._shuffle_indices_py(n, seed),
+                                  csrc.shuffle_indices(n, seed))
+
+
+def test_unavailable_runtime_warns_with_reason(monkeypatch, tmp_path):
+    """A failed build or load is reported once, with its reason — never
+    a silent switch to the pure-Python paths."""
+    monkeypatch.setattr(csrc, "_LIB", None)
+    monkeypatch.setattr(csrc, "_TRIED", False)
+    bad = tmp_path / "libapex_tpu_host.so"
+    bad.write_bytes(b"not an ELF file")
+    monkeypatch.setattr(csrc, "_SO", str(bad))
+    with pytest.warns(RuntimeWarning, match="native host runtime"):
+        assert csrc.available() is False
+    np.testing.assert_array_equal(
+        csrc.flat_layout([100, 50], align=128)[0], [0, 128])

@@ -81,22 +81,21 @@ def test_dp102_lossy_roundtrip():
 
 
 def test_dp103_low_precision_large_reduction():
-    # a raw lax-level reduce keeps the bf16 accumulator (jnp.sum — even
-    # with dtype=bf16 — upcasts to f32 internally, which is why only
-    # hand-written lax reductions can hit this)
+    # a reduce that keeps the bf16 accumulator: a raw lax-level one, or
+    # (jax 0.9) jnp.sum asked for dtype=bf16
     def f(x):
         return jax.lax.reduce_sum_p.bind(x, axes=(0,))
 
-    fs = lint.lint_program(f, (SDS((1 << 18,), jnp.bfloat16),))
-    assert "DP103" in rules_of(fs)
+    def f_jnp(x):
+        return jnp.sum(x, dtype=jnp.bfloat16)
 
-    # jnp's default f32 accumulation must NOT flag, dtype= included
-    def g(x):
-        return jnp.sum(x) + jnp.sum(x, dtype=jnp.bfloat16).astype(
-            jnp.float32)
+    for fn in (f, f_jnp):
+        fs = lint.lint_program(fn, (SDS((1 << 18,), jnp.bfloat16),))
+        assert "DP103" in rules_of(fs)
 
+    # jnp's default f32 accumulation must NOT flag
     assert rules_of(lint.lint_program(
-        g, (SDS((1 << 18,), jnp.bfloat16),))) == []
+        jnp.sum, (SDS((1 << 18,), jnp.bfloat16),))) == []
 
 
 def test_dp104_master_update_in_low_precision():

@@ -638,8 +638,7 @@ def test_serve_chaos_probe_full_matrix():
     assert r.returncode == 0, r.stdout + r.stderr
     import json as _json
 
-    # the JSON rides one line; the OK banner follows it (reverse-scan,
-    # the bench _run_isolated convention)
+    # the JSON rides one line; the OK banner follows it (reverse-scan)
     line = next(ln for ln in reversed(r.stdout.strip().splitlines())
                 if ln.startswith("{"))
     out = _json.loads(line)

@@ -153,9 +153,7 @@ def _oracle64(q, k, v, **kw):
     """TRUE fp64 reference (the satellite's oracle) — the conftest
     disables x64 globally, so the cast must run under enable_x64 or it
     silently truncates to fp32."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         out = attention_reference(q.astype(jnp.float64),
                                   k.astype(jnp.float64),
                                   v.astype(jnp.float64), **kw)
@@ -208,9 +206,7 @@ def test_packed_matches_unpacked_and_oracle(causal, bias_kind, seg,
     # kernel's own oracle parity lives in test_flash_attention.py)
     if (causal, bias_kind, seg) in ((False, "none", False),
                                     (True, "full", True)):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64():
             def loss64(q, k, v):
                 out = attention_reference(q, k, v, causal=causal,
                                           bias=None if bias is None
