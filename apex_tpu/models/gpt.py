@@ -214,10 +214,24 @@ class GPT:
 
     def _attention(self, block_params, qkv_mod, proj_mod, x, key):
         """x: (S[, /tp], B, H) local.  Heads sharded over tp."""
-        c = self.c
-        qkv = qkv_mod.apply(block_params["qkv"], x)  # (S, B, 3H/tp)
-        qkv = _cn(qkv, "qkv")
+        with jax.named_scope("qkv"):
+            qkv = qkv_mod.apply(block_params["qkv"], x)  # (S, B, 3H/tp)
+            qkv = _cn(qkv, "qkv")
         s, b, _ = qkv.shape
+        # the layout copies into and out of the kernel's (B, nh, S, d)
+        # are part of the kernel's price, so they carry its scope
+        with jax.named_scope("flash"):
+            ctx = self._attention_core(qkv, key, x.dtype)
+            ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, -1)  # (S,B,H/tp)
+            ctx = _cn(ctx, "attn_ctx")
+        with jax.named_scope("proj"):
+            return proj_mod.apply(block_params["proj"], ctx)
+
+    def _attention_core(self, qkv, key, dtype):
+        """Causal attention over the packed (S, B, 3H/tp) projection:
+        the context, (B, heads/tp, S, head_dim)."""
+        c = self.c
+        s = qkv.shape[0]
         nh_local = qkv.shape[-1] // (3 * c.head_dim)
         # one transpose of the PACKED tensor instead of three strided
         # slice+transpose copies (ops/fused_dense.qkv_split_heads)
@@ -226,27 +240,23 @@ class GPT:
         if c.use_flash_attention:
             from apex_tpu.ops.flash_attention import flash_attention
             rate = c.dropout if key is not None else 0.0
-            ctx = flash_attention(q, k, v, causal=True,
-                                  softmax_scale=1.0 / math.sqrt(c.head_dim),
-                                  dropout_rate=rate,
-                                  dropout_key=key if rate > 0 else None,
-                                  block_q=c.attn_block_q,
-                                  block_k=c.attn_block_k,
-                                  heads_per_step=c.attn_heads_per_step)
-        else:
-            scores = jnp.einsum("bnsh,bnth->bnst", q, k,
-                                preferred_element_type=jnp.float32
-                                ).astype(x.dtype)
-            probs = scaled_upper_triang_masked_softmax(
-                scores.reshape(-1, s, s),
-                1.0 / math.sqrt(c.head_dim)).reshape(scores.shape)
-            probs = self._dropout(key, probs)
-            ctx = jnp.einsum("bnst,bnth->bnsh", probs, v,
-                             preferred_element_type=jnp.float32
-                             ).astype(x.dtype)
-        ctx = ctx.transpose(2, 0, 1, 3).reshape(s, b, -1)  # (S,B,H/tp)
-        ctx = _cn(ctx, "attn_ctx")
-        return proj_mod.apply(block_params["proj"], ctx)
+            return flash_attention(q, k, v, causal=True,
+                                   softmax_scale=1.0 / math.sqrt(c.head_dim),
+                                   dropout_rate=rate,
+                                   dropout_key=key if rate > 0 else None,
+                                   block_q=c.attn_block_q,
+                                   block_k=c.attn_block_k,
+                                   heads_per_step=c.attn_heads_per_step)
+        scores = jnp.einsum("bnsh,bnth->bnst", q, k,
+                            preferred_element_type=jnp.float32
+                            ).astype(dtype)
+        probs = scaled_upper_triang_masked_softmax(
+            scores.reshape(-1, s, s),
+            1.0 / math.sqrt(c.head_dim)).reshape(scores.shape)
+        probs = self._dropout(key, probs)
+        return jnp.einsum("bnst,bnth->bnsh", probs, v,
+                          preferred_element_type=jnp.float32
+                          ).astype(dtype)
 
     def _block(self, i, params, x, key):
         # `_tap` points (flight-recorder stat taps, monitor.trace): the
@@ -258,20 +268,32 @@ class GPT:
         k1 = k2 = k3 = None
         if key is not None:
             k1, k2, k3 = jax.random.split(key, 3)
-        h = _tap(self._ln(bp["ln1"], x), f"block{i}/ln1")
-        attn = self._attention(bp, qkv_mod, proj_mod, h, k1)
-        attn = _cn(attn, "attn_out")
-        attn = _tap(attn, f"block{i}/attn")
-        x = x + self._dropout(k2, attn)
-        h = _tap(self._ln(bp["ln2"], x), f"block{i}/ln2")
-        m = fc1.apply(bp["fc1"], h)
-        m = _cn(m, "ffn1")
-        m = jax.nn.gelu(m, approximate=True)
-        m = fc2.apply(bp["fc2"], m)
-        m = _cn(m, "ffn_out")
-        m = _tap(m, f"block{i}/mlp")
-        x = x + self._dropout(k3, m)
+        with jax.named_scope(f"block{i}"):
+            with jax.named_scope("ln1"):
+                h = _tap(self._ln(bp["ln1"], x), f"block{i}/ln1")
+            # a residual add is fused into the GEMM before it, so it
+            # carries that sublayer's scope: nothing of a block is
+            # outside its four sublayers
+            with jax.named_scope("attn"):
+                attn = self._attention(bp, qkv_mod, proj_mod, h, k1)
+                attn = _cn(attn, "attn_out")
+                attn = _tap(attn, f"block{i}/attn")
+                x = x + self._dropout(k2, attn)
+            with jax.named_scope("ln2"):
+                h = _tap(self._ln(bp["ln2"], x), f"block{i}/ln2")
+            with jax.named_scope("mlp"):
+                m = self._mlp(bp, fc1, fc2, h)
+                m = _tap(m, f"block{i}/mlp")
+                x = x + self._dropout(k3, m)
         return x
+
+    def _mlp(self, bp, fc1, fc2, h):
+        with jax.named_scope("fc1"):
+            m = _cn(fc1.apply(bp["fc1"], h), "ffn1")
+        with jax.named_scope("gelu"):
+            m = jax.nn.gelu(m, approximate=True)
+        with jax.named_scope("fc2"):
+            return _cn(fc2.apply(bp["fc2"], m), "ffn_out")
 
     def apply(self, params, tokens, key=None):
         """tokens: (B, S) global int ids (replicated over tp).
@@ -279,12 +301,7 @@ class GPT:
         path to logits/loss below.  Shard-local: call inside shard_map.
         """
         c = self.c
-        ids = tokens.T  # (S, B)
-        h = self.embed.apply(params["embed"], ids)  # (S,B,H) or (S/tp,B,H)
-        pos = params["pos_embed"][: tokens.shape[1]][:, None, :]
-        if c.sequence_parallel:
-            pos = scatter_to_sequence_parallel_region(pos, c.axis_name)
-        h = h + pos.astype(h.dtype)
+        h = self._embed(params, tokens.T)  # (S,B,H) or (S/tp,B,H)
         if key is not None:
             key = model_parallel_fold_in(key, c.axis_name)
         for i in range(c.num_layers):
@@ -317,13 +334,19 @@ class GPT:
         h = self._ln_final(params, h)
         return h
 
+    def _embed(self, params, ids):
+        """ids: (S, B).  Token plus position embedding."""
+        c = self.c
+        with jax.named_scope("embed"):
+            h = self.embed.apply(params["embed"], ids)
+            pos = params["pos_embed"][: ids.shape[0]][:, None, :]
+            if c.sequence_parallel:
+                pos = scatter_to_sequence_parallel_region(pos, c.axis_name)
+            return h + pos.astype(h.dtype)
+
     def _ln_final(self, params, h):
-        p = params["final_ln"]
-        w, b = p["weight"], p["bias"]
-        if self.c.sequence_parallel:
-            w = copy_to_tensor_model_parallel_region(w, self.c.axis_name)
-            b = copy_to_tensor_model_parallel_region(b, self.c.axis_name)
-        return fused_layer_norm(h, w, b)
+        with jax.named_scope("final_ln"):
+            return self._ln(params["final_ln"], h)
 
     def logits_local(self, params, h):
         """LM head with tied embedding weight → vocab-sharded logits
@@ -335,23 +358,28 @@ class GPT:
         # gather's reduce-scatter under SP, by the copy's psum otherwise
         # (≡ ColumnParallelLinear; both together scale every gradient
         # upstream of the head by tp)
-        if c.sequence_parallel:
-            x = gather_from_sequence_parallel_region(h, c.axis_name)
-        else:
-            x = copy_to_tensor_model_parallel_region(h, c.axis_name)
-        out_dtype = c.logits_dtype or jnp.float32
-        return jnp.einsum("sbh,vh->sbv", x, w,
-                          preferred_element_type=jnp.float32
-                          ).astype(out_dtype)
+        with jax.named_scope("head"):
+            if c.sequence_parallel:
+                x = gather_from_sequence_parallel_region(h, c.axis_name)
+            else:
+                x = copy_to_tensor_model_parallel_region(h, c.axis_name)
+            out_dtype = c.logits_dtype or jnp.float32
+            return jnp.einsum("sbh,vh->sbv", x, w,
+                              preferred_element_type=jnp.float32
+                              ).astype(out_dtype)
 
     def loss(self, params, tokens, labels, key=None):
         """Mean LM loss.  tokens/labels: (B, S) global."""
         h = self.apply(params, tokens, key)
         logits = self.logits_local(params, h)  # (S,B,V/tp)
-        loss = vocab_parallel_cross_entropy(
-            logits, labels.T, axis_name=self.c.axis_name,
-            fused=self.c.fused_xent)
-        return jnp.mean(loss)
+        return self._mean_loss(logits, labels.T)
+
+    def _mean_loss(self, logits, labels):
+        """logits (S, B, V/tp), labels (S, B) -> the mean token loss."""
+        with jax.named_scope("loss"):
+            return jnp.mean(vocab_parallel_cross_entropy(
+                logits, labels, axis_name=self.c.axis_name,
+                fused=self.c.fused_xent))
 
 
 class GPTPipelined(GPT):
@@ -420,35 +448,31 @@ class GPTPipelined(GPT):
     def _block_shared(self, bp, x, key):
         """_block with the (shared-config) layer modules of block 0."""
         qkv_mod, proj_mod, fc1, fc2 = self.blocks[0]
-        h = self._ln(bp["ln1"], x)
-        attn = self._attention(bp, qkv_mod, proj_mod, h, key)
-        x = x + attn
-        h = self._ln(bp["ln2"], x)
-        m = fc1.apply(bp["fc1"], h)
-        m = jax.nn.gelu(m, approximate=True)
-        m = fc2.apply(bp["fc2"], m)
-        return x + m
+        # the scope is opened inside the scanned body, so that the
+        # path reads while/body/block/ln1 in one piece
+        with jax.named_scope("block"):
+            with jax.named_scope("ln1"):
+                h = self._ln(bp["ln1"], x)
+            with jax.named_scope("attn"):
+                x = x + self._attention(bp, qkv_mod, proj_mod, h, key)
+            with jax.named_scope("ln2"):
+                h = self._ln(bp["ln2"], x)
+            with jax.named_scope("mlp"):
+                return x + self._mlp(bp, fc1, fc2, h)
 
     def loss(self, params, tokens, labels, key=None):
         """tokens/labels: (B, S); B = num_microbatches × microbatch size.
         Shard-local (call inside shard_map over the full mesh)."""
         from apex_tpu.transformer.pipeline_parallel.schedules import (
             spmd_pipeline)
-        c = self.c
         m = self.num_microbatches
         B, S = tokens.shape
         assert B % m == 0
         mb = B // m
         ids = tokens.reshape(m, mb, S).transpose(0, 2, 1)  # (m, S, mb)
 
-        def embed_one(ids_mb):
-            h = self.embed.apply(params["embed"], ids_mb)
-            pos = params["pos_embed"][:S][:, None, :]
-            if c.sequence_parallel:
-                pos = scatter_to_sequence_parallel_region(pos, c.axis_name)
-            return h + pos.astype(h.dtype)
-
-        h_mbs = jax.vmap(embed_one)(ids)  # (m, S[, /tp], mb, H)
+        h_mbs = jax.vmap(lambda ids_mb: self._embed(params, ids_mb))(
+            ids)  # (m, S[, /tp], mb, H)
 
         # local stage params: drop the sharded pp dim (local size 1)
         stage_blocks = jax.tree_util.tree_map(lambda l: l[0],
@@ -460,9 +484,7 @@ class GPTPipelined(GPT):
         def head_one(h_mb, labels_mb):
             h_f = self._ln_final(params, h_mb)
             logits = self.logits_local(params, h_f)  # (S, mb, V/tp)
-            return jnp.mean(vocab_parallel_cross_entropy(
-                logits, labels_mb, axis_name=c.axis_name,
-                fused=c.fused_xent))
+            return self._mean_loss(logits, labels_mb)
 
         lbl = labels.reshape(m, mb, S).transpose(0, 2, 1)  # (m, S, mb)
         # head + loss run on the LAST STAGE inside the clocked scan and

@@ -37,6 +37,14 @@ Three layers:
                (`crosscheck_comms`; CI-gated by
                `scripts/timeline_probe.py`)
 
+  * scopes   — the step named from inside (ISSUE 25): the
+               vocabulary of `jax.named_scope` paths and Pallas kernel
+               names the program uses, the rule that says which scope
+               owns each instruction of a compiled step
+               (`scopes.owners`), and the step a process ran
+               (`scopes.step_owners`); a trace's device time by owner
+               is a lookup in that map
+
 See docs/observability.md for the JSONL schema and recipes, and
 examples/train_with_monitor.py for the end-to-end loop.
 """
@@ -67,6 +75,7 @@ from apex_tpu.monitor.comms import (  # noqa: F401
     device_link_bandwidth,
     render_comms_table,
 )
+from apex_tpu.monitor import scopes  # noqa: F401
 from apex_tpu.monitor import timeline  # noqa: F401
 from apex_tpu.monitor.timeline import (  # noqa: F401
     TIMELINE_SCHEMA_VERSION,

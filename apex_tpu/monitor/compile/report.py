@@ -191,6 +191,12 @@ def analyze_step(step_fn, args: Sequence[Any], *,
     — no second XLA compile) and attach `CommsReport.to_dict()` as
     `report.comms` (ISSUE 7); replica groups map back to the step's
     `mesh_axis_names`/`mesh_axis_sizes` when the builder attached them.
+
+    Whose time a profile of this program shows: `monitor.scopes.owners`
+    over the executable's text maps every instruction to the scope of
+    the program that owns it, and `scopes.step_owners()` does so for
+    the step a process ran; docs/observability.md, "Time by owner",
+    joins that with a `ProfileCapture`.
     """
     lower = getattr(step_fn, "lower", None)
     if lower is None:

@@ -1,0 +1,148 @@
+"""Who owns a step's device time: the names the program gives itself.
+
+The model, the step builder and the optimizer open a `jax.named_scope`
+per sublayer and phase, and every Pallas kernel passes a `name=`.  Both
+are metadata at trace time: they end up in the compiled program's text
+(`op_name="jit(local_step)/jvp(block3)/attn/qkv/dot_general"`, the
+instruction `%flash_fwd.7`) and cost nothing per step.  A profiler
+trace names device events by instruction, so `owners` over that text
+says whose time each event is.
+
+A fusion carries the `op_name` of one of its instructions, and the
+compiler's own copies, bitcasts and flat-buffer updates carry none:
+those take their users' owner, or else their producers' (`owners`).
+"""
+
+from __future__ import annotations
+
+import re
+
+from apex_tpu.monitor.comms.hlo import parse_module
+
+_SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
+              "ln2", "mlp", "mlp/fc1", "mlp/gelu", "mlp/fc2")
+# every scope path the program may open; `block{i}` is a layer by index
+# (`_tap` spells it the same way), `block` a layer of a scanned stack
+OWNERS = (
+    "unflatten", "dp_reduce", "pp_sync",
+    "optimizer", "optimizer/flatten_grads", "optimizer/adam",
+    "embed", "final_ln", "head", "loss",
+    *(f"block{{i}}/{s}" for s in _SUBLAYERS),
+    *(f"block/{s}" for s in _SUBLAYERS))
+# the `name=` of every pl.pallas_call in apex_tpu/ops: an unnamed call
+# would take its enclosing scope's name and pass for that scope
+KERNELS = (
+    "flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
+    "flash_decode", "adam_flat", "adam_flat_seg", "sgd_flat",
+    "adagrad_flat", "lamb_phase1", "lamb_phase1_seg", "lamb_phase2",
+    "lamb_phase2_seg", "per_tensor_sumsq", "xent_fwd", "xent_bwd",
+    "ln_fwd", "ln_bwd", "softmax_fwd", "softmax_bwd", "fused_dense",
+    "welford")
+UNOWNED = "unowned"
+
+# jvp( transpose( vmap( ... and every ")": a transform wraps the first
+# scope inside it.  "jit(f" stays whole, so that a jitted function that
+# happens to be called `loss` is not taken for the scope
+_WRAPPER = re.compile(r"\b(?!jit\()\w+\(|\)")
+_VOCABULARY = "|".join(          # longest first: the first match is the longest
+    re.escape(p).replace(re.escape("{i}"), r"\d+")
+    for p in sorted(OWNERS, key=len, reverse=True))
+_PATH = re.compile(f"(?:^|/)({_VOCABULARY})(?=/|$)")
+_WHOLE_PATH = re.compile(_VOCABULARY)
+
+
+def owner_of(op_name: str):
+    """(owner, direction) an `op_name` states itself: the longest
+    vocabulary path in it, or None; "bwd" under `transpose(jvp(`, "fwd"
+    under `jvp(`, else "step"."""
+    direction = ("bwd" if "transpose(jvp(" in op_name
+                 else "fwd" if "jvp(" in op_name else "step")
+    m = _PATH.search(_WRAPPER.sub("", op_name))
+    return (m.group(1) if m else None), direction
+
+
+def _shared(found):
+    """What (owner, direction) pairs agree on: the longest vocabulary
+    path all the owners start with and the first pair's direction (an
+    instruction runs for its first user), or None."""
+    found = [f for f in found if f is not None]
+    if not found:
+        return None
+    paths = [owner.split("/") for owner, _ in found]
+    n = 0
+    while all(len(p) > n and p[n] == paths[0][n] for p in paths):
+        n += 1
+    while n and not _WHOLE_PATH.fullmatch("/".join(paths[0][:n])):
+        n -= 1
+    return ("/".join(paths[0][:n]), found[0][1]) if n else None
+
+
+def owners(hlo_text: str) -> dict:
+    """{instruction: (owner, direction, opcode)} for a compiled
+    program's text (`compiled.as_text()`).
+
+    An instruction's owner is the longest vocabulary path in its own
+    `op_name`; failing that, what its users share (followed through
+    users that state none), failing that, what its operands' producers
+    share; else `UNOWNED`.  The direction comes with the owner."""
+    out = {}
+    for comp in parse_module(hlo_text):
+        instrs = comp.instructions
+        own = {i.name: owner_of(i.op_name) for i in instrs}
+        found = {n: (o if o[0] else None) for n, o in own.items()}
+        users = {i.name: [] for i in instrs}
+        for i in instrs:
+            for operand in i.operand_names:
+                if operand in users:
+                    users[operand].append(i.name)
+        # printed order is a topological one: users are resolved before
+        # what they use going backwards, producers going forwards
+        for i in reversed(instrs):
+            if found[i.name] is None:
+                found[i.name] = _shared(found[u] for u in users[i.name])
+        for i in instrs:
+            if found[i.name] is None:
+                found[i.name] = _shared(
+                    found.get(p) for p in i.operand_names)
+            owner, direction = found[i.name] or (UNOWNED, own[i.name][1])
+            out[i.name] = (owner, direction, i.opcode)
+    return out
+
+
+_programs = {}     # the steps this process built: name -> (jitted, shapes)
+
+
+def register(name: str, jitted, args) -> bool:
+    """Called by a step builder when it builds a program: keeps the
+    jitted callable and the shapes, dtypes and shardings of `args`
+    under `name`.  One entry a name; held strongly, because the reader
+    runs after the job's own reference to the step is gone.  False,
+    and nothing kept, where `args` are tracers: that build runs under
+    another transformation, not on a device."""
+    import jax
+
+    if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
+        return False
+
+    def abstract(a):
+        # an uncommitted array's placement is JAX's choice, not the step's
+        placed = getattr(a, "committed", True)
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=getattr(a, "sharding", None) if placed else None)
+
+    _programs[name] = (jitted, jax.tree.map(abstract, args))
+    return True
+
+
+def step_text(name: str = "local_step") -> str:
+    """The compiled text of the registered program `name`.  After the
+    program has run this lowers and compiles nothing anew: tracing,
+    lowering and the executable come from JAX's in-process caches."""
+    jitted, args = _programs[name]
+    return jitted.lower(*args).compile().as_text()
+
+
+def step_owners(name: str = "local_step") -> dict:
+    """`owners` of the registered program `name`."""
+    return owners(step_text(name))

@@ -869,6 +869,7 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_fwd",
     )(qf, kf, vf, bias_t, qs, ks, seed)
     return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
@@ -969,6 +970,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
                 dimension_semantics=("parallel", "arbitrary",
                                      "arbitrary")),
             interpret=pallas_interpret(),
+            name="flash_bwd",
         )(*args)
         return (dq.reshape(q.shape), dk.reshape(k.shape),
                 dv.reshape(v.shape), None)
@@ -1001,6 +1003,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=pallas_interpret(),
+            name="flash_bwd",
         )(*args)
         dq, dk, dv = outs[:3]
         dbias = None
@@ -1027,6 +1030,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_compiler_params(3),
         interpret=pallas_interpret(),
+        name="flash_bwd_dq",
     )(*args)
     dbias = None
     if dbias_full:
@@ -1069,6 +1073,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
         scratch_shapes=dkv_scratch,
         compiler_params=dkv_params,
         interpret=pallas_interpret(),
+        name="flash_bwd_dkv",
     )(*args)
     dk, dv = outs[:2]
     if dbias_sk:

@@ -298,6 +298,7 @@ def _decode_pallas(q, k_pages, v_pages, block_table, lengths, scale, hp):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
+        name="flash_decode",
     )(lengths.astype(jnp.int32), block_table.astype(jnp.int32),
       qr, k_pages, v_pages)
     return (out.reshape(n_slots, hkv, G, q_len, d)

@@ -100,6 +100,7 @@ def _matmul_pallas(x2, w, b, activation, bm=256, bn=256, bk=512):
         out_shape=jax.ShapeDtypeStruct((mp, np_), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=pallas_interpret(),
+        name="fused_dense",
     )(xp, wp, bp)
     return out[:m, :n]
 

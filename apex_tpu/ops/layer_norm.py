@@ -148,6 +148,7 @@ def _fwd_pallas(x2, weight, bias, eps, rms):
             jax.ShapeDtypeStruct((prows, 1), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="ln_fwd",
     )(x2p, w, b)
     return y[:rows], mean[:rows], rstd[:rows]
 
@@ -185,6 +186,7 @@ def _bwd_pallas(g2, x2, mean, rstd, weight, rms):
             jax.ShapeDtypeStruct((1, hidden), jnp.float32),
         ],
         interpret=pallas_interpret(),
+        name="ln_bwd",
     )(g2p, x2p, meanp, rstdp, w)
     dw = dwp[0] if affine else None
     db = dbp[0] if affine else None

@@ -68,34 +68,6 @@ def _from2d(x2, n):
     return x2.reshape(-1)[:n]
 
 
-def _elementwise_call(kernel, arrays, n_out, interpret_override=None):
-    """Run an elementwise kernel over equally-shaped flat buffers.
-
-    The first `n_out` arrays are updated in place (aliased), mirroring
-    multi_tensor_apply's in-place tensor-list updates.
-    """
-    two_d = [_to2d(a)[0] for a in arrays]
-    n = arrays[0].shape[0]
-    rows = two_d[0].shape[0]
-    R = _block_rows(rows, "elementwise")
-    grid = rows // R
-    spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
-    interp = pallas_interpret() if interpret_override is None else interpret_override
-    outs = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[spec] * len(two_d),
-        out_specs=[spec] * n_out,
-        out_shape=[jax.ShapeDtypeStruct(two_d[0].shape, two_d[i].dtype)
-                   for i in range(n_out)],
-        input_output_aliases={i: i for i in range(n_out)},
-        interpret=interp,
-    )(*two_d)
-    if n_out == 1:
-        outs = [outs] if not isinstance(outs, (list, tuple)) else outs
-    return [_from2d(o, n) for o in outs]
-
-
 # ------------------------------- Adam ---------------------------------------
 
 def _adam_kernel(p_ref, m_ref, v_ref, g_ref, sc_ref,
@@ -199,6 +171,7 @@ def adam_flat(p, m, v, g, lr, step, *, beta1=0.9, beta2=0.999, eps=1e-8,
                    jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
         input_output_aliases={0: 0, 1: 1, 2: 2},
         interpret=pallas_interpret(),
+        name="adam_flat",
     )(p2, m2, v2, g2, scalars)
     return _from2d(pn, np_), _from2d(mn, np_), _from2d(vn, np_)
 
@@ -326,6 +299,7 @@ def adam_flat_seg(p, m, v, g, lr, step, *, wd_values, lr_scale_values,
                    jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
         input_output_aliases={0: 0, 1: 1, 2: 2},
         interpret=pallas_interpret(),
+        name="adam_flat_seg",
     )(p2, m2, v2, g2, scalars, lo, hi, vals, off)
     return _from2d(pn, np_), _from2d(mn, np_), _from2d(vn, np_)
 
@@ -444,6 +418,7 @@ def sgd_flat(p, buf, g, lr, *, momentum=0.0, dampening=0.0, nesterov=False,
                    jax.ShapeDtypeStruct(b2.shape, b2.dtype)],
         input_output_aliases={0: 0, 1: 1},
         interpret=pallas_interpret(),
+        name="sgd_flat",
     )(p2, b2, g2, scalars)
     return _from2d(pn, n), _from2d(bn, n)
 
@@ -499,6 +474,7 @@ def adagrad_flat(p, h, g, lr, *, eps=1e-10, weight_decay=0.0,
                    jax.ShapeDtypeStruct(h2.shape, jnp.float32)],
         input_output_aliases={0: 0, 1: 1},
         interpret=pallas_interpret(),
+        name="adagrad_flat",
     )(p2, h2, g2, scalars)
     return _from2d(pn, n), _from2d(hn, n)
 
@@ -627,6 +603,7 @@ def lamb_phase1_flat(m, v, g, p, clip_ratio, step, *, beta1, beta2, eps,
                    jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
         input_output_aliases={0: 0, 1: 1},
         interpret=pallas_interpret(),
+        name="lamb_phase1",
     )(m2, v2, g2, p2, scalars)
     return _from2d(mn, n), _from2d(vn, n), _from2d(u, n)
 
@@ -686,6 +663,7 @@ def lamb_phase1_seg(m, v, g, p, clip_ratio, step, *, wd_values, spec,
                    jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
         input_output_aliases={0: 0, 1: 1},
         interpret=pallas_interpret(),
+        name="lamb_phase1_seg",
     )(m2, v2, g2, p2, scalars, lo, hi, vals8, off)
     return _from2d(mn, n), _from2d(vn, n), _from2d(u, n)
 
@@ -754,6 +732,7 @@ def lamb_phase2_seg(p, u, ratio_values, spec, lr, *, row_offset=0,
         out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
         input_output_aliases={0: 0},
         interpret=pallas_interpret(),
+        name="lamb_phase2_seg",
     )(p2, u2, lo, hi, vals8, scalars, off)
     return _from2d(pn, n)
 
@@ -778,6 +757,7 @@ def lamb_phase2_flat(p, u, ratio_elem, lr, use_pallas_override=None):
         out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
         input_output_aliases={0: 0},
         interpret=pallas_interpret(),
+        name="lamb_phase2",
     )(p2, u2, r2, scalars)
     return _from2d(pn, n)
 
@@ -926,6 +906,7 @@ def _per_tensor_sumsq_2d(x2, spec, n_seg, row_offset):
         out_shape=jax.ShapeDtypeStruct((8, npad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, npad), jnp.float32)],
         interpret=pallas_interpret(),
+        name="per_tensor_sumsq",
     )(x2, lo, hi, off)
     return out[0, :n_seg]
 

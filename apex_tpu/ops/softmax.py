@@ -114,6 +114,7 @@ def _fwd_pallas(x2, mask2, scale, causal, sq):
         out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((prows, sk), x2.dtype),
         interpret=pallas_interpret(),
+        name="softmax_fwd",
     )(*inputs)
     return y[:rows]
 
@@ -131,6 +132,7 @@ def _bwd_pallas(g2, y2, scale):
         out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((prows, sk), g2.dtype),
         interpret=pallas_interpret(),
+        name="softmax_bwd",
     )(gp, yp)
     return dx[:rows]
 

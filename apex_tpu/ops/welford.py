@@ -68,6 +68,7 @@ def _channel_sums_impl(x2):
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         interpret=pallas_interpret(),
+        name="welford",
     )(xp)
     return s[0], q[0]
 

@@ -85,6 +85,7 @@ def _fwd_pallas(x2, labels, smoothing):
         out_shape=[jax.ShapeDtypeStruct((prows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((prows, 1), jnp.float32)],
         interpret=pallas_interpret(),
+        name="xent_fwd",
     )(xp, lbl)
     return loss[:rows, 0], lse[:rows]
 
@@ -107,6 +108,7 @@ def _bwd_pallas(g, x2, labels, lse, smoothing):
         out_specs=pl.BlockSpec((blk, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((prows, v), x2.dtype),
         interpret=pallas_interpret(),
+        name="xent_bwd",
     )(gp, xp, lbl, lsep)
     return dx[:rows]
 

@@ -102,8 +102,9 @@ class FusedAdam(F.FlatCheckpointMixin):
         # pre-cast (the unscale/moment math still runs in fp32 in-kernel)
         gdts = {l.dtype for l in jax.tree_util.tree_leaves(grads)}
         gdt = gdts.pop() if len(gdts) == 1 else jnp.float32
-        g_flat = F.flatten(grads, gdt, pad_to=K.FLAT_TILE,
-                           align=self.spec.align)
+        with jax.named_scope("flatten_grads"):
+            g_flat = F.flatten(grads, gdt, pad_to=K.FLAT_TILE,
+                               align=self.spec.align)
         p_tree, new_state = self.step_flat(state, g_flat, lr=lr,
                                            inv_scale=inv_scale,
                                            found_inf=found_inf)
@@ -115,32 +116,33 @@ class FusedAdam(F.FlatCheckpointMixin):
         to state.params length).  This is the zero-copy hot path: a train
         step that differentiates w.r.t. the flat param view gets its grad
         here directly, skipping the per-leaf flatten entirely."""
-        found = jnp.asarray(found_inf)
-        step_next = state.step + jnp.where(found, 0, 1).astype(jnp.int32)
-        if self._per_leaf:
-            p, m, v = K.adam_flat_seg(
-                state.params, state.exp_avg, state.exp_avg_sq, g_flat,
-                lr=self.lr if lr is None else lr,
-                step=step_next.astype(jnp.float32),
-                wd_values=self._seg_wd, lr_scale_values=self._seg_lrs,
-                spec=self.spec,
-                beta1=self.beta1, beta2=self.beta2, eps=self.eps,
-                adam_w_mode=self.adam_w_mode,
-                bias_correction=self.bias_correction,
-                inv_scale=inv_scale, found_inf=found,
-                use_pallas_override=self.use_pallas)
-        else:
-            p, m, v = K.adam_flat(
-                state.params, state.exp_avg, state.exp_avg_sq, g_flat,
-                lr=self.lr if lr is None else lr,
-                step=step_next.astype(jnp.float32),
-                beta1=self.beta1, beta2=self.beta2, eps=self.eps,
-                weight_decay=self.weight_decay,
-                adam_w_mode=self.adam_w_mode,
-                bias_correction=self.bias_correction, inv_scale=inv_scale,
-                found_inf=found, use_pallas_override=self.use_pallas)
-        new_state = FusedAdamState(step=step_next, params=p, exp_avg=m,
-                                   exp_avg_sq=v)
+        with jax.named_scope("adam"):
+            found = jnp.asarray(found_inf)
+            step_next = state.step + jnp.where(found, 0, 1).astype(jnp.int32)
+            if self._per_leaf:
+                p, m, v = K.adam_flat_seg(
+                    state.params, state.exp_avg, state.exp_avg_sq, g_flat,
+                    lr=self.lr if lr is None else lr,
+                    step=step_next.astype(jnp.float32),
+                    wd_values=self._seg_wd, lr_scale_values=self._seg_lrs,
+                    spec=self.spec,
+                    beta1=self.beta1, beta2=self.beta2, eps=self.eps,
+                    adam_w_mode=self.adam_w_mode,
+                    bias_correction=self.bias_correction,
+                    inv_scale=inv_scale, found_inf=found,
+                    use_pallas_override=self.use_pallas)
+            else:
+                p, m, v = K.adam_flat(
+                    state.params, state.exp_avg, state.exp_avg_sq, g_flat,
+                    lr=self.lr if lr is None else lr,
+                    step=step_next.astype(jnp.float32),
+                    beta1=self.beta1, beta2=self.beta2, eps=self.eps,
+                    weight_decay=self.weight_decay,
+                    adam_w_mode=self.adam_w_mode,
+                    bias_correction=self.bias_correction, inv_scale=inv_scale,
+                    found_inf=found, use_pallas_override=self.use_pallas)
+            new_state = FusedAdamState(step=step_next, params=p, exp_avg=m,
+                                       exp_avg_sq=v)
         return F.unflatten(p, self.spec), new_state
 
     # checkpoint parity ≡ torch optimizer state_dict: FlatCheckpointMixin

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from apex_tpu.monitor import scopes
 from apex_tpu.optimizers import flat as F
 from apex_tpu.parallel.mesh import DP_AXIS, PP_AXIS
 
@@ -81,12 +82,14 @@ def make_tp_dp_train_step(model, optimizer, mesh, *,
         # arrive pre-flattened) was tried and is ~40% SLOWER: the
         # unflatten-transpose becomes one full-buffer scatter-add per
         # leaf.  Per-leaf grads + one concatenate is the fast shape.
-        params = F.unflatten(opt_state.params, optimizer.spec)
+        with jax.named_scope("unflatten"):
+            params = F.unflatten(opt_state.params, optimizer.spec)
 
         loss, grads = jax.value_and_grad(lambda p: lf(p, tokens, labels))(
             params)
-        grads = jax.tree_util.tree_map(
-            lambda g: jax.lax.pmean(g, DP_AXIS), grads)
+        with jax.named_scope("dp_reduce"):
+            grads = jax.tree_util.tree_map(
+                lambda g: jax.lax.pmean(g, DP_AXIS), grads)
         if pp_partial_grads:
             # pp-REPLICATED leaves (tied embedding, position embeddings,
             # final LN) get per-stage PARTIAL grads under the pipeline —
@@ -102,9 +105,12 @@ def make_tp_dp_train_step(model, optimizer, mesh, *,
                 if PP_AXIS in names:
                     return g  # pp-sharded leaf: its grad is stage-local
                 return jax.lax.psum(g, PP_AXIS)
-            grads = jax.tree_util.tree_map(_pp_sync, grads, specs)
-        _, new_state = optimizer.step(opt_state, grads)
-        return new_state, jax.lax.pmean(loss, DP_AXIS)
+            with jax.named_scope("pp_sync"):
+                grads = jax.tree_util.tree_map(_pp_sync, grads, specs)
+        with jax.named_scope("optimizer"):
+            _, new_state = optimizer.step(opt_state, grads)
+        with jax.named_scope("dp_reduce"):
+            return new_state, jax.lax.pmean(loss, DP_AXIS)
 
     state_spec_leaves = None
 
@@ -124,18 +130,28 @@ def make_tp_dp_train_step(model, optimizer, mesh, *,
     # re-specializes per input shape/dtype on its own
     cache = {}
 
-    def _jitted_for(opt_state):
+    def _jitted_for(opt_state, *batch):
         k = jax.tree_util.tree_structure(opt_state)
         fn = cache.get(k)
         if fn is None:
-            fn = cache[k] = build(opt_state)
+            fn = build(opt_state)
+            # once a program, not a step: monitor.scopes can then say
+            # which scope owns each instruction of the step that ran.
+            # Under another transformation (the linter's make_jaxpr)
+            # the arguments are tracers and nothing will run: such a
+            # build is not kept, so the first real call registers
+            if scopes.register(local_step.__name__, fn,
+                               (opt_state, *batch)):
+                cache[k] = fn
         return fn
 
     def step(opt_state, tokens, labels):
-        return _jitted_for(opt_state)(opt_state, tokens, labels)
+        return _jitted_for(opt_state, tokens, labels)(
+            opt_state, tokens, labels)
 
     def lower(opt_state, tokens, labels):
-        return _jitted_for(opt_state).lower(opt_state, tokens, labels)
+        return _jitted_for(opt_state, tokens, labels).lower(
+            opt_state, tokens, labels)
 
     def _cache_size():
         # aggregate over the per-structure jits so RecompileSentry's

@@ -264,14 +264,22 @@ def flagship_step(devices, tp, **cfg_overrides):
     return step, (state, tokens, tokens)
 
 
-def test_flagship_step_compiles_and_fits_one_chip(topo, on_chip):
+@pytest.fixture(scope="module")
+def flagship_compiled(topo):
+    """One compile of the flagship step for the two tests below."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(_common, "on_chip", lambda: True)
+        step, args = flagship_step(topo.devices[:1], tp=1)
+        return step.lower(*args).compile()
+
+
+def test_flagship_step_compiles_and_fits_one_chip(flagship_compiled):
     """The 350M flagship step (h1024 L24, vocab 50304, bf16, flash
     attention, bf16 Adam state, batch 12 x 1024, no remat, donated
     state) compiles for one v5e with its flash and Adam kernels in it
     and leaves room on the chip — about 1 GiB when this was written.
     Every later PR that grows the step's temporaries meets this first."""
-    step, args = flagship_step(topo.devices[:1], tp=1)
-    compiled = step.lower(*args).compile()
+    compiled = flagship_compiled
     # 24 layers x (flash fwd + flash bwd) + the Adam pass
     assert _n_kernels(compiled) >= 49
     m = compiled.memory_analysis()
@@ -280,3 +288,38 @@ def test_flagship_step_compiles_and_fits_one_chip(topo, on_chip):
     assert _bytes(compiled) < HBM_BYTES, (
         f"arguments {m.argument_size_in_bytes / GIB:.2f} GiB + temporaries "
         f"{m.temp_size_in_bytes / GIB:.2f} GiB no longer fit one chip")
+
+
+def test_flagship_step_is_named_from_inside(flagship_compiled):
+    """What a trace of the chip will show: every Mosaic kernel of the
+    step under its own name, and every instruction that takes core
+    time owned by a scope of the program (monitor.scopes)."""
+    import re
+
+    from apex_tpu.monitor import scopes
+    from apex_tpu.monitor.comms.hlo import parse_module
+
+    text = flagship_compiled.as_text()
+    (entry,) = [c for c in parse_module(text) if c.is_entry]
+    kernels = re.findall(
+        r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+    by_name = {}
+    for name in kernels:
+        by_name.setdefault(name.split(".")[0], []).append(name)
+    assert {k: len(v) for k, v in by_name.items()} == {
+        "flash_fwd": 24, "flash_bwd": 24, "adam_flat": 1}
+
+    found = scopes.owners(text)
+    timed = ("fusion", "copy", "custom-call", "all-reduce", "all-gather",
+             "reduce-scatter", "convolution", "dot")
+    unowned = [i.name for i in entry.instructions
+               if i.opcode in timed and found[i.name][0] == scopes.UNOWNED]
+    assert not unowned    # the count this test states: none
+    # the flat gradient is built by fusions that carry no op_name: the
+    # rule hands them the Adam kernel's owner
+    flat = [i.name for i in entry.instructions if i.name.startswith(
+        "constant_dynamic-update-slice_fusion")]
+    assert len(flat) > 200 and {found[n][0] for n in flat} == {
+        "optimizer/adam"}
+    directions = {d for _, d, _ in found.values()}
+    assert directions == {"fwd", "bwd", "step"}

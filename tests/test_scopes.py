@@ -1,0 +1,295 @@
+"""monitor.scopes: the vocabulary of owners, the ownership rule over a
+compiled program's text, and the step a process ran.
+
+The rule is held to a recorded text: `data/step_v5e_excerpt.hlo.txt` is
+cut from the GPT-350M step compiled for the described v5e (real
+instructions of its entry computation, in their order; layouts,
+backend_config and frontend attributes taken out)."""
+
+import ast
+import contextlib
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
+
+from apex_tpu.models.gpt import GPT, GPTConfig
+from apex_tpu.monitor import scopes
+from apex_tpu.monitor.comms.hlo import parse_module
+from apex_tpu.optimizers.fused_adam import FusedAdam
+from apex_tpu.parallel import mesh as M
+from apex_tpu.transformer.training import (
+    init_sharded_optimizer,
+    make_tp_dp_train_step,
+)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+PACKAGE = os.path.join(os.path.dirname(HERE), "apex_tpu")
+FLASH0 = "block0/attn/flash"
+
+
+# ------------------------------ the rule ------------------------------
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(local_step)/jvp(block3)/attn/qkv/dot_general",
+     ("block3/attn/qkv", "fwd")),
+    ("jit(local_step)/transpose(jvp(block11))/mlp/fc2/dot_general",
+     ("block11/mlp/fc2", "bwd")),
+    ("jit(local_step)/jvp(block0)/attn/flash/flash_fwd/pallas_call",
+     (FLASH0, "fwd")),
+    ("jit(step)/jvp(while)/body/block/ln1/reduce_sum", ("block/ln1", "fwd")),
+    ("jit(local_step)/optimizer/adam/adam_flat/pallas_call",
+     ("optimizer/adam", "step")),
+    ("jit(local_step)/optimizer/convert_element_type", ("optimizer", "step")),
+    ("jit(local_step)/jvp(block2)/mlp/gelu/jit(gelu)/tanh",
+     ("block2/mlp/gelu", "fwd")),
+    # direction without an owner; a jitted function is not a scope; a
+    # block alone, or a sublayer outside a block, is no vocabulary path
+    ("jit(local_step)/jvp()/dot_general", (None, "fwd")),
+    ("jit(loss)/reduce_sum", (None, "step")),
+    ("jit(f)/block7/add", (None, "step")),
+    ("jit(f)/attn/qkv/dot_general", (None, "step")),
+    ("", (None, "step")),
+])
+def test_owner_of_an_op_name(op_name, want):
+    assert scopes.owner_of(op_name) == want
+
+
+@pytest.fixture(scope="module")
+def excerpt():
+    with open(os.path.join(HERE, "data", "step_v5e_excerpt.hlo.txt")) as f:
+        return scopes.owners(f.read())
+
+
+@pytest.mark.parametrize("instruction,want,why", [
+    ("flash_fwd.24", (FLASH0, "fwd", "custom-call"), "its own op_name"),
+    ("flash_bwd.47", (FLASH0, "bwd", "custom-call"),
+     "its own op_name, under transpose(jvp("),
+    ("fusion.2006", ("block0/mlp/fc1", "bwd", "fusion"),
+     "a fusion carries one of its instructions' op_name"),
+    ("adam_flat.1", ("optimizer/adam", "step", "custom-call"),
+     "outside the differentiated function the direction is step"),
+    ("bitcast.1938", (FLASH0, "fwd", "bitcast"),
+     "no op_name: its one user, the kernel"),
+    ("copy-start.1033", (FLASH0, "bwd", "copy-start"),
+     "through copy-done.1033, which has no op_name either, to the "
+     "backward kernel; the direction comes with the owner"),
+    ("constant_dynamic-update-slice_fusion.262",
+     ("optimizer/adam", "step", "fusion"),
+     "the flat gradient: three fusions without op_name down to "
+     "fusion.9, whose own op_name says optimizer/adam"),
+    ("copy-done.986", (FLASH0, "fwd", "copy-done"),
+     "its users lead to kernels of block0 and block1, which share no "
+     "path; its producer chain starts at broadcast.31, owned through "
+     "flash_fwd.24"),
+    ("constant.88", (scopes.UNOWNED, "step", "constant"),
+     "kernels of two blocks use it and nothing produces it"),
+    ("tuple.640", (scopes.UNOWNED, "step", "tuple"),
+     "the root: no user, and its operands are the optimizer's and "
+     "the loss's"),
+])
+def test_ownership_rule_on_recorded_text(excerpt, instruction, want, why):
+    assert excerpt[instruction] == want, why
+
+
+def test_users_that_disagree_share_their_longest_path():
+    """Not recorded (the flagship step has no such instruction): a
+    copy used by the QKV GEMM and by the flash kernel of one block
+    belongs to that block's attention; used by two sublayers of the
+    block, to nothing the vocabulary names, so its producer decides."""
+    text = """HloModule m
+
+ENTRY %main () -> () {
+  %p = f32[8] parameter(0), metadata={op_name="jit(f)/jvp(block1)/ln1/mul"}
+  %copy.1 = f32[8] copy(%p)
+  %copy.2 = f32[8] copy(%p)
+  %a = f32[8] negate(%copy.1), metadata={op_name="jit(f)/jvp(block1)/attn/qkv/neg"}
+  %b = f32[8] negate(%copy.1), metadata={op_name="jit(f)/jvp(block1)/attn/flash/neg"}
+  %c = f32[8] negate(%copy.2), metadata={op_name="jit(f)/jvp(block1)/attn/proj/neg"}
+  %d = f32[8] negate(%copy.2), metadata={op_name="jit(f)/transpose(jvp(block1))/mlp/fc1/neg"}
+}
+"""
+    found = scopes.owners(text)
+    assert found["copy.1"] == ("block1/attn", "fwd", "copy")
+    assert found["copy.2"] == ("block1/ln1", "fwd", "copy")
+
+
+# --------------------------- the vocabulary ---------------------------
+
+def _calls(tree, *dotted):
+    """Call nodes of `tree` whose callee is spelled `a.b`."""
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                and isinstance(node.func.value, ast.Name)
+                and (node.func.value.id, node.func.attr) == dotted):
+            yield node
+
+
+def _spelled(node):
+    """A string argument as the source spells it, an f-string's fields
+    as `{name}`; None if it is not a string literal."""
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return node.value
+    if isinstance(node, ast.JoinedStr):
+        return "".join(
+            part.value if isinstance(part, ast.Constant)
+            else "{" + ast.unparse(part.value) + "}" for part in node.values)
+    return None
+
+
+def _sources():
+    for path in sorted(glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                                 recursive=True)):
+        with open(path) as f:
+            yield os.path.relpath(path, PACKAGE), ast.parse(f.read())
+
+
+def test_every_scope_in_the_program_is_in_the_vocabulary():
+    """A `jax.named_scope` the vocabulary does not know would open a
+    path that `owners` cannot read: its time would fall to `unowned` or
+    to an enclosing scope.  A scope may spell a whole path or a run of
+    its segments (`block{i}`, `attn`, `qkv` nest into one path)."""
+    runs = set()
+    for path in scopes.OWNERS:
+        parts = path.split("/")
+        runs |= {"/".join(parts[a:b]) for a in range(len(parts))
+                 for b in range(a + 1, len(parts) + 1)}
+    used = {}
+    for where, tree in _sources():
+        for call in _calls(tree, "jax", "named_scope"):
+            used.setdefault(_spelled(call.args[0]), where)
+    assert len(used) >= 20    # the walk finds them at all
+    strangers = {name: where for name, where in used.items()
+                 if name not in runs}
+    assert not strangers
+    # and no vocabulary path is one the program never opens
+    unopened = [p for p in scopes.OWNERS
+                if not set(p.split("/")) <= {s for u in used
+                                             for s in u.split("/")}]
+    assert not unopened
+
+
+def test_every_pallas_call_has_a_name_from_the_vocabulary():
+    """An unnamed Pallas call is named after the scope around it
+    (`%mlp.1`) and would pass for that scope."""
+    names = []
+    for where, tree in _sources():
+        for call in _calls(tree, "pl", "pallas_call"):
+            given = {k.arg: k.value for k in call.keywords}
+            assert "name" in given, f"{where}:{call.lineno} has no name="
+            name = _spelled(given["name"])
+            assert name in scopes.KERNELS, f"{where}:{call.lineno}: {name}"
+            names.append(name)
+    assert len(names) >= 23
+    assert set(names) == set(scopes.KERNELS)
+
+
+# ------------------------- the step that ran -------------------------
+
+def _tiny_step(tp, sequence_parallel):
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(
+        tensor_model_parallel_size=tp, devices=jax.devices()[:2 * tp])
+    model = GPT(GPTConfig(vocab_size=64, seq_len=16, hidden=32, num_layers=2,
+                          num_heads=4, dropout=0.0,
+                          sequence_parallel=sequence_parallel))
+    params = model.init(jax.random.PRNGKey(8))
+    opt = FusedAdam(lr=3e-3, use_pallas=False)
+    state = init_sharded_optimizer(opt, model, params, mesh)
+    step = make_tp_dp_train_step(model, opt, mesh, donate=False)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (4, 16), 0, 64)
+    return step, state, tokens, jnp.roll(tokens, -1, axis=1)
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    """Every backend compile of this process, from here on (JAX has no
+    way to take a listener off again: one for the module)."""
+    seen = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _, **kw: seen.append(event)
+        if event.endswith("backend_compile_duration") else None)
+    return seen
+
+
+@pytest.mark.parametrize("tp,sequence_parallel", [(1, False), (2, True)])
+def test_step_owners_names_every_gemm_of_the_step_that_ran(
+        tp, sequence_parallel, compiles):
+    step, state, tokens, labels = _tiny_step(tp, sequence_parallel)
+    state, loss = step(state, tokens, labels)
+    assert np.isfinite(float(loss))
+
+    compiled_before = len(compiles)
+    text = scopes.step_text()
+    found = scopes.owners(text)
+    # the executable the step ran, not a second one
+    assert len(compiles) == compiled_before > 0
+
+    gemms = [i for comp in parse_module(text) for i in comp.instructions
+             if i.op_name.endswith("dot_general")]
+    assert len(gemms) >= 2 * 3 * 4 + 3    # 2 layers x (fwd + 2 bwd) x 4
+    for i in gemms:
+        owner, direction, _ = found[i.name]
+        assert owner.startswith(("block0/", "block1/", "head", "embed")), (
+            i.name, i.op_name, owner)
+        assert direction in ("fwd", "bwd")
+    owners = {owner for owner, _, _ in found.values()}
+    assert {"unflatten", "dp_reduce", "optimizer/adam",
+            "optimizer/flatten_grads", "embed", "final_ln", "head", "loss",
+            "block1/ln2", "block0/mlp/gelu"} <= owners
+    # the TP/SP collectives inherit the sublayer that calls them
+    kinds = ("all-reduce", "all-gather", "reduce-scatter")
+    collectives = [v for v in found.values() if v[2] in kinds]
+    assert collectives and all(v[0] != scopes.UNOWNED for v in collectives)
+    if sequence_parallel:
+        assert any(owner == "block0/attn/qkv" and opcode == "all-gather"
+                   for owner, _, opcode in found.values())
+
+
+def test_scopes_change_no_arithmetic(monkeypatch):
+    """The same step traced with `jax.named_scope` a no-op gives the
+    same loss and the same new state, bit for bit."""
+    def two_steps():
+        step, state, tokens, labels = _tiny_step(2, True)
+        state, _ = step(state, tokens, labels)
+        state, loss = step(state, tokens, labels)
+        return jax.tree.map(np.asarray, (loss, state))
+
+    with_scopes = two_steps()
+    assert "block0/ln1" in {v[0] for v in scopes.step_owners().values()}
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = two_steps()
+    assert {v[0] for v in scopes.step_owners().values()} == {scopes.UNOWNED}
+    jax.tree.map(np.testing.assert_array_equal, with_scopes, without)
+
+
+def test_a_scanned_stack_is_owned_as_block():
+    """GPTPipelined scans one block body over its layers: the scope is
+    `block`, without an index, opened inside the scanned body."""
+    from apex_tpu.models.gpt import GPTPipelined
+
+    model = GPTPipelined(
+        GPTConfig(vocab_size=64, seq_len=16, hidden=32, num_layers=2,
+                  num_heads=4, dropout=0.0),
+        num_microbatches=2, pipeline_parallel_size=1)
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(devices=jax.devices()[:1])
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # (pp, chunks, layers, ...) -> this stage's (layers, ...)
+    stage = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape[2:], l.dtype),
+        params["blocks"])
+    x = jax.ShapeDtypeStruct((16, 2, 32), jnp.float32)
+    grad = jax.grad(lambda p, x: model._stage_fn(p, x, 0).sum())
+    text = jax.jit(shard_map(
+        grad, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+        check_vma=False)).lower(stage, x).compile().as_text()
+    owners = {owner for owner, _, _ in scopes.owners(text).values()}
+    assert {"block/ln1", "block/attn/qkv", "block/attn/flash",
+            "block/mlp/fc2"} <= owners
