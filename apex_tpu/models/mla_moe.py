@@ -1,0 +1,373 @@
+"""MLAMoE — the DeepSeek-V3 family's block, as one chip of an
+expert-parallel group holds it: latent attention, a sigmoid-routed
+fine-grained expert layer with a shared expert, multi-token prediction.
+
+The layers, by the keys of the family's `config.json` (no bias
+anywhere, RMSNorm, untied embedding and head, no position table):
+
+* block: `h += MLA(RMSNorm(h))`, `h += FFN(RMSNorm(h))`;
+* MLA: `c_q = RMSNorm(a W_qa)`; `q = c_q W_qb`, per head (nope | rope);
+  `[c_kv | k_r] = a W_kva`, `c_kv = RMSNorm(c_kv)`; `[k_nope | v] =
+  c_kv W_kvb` per head; interleaved RoPE on `q_r` and on the one `k_r`
+  all heads share; causal softmax attention with keys of
+  `qk_nope + qk_rope` and values of `v_head_dim`
+  (`ops.flash_attention.flash_attention` takes the two widths apart);
+  the output projection;
+* FFN of the leading `first_k_dense_replace` layers: a SwiGLU of
+  `intermediate_size`; of the others: `moe.HeldExpertsMLP`, which
+  routes over all `n_routed_experts` and computes the part of the
+  result that experts `[experts_first, experts_first + experts_count)`
+  give, plus the shared expert;
+* multi-token prediction (arXiv:2412.19437 section 2.2), depth 1:
+  `h' = W_eh [RMSNorm(h_i) ; RMSNorm(E[t_{i+1}])]`, one more expert
+  block, a norm, the main model's embedding and head, cross entropy
+  against `t_{i+2}`; `loss = L_main + mtp_lambda * L_mtp`.
+
+The config says what is held here: how many leading dense and expert
+layers, whether the MTP module, which experts, how many rows of the
+vocabulary.  The layers left out lie on other chips as pipeline
+stages, the experts left out on the other chips of the expert-parallel
+group; this module has no code that stands in for either.
+
+Runs shard-local inside `shard_map` over the (pp, dp, tp) mesh like
+`models.gpt.GPT`, with `init`, `partition_specs`, `apply`,
+`logits_local` and `loss` of the same meaning, so
+`make_tp_dp_train_step` drives it unchanged.  Tensor parallelism 1
+only: the model's parallel axis is the experts', told by the config.
+Activations are (B, S, H).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from apex_tpu.moe.layer import HeldExpertsMLP
+from apex_tpu.ops.layer_norm import fused_rms_norm
+from apex_tpu.parallel.mesh import TP_AXIS
+from apex_tpu.transformer.tensor_parallel.cross_entropy import (
+    vocab_parallel_cross_entropy,
+)
+from apex_tpu.transformer.tensor_parallel.layers import (
+    VocabParallelEmbedding,
+)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAMoEConfig:
+    vocab_size: int = 16256          # rows of embedding and head held here
+    hidden: int = 2048
+    num_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 7168    # the leading dense layers' SwiGLU
+    moe_intermediate_size: int = 768
+    n_routed_experts: int = 256      # the router's width, as published
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rope_theta: float = 32e6
+    rms_norm_eps: float = 1e-6
+    # what this chip holds
+    first_k_dense_replace: int = 1   # leading dense layers
+    num_expert_layers: int = 4       # expert layers after them
+    mtp: bool = True                 # the multi-token-prediction module
+    experts_first: int = 0           # experts [first, first + count)
+    experts_count: int = 16
+    mtp_lambda: float = 0.3
+    init_std: float = 0.02
+    router_bias_range: float = 0.05  # the seeded, fixed e_score_correction_bias
+    dtype: Any = jnp.float32
+    logits_dtype: Any = None         # None keeps fp32 logits
+    # the flash kernels' dispatch, as `flash_attention` takes it: None
+    # lets the backend decide (the kernels on a TPU, the jnp reference
+    # elsewhere), True forces the kernels (interpreted off the chip),
+    # False the jnp reference
+    flash_override: Any = None
+    fused_xent: Any = None
+    axis_name: str = TP_AXIS
+
+    @property
+    def num_layers(self) -> int:
+        return self.first_k_dense_replace + self.num_expert_layers
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+def rope_interleaved(x, positions, theta: float):
+    """Rotary embedding over the last axis of x (..., S, n, d), pairs
+    (2i, 2i+1) turned by `positions * theta**(-2i/d)`
+    (`rope_interleave`).  Computed in fp32; the pair's partner comes
+    by a roll along the lanes, not by a reshape to (d/2, 2)."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq   # (S, d/2)
+    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]    # (S, 1, d)
+    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    even = (jnp.arange(d) % 2) == 0
+    partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1),
+                        jnp.roll(x32, 1, axis=-1))
+    return (x32 * cos + partner * sin).astype(x.dtype)
+
+
+class MLAMoE:
+    def __init__(self, config: MLAMoEConfig):
+        self.c = c = config
+        self.embed = VocabParallelEmbedding(
+            c.vocab_size, c.hidden, init_std=c.init_std,
+            axis_name=c.axis_name)
+        self.experts = HeldExpertsMLP(
+            c.hidden, c.moe_intermediate_size, c.n_routed_experts,
+            first=c.experts_first, count=c.experts_count,
+            top_k=c.num_experts_per_tok, n_shared=c.n_shared_experts,
+            scale=c.routed_scaling_factor, renormalize=c.norm_topk_prob,
+            init_std=c.init_std, bias_range=c.router_bias_range)
+        # block indices: the layers held, then the MTP module's own
+        self.n_blocks = c.num_layers + int(c.mtp)
+
+    def _is_dense(self, i: int) -> bool:
+        return i < self.c.first_k_dense_replace
+
+    # ------------------------------ params --------------------------------
+    def _init_block(self, key, i: int) -> dict:
+        c = self.c
+        ks = jax.random.split(key, 8)
+        nh, h = c.num_heads, c.hidden
+
+        def normal(k, *shape):
+            return jax.random.normal(k, shape, c.dtype) * c.init_std
+
+        def ones(n):
+            return {"weight": jnp.ones((n,), c.dtype)}
+
+        attn = {
+            "q_a": normal(ks[0], h, c.q_lora_rank),
+            "q_a_norm": ones(c.q_lora_rank),
+            "q_b": normal(ks[1], c.q_lora_rank, nh * c.qk_head_dim),
+            "kv_a": normal(ks[2], h, c.kv_lora_rank + c.qk_rope_head_dim),
+            "kv_a_norm": ones(c.kv_lora_rank),
+            "kv_b": normal(ks[3], c.kv_lora_rank,
+                           nh * (c.qk_nope_head_dim + c.v_head_dim)),
+            "proj": normal(ks[4], nh * c.v_head_dim, h),
+        }
+        if self._is_dense(i):
+            mlp = {"gate_up": normal(ks[5], h, 2 * c.intermediate_size),
+                   "down": normal(ks[6], c.intermediate_size, h)}
+        else:
+            mlp = self.experts.init(ks[7], c.dtype)
+        return {"ln1": ones(h), "attn": attn, "ln2": ones(h), "mlp": mlp}
+
+    def init(self, key):
+        c = self.c
+        keys = jax.random.split(key, 3 + self.n_blocks)
+        params = {
+            "embed": self.embed.init(keys[0], c.dtype),
+            "head": {"weight": jax.random.normal(
+                keys[1], (c.vocab_size, c.hidden), c.dtype) * c.init_std},
+            "final_ln": {"weight": jnp.ones((c.hidden,), c.dtype)},
+        }
+        for i in range(self.n_blocks):
+            params[f"block{i}"] = self._init_block(keys[3 + i], i)
+        if c.mtp:
+            params["mtp"] = {
+                "hnorm": {"weight": jnp.ones((c.hidden,), c.dtype)},
+                "enorm": {"weight": jnp.ones((c.hidden,), c.dtype)},
+                "proj": jax.random.normal(
+                    keys[2], (2 * c.hidden, c.hidden), c.dtype) * c.init_std,
+                "final_ln": {"weight": jnp.ones((c.hidden,), c.dtype)},
+            }
+        return params
+
+    def partition_specs(self):
+        """PartitionSpec pytree matching init(): the vocabulary's rows
+        over the tp axis (of size 1), everything else replicated."""
+        c = self.c
+        shapes = jax.eval_shape(self.init, jax.random.PRNGKey(0))
+        specs = jax.tree.map(lambda _: P(), shapes)
+        specs["embed"] = {"weight": P(c.axis_name, None)}
+        specs["head"] = {"weight": P(c.axis_name, None)}
+        return specs
+
+    # ------------------------------ forward -------------------------------
+    def _norm(self, p, x):
+        return fused_rms_norm(x, p["weight"], eps=self.c.rms_norm_eps)
+
+    def _dot(self, x, w):
+        return jnp.dot(x, w, preferred_element_type=jnp.float32
+                       ).astype(x.dtype)
+
+    def _attention(self, p, a):
+        """a: (B, S, H), normed.  The latent attention's output, before
+        the residual add."""
+        c = self.c
+        b, s, _ = a.shape
+        nh, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim,
+                          c.qk_rope_head_dim, c.v_head_dim)
+        with jax.named_scope("q_a"):
+            c_q = self._norm(p["q_a_norm"], self._dot(a, p["q_a"]))
+        with jax.named_scope("q_b"):
+            q = self._dot(c_q, p["q_b"]).reshape(b, s, nh, dn + dr)
+        with jax.named_scope("kv_a"):
+            ckv = self._dot(a, p["kv_a"])
+            k_r = ckv[..., c.kv_lora_rank:]
+            c_kv = self._norm(p["kv_a_norm"], ckv[..., :c.kv_lora_rank])
+        with jax.named_scope("kv_b"):
+            kv = self._dot(c_kv, p["kv_b"]).reshape(b, s, nh, dn + dv)
+        with jax.named_scope("rope"):
+            pos = jnp.arange(s)
+            q_r = rope_interleaved(q[..., dn:], pos, c.rope_theta)
+            k_r = rope_interleaved(k_r[:, :, None, :], pos, c.rope_theta)
+            q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, nh, dr))],
+                axis=-1)
+        # the head-major copies of q, k, v and of the context are the
+        # kernels' price, as in the GPT block: they carry their scope
+        with jax.named_scope("flash"):
+            from apex_tpu.ops.flash_attention import flash_attention
+            ctx = flash_attention(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                kv[..., dn:].transpose(0, 2, 1, 3), causal=True,
+                softmax_scale=1.0 / math.sqrt(c.qk_head_dim),
+                use_pallas_override=c.flash_override)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * dv)
+        with jax.named_scope("proj"):
+            return self._dot(ctx, p["proj"])
+
+    def _mlp(self, i, p, m):
+        """(the FFN's output, HeldExpertsStats or None)."""
+        if self._is_dense(i):
+            with jax.named_scope("gate_up"):
+                gu = self._dot(m, p["gate_up"])
+                gate, up = jnp.split(gu, 2, axis=-1)
+                act = jax.nn.silu(gate) * up
+            with jax.named_scope("down"):
+                return self._dot(act, p["down"]), None
+        return self.experts.apply(p, m)
+
+    def _block(self, i, p, x):
+        with jax.named_scope(f"block{i}"):
+            with jax.named_scope("ln1"):
+                a = self._norm(p["ln1"], x)
+            with jax.named_scope("attn"):
+                x = x + self._attention(p["attn"], a)
+            with jax.named_scope("ln2"):
+                m = self._norm(p["ln2"], x)
+            with jax.named_scope("mlp"):
+                y, stats = self._mlp(i, p["mlp"], m)
+                return x + y, stats
+
+    def _embed(self, params, ids):
+        with jax.named_scope("embed"):
+            return self.embed.apply(params["embed"], ids)
+
+    def trunk(self, params, tokens):
+        """tokens (B, S) -> (the residual stream after the last held
+        layer, (B, S, H), before the final norm; the expert layers'
+        HeldExpertsStats in layer order)."""
+        h = self._embed(params, tokens)
+        stats = []
+        for i in range(self.c.num_layers):
+            h, st = self._block(i, params[f"block{i}"], h)
+            if st is not None:
+                stats.append(st)
+        return h, stats
+
+    def apply(self, params, tokens, key=None):
+        """tokens: (B, S) ids within the held rows.  The hidden states
+        the head reads, (B, S, H).  Shard-local: call inside
+        shard_map."""
+        h, _ = self.trunk(params, tokens)
+        return self._final_ln(params, h)
+
+    def _final_ln(self, params, h):
+        with jax.named_scope("final_ln"):
+            return self._norm(params["final_ln"], h)
+
+    def mtp_hidden(self, params, h, next_tokens):
+        """The MTP module up to its own final norm: `h` the trunk's
+        residual stream (B, S, H), `next_tokens` (B, S) the token after
+        each position.  Returns (hidden the shared head reads, the MTP
+        block's HeldExpertsStats)."""
+        p = params["mtp"]
+        with jax.named_scope("mtp"):
+            with jax.named_scope("proj"):
+                # embed's own scope is the main model's: this lookup
+                # is the module's
+                e = self.embed.apply(params["embed"], next_tokens)
+                both = jnp.concatenate(
+                    [self._norm(p["hnorm"], h), self._norm(p["enorm"], e)],
+                    axis=-1)
+                x = self._dot(both, p["proj"])
+        i = self.c.num_layers
+        x, stats = self._block(i, params[f"block{i}"], x)
+        with jax.named_scope("mtp"):
+            return self._norm(p["final_ln"], x), stats
+
+    def logits_local(self, params, h):
+        """The untied head over the held rows: (B, S, V/tp)."""
+        with jax.named_scope("head"):
+            return self._head(params, h)
+
+    def _head(self, params, h):
+        out_dtype = self.c.logits_dtype or jnp.float32
+        return jnp.einsum("bsh,vh->bsv", h, params["head"]["weight"],
+                          preferred_element_type=jnp.float32
+                          ).astype(out_dtype)
+
+    def _xent(self, logits, labels):
+        return vocab_parallel_cross_entropy(
+            logits, labels, axis_name=self.c.axis_name,
+            fused=self.c.fused_xent)
+
+    def token_losses(self, params, tokens, labels):
+        """(main, mtp, stats): per-token cross entropies (B, S) fp32 of
+        the main head against `labels` and of the MTP head against the
+        labels one further on (`labels` rolled by one: a sequence's
+        last position is given its first label, as the benchmark's
+        seeded batches give the main head), or None without the
+        module; and every expert layer's HeldExpertsStats."""
+        h, stats = self.trunk(params, tokens)
+        logits = self.logits_local(params, self._final_ln(params, h))
+        with jax.named_scope("loss"):
+            main = self._xent(logits, labels)
+        if not self.c.mtp:
+            return main, None, stats
+        hm, st = self.mtp_hidden(params, h, labels)
+        with jax.named_scope("mtp"), jax.named_scope("head"):
+            mtp = self._xent(self._head(params, hm),
+                             jnp.roll(labels, -1, axis=1))
+        return main, mtp, stats + [st]
+
+    def loss(self, params, tokens, labels, key=None):
+        """`L_main + mtp_lambda * L_mtp`, each the mean over tokens.
+        tokens/labels: (B, S)."""
+        main, mtp, _ = self.token_losses(params, tokens, labels)
+        with jax.named_scope("loss"):
+            total = jnp.mean(main)
+            if mtp is not None:
+                total = total + self.c.mtp_lambda * jnp.mean(mtp)
+            return total
+
+    def routing_counts(self, params, tokens, labels):
+        """Forward only, without the heads: (counts (layers,
+        experts_count) int32, overflow (layers,) int32) of every expert
+        layer held, the MTP block's last: what a router-bias update
+        reads, and whether the grouped buffers' bound held."""
+        h, stats = self.trunk(params, tokens)
+        if self.c.mtp:
+            stats = stats + [self.mtp_hidden(params, h, labels)[1]]
+        return (jnp.stack([s.counts for s in stats]),
+                jnp.stack([s.overflow for s in stats]))

@@ -20,19 +20,29 @@ for anyway).
 
 from __future__ import annotations
 
-from apex_tpu.moe.layer import MoEAux, MoEMLP, mean_aux  # noqa: F401
+from apex_tpu.moe.layer import (  # noqa: F401
+    HeldExpertsMLP,
+    HeldExpertsStats,
+    MoEAux,
+    MoEMLP,
+    mean_aux,
+)
 from apex_tpu.moe.router import (  # noqa: F401
     RouterOutput,
+    SigmoidRouterOutput,
     capacity_destinations,
     expert_capacity,
+    sigmoid_topk_gates,
     topk_gates,
     topk_gates_blocked,
     topk_gates_dense,
 )
 
 __all__ = [
+    "HeldExpertsMLP", "HeldExpertsStats",
     "MoEAux", "MoEMLP", "mean_aux", "MoERecorder",
-    "RouterOutput", "capacity_destinations", "expert_capacity",
+    "RouterOutput", "SigmoidRouterOutput", "capacity_destinations",
+    "expert_capacity", "sigmoid_topk_gates",
     "topk_gates", "topk_gates_blocked", "topk_gates_dense",
 ]
 

@@ -28,8 +28,72 @@ BITWISE, not just close.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
+import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+class ExpertGroups(NamedTuple):
+    """The assignments to a run of held experts, sorted by expert.
+
+    token, slot: (rows,) int32, the token and the top-k slot of each
+    grouped row; sizes: (count,) int32 rows of each held expert, in
+    expert order (what a grouped GEMM takes); valid: (rows,) bool, the
+    rows that hold an assignment (the first `sum(sizes)`); counts:
+    (count,) int32 assignments each held expert was sent, bound or no
+    bound; overflow: int32 assignments to held experts that found no
+    row."""
+
+    token: jnp.ndarray
+    slot: jnp.ndarray
+    sizes: jnp.ndarray
+    valid: jnp.ndarray
+    counts: jnp.ndarray
+    overflow: jnp.ndarray
+
+
+def group_by_expert(idx, first: int, count: int, rows: int) -> ExpertGroups:
+    """Sort the (token, slot) assignments `idx` (T, k) to experts
+    `[first, first + count)` by expert, into at most `rows` rows.
+
+    One stable sort of the T*k assignments: those to held experts come
+    first, by expert and within an expert by token; those to experts
+    held elsewhere sort behind them and are left out.  There is no
+    per-expert capacity: `rows` bounds the total, and an expert may
+    take any share of it.  Assignments beyond the bound fall off the
+    end of the sorted order (the last experts' last tokens) and are
+    counted in `overflow`."""
+    t, k = idx.shape
+    local = idx.reshape(-1).astype(jnp.int32) - first
+    # the sort key: the held experts 0..count-1, everything else `count`
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)[:rows]
+    counts = jnp.zeros((count,), jnp.int32).at[key].add(1, mode="drop")
+    ends = jnp.minimum(jnp.cumsum(counts), rows)
+    sizes = jnp.diff(ends, prepend=0)
+    valid = jnp.arange(order.shape[0]) < ends[-1]
+    return ExpertGroups(token=order // k, slot=order % k, sizes=sizes,
+                        valid=valid, counts=counts,
+                        overflow=jnp.sum(counts) - ends[-1])
+
+
+def gather_groups(x, groups: ExpertGroups) -> jnp.ndarray:
+    """x (T, H) -> (rows, H): every grouped row's token.  Rows that
+    hold no assignment repeat some token; nothing reads them."""
+    return jnp.take(x, groups.token, axis=0)
+
+
+def scatter_groups(y, weight, groups: ExpertGroups, tokens: int):
+    """The inverse of `gather_groups`, weighted: (rows, H) expert
+    outputs -> (T, H), each row times its assignment's `weight` (T, k)
+    added to its token, in fp32.  Rows that hold no assignment add
+    nothing."""
+    w = jnp.where(groups.valid, weight[groups.token, groups.slot], 0.0)
+    y = jnp.where(groups.valid[:, None], y.astype(jnp.float32), 0.0)
+    return jnp.zeros((tokens, y.shape[1]), jnp.float32).at[
+        groups.token].add(y * w[:, None])
 
 
 def dispatch(x, dest, n_experts: int, capacity: int) -> jnp.ndarray:

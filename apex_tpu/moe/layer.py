@@ -294,6 +294,150 @@ class MoEMLP:
         return y.reshape(*lead_shape, self.hidden), aux
 
 
+class HeldExpertsStats(NamedTuple):
+    """What one call of `HeldExpertsMLP.apply` counted.  counts:
+    (count,) int32 assignments each held expert was sent (what a
+    router-bias update reads); overflow: int32 assignments to held
+    experts beyond the grouped buffer's row bound, which were left
+    out (0 in a sound run)."""
+
+    counts: jnp.ndarray
+    overflow: jnp.ndarray
+
+
+def swiglu(x, w_gate_up, w_down):
+    """(silu(x @ W_g) * (x @ W_u)) @ W_d with `w_gate_up` = [W_g | W_u]
+    side by side, (H, 2F): bf16-friendly operands, fp32 accumulation,
+    no biases."""
+    gu = jnp.dot(x, w_gate_up,
+                 preferred_element_type=jnp.float32).astype(x.dtype)
+    gate, up = jnp.split(gu, 2, axis=-1)
+    return jnp.dot(jax.nn.silu(gate) * up, w_down,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+class HeldExpertsMLP:
+    """The share of a fine-grained MoE layer that one chip of an
+    expert-parallel group holds: experts `[first, first + count)` of
+    `n_experts`, and the shared expert every chip computes alike.
+
+    The router keeps its whole width: every token chooses `top_k` of
+    all `n_experts` by the bias-corrected sigmoid gate
+    (`router.sigmoid_topk_gates`).  The layer has parameters for its
+    own experts only and computes their part of the result; what the
+    experts held elsewhere would add is left out (under expert
+    parallelism their chips add it, through an exchange this layer
+    does not have).  The assignments to held experts are sorted by
+    expert (`dispatch.group_by_expert`), their tokens gathered into
+    one (rows, H) buffer, and every expert's SwiGLU runs over its own
+    run of rows as a grouped GEMM (`jax.lax.ragged_dot`, which the TPU
+    compiler turns into a grouped-matmul kernel of its own).  There is
+    no per-expert capacity and no dropped token: `rows` is a static
+    bound on the total, twice what uniform routing sends
+    (`rows_bound`), and `HeldExpertsStats.overflow` counts what would
+    not fit.
+
+    Experts are SwiGLUs without biases, gate and up projection side by
+    side in one tensor: `experts_gate_up` (count, H, 2F),
+    `experts_down` (count, F, H); the shared expert's `shared_gate_up`
+    (H, 2F'), `shared_down` (F', H) with F' = n_shared * F; `router`
+    (H, n_experts) and `router_bias` (n_experts,), which steers the
+    choice only and gets no gradient."""
+
+    def __init__(self, hidden: int, ffn_hidden: int, n_experts: int, *,
+                 first: int, count: int, top_k: int, n_shared: int = 1,
+                 scale: float = 1.0, renormalize: bool = True,
+                 init_std: float = 0.02, bias_range: float = 0.0):
+        if not 0 <= first <= first + count <= n_experts:
+            raise ValueError(
+                f"held experts [{first}, {first + count}) are not "
+                f"within the {n_experts} routed")
+        if top_k > n_experts:
+            raise ValueError(f"top_k={top_k} > n_experts={n_experts}")
+        self.hidden, self.ffn_hidden = hidden, ffn_hidden
+        self.n_experts, self.first, self.count = n_experts, first, count
+        self.top_k, self.n_shared = top_k, n_shared
+        self.scale, self.renormalize = scale, renormalize
+        self.init_std, self.bias_range = init_std, bias_range
+
+    def rows_bound(self, tokens: int) -> int:
+        """Rows of the grouped buffer: twice the assignments uniform
+        routing sends to the held experts, rounded up to the sublane
+        tile, and never more than every assignment."""
+        expected = tokens * self.top_k * self.count / self.n_experts
+        return min(tokens * self.top_k, -(-int(2 * expected) // 8) * 8)
+
+    def init(self, key, dtype=jnp.float32) -> dict:
+        ks = jax.random.split(key, 6)
+        h, f, n = self.hidden, self.ffn_hidden, self.count
+
+        def normal(k, shape):
+            return jax.random.normal(k, shape, dtype) * self.init_std
+
+        params = {
+            "router": normal(ks[0], (h, self.n_experts)),
+            "router_bias": jax.random.uniform(
+                ks[1], (self.n_experts,), dtype, -1.0, 1.0)
+            * self.bias_range,
+            "experts_gate_up": normal(ks[2], (n, h, 2 * f)),
+            "experts_down": normal(ks[3], (n, f, h)),
+        }
+        if self.n_shared:
+            params["shared_gate_up"] = normal(
+                ks[4], (h, 2 * self.n_shared * f))
+            params["shared_down"] = normal(ks[5], (self.n_shared * f, h))
+        return params
+
+    def partition_specs(self) -> dict:
+        """Replicated over the mesh's axes: the layer's parameters are
+        already one chip's share, told by `first` and `count`."""
+        names = ["router", "router_bias", "experts_gate_up", "experts_down"]
+        if self.n_shared:
+            names += ["shared_gate_up", "shared_down"]
+        return {name: P() for name in names}
+
+    def apply(self, params, x):
+        """x: (..., H).  Returns (y, HeldExpertsStats), y in x's shape
+        and dtype: the held experts' weighted outputs plus the shared
+        expert's."""
+        lead = x.shape[:-1]
+        xt = x.reshape(-1, self.hidden)
+        t = xt.shape[0]
+        with jax.named_scope("router"):
+            gates = R.sigmoid_topk_gates(
+                xt, params["router"], params["router_bias"], self.top_k,
+                scale=self.scale, renormalize=self.renormalize)
+        with jax.named_scope("dispatch"):
+            groups = D.group_by_expert(gates.idx, self.first, self.count,
+                                       self.rows_bound(t))
+            xg = D.gather_groups(xt, groups)
+        # Rows past the last group belong to no expert.  A grouped GEMM
+        # leaves them as it finds them (on the chip: whatever the buffer
+        # held, forward and backward alike), so they are zeroed where
+        # they enter and where they leave each product: no such row's
+        # value or cotangent reaches a token or a weight.
+        rows = groups.valid[:, None]
+        with jax.named_scope("experts"):
+            gu = lax.ragged_dot(
+                jnp.where(rows, xg, 0), params["experts_gate_up"],
+                groups.sizes,
+                preferred_element_type=jnp.float32).astype(xt.dtype)
+            gate, up = jnp.split(gu, 2, axis=-1)
+            yg = lax.ragged_dot(
+                jnp.where(rows, jax.nn.silu(gate) * up, 0),
+                params["experts_down"], groups.sizes,
+                preferred_element_type=jnp.float32)
+        with jax.named_scope("combine"):
+            y = D.scatter_groups(yg, gates.weight, groups, t)
+        if self.n_shared:
+            with jax.named_scope("shared"):
+                y = y + swiglu(xt, params["shared_gate_up"],
+                               params["shared_down"])
+        stats = HeldExpertsStats(counts=groups.counts,
+                                 overflow=groups.overflow)
+        return y.astype(x.dtype).reshape(*lead, self.hidden), stats
+
+
 def mean_aux(auxes) -> MoEAux:
     """Average a list of per-layer MoEAux into one (fp32 scalars)."""
     n = jnp.asarray(len(auxes), jnp.float32)

@@ -140,6 +140,38 @@ def topk_gates(x, wg, top_k: int,
     return topk_gates_blocked(x, wg, top_k, block_rows)
 
 
+class SigmoidRouterOutput(NamedTuple):
+    """scores: (T, E) fp32 sigmoid affinities of every expert; idx:
+    (T, k) int32 chosen experts, the highest biased score first;
+    weight: (T, k) fp32, what each chosen expert's output is scaled
+    by."""
+
+    scores: jnp.ndarray
+    idx: jnp.ndarray
+    weight: jnp.ndarray
+
+
+def sigmoid_topk_gates(x, wr, bias, top_k: int, *, scale: float = 1.0,
+                       renormalize: bool = True) -> SigmoidRouterOutput:
+    """The bias-corrected sigmoid gate (DeepSeek-V3's `noaux_tc` with
+    one group): scores `s = sigmoid(x @ wr)` in fp32; the `top_k`
+    experts of `s + bias` are chosen, and the bias has no further
+    part: the weights are the chosen experts' own scores, divided by
+    their sum (`renormalize`) and multiplied by `scale`.
+
+    `bias` (E,) steers load and is not trained by the loss: it is
+    taken under `stop_gradient`.  Ties go to the lower index, as in
+    `topk_gates`."""
+    scores = jax.nn.sigmoid(gate_logits(x, wr))               # (T, E) fp32
+    biased = scores + lax.stop_gradient(bias.astype(jnp.float32))
+    _, idx = lax.top_k(biased, top_k)
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    return SigmoidRouterOutput(scores=scores, idx=idx,
+                               weight=weight * scale)
+
+
 def capacity_destinations(idx, n_experts: int, capacity: int):
     """Flat destination rows for each (token, slot) assignment.
 

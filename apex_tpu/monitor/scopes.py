@@ -20,13 +20,22 @@ import re
 from apex_tpu.monitor.comms.hlo import parse_module
 
 _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
-              "ln2", "mlp", "mlp/fc1", "mlp/gelu", "mlp/fc2")
+              "ln2", "mlp", "mlp/fc1", "mlp/gelu", "mlp/fc2",
+              # the latent-attention block (models/mla_moe.py): its five
+              # projections and the rotary embedding ...
+              "attn/q_a", "attn/q_b", "attn/kv_a", "attn/kv_b", "attn/rope",
+              # ... its dense SwiGLU, and the held-experts layer
+              "mlp/gate_up", "mlp/down", "mlp/router", "mlp/dispatch",
+              "mlp/experts", "mlp/shared", "mlp/combine")
 # every scope path the program may open; `block{i}` is a layer by index
 # (`_tap` spells it the same way), `block` a layer of a scanned stack
 OWNERS = (
     "unflatten", "dp_reduce", "pp_sync",
     "optimizer", "optimizer/flatten_grads", "optimizer/adam",
     "embed", "final_ln", "head", "loss",
+    # the multi-token-prediction module around its block (a `block{i}`
+    # of its own): input norms and projection, final norm, head and loss
+    "mtp", "mtp/proj", "mtp/head",
     *(f"block{{i}}/{s}" for s in _SUBLAYERS),
     *(f"block/{s}" for s in _SUBLAYERS))
 # the `name=` of every pl.pallas_call in apex_tpu/ops: an unnamed call

@@ -16,7 +16,9 @@ parallel extension rotates K/V blocks over ICI between the same
 per-block inner steps (SURVEY §2.4 CP note).
 
 Layout: (batch, heads, seq, head_dim); head_dim padded to the 128-lane
-tile inside the kernel when needed.  `flash_attention_qkv` is the same
+tile inside the kernel when needed.  v's head_dim may differ from q's
+and k's: the kernel bodies read every width off their blocks, so the
+same bodies serve both and each operand keeps its own width.  `flash_attention_qkv` is the same
 kernels over the packed QKV projection's own layout, (S, B, 3*heads*d)
 in and (S, B, heads*d) out: the BlockSpecs address 128-lane blocks of
 whole heads where they lie, so a model makes no (B, heads, S, d) copy
@@ -266,6 +268,7 @@ def attention_reference(q, k, v, *, causal=False, softmax_scale=None,
                         dropout_rate=0.0, dropout_key=None):
     """Plain softmax attention, fp32 accumulation (the parity oracle,
     ≡ the python fallback paths in apex/contrib/multihead_attn).
+    v's width may differ from q's and k's; the output has v's.
     Dropout masks the post-softmax attention weights (bernoulli stream —
     a different stream than the kernel's philox, same distribution)."""
     d = q.shape[-1]
@@ -938,7 +941,7 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
               block_q=None, block_k=None, bias=None, q_seg=None,
               kv_seg=None, q_off=0, k_off=0, heads_per_step=1):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]   # dv: v's and the output's width
     bias_kind = _bias_kind(bias, sk)
     bq, bk = _resolve_blocks(sq, sk, block_q, block_k,
                               full_bias=bias_kind == "full")
@@ -962,7 +965,7 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
             has_seg=has_seg)
         scratch = [pltpu.VMEM((1, bq), jnp.float32),
                    pltpu.VMEM((1, bq), jnp.float32),
-                   pltpu.VMEM((d, bq), jnp.float32)]
+                   pltpu.VMEM((dv, bq), jnp.float32)]
     else:
         kernel = functools.partial(
             _fwd_kernel_packed, scale=scale, causal=causal, bq=bq,
@@ -970,26 +973,26 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
             bias_kind=bias_kind, bias_per_head=nh > 1, has_seg=has_seg)
         scratch = [pltpu.VMEM((hp, bq), jnp.float32),
                    pltpu.VMEM((hp, bq), jnp.float32),
-                   pltpu.VMEM((hp, d, bq), jnp.float32)]
+                   pltpu.VMEM((hp, dv, bq), jnp.float32)]
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh // hp, nq, nk),
         in_specs=[
             pl.BlockSpec((hp, bq, d), lambda i, j, t: (i, j, 0)),
             pl.BlockSpec((hp, bk, d), lambda i, j, t: (i, t, 0)),
-            pl.BlockSpec((hp, bk, d), lambda i, j, t: (i, t, 0)),
+            pl.BlockSpec((hp, bk, dv), lambda i, j, t: (i, t, 0)),
             bspec, qsspec, ksspec,
             pl.BlockSpec((3, 1), lambda i, j, t: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((hp, bq, d), lambda i, j, t: (i, j, 0)),
+            pl.BlockSpec((hp, bq, dv), lambda i, j, t: (i, j, 0)),
             # lse as (bh, nq, bq): one whole-head(-group) block resident
             # per i (a (bh, sq, 1) fp32 array would tile-pad to 128x its
             # size; 2-D (1, bq) blocks violate the (8, 128) tile rule)
             pl.BlockSpec((hp, nq, bq), lambda i, j, t: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, nq, bq), jnp.float32),
         ],
         scratch_shapes=scratch,
@@ -1002,7 +1005,7 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
         interpret=pallas_interpret(),
         name="flash_fwd",
     )(qf, kf, vf, bias_t, qs, ks, seed)
-    return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
+    return o.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
 def _head_row_spec(nq, bq):
@@ -1015,8 +1018,13 @@ def _head_row_spec(nq, bq):
 def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
               seed=None, block_q=None, block_k=None, bias=None,
               q_seg=None, kv_seg=None, want_dbias=False,
-              grad_dtype=None, q_off=0, k_off=0, heads_per_step=1):
+              grad_dtype=None, q_off=0, k_off=0, heads_per_step=1,
+              fused=None):
     """Returns (dq, dk, dv, dbias) — dbias is None unless want_dbias.
+
+    fused: None chooses the single-pass backward by the VMEM cap below;
+    True or False is a tuned config's (or a caller's) own choice, for a
+    shape whose whole-head dk and dv sums were measured to fit.
 
     grad_dtype overrides the dq/dk/dv output dtype (default: the input
     dtypes).  The ring-attention backward passes fp32 so per-ring-step
@@ -1024,12 +1032,16 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
     bf16 once per ring hop (the kernels accumulate in fp32 scratch
     either way; this only moves the final rounding)."""
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, dv = k.shape[2], v.shape[3]   # dv: the width of v, o, do and dv
     bias_kind = _bias_kind(bias, sk)
     bq, bk = _resolve_blocks(sq, sk, block_q, block_k,
                               full_bias=bias_kind == "full")
     hp = _resolve_heads_per_step(heads_per_step, h,
                                  want_dbias=want_dbias)
+    # the fused kernels keep whole-(sk, .) fp32 sums of dk and dv in
+    # VMEM: the cap is on their mean width, which is d where both agree
+    fused_fits = (sk * (d + dv) <= 2 * _FUSED_BWD_CAP if fused is None
+                  else bool(fused))
     nq, nk = sq // bq, sk // bk
     bh = b * h
     seed = _seed3(seed, q_off, k_off)
@@ -1054,6 +1066,8 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
             delta.reshape(bh, nq, bq), bias_t, qsegs, ksegs, seed]
     qspec = pl.BlockSpec((1, bq, d), lambda i, j, t: (i, j, 0))
     kspec = pl.BlockSpec((1, bk, d), lambda i, j, t: (i, t, 0))
+    dospec = pl.BlockSpec((1, bq, dv), lambda i, j, t: (i, j, 0))
+    vspec = pl.BlockSpec((1, bk, dv), lambda i, j, t: (i, t, 0))
     r1 = _head_row_spec(nq, bq)
     sspec1 = pl.BlockSpec((3, 1), lambda i, j, t: (0, 0))
 
@@ -1075,28 +1089,30 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
     # head-packed single-pass backward: only when the fused path is
     # live anyway, no bias gradient is wanted (dbias writes are
     # per-head), and the (hp, sk, d) dk/dv scratch pair fits VMEM
-    if (hp > 1 and sk * d <= _FUSED_BWD_CAP and not want_dbias
-            and hp * sk * d <= _FUSED_BWD_CAP_PACKED):
+    if (hp > 1 and fused_fits and not want_dbias
+            and hp * sk * (d + dv) <= 2 * _FUSED_BWD_CAP_PACKED):
         bspec_p, qsspec_p, ksspec_p = _extras_specs(
             h, nq, bq, nk, bk, bias_kind, nb, nh, has_seg,
             jt_from_args=lambda j, t: (j, t), hp=hp)
         qspec_p = pl.BlockSpec((hp, bq, d), lambda i, j, t: (i, j, 0))
         kspec_p = pl.BlockSpec((hp, bk, d), lambda i, j, t: (i, t, 0))
+        dospec_p = pl.BlockSpec((hp, bq, dv), lambda i, j, t: (i, j, 0))
+        vspec_p = pl.BlockSpec((hp, bk, dv), lambda i, j, t: (i, t, 0))
         rp = pl.BlockSpec((hp, nq, bq), lambda i, j, t: (i, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel_packed, nq=nq, nk=nk,
                               hp=hp, bias_per_head=nh > 1, **static),
             grid=(bh // hp, nq, nk),
-            in_specs=[qspec_p, kspec_p, kspec_p, qspec_p, rp, rp,
+            in_specs=[qspec_p, kspec_p, vspec_p, dospec_p, rp, rp,
                       bspec_p, qsspec_p, ksspec_p,
                       pl.BlockSpec((3, 1), lambda i, j, t: (0, 0))],
-            out_specs=[qspec_p, kspec_p, kspec_p],
+            out_specs=[qspec_p, kspec_p, vspec_p],
             out_shape=[jax.ShapeDtypeStruct((bh, sq, d), dq_dt),
                        jax.ShapeDtypeStruct((bh, sk, d), dk_dt),
-                       jax.ShapeDtypeStruct((bh, sk, d), dv_dt)],
+                       jax.ShapeDtypeStruct((bh, sk, dv), dv_dt)],
             scratch_shapes=[pltpu.VMEM((hp, bq, d), jnp.float32),
                             pltpu.VMEM((hp, sk, d), jnp.float32),
-                            pltpu.VMEM((hp, sk, d), jnp.float32)],
+                            pltpu.VMEM((hp, sk, dv), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary",
                                      "arbitrary")),
@@ -1108,11 +1124,11 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
 
     # single-pass fused backward while the full-(sk, d) dk/dv scratch
     # fits VMEM comfortably; two-kernel fallback for long context
-    if sk * d <= _FUSED_BWD_CAP and not dbias_sk:
-        out_specs = [qspec, kspec, kspec]
+    if fused_fits and not dbias_sk:
+        out_specs = [qspec, kspec, vspec]
         out_shape = [jax.ShapeDtypeStruct((bh, sq, d), dq_dt),
                      jax.ShapeDtypeStruct((bh, sk, d), dk_dt),
-                     jax.ShapeDtypeStruct((bh, sk, d), dv_dt)]
+                     jax.ShapeDtypeStruct((bh, sk, dv), dv_dt)]
         if dbias_full:
             out_specs.append(pl.BlockSpec((1, bk, bq),
                                           lambda i, j, t: (i, t, j)))
@@ -1122,13 +1138,13 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
             functools.partial(_bwd_fused_kernel, nq=nq, nk=nk,
                               want_dbias=dbias_full, **static),
             grid=(bh, nq, nk),
-            in_specs=[qspec, kspec, kspec, qspec, r1, r1,
+            in_specs=[qspec, kspec, vspec, dospec, r1, r1,
                       bspec, qsspec, ksspec, sspec1],
             out_specs=out_specs,
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
                             pltpu.VMEM((sk, d), jnp.float32),
-                            pltpu.VMEM((sk, d), jnp.float32)],
+                            pltpu.VMEM((sk, dv), jnp.float32)],
             # dk/dv accumulate across the q-block axis too, so only the
             # leading batch*head axis is order-independent here
             compiler_params=pltpu.CompilerParams(
@@ -1154,7 +1170,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
         functools.partial(_bwd_dq_kernel, nk=nk, want_dbias=dbias_full,
                           **static),
         grid=(bh, nq, nk),
-        in_specs=[qspec, kspec, kspec, qspec, r1, r1,
+        in_specs=[qspec, kspec, vspec, dospec, r1, r1,
                   bspec, qsspec, ksspec, sspec1],
         out_specs=dq_specs if dbias_full else dq_specs[0],
         out_shape=dq_shape if dbias_full else dq_shape[0],
@@ -1172,16 +1188,18 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
     # dkv grid: k blocks outer, q blocks inner-sequential
     qspec2 = pl.BlockSpec((1, bq, d), lambda i, t, j: (i, j, 0))
     kspec2 = pl.BlockSpec((1, bk, d), lambda i, t, j: (i, t, 0))
+    dospec2 = pl.BlockSpec((1, bq, dv), lambda i, t, j: (i, j, 0))
+    vspec2 = pl.BlockSpec((1, bk, dv), lambda i, t, j: (i, t, 0))
     r2 = _head_row_spec(nq, bq)
     sspec2 = pl.BlockSpec((3, 1), lambda i, t, j: (0, 0))
     bspec2, qsspec2, ksspec2 = _extras_specs(
         h, nq, bq, nk, bk, bias_kind, nb, nh, has_seg,
         jt_from_args=lambda t, j: (j, t))
-    dkv_specs = [kspec2, kspec2]
+    dkv_specs = [kspec2, vspec2]
     dkv_shape = [jax.ShapeDtypeStruct((bh, sk, d), dk_dt),
-                 jax.ShapeDtypeStruct((bh, sk, d), dv_dt)]
+                 jax.ShapeDtypeStruct((bh, sk, dv), dv_dt)]
     dkv_scratch = [pltpu.VMEM((bk, d), jnp.float32),
-                   pltpu.VMEM((bk, d), jnp.float32)]
+                   pltpu.VMEM((bk, dv), jnp.float32)]
     if dbias_sk:
         # db rides as (bh, nk, bk) whole-head rows (the lse layout);
         # shared across both block axes → t must not Megacore-split
@@ -1197,7 +1215,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
         functools.partial(_bwd_dkv_kernel, nq=nq, want_dbias=dbias_sk,
                           **static),
         grid=(bh, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, r2, r2,
+        in_specs=[qspec2, kspec2, vspec2, dospec2, r2, r2,
                   bspec2, qsspec2, ksspec2, sspec2],
         out_specs=dkv_specs,
         out_shape=dkv_shape,
@@ -1329,9 +1347,10 @@ _flash_qkv.defvjp(_flash_qkv_fwd, _flash_qkv_bwd)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 14))
 def _flash(q, k, v, bias, q_seg, kv_seg, scale, causal, dropout_rate,
-           block_q, block_k, heads_per_step, bias_grad, seed):
+           block_q, block_k, heads_per_step, bias_grad, seed,
+           fused_bwd=None):
     o, _ = _fwd_impl(q, k, v, scale, causal, dropout_rate, seed,
                      block_q, block_k, bias, q_seg, kv_seg,
                      heads_per_step=heads_per_step)
@@ -1339,7 +1358,7 @@ def _flash(q, k, v, bias, q_seg, kv_seg, scale, causal, dropout_rate,
 
 
 def _flash_fwd(q, k, v, bias, q_seg, kv_seg, scale, causal, dropout_rate,
-               block_q, block_k, heads_per_step, bias_grad, seed):
+               block_q, block_k, heads_per_step, bias_grad, seed, fused_bwd):
     o, lse = _fwd_impl(q, k, v, scale, causal, dropout_rate, seed,
                        block_q, block_k, bias, q_seg, kv_seg,
                        heads_per_step=heads_per_step)
@@ -1347,7 +1366,7 @@ def _flash_fwd(q, k, v, bias, q_seg, kv_seg, scale, causal, dropout_rate,
 
 
 def _flash_bwd(scale, causal, dropout_rate, block_q, block_k,
-               heads_per_step, bias_grad, res, do):
+               heads_per_step, bias_grad, fused_bwd, res, do):
     q, k, v, bias, q_seg, kv_seg, o, lse, seed = res
     # a key-broadcast (.., *, 1) bias adds a per-query constant to the
     # scores — softmax cancels it, so its gradient is EXACTLY zero (no
@@ -1358,7 +1377,8 @@ def _flash_bwd(scale, causal, dropout_rate, block_q, block_k,
                                   dropout_rate, seed, block_q, block_k,
                                   bias, q_seg, kv_seg,
                                   want_dbias=want_dbias,
-                                  heads_per_step=heads_per_step)
+                                  heads_per_step=heads_per_step,
+                                  fused=fused_bwd)
     import numpy as _np
 
     def _int_zero(x):
@@ -1407,7 +1427,7 @@ _TUNED_SCORE_ELEMS_CAP = 1024 * 1024
 
 
 def _tuned_flash_config(b, h, sq, sk, d, dtype, causal, bias_kind,
-                        has_seg):
+                        has_seg, dv=None):
     """Trace-time autotuner lookup (apex_tpu.tune): a pure host-side
     dict access — zero collectives, no host syncs.  None on a miss, so
     an empty cache leaves every call on today's heuristics.
@@ -1424,7 +1444,7 @@ def _tuned_flash_config(b, h, sq, sk, d, dtype, causal, bias_kind,
         return None   # tuned entries are swept at self-attention shapes
     cfg = tune.tuned("flash_sdpa",
                      tune.flash_attrs(b, h, sq, sk, d, dtype, causal,
-                                      bias=bias_kind, seg=has_seg))
+                                      bias=bias_kind, seg=has_seg, dv=dv))
     if not cfg:
         return None
     bq = cfg.get("block_q")
@@ -1432,6 +1452,7 @@ def _tuned_flash_config(b, h, sq, sk, d, dtype, causal, bias_kind,
     hp = cfg.get("heads_per_step", 1)
     ok = (all(v is None or (isinstance(v, int) and 8 <= v <= 4096)
               for v in (bq, bk))
+          and cfg.get("fused_bwd") in (None, True, False)
           and isinstance(hp, int) and 1 <= hp <= 16
           and hp * (bq or 1024) * (bk or 1024) <= _TUNED_SCORE_ELEMS_CAP)
     if not ok:
@@ -1457,6 +1478,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     heads_per_step: Optional[int] = None,
+                    fused_backward: Optional[bool] = None,
                     # True by default DELIBERATELY: a trainable bias
                     # silently freezing (the round-3 contract) is wrong
                     # training with no error; the full-bias dbias
@@ -1465,6 +1487,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     bias_grad: bool = True,
                     use_pallas_override: Optional[bool] = None):
     """Flash attention over (batch, heads, seq, head_dim).
+
+    q and k share one width and v may have another (latent attention:
+    keys of 192, values of 128): the output and every gradient have the
+    width of what they belong to, QK^T and dQ, dK contract over q's
+    width and P.V, dV, dP over v's, and no operand is padded to
+    another's.  The default `softmax_scale` is 1/sqrt(q's width).
 
     ≡ apex.contrib.fmha.FMHAFun (apex/contrib/fmha/fmha.py:33-72) with
     the seq≤512/head-64 restriction removed, and the core of the
@@ -1503,6 +1531,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     an empty cache is byte-identical to explicit None everywhere.
     Explicit blocks that do not divide the sequence fall back to the
     largest dividing block (warn once) instead of failing.
+    fused_backward: None leaves the choice between the single-pass
+    backward (one recompute of the scores, whole-head dk and dv sums in
+    VMEM) and the two-kernel one to the built-in VMEM cap; True or
+    False decides it, as a tuned config's `fused_bwd` does for a shape
+    measured on the chip (4096 x 192/128 fits, and the cap, swept at
+    one width, says it does not).
 
     segment_ids: (b, s) int — tokens attend only where ids are equal;
     this is the TPU-native form of the reference fmha's cu_seqlens
@@ -1547,22 +1581,25 @@ def flash_attention(q, k, v, *, causal: bool = False,
     kernel_ok = (use_pallas(use_pallas_override)
                  and _pick_block(q.shape[2]) and _pick_block(k.shape[2]))
     if kernel_ok:
-        if block_q is None and block_k is None and heads_per_step is None:
+        if (block_q is None and block_k is None and heads_per_step is None
+                and fused_backward is None):
             # fully-unspecified config → consult the autotuner cache
             # (explicit knobs always win; a miss keeps the heuristics)
             cfg = _tuned_flash_config(
                 b, h, sq, sk, q.shape[3], q.dtype, causal,
-                _bias_kind(bias, sk), q_segment_ids is not None)
+                _bias_kind(bias, sk), q_segment_ids is not None,
+                dv=v.shape[3])
             if cfg:
                 block_q = cfg.get("block_q")
                 block_k = cfg.get("block_k")
                 heads_per_step = cfg.get("heads_per_step")
+                fused_backward = cfg.get("fused_bwd")
         seed = _dropout_seed(dropout_rate, dropout_key)
         _calls["head_major"] += 1
         return _flash(q, k, v, bias, q_segment_ids, kv_segment_ids,
                       scale, causal, float(dropout_rate),
                       block_q, block_k, int(heads_per_step or 1),
-                      bool(bias_grad), seed)
+                      bool(bias_grad), seed, fused_backward)
     # fallback keeps the same dbias semantics: AD through the dense
     # path yields the (broadcast-reduced) dbias when bias_grad, and a
     # stop_gradient reproduces the constant-bias contract otherwise

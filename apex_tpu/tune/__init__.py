@@ -61,18 +61,24 @@ def pow2_bucket(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def flash_attrs(b, h, sq, sk, d, dtype, causal, bias="none", seg=False):
+def flash_attrs(b, h, sq, sk, d, dtype, causal, bias="none", seg=False,
+                dv=None):
     """The ONE definition of the flash_sdpa lookup-key attrs — shared
     by the runtime lookup (ops/flash_attention.py), the sweep driver
     (tune/search.py), and the committed defaults (tune/defaults.py).
     A key-schema change here reaches all three or none.  dtype None
-    means the bench dtype, bfloat16."""
+    means the bench dtype, bfloat16.  `dv`, v's width, is part of the
+    key only where it differs from `d`: every key made before the two
+    could differ reads as it did."""
     import jax.numpy as jnp
 
     dtype = jnp.bfloat16 if dtype is None else dtype
-    return dict(b=int(b), h=int(h), sq=int(sq), sk=int(sk), d=int(d),
-                dtype=jnp.dtype(dtype).name, causal=bool(causal),
-                bias=bias, seg=bool(seg))
+    attrs = dict(b=int(b), h=int(h), sq=int(sq), sk=int(sk), d=int(d),
+                 dtype=jnp.dtype(dtype).name, causal=bool(causal),
+                 bias=bias, seg=bool(seg))
+    if dv is not None and int(dv) != int(d):
+        attrs["dv"] = int(dv)
+    return attrs
 
 
 def decode_attrs(n_slots, q_len, hq, hkv, d, page_size, dtype):

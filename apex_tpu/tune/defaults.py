@@ -25,12 +25,13 @@ while keeping the (hp·bk·bq) fp32 score tile at 2 MB of VMEM.
 from __future__ import annotations
 
 
-def _flash(b, h, sq, sk, d, dtype, causal, bias="none", seg=False):
+def _flash(b, h, sq, sk, d, dtype, causal, bias="none", seg=False,
+           dv=None):
     from apex_tpu import tune
     from apex_tpu.tune.cache import make_key
     return make_key("flash_sdpa",
                     tune.flash_attrs(b, h, sq, sk, d, dtype, causal,
-                                     bias=bias, seg=seg))
+                                     bias=bias, seg=seg, dv=dv))
 
 
 def _mk(config, note):
@@ -60,6 +61,14 @@ def _v5e_entries():
     # long-context 32k: b1 h8 s32768 d64 causal (bench.py); blocks stay
     # within the sweep's own hp*bq*bk <= 512k score-tile cap
     e[_flash(1, 8, 32768, 32768, 64, "bfloat16", True)] = _mk(pack2, note)
+    # latent attention, keys 192 and values 128, 2 x 32 heads x 4096
+    # (models/mla_moe.py at the benchmark's cell): the single-pass
+    # backward fits VMEM there though the one-width cap says no, and
+    # (1024, 512) blocks beat the heuristics' (512, 1024)
+    e[_flash(2, 32, 4096, 4096, 192, "bfloat16", True, dv=128)] = _mk(
+        {"block_q": 1024, "block_k": 512, "fused_bwd": True},
+        "v5e, PR 28 chip run, forward + backward a layer: 15.2 ms; "
+        "fused at the heuristics' blocks 16.7, two-kernel 19.0")
     # flat-optimizer block rows at the 1B Adam bench point: the swept
     # heuristic value, committed so the fingerprint records it
     from apex_tpu.tune.cache import make_key

@@ -101,11 +101,23 @@ def _smoke_case(name, device):
         assert config["heads_per_step"] > 1
         case = chip_smoke.flash_case(name, chip_smoke.PACKED_FLASH_SHAPE,
                                      config)
+    if name == "flash_mla_192_128":
+        # likewise: the single-pass backward at (1024, 512) blocks
+        from apex_tpu import tune
+        from apex_tpu.tune import defaults
+        key = tune.make_key("flash_sdpa", tune.flash_attrs(
+            2, 32, 4096, 4096, 192, "bfloat16", True, dv=128))
+        config = dict(defaults.DEFAULTS["v5e"][key]["config"])
+        config["fused_backward"] = config.pop("fused_bwd")
+        assert config["fused_backward"] is True
+        case = chip_smoke.flash_case(name, (2, 32, 4096, 192), config,
+                                     v_dim=128)
     return case
 
 
 @pytest.mark.parametrize("name,min_kernels", [
     ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_packed", 2),
+    ("flash_mla_192_128", 2), ("moe_held_experts", 6),
     ("adam_flat_fp32", 1),
     ("adam_flat_bf16", 1), ("xent_pallas", 2), ("xent_vocab_parallel", 0),
     ("flash_decode", 1)])
@@ -377,3 +389,97 @@ def test_flagship_flash_reads_the_projection_where_it_lies(flagship_compiled):
     sizes = dict(calls)
     assert all(sizes[k].count(context) == (3 if k.startswith("flash_bwd")
                                            else 1) for k in kernels)
+
+
+# ---------------- the latent-attention, sparse-expert step ----------------
+
+def mla_moe_step(devices):
+    """(step, args): the benchmark's `joyai-llm-flash` step (one dense
+    and four expert layers and the MTP module, 16 of 256 experts, 16,256
+    vocabulary rows, bf16 Adam state, 2 x 4096, donated state) over
+    described `devices`, every argument a shape."""
+    import json
+
+    from apex_tpu.models.mla_moe import MLAMoE
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.parallel import mesh as M
+    from apex_tpu.transformer.training import (
+        init_sharded_optimizer,
+        make_tp_dp_train_step,
+    )
+    from benchmarks.jobs.mla_moe_train import model_config
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(tensor_model_parallel_size=1,
+                                       devices=list(devices))
+    model = MLAMoE(model_config(config, dtype=jnp.bfloat16,
+                                logits_dtype=jnp.bfloat16))
+    opt = FusedAdam(lr=1e-5, master_dtype=jnp.bfloat16)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda p: init_sharded_optimizer(opt, model, p, mesh), params)
+
+    def placed(sds, spec):
+        return jax.ShapeDtypeStruct(sds.shape, sds.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    state = type(state)(placed(state[0], P()), *(
+        placed(buf, P(("pp", "tp"))) for buf in state[1:]))
+    tokens = placed(_sds((2, 4096), jnp.int32), P("dp"))
+    return make_tp_dp_train_step(model, opt, mesh, donate=True), (
+        state, tokens, tokens)
+
+
+def test_mla_moe_step_compiles_fits_and_is_named(topo, on_chip):
+    """The second model's step at the benchmark's sizes: 680.8M
+    parameters held, compiled for one v5e with its flash kernels (keys
+    192, values 128), the compiler's grouped-matmul kernels and the Adam
+    pass in it, inside the chip's memory, and all but a few shared index
+    fusions owned by a scope."""
+    import re
+
+    from apex_tpu.monitor import scopes
+    from apex_tpu.monitor.comms.hlo import parse_module
+
+    step, args = mla_moe_step(topo.devices[:1])
+    # the flat state: 680,834,304 parameters, each leaf tile-aligned
+    assert 680_834_304 <= args[0][1].shape[0] < 680_834_304 * 1.001
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+    by_name = {}
+    for name in kernels:
+        by_name[name.split(".")[0]] = by_name.get(name.split(".")[0], 0) + 1
+    # 6 blocks (5 layers and the MTP module's); off the chip the tuner
+    # has no v5e entry, so the backward is the two-kernel one
+    assert {k: v for k, v in by_name.items() if k.startswith(
+        ("flash", "adam"))} == {"flash_fwd": 6, "flash_bwd_dq": 6,
+                                "flash_bwd_dkv": 6, "adam_flat": 1}
+    # 5 expert layers x 2 grouped GEMMs x (forward, dgrad, wgrad)
+    assert by_name["ragged-dot-none"] == 30
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes - m.alias_size_in_bytes < 2 ** 20
+    assert _bytes(compiled) < HBM_BYTES
+    # no operand padded to another's width: nothing 256 wide a head
+    assert "bf16[64,4096,256]" not in text
+
+    found = scopes.owners(text)
+    (entry,) = [c for c in parse_module(text) if c.is_entry]
+    timed = ("fusion", "copy", "custom-call", "convolution", "dot", "sort",
+             "scatter", "gather")
+    unowned = [i.name for i in entry.instructions
+               if i.opcode in timed and found[i.name][0] == scopes.UNOWNED]
+    assert len(unowned) <= 8, unowned
+    # the compiler drops the grouped-matmul kernels' op_name, so each is
+    # owned through its users: the expert scope, but for the forward
+    # down-projection, whose one user is the weighted scatter-add
+    grouped = [found[n][0] for n in kernels
+               if n.startswith("ragged-dot-none")]
+    assert set(grouped) == {f"block{i}/mlp/{s}" for i in range(1, 6)
+                            for s in ("experts", "combine")}
+    assert sum(g.endswith("combine") for g in grouped) == 5

@@ -1,0 +1,197 @@
+"""Flash attention whose keys and values differ in width (latent
+attention: q and k 192 wide, v 128): forward and backward of every
+kernel path against `attention_reference` in interpret mode, and that
+with one width the kernels trace to the program they traced to before
+the second width could be told apart."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu import tune
+from apex_tpu.ops import flash_attention as FA
+
+D_QK, D_V = 192, 128
+
+
+def _qkvdo(b, h, s, dtype, d_qk=D_QK, d_v=D_V):
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    return (jax.random.normal(ks[0], (b, h, s, d_qk), dtype),
+            jax.random.normal(ks[1], (b, h, s, d_qk), dtype),
+            jax.random.normal(ks[2], (b, h, s, d_v), dtype),
+            jax.random.normal(ks[3], (b, h, s, d_v), dtype))
+
+
+def _fwd_bwd(attn, q, k, v, do):
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out,) + vjp(do)
+
+
+# the fused single-pass backward, the two-kernel backward (the path
+# 4096 x 192 takes: a whole head's dk and dv sums pass the fused
+# kernel's VMEM cap) and the head-packed kernels
+@pytest.mark.parametrize("path,cap,kw", [
+    ("fused", None, {}),
+    ("two_kernel", 1, {}),
+    ("packed", None, {"heads_per_step": 2}),
+])
+@pytest.mark.parametrize("causal", [True, False])
+def test_two_widths_match_the_reference(monkeypatch, path, cap, kw, causal):
+    if cap is not None:
+        monkeypatch.setattr(FA, "_FUSED_BWD_CAP", cap)
+    q, k, v, do = _qkvdo(1, 2, 256, jnp.float32)
+    got = _fwd_bwd(lambda q, k, v: FA.flash_attention(
+        q, k, v, causal=causal, use_pallas_override=True, block_q=128,
+        block_k=128, **kw), q, k, v, do)
+    want = _fwd_bwd(lambda q, k, v: FA.attention_reference(
+        q, k, v, causal=causal), q, k, v, do)
+    # o and dv have v's width, dq and dk the keys'
+    assert [a.shape[-1] for a in got] == [D_V, D_QK, D_QK, D_V]
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-5)
+
+
+def test_two_widths_in_bf16_and_the_default_scale():
+    """The default softmax scale is 1/sqrt(the keys' width)."""
+    q, k, v, do = _qkvdo(2, 2, 128, jnp.bfloat16)
+    got = _fwd_bwd(lambda q, k, v: FA.flash_attention(
+        q, k, v, causal=True, use_pallas_override=True), q, k, v, do)
+    want = _fwd_bwd(lambda q, k, v: FA.attention_reference(
+        q, k, v, causal=True, softmax_scale=D_QK ** -0.5), q, k, v, do)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   atol=5e-2, rtol=5e-2)
+
+
+def test_no_operand_is_padded_to_anothers_width():
+    """Every array of the traced program that has a head's width has
+    its own: at a sequence of 384 in blocks of 128 no dimension is 256
+    (the keys' width padded to whole lanes, or v's to the keys'), and
+    the kernels' operands and results are 192 and 128 wide."""
+    q, k, v, do = _qkvdo(1, 2, 384, jnp.bfloat16)
+    text = str(jax.make_jaxpr(lambda *a: _fwd_bwd(
+        lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, use_pallas_override=True), *a))(
+        q, k, v, do)).replace(" ", "")
+    assert "256]" not in text and "[256," not in text
+    # flattened to (b*h, s, d): q, k, dq, dk and v, o, do, dv
+    assert text.count("bf16[2,384,192]") >= 4
+    assert text.count("bf16[2,384,128]") >= 4
+
+
+# sha256 of str(jax.make_jaxpr(...)) at the parent of the PR that let v
+# have a width of its own (e1fe135), forward and backward in one jaxpr,
+# taken with this installation's JAX.  One width must trace to the same
+# text: the dense cells compile to the program they had.
+PARENT_JAXPR = {
+    "head_major_d64": "e596976624fe4190dd94e68a9b68981f03efd2a8a87538dd64608b"
+                      "c38ee8dc0e",
+    "head_major_d128": "96e1f1340e9b75f91f82b84ebcf133d88ef949847de044fcaa9851"
+                       "934b9f5abc",
+    "head_packed_d64": "0ef7505eb21ef7dd161ebf6d0d6cf7194b402e2ea2d00b06022c99"
+                       "302728a215",
+    "two_kernel_s8192": "802149358ac273491ad92e311e4b1721b54362a066d937136b76c"
+                        "0d7b347d32b",
+    "projection_layout": "fb5ef76b3a5ae158afc1239a09fc2967fcfcfbaa9857f52c43e5"
+                         "888d6f27f747",
+}
+
+
+def _head_major_text(shape, **kw):
+    q = jnp.zeros(shape, jnp.bfloat16)
+    return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, use_pallas_override=True, **kw),
+        q, k, v)[1](q))(q, q, q))
+
+
+def _projection_text():
+    x = jnp.zeros((256, 2, 3 * 4 * 64), jnp.bfloat16)
+    return str(jax.make_jaxpr(lambda x: jax.vjp(
+        lambda x: FA.flash_attention_qkv(
+            x, 4, causal=True, use_pallas_override=True),
+        x)[1](jnp.zeros((256, 2, 256), jnp.bfloat16)))(x))
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the recorded texts are JAX 0.9.0's")
+@pytest.mark.parametrize("case,trace", [
+    ("head_major_d64", lambda: _head_major_text((2, 4, 256, 64))),
+    ("head_major_d128", lambda: _head_major_text((1, 2, 512, 128))),
+    ("head_packed_d64", lambda: _head_major_text((2, 4, 256, 64),
+                                                 heads_per_step=2)),
+    ("two_kernel_s8192", lambda: _head_major_text((1, 2, 8192, 64))),
+    ("projection_layout", _projection_text),
+])
+def test_one_width_traces_to_the_parents_jaxpr(case, trace):
+    assert hashlib.sha256(trace().encode()).hexdigest() == PARENT_JAXPR[case]
+
+
+def test_the_tuner_key_has_the_second_width_only_where_it_differs():
+    one = tune.flash_attrs(2, 32, 4096, 4096, 128, jnp.bfloat16, True)
+    same = tune.flash_attrs(2, 32, 4096, 4096, 128, jnp.bfloat16, True,
+                            dv=128)
+    two = tune.flash_attrs(2, 32, 4096, 4096, 192, jnp.bfloat16, True, dv=128)
+    assert one == same and "dv" not in one
+    assert two["d"] == 192 and two["dv"] == 128
+    assert tune.make_key("flash_sdpa", two) != tune.make_key(
+        "flash_sdpa", dict(two, dv=192))
+
+
+def test_a_tuned_config_is_looked_up_under_both_widths(monkeypatch):
+    seen = []
+    monkeypatch.setattr(tune, "tuned",
+                        lambda op, attrs: seen.append((op, attrs)))
+    q, k, v, _ = _qkvdo(1, 2, 128, jnp.bfloat16)
+    FA.flash_attention(q, k, v, causal=True, use_pallas_override=True)
+    assert seen == [("flash_sdpa", tune.flash_attrs(
+        1, 2, 128, 128, D_QK, jnp.bfloat16, True, dv=D_V))]
+
+
+@pytest.mark.parametrize("fused", [True, False, None])
+def test_a_tuned_config_decides_the_backward(monkeypatch, fused):
+    """`fused_bwd` of a tuned config (or `fused_backward=`) overrules
+    the VMEM cap's choice between the single-pass backward and the two
+    kernels, either way, and the gradients are the same."""
+    # a cap under which the heuristic takes the two kernels
+    monkeypatch.setattr(FA, "_FUSED_BWD_CAP", 1)
+    config = {"block_q": 128, "block_k": 128}
+    if fused is not None:
+        config["fused_bwd"] = fused
+    monkeypatch.setattr(tune, "tuned", lambda op, attrs: config)
+    q, k, v, do = _qkvdo(1, 2, 256, jnp.float32)
+
+    def run(q, k, v, do):
+        return _fwd_bwd(lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, use_pallas_override=True), q, k, v, do)
+
+    text = str(jax.make_jaxpr(run)(q, k, v, do))
+    assert ("name=flash_bwd\n" in text) == bool(fused)
+    assert ("name=flash_bwd_dkv" in text) == (not fused)
+    want = _fwd_bwd(lambda q, k, v: FA.attention_reference(
+        q, k, v, causal=True), q, k, v, do)
+    for g, w in zip(run(q, k, v, do), want):
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-5)
+
+
+def test_the_committed_v5e_config_for_latent_attention(monkeypatch):
+    from apex_tpu.tune import defaults
+
+    key = tune.make_key("flash_sdpa", tune.flash_attrs(
+        2, 32, 4096, 4096, D_QK, "bfloat16", True, dv=D_V))
+    config = defaults.DEFAULTS["v5e"][key]["config"]
+    assert config == {"block_q": 1024, "block_k": 512, "fused_bwd": True}
+    # FA's validation of a cache hit lets it through, and not a
+    # `fused_bwd` that is no bool
+    monkeypatch.setattr(tune, "tuned", lambda op, attrs: config)
+    args = (2, 32, 4096, 4096, D_QK, jnp.bfloat16, True, "none", False)
+    assert FA._tuned_flash_config(*args, dv=D_V) == config
+    monkeypatch.setattr(tune, "tuned",
+                        lambda op, attrs: dict(config, fused_bwd="yes"))
+    with pytest.warns(UserWarning, match="out-of-range tuned config"):
+        assert FA._tuned_flash_config(*args, dv=D_V) is None

@@ -251,6 +251,63 @@ def test_step_owners_names_every_gemm_of_the_step_that_ran(
                    for owner, _, opcode in found.values())
 
 
+def test_step_owners_names_the_latent_attention_block():
+    """The vocabulary over the second model: a step of `MLAMoE` (one
+    dense layer, one expert layer, the MTP module) through the same
+    builder, every GEMM and every grouped GEMM owned by the scope that
+    asked for it, and every new path of the vocabulary opened."""
+    from apex_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
+
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(devices=jax.devices()[:1])
+    model = MLAMoE(MLAMoEConfig(
+        vocab_size=64, hidden=32, num_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, intermediate_size=48, moe_intermediate_size=8,
+        n_routed_experts=16, num_experts_per_tok=3, num_expert_layers=1,
+        experts_first=4, experts_count=8, rope_theta=1e4))
+    params = model.init(jax.random.PRNGKey(8))
+    opt = FusedAdam(lr=3e-3, use_pallas=False)
+    state = init_sharded_optimizer(opt, model, params, mesh)
+    step = make_tp_dp_train_step(model, opt, mesh, donate=False)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 16), 0, 64)
+    state, loss = step(state, tokens, jnp.roll(tokens, -1, axis=1))
+    assert np.isfinite(float(loss))
+
+    text = scopes.step_text()
+    found = scopes.owners(text)
+    gemms = [i for comp in parse_module(text) for i in comp.instructions
+             if i.op_name.endswith("dot_general")]
+    assert len(gemms) >= 3 * (3 * 5 + 2 + 4 + 2 + 1)
+    for i in gemms:
+        owner, direction, _ = found[i.name]
+        assert owner.startswith(("block0/", "block1/", "block2/", "head",
+                                 "mtp/")), (i.name, i.op_name, owner)
+        assert direction in ("fwd", "bwd")
+    owners = {owner for owner, _, _ in found.values()}
+    new = {f"block2/{s}" for s in (
+        "attn/q_a", "attn/q_b", "attn/kv_a", "attn/kv_b", "attn/rope",
+        "attn/flash", "attn/proj", "mlp/router", "mlp/dispatch",
+        "mlp/experts", "mlp/shared", "mlp/combine")}
+    assert new | {"block0/mlp/gate_up", "block0/mlp/down", "mtp/proj",
+                  "mtp/head", "embed", "final_ln", "head", "loss",
+                  "optimizer/adam", "unflatten"} <= owners
+    # the grouped GEMMs of both expert layers: gate-and-up and down,
+    # each forward, input gradient and weight gradient (off the chip a
+    # ragged_dot is so many masked dot_generals under the same name)
+    for block in ("block1", "block2"):
+        grouped = [found[i.name][1] for i in gemms
+                   if found[i.name][0] == f"{block}/mlp/experts"]
+        assert grouped.count("fwd") >= 2 and grouped.count("bwd") >= 4
+    # every path the vocabulary added is one this step opens
+    added = {p.replace("block{i}/", "") for p in scopes.OWNERS
+             if p.startswith("block{i}/")} - {
+        "ln1", "attn", "attn/qkv", "attn/flash", "attn/proj", "ln2", "mlp",
+        "mlp/fc1", "mlp/gelu", "mlp/fc2"}
+    assert added == {s.split("/", 1)[1] for s in new} - {
+        "attn/flash", "attn/proj"} | {"mlp/gate_up", "mlp/down"}
+
+
 def test_scopes_change_no_arithmetic(monkeypatch):
     """The same step traced with `jax.named_scope` a no-op gives the
     same loss and the same new state, bit for bit."""
