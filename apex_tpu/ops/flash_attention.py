@@ -8,8 +8,23 @@
 re-designed as ONE blockwise kernel with no sequence-length cap: online
 softmax (running max/denominator) tiles (bq × bk) score blocks through
 VMEM so the (sq × sk) score matrix never reaches HBM.  The backward
-recomputes scores blockwise (flash-attention-2 style: dq in one grid,
-dk/dv in another) from the saved logsumexp.
+recomputes scores blockwise from the saved logsumexp: in one pass
+(dq, dk and dv from one recompute) while a head's dk and dv sums fit
+VMEM, flash-attention-2 style beyond (dq in one grid, dk/dv in
+another).
+
+Two sizes, and they are not the same thing.  The BLOCK (bq, bk) is what
+a grid step is handed: one DMA'd window of q and of k, v.  It is large,
+one block a head up to 1024, because every grid step costs its
+pipeline overhead whether it computes or not.  The compute TILE is what
+the step body computes at a time under a causal mask: a static window
+of the block it already holds, a few hundred q rows wide (_pick_tile)
+and as many k rows deep as those queries see (_tile_cases).  The k rows
+above the diagonal are not in the program, a tile the diagonal crosses
+carries the mask, a tile below it runs without mask arithmetic
+(_tile_dispatch), so a causal call stops computing most of the half of
+the score square its mask throws away, with no grid step, branch or
+DMA a tile where the block's place on the diagonal is static.
 
 The blockwise structure is deliberately ring-friendly: a context-
 parallel extension rotates K/V blocks over ICI between the same
@@ -30,7 +45,7 @@ from __future__ import annotations
 import functools
 import math
 import warnings
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,10 +89,99 @@ def _causal_mask(st, j, t, bq, bk):
     return jnp.where(krow > qcol, _NEG_INF, st)
 
 
-def _mask_bias(st, j, t, bq, bk, causal_masked, bias_kind, bias_ref,
+class _Tile(NamedTuple):
+    """One compute tile of a block: `q` = (q0, cq) rows of the q block
+    (lanes of the transposed score tile), `k` = (k0, ck) rows of the k
+    block; None is the whole block, the one tile every block was.
+    `masked`: the diagonal crosses the tile, and then a score is above
+    it where its row less its column in the tile passes `shift` (the
+    block's place on the diagonal is static wherever tiles are cut, so
+    the mask is two iotas against a constant)."""
+    masked: bool
+    q: Optional[tuple] = None
+    k: Optional[tuple] = None
+    shift: int = 0
+
+
+def _rows(win):
+    return slice(win[0], win[0] + win[1])
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_cases(bq, bk, nq, nk, cq):
+    """The causal tilings of a (bq, bk) block cut into q windows of cq
+    rows, by the block's place on the diagonal: ({delta: tiles},
+    interior tiles or None) with delta = j*bq - t*bk, the q block's
+    first position less the k block's.  A block with delta >= bk - 1
+    lies wholly below the diagonal (every window runs on all of its k
+    rows, unmasked), one with delta <= -bq wholly above (never run);
+    between them the diagonal crosses it, and delta is one of the few
+    multiples of gcd(bq, bk) in that range.  In such a block a q window
+    is ONE tile: the k rows from the block's first to the last its last
+    query sees, masked where the diagonal crosses them; the rows beyond
+    are not computed, and a window that sees none is left out.  One
+    tile a window, so a window's softmax is not rescaled inside a
+    block, and its first row is the block's first: a head's row never
+    starts on a tile of nothing.  None where the block is one window,
+    or sits on the diagonal in more ways than are worth a branch each."""
+    if cq == bq:
+        return None
+    deltas = {j * bq - t * bk for j in range(nq) for t in range(nk)}
+    crossing = sorted(d for d in deltas if -bq < d < bk - 1)
+    if len(crossing) > 4:
+        return None
+
+    def tiles(delta):
+        out = []
+        for q0 in range(0, bq, cq):
+            # in-tile row r (a key) and column c (a query) stand at
+            # t*bk + r and j*bq + q0 + c: above the diagonal where
+            # r - c > shift, so the window's last query sees `shift + cq`
+            # rows of the block (whole lanes of them are computed)
+            shift = delta + q0
+            rows = min(bk, -(-(shift + cq) // _LANES) * _LANES)
+            if rows > 0:
+                out.append(_Tile(shift < rows - 1, (q0, cq), (0, rows),
+                                 shift))
+        return tuple(out)
+
+    interior = (tuple(_Tile(False, (q0, cq), (0, bk))
+                      for q0 in range(0, bq, cq))
+                if any(d >= bk - 1 for d in deltas) else None)
+    return {d: tiles(d) for d in crossing}, interior
+
+
+def _tile_dispatch(steps_fn, j, t, bq, bk, nq, nk, causal, tile):
+    """Run steps_fn(tiles) on the tiles of block (j, t) that a causal
+    mask keeps, `tile` q rows wide.  The grid, the DMAs and the block
+    stay what they were: the tiles are static windows of the block the
+    step was handed, so k rows above the diagonal are not in the
+    program at all.  With one block a head the whole choice is made
+    while tracing; with several, a block is interior, above the
+    diagonal, or crosses it at one of a few static offsets: one branch
+    a case.  A non-causal call and a block of one tile take
+    _causal_dispatch, as every call did."""
+    cases = _tile_cases(bq, bk, nq, nk, tile) if causal else None
+    if cases is None:
+        _causal_dispatch(lambda masked: steps_fn((_Tile(masked),)),
+                         j, t, bq, bk, causal)
+        return
+    crossing, interior = cases
+    if nq == 1 and nk == 1:
+        steps_fn(crossing[0])
+        return
+    delta = j * bq - t * bk
+    for d, tiles in crossing.items():
+        pl.when(delta == d)(functools.partial(steps_fn, tiles))
+    if interior is not None:
+        pl.when(delta >= bk - 1)(functools.partial(steps_fn, interior))
+
+
+def _mask_bias(st, j, t, bq, bk, tile, bias_kind, bias_ref,
                has_seg, qseg_ref, kseg_ref):
     """Apply (in order) additive bias, segment mask, causal mask to a
-    TRANSPOSED (bk, bq) score block.
+    TRANSPOSED score tile: the (bk, bq) block, or the (ck, cq) window
+    of it that `tile` names.
 
     ≡ the reference's additive-mask softmax fusion
     (apex/contrib/csrc/multihead_attn/softmax.cuh:27-200 computes
@@ -89,16 +193,25 @@ def _mask_bias(st, j, t, bq, bk, causal_masked, bias_kind, bias_ref,
     bias_kind: "none" | "full" (a transposed (bk, bq) block of a
     (.., sq, sk) bias) | "sk" (a (.., 1, sk) key-compact bias riding as
     a (bk,) row — padding masks / ALiBi never expand to S² in HBM)."""
+    if tile.q is None:
+        at_q = at_k = ()
+        ck = bk
+    else:
+        at_q, at_k, ck = (_rows(tile.q),), (_rows(tile.k),), tile.k[1]
     if bias_kind == "full":
-        st = st + bias_ref[0, 0]                        # (bk, bq)
+        st = st + bias_ref[(0, 0) + at_k + at_q]            # (bk, bq)
     elif bias_kind == "sk":
-        st = st + bias_ref[0, 0, 0].reshape(bk, 1)      # k-varying row
+        st = st + bias_ref[(0, 0, 0) + at_k].reshape(ck, 1)  # k-varying row
     if has_seg:
-        qs = qseg_ref[0, j]                             # (bq,) lanes
-        ks = kseg_ref[0, t].reshape(bk, 1)              # (bk, 1) sublanes
+        qs = qseg_ref[(0, j) + at_q]                    # (bq,) lanes
+        ks = kseg_ref[(0, t) + at_k].reshape(ck, 1)     # (bk, 1) sublanes
         st = jnp.where(ks != qs, _NEG_INF, st)
-    if causal_masked:
+    if tile.masked and tile.q is None:
         st = _causal_mask(st, j, t, bq, bk)
+    elif tile.masked:
+        above = (lax.broadcasted_iota(jnp.int32, st.shape, 0)
+                 - lax.broadcasted_iota(jnp.int32, st.shape, 1))
+        st = jnp.where(above > tile.shift, _NEG_INF, st)
     return st
 
 
@@ -197,7 +310,7 @@ def _fmix32(h):
     return h
 
 
-def _dropout_keep(seed_ref, i, j, t, shape, rate):
+def _dropout_keep(seed_ref, i, j, t, shape, rate, tile=None):
     """Deterministic per-score-block keep mask from a COORDINATE hash.
 
     ≡ the reference FMHA's philox dropout (apex/contrib/csrc/fmha/src/
@@ -213,11 +326,18 @@ def _dropout_keep(seed_ref, i, j, t, shape, rate):
     dropout composes across ring steps (fwd and bwd see one mask).
     The hardware PRNG (pltpu.prng_random_bits) is NOT usable here: its
     stream→element mapping follows each kernel's codegen, so forward
-    and backward kernels with different structure silently disagree."""
+    and backward kernels with different structure silently disagree.
+
+    `shape` is the block's, (bk, bq); `tile` names a window of it, and
+    the mask is then that window's part of the same bits."""
     bk, bq = shape
-    krow = (seed_ref[2, 0] + t * bk
+    k_first, q_first = t * bk, j * bq
+    if tile is not None and tile.q is not None:
+        k_first, q_first = k_first + tile.k[0], q_first + tile.q[0]
+        shape = (tile.k[1], tile.q[1])
+    krow = (seed_ref[2, 0] + k_first
             + lax.broadcasted_iota(jnp.int32, shape, 0))  # k global
-    qcol = (seed_ref[1, 0] + j * bq
+    qcol = (seed_ref[1, 0] + q_first
             + lax.broadcasted_iota(jnp.int32, shape, 1))  # q global
     h = seed_ref[0, 0] * jnp.int32(1000003) + jnp.int32(i)
     v = (h + krow * jnp.int32(-1640531535)       # 0x9e3779b1
@@ -334,58 +454,80 @@ class _Heads:
     def last(self, cond):
         return cond & (self.h == self.lanes - 1) if self.lanes else cond
 
-    def whole(self, ref):
-        return ref[self.blk]
+    def whole(self, ref, win=None):
+        """The block, or its rows `win` = (first, count)."""
+        if win is None:
+            return ref[self.blk]
+        return ref[_rows(win)] if self.lanes else ref[0, _rows(win)]
+
+    def stat_at(self, qwin):
+        """Index of this head's statistics and accumulator columns at
+        the q window."""
+        if qwin is None:
+            return self.stat
+        cols = (slice(None), _rows(qwin))
+        return (self.h,) + cols if self.lanes else cols
+
+    def row(self, ref, j, qwin):
+        """This head's (bq,) row of q block j in an lse or delta block,
+        or the window's part of it."""
+        row = ref[self.h, j]
+        return row if qwin is None else row[_rows(qwin)]
 
     def taker(self):
-        """own(ref): the block with every head but this one zeroed.
-        One taker a step body: a block is masked once however many
-        matmuls read it."""
+        """own(ref, win): the block, or its rows `win`, with every head
+        but this one zeroed.  One taker a branch of a step body: rows
+        are masked once however many matmuls and tiles read them."""
         if self.lanes <= 1:
             return self.whole
         seen = {}
 
-        def own(ref):
-            if id(ref) not in seen:
+        def own(ref, win=None):
+            if (id(ref), win) not in seen:
                 lane = lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
                 mine = lax.div(lane, self.d) == self.h
-                seen[id(ref)] = ref[...] * mine.astype(ref.dtype)
-            return seen[id(ref)]
+                rows = ref[...] if win is None else ref[_rows(win)]
+                seen[id(ref), win] = rows * mine.astype(ref.dtype)
+            return seen[id(ref), win]
         return own
 
-    def pv(self, v_ref, p, vt_scr):
+    def pv(self, v_ref, p, vt_scr, kwin=None, first=True):
         """(d, bq) of this head: its v rows, (bk, d), contracted with
-        the (bk, bq) weights over bk.  From a 128-lane block of several
-        heads, v is transposed once a block (on its first head) into
-        `vt_scr`, (128, bk), where a head is d rows: the product then
-        streams d rows through the MXU as the head-major one does, and
-        not all 128 for d of them."""
+        the (bk, bq) weights over bk (or the rows `kwin` with a tile's
+        weights).  From a 128-lane block of several heads, v is
+        transposed once a block (on its first head, by the `first` tile
+        of the step) into `vt_scr`, (128, bk), where a head is d rows:
+        the product then streams d rows through the MXU as the
+        head-major one does, and not all 128 for d of them."""
         if self.lanes <= 1:
             return jax.lax.dot_general(
-                self.whole(v_ref), p.astype(v_ref.dtype),
+                self.whole(v_ref, kwin), p.astype(v_ref.dtype),
                 (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         (vt_scr,) = vt_scr
 
-        @pl.when(self.h == 0)
-        def _transpose():
-            vt_scr[...] = v_ref[...].T
+        if first:
+            @pl.when(self.h == 0)
+            def _transpose():
+                vt_scr[...] = v_ref[...].T
 
         mine = pl.ds(pl.multiple_of(self.h * self.d, self.d), self.d)
+        cols = slice(None) if kwin is None else _rows(kwin)
         return jax.lax.dot_general(
-            vt_scr[mine, :], p.astype(v_ref.dtype), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            vt_scr[mine, cols], p.astype(v_ref.dtype),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    def delta(self, delta_ref, own_do, j):
-        """rowsum(dO * O) of this head over the q block, a (bq,) or
-        (1, bq) row.  Head-major it comes computed, as (1, nq, bq).  In
-        the projection's layout `delta_ref` is the context's own block
-        and the sum is taken here, in fp32 on the VPU (`own_do()`: dO
-        with the other heads zeroed): made outside, it costs a
-        transposed fp32 copy of dO * O a layer."""
+    def delta(self, delta_ref, own_do, j, qwin=None):
+        """rowsum(dO * O) of this head over the q block (or its window),
+        a (bq,) or (1, bq) row.  Head-major it comes computed, as
+        (1, nq, bq).  In the projection's layout `delta_ref` is the
+        context's own block and the sum is taken here, in fp32 on the
+        VPU (`own_do()`: dO with the other heads zeroed): made outside,
+        it costs a transposed fp32 copy of dO * O a layer."""
         if not self.lanes:
-            return delta_ref[0, j]
-        prod = (delta_ref[...].astype(jnp.float32)
+            return self.row(delta_ref, j, qwin)
+        ctx = delta_ref[...] if qwin is None else delta_ref[_rows(qwin)]
+        prod = (ctx.astype(jnp.float32)
                 * own_do().astype(jnp.float32))             # (bq, 128)
         return jnp.sum(prod.T, axis=0, keepdims=True)
 
@@ -395,7 +537,7 @@ class _Heads:
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
                 seed_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *vt_scr, scale, causal, bq, bk, nk,
-                dropout_rate, bias_kind, has_seg, lanes=0):
+                tile, dropout_rate, bias_kind, has_seg, lanes=0):
     """Scores run TRANSPOSED (bk, bq): the softmax statistics (m, l,
     lse) are then (1, bq) lane-major rows — fully-packed vregs instead
     of 1/128-occupied columns, and the lse/delta HBM arrays are
@@ -405,7 +547,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
     `lanes`: the blocks' layout (_Heads).  In the projection's layout
     the statistics and the accumulator have a leading axis over the
     block's heads, and (lanes, d, bq) read as (128, bq) is the output
-    block transposed."""
+    block transposed.  `tile`: the q window a causal block is computed
+    in (_pick_tile, _tile_dispatch)."""
     i = pl.program_id(0)
     j = pl.program_id(1)  # q block
     t = pl.program_id(2)  # k block
@@ -420,37 +563,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, qseg_ref, kseg_ref,
         else:
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _step(masked):
+    def _steps(tiles):
         own = hd.taker()
+        for n, tile in enumerate(tiles):
+            _step(own, tile, first=n == 0)
+
+    def _step(own, tile, first):
+        qw, kw = tile.q, tile.k
+        at = hd.stat_at(qw)
         # native-dtype operands: MXU wants bf16 x bf16 -> fp32; a
         # pre-upcast to fp32 would push the matmul off the MXU
-        st = jax.lax.dot_general(own(k_ref), hd.whole(q_ref),
+        st = jax.lax.dot_general(own(k_ref, kw), hd.whole(q_ref, qw),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        st = _mask_bias(st, j, t, bq, bk, masked, bias_kind, bias_ref,
+        st = _mask_bias(st, j, t, bq, bk, tile, bias_kind, bias_ref,
                         has_seg, qseg_ref, kseg_ref)
-        m_prev = m_scr[hd.stat]                                 # (1, bq)
+        m_prev = m_scr[at]                                      # (1, bq)
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         p = jnp.exp(st - m_new)                                 # (bk, bq)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[hd.stat] = (l_scr[hd.stat] * alpha
-                          + jnp.sum(p, axis=0, keepdims=True))
+        l_scr[at] = l_scr[at] * alpha + jnp.sum(p, axis=0, keepdims=True)
         if dropout_rate > 0.0:
             # dropout is linear in p, so masking before the (deferred)
             # 1/l normalization equals dropout(softmax(s)) exactly; the
             # denominator l stays the raw softmax sum
             keep = _dropout_keep(seed_ref, hd.flat, j, t, (bk, bq),
-                                 dropout_rate)
+                                 dropout_rate, tile)
             p_acc = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
         else:
             p_acc = p
         # acc is kept transposed (d, bq) so alpha/l rows broadcast along
         # lanes; (bk, d)^T-contract (bk, bq) -> (d, bq)
-        acc_scr[hd.stat] = acc_scr[hd.stat] * alpha + hd.pv(
-            v_ref, p_acc, vt_scr)
-        m_scr[hd.stat] = m_new
+        acc_scr[at] = acc_scr[at] * alpha + hd.pv(
+            v_ref, p_acc, vt_scr, kw, first)
+        m_scr[at] = m_new
 
-    _causal_dispatch(_step, j, t, bq, bk, causal)
+    _tile_dispatch(_steps, j, t, bq, bk, lse_ref.shape[1], nk, causal, tile)
 
     @pl.when(t == nk - 1)
     def _epilogue():
@@ -596,8 +744,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = jax.lax.dot_general(k_ref[0], q_ref[0],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        st = _mask_bias(st, j, t, bq, bk, masked, bias_kind, bias_ref,
-                        has_seg, qseg_ref, kseg_ref)
+        st = _mask_bias(st, j, t, bq, bk, _Tile(masked), bias_kind,
+                        bias_ref, has_seg, qseg_ref, kseg_ref)
         p = jnp.exp(st - lse_ref[0, j])                         # (bk, bq)
         dp = jax.lax.dot_general(v_ref[0], do_ref[0],
                                  (((1,), (1,)), ((), ())),
@@ -646,8 +794,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = jax.lax.dot_general(k_ref[0], q_ref[0],
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        st = _mask_bias(st, j, t, bq, bk, masked, bias_kind, bias_ref,
-                        has_seg, qseg_ref, kseg_ref)
+        st = _mask_bias(st, j, t, bq, bk, _Tile(masked), bias_kind,
+                        bias_ref, has_seg, qseg_ref, kseg_ref)
         p = jnp.exp(st - lse_ref[0, j])                 # (bk, bq)
         if dropout_rate > 0.0:
             keep = _dropout_keep(seed_ref, i, j, t, (bk, bq), dropout_rate)
@@ -691,7 +839,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       bias_ref, qseg_ref, kseg_ref,
                       seed_ref, dq_ref, dk_ref, dv_ref, *rest,
                       scale, causal, bq, bk,
-                      nq, nk, dropout_rate, bias_kind, has_seg,
+                      nq, nk, tile, dropout_rate, bias_kind, has_seg,
                       want_dbias=False, lanes=0):
     """Single-pass backward: dq, dk, dv from ONE score/exp recompute.
 
@@ -727,40 +875,50 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _step(masked):
+    def _steps(tiles):
         own = hd.taker()
-        rows = (pl.ds(t * bk, bk), slice(None))
-        st = jax.lax.dot_general(own(k_ref), hd.whole(q_ref),
+        for tile in tiles:
+            _step(own, tile)
+
+    def _step(own, tile):
+        qw, kw = tile.q, tile.k
+        if qw is None:
+            rows, cols, at = (pl.ds(t * bk, bk), slice(None)), Ellipsis, 0
+        else:
+            rows = (pl.ds(t * bk + kw[0], kw[1]), slice(None))
+            cols, at = _rows(qw), (0, _rows(kw), _rows(qw))
+        st = jax.lax.dot_general(own(k_ref, kw), hd.whole(q_ref, qw),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        st = _mask_bias(st, j, t, bq, bk, masked, bias_kind, bias_ref,
+        st = _mask_bias(st, j, t, bq, bk, tile, bias_kind, bias_ref,
                         has_seg, qseg_ref, kseg_ref)
-        p = jnp.exp(st - lse_ref[hd.h, j])              # (bk, bq)
-        dp = jax.lax.dot_general(hd.whole(v_ref), own(do_ref),
+        p = jnp.exp(st - hd.row(lse_ref, j, qw))        # (bk, bq)
+        dp = jax.lax.dot_general(hd.whole(v_ref, kw), own(do_ref, qw),
                                  (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dropout_rate > 0.0:
             keep = _dropout_keep(seed_ref, hd.flat, j, t, (bk, bq),
-                                 dropout_rate)
+                                 dropout_rate, tile)
             inv = 1.0 / (1.0 - dropout_rate)
             p_v = jnp.where(keep, p, 0.0) * inv
             dp = jnp.where(keep, dp, 0.0) * inv
         else:
             p_v = p
         dv_scr[rows] += jax.lax.dot_general(
-            p_v.astype(do_ref.dtype), own(do_ref), (((1,), (0,)), ((), ())),
+            p_v.astype(do_ref.dtype), own(do_ref, qw),
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bk, d)
-        ds = p * (dp - hd.delta(delta_ref, lambda: own(do_ref), j))
+        ds = p * (dp - hd.delta(delta_ref, lambda: own(do_ref, qw), j, qw))
         if want_dbias:
-            db_ref[0] = ds
+            db_ref[at] = ds
         dk_scr[rows] += scale * jax.lax.dot_general(
-            ds.astype(q_ref.dtype), own(q_ref), (((1,), (0,)), ((), ())),
+            ds.astype(q_ref.dtype), own(q_ref, qw), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bk, d)
-        dq_scr[...] += scale * jax.lax.dot_general(
-            ds.astype(k_ref.dtype), own(k_ref), (((0,), (0,)), ((), ())),
+        dq_scr[cols] += scale * jax.lax.dot_general(
+            ds.astype(k_ref.dtype), own(k_ref, kw), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)         # (bq, d)
 
-    _causal_dispatch(_step, j, t, bq, bk, causal)
+    _tile_dispatch(_steps, j, t, bq, bk, nq, nk, causal, tile)
 
     @pl.when(hd.last(t == nk - 1))
     def _write_dq():
@@ -851,6 +1009,61 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref,
     dv_ref[...] = dv_scr[:, pl.ds(t * bk, bk), :].astype(dv_ref.dtype)
 
 
+# ------------------------------- counters ----------------------------------
+
+# flash attention calls traced since the last reset, by the layout their
+# kernels took (the packed projection's own, or head-major), and the
+# score elements of every kernel traced for them, forward and backward:
+# the ones its static blocks and compute tiles compute and the ones the
+# mask keeps
+_calls = {"projection_layout": 0, "head_major": 0,
+          "scores_computed": 0, "scores_required": 0}
+
+
+def stats():
+    """{"projection_layout", "head_major"}: the flash attention calls
+    traced since the last reset whose kernels read the packed QKV
+    projection where it lay, and those that were handed (B, heads, S, d)
+    arrays (a call that took the jnp reference is neither).
+    {"scores_computed", "scores_required"}: over the kernels traced for
+    them, forward and backward, the score elements a kernel computes
+    (every block and tile that runs, whole; twice where the backward is
+    two kernels) and the (q, k) pairs a causal mask keeps (all of them
+    without one).  Their ratio is what the causal tiling leaves of the
+    half square a mask throws away: 2.0 for one block a head computed
+    in one piece, towards 1.0 as tiles above the diagonal drop out."""
+    return dict(_calls)
+
+
+def reset_stats():
+    for key in _calls:
+        _calls[key] = 0
+
+
+def _count_scores(heads, sq, sk, bq, bk, causal, tile, passes=1):
+    """One traced kernel (or the `passes` that recompute the same
+    scores) into the counters.  `tile` is the q window its step body
+    cuts a (bq, bk) block into: _pick_tile's, or bq for the kernels
+    that compute a block in one piece."""
+    computed = required = sq * sk
+    if causal:
+        low = min(sq, sk)
+        required = low * (low + 1) // 2 + (sq - low) * sk
+        nq, nk = sq // bq, sk // bk
+        cases = _tile_cases(bq, bk, nq, nk, tile)
+        computed = 0
+        for delta in (j * bq - t * bk for j in range(nq) for t in range(nk)):
+            if delta <= -bq:
+                continue                # above the diagonal: never run
+            if cases is None:
+                computed += bq * bk
+            else:
+                tiles = cases[0].get(delta, cases[1])
+                computed += sum(t.q[1] * t.k[1] for t in tiles)
+    _calls["scores_computed"] += passes * heads * computed
+    _calls["scores_required"] += passes * heads * required
+
+
 # ----------------------------- host-side plumbing ---------------------------
 
 def _pick_block(seq, cap=512):
@@ -858,6 +1071,24 @@ def _pick_block(seq, cap=512):
         if b <= cap and seq % b == 0:
             return b
     return None
+
+
+def _pick_tile(bq, backward):
+    """The q window, in rows, that a step body cuts a causal block's bq
+    rows into (_tile_dispatch, _tile_cases); bq itself, one window,
+    where it does not divide them.
+
+    Swept on the v5e at the benchmark's shapes (PERF.md, PR 29: two
+    heads of 64 a lane block at S = 1024 and 512, keys 192 / values 128
+    head-major at 4096), 128, 256 and 512 wide: every tile pays the
+    latency of its own chain (matmul, row max, exp, matmul), a few
+    hundred cycles the next tile does not hide, so the forward, two
+    matmuls a tile, is fastest at 512 and loses to one tile at 256 and
+    below; the backward, five matmuls and four fp32 temporaries a tile,
+    at 256.  The widths and the layout moved neither optimum, so the
+    rule does not read them."""
+    window = 256 if backward else 512
+    return window if bq % window == 0 else bq
 
 
 _BLOCK_FALLBACK_WARNED = set()
@@ -888,9 +1119,14 @@ def _fit_block(blk, seq, name):
 
 def _resolve_blocks(sq, sk, block_q, block_k, full_bias=False):
     """Default blocks, swept on v5e (docs/PERF.md): single block per
-    axis when the sequence fits (<=1024 — grid overhead dominates the
-    extra causal-mask work), else (512, 1024) to cap the fp32 score
-    tile at 2 MB of VMEM while keeping k-side matmuls wide.  Explicit
+    axis when the sequence fits (<=1024), else (512, 1024) to keep the
+    k-side matmuls wide.  That sweep (round 4) cut the GRID: a grid
+    step costs its pipeline overhead, a skipped one too, and blocks of
+    512 lost to one block a head on it.  It says nothing against
+    computing a block in pieces: the half square a causal mask throws
+    away and the size of the fp32 score temporaries are the compute
+    tiles' business now (_pick_tile), inside whatever block this
+    returns.  Explicit
     blocks that do not divide the sequence fall back to the largest
     dividing power-of-two block (warn once) so tuned configs never
     hard-fail on off-size sequences.  A fused FULL bias adds a
@@ -937,6 +1173,20 @@ def _flatten_bh(x):
     return x.reshape(b * h, s, d)
 
 
+_HEAD_MAJOR_CALLS = {}
+
+
+def _one_call(key, build):
+    """build(), once a `key`: the head-major pallas_call of one shape
+    and set of options.  Every layer of a model then calls one object,
+    and its kernel body (a branch a place on the diagonal, a body a
+    tile) is traced and lowered once a program, not once a layer
+    (_qkv_call's reason).  `key` names everything `build` reads."""
+    if key not in _HEAD_MAJOR_CALLS:
+        _HEAD_MAJOR_CALLS[key] = build()
+    return _HEAD_MAJOR_CALLS[key]
+
+
 def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
               block_q=None, block_k=None, bias=None, q_seg=None,
               kv_seg=None, q_off=0, k_off=0, heads_per_step=1):
@@ -949,6 +1199,8 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
     qf, kf, vf = _flatten_bh(q), _flatten_bh(k), _flatten_bh(v)
     bh = b * h
     nq, nk = sq // bq, sk // bk
+    tile = _pick_tile(bq, backward=False) if hp == 1 else bq
+    _count_scores(bh, sq, sk, bq, bk, causal, tile)
     seed = _seed3(seed, q_off, k_off)
     has_seg = q_seg is not None
     nb = bias.shape[0] if bias is not None else 1
@@ -961,8 +1213,8 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
     if hp == 1:
         kernel = functools.partial(
             _fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-            nk=nk, dropout_rate=dropout_rate, bias_kind=bias_kind,
-            has_seg=has_seg)
+            nk=nk, tile=tile, dropout_rate=dropout_rate,
+            bias_kind=bias_kind, has_seg=has_seg)
         scratch = [pltpu.VMEM((1, bq), jnp.float32),
                    pltpu.VMEM((1, bq), jnp.float32),
                    pltpu.VMEM((dv, bq), jnp.float32)]
@@ -974,7 +1226,10 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
         scratch = [pltpu.VMEM((hp, bq), jnp.float32),
                    pltpu.VMEM((hp, bq), jnp.float32),
                    pltpu.VMEM((hp, dv, bq), jnp.float32)]
-    o, lse = pl.pallas_call(
+    o, lse = _one_call((
+        "fwd", bh, h, sq, sk, d, dv, q.dtype, bq, bk, hp, tile, bias_kind,
+        nb, nh, has_seg, scale, causal, dropout_rate, pallas_interpret(),
+    ), lambda: pl.pallas_call(
         kernel,
         grid=(bh // hp, nq, nk),
         in_specs=[
@@ -1004,7 +1259,7 @@ def _fwd_impl(q, k, v, scale, causal, dropout_rate=0.0, seed=None,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=pallas_interpret(),
         name="flash_fwd",
-    )(qf, kf, vf, bias_t, qs, ks, seed)
+    ))(qf, kf, vf, bias_t, qs, ks, seed)
     return o.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
@@ -1091,6 +1346,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
     # per-head), and the (hp, sk, d) dk/dv scratch pair fits VMEM
     if (hp > 1 and fused_fits and not want_dbias
             and hp * sk * (d + dv) <= 2 * _FUSED_BWD_CAP_PACKED):
+        _count_scores(bh, sq, sk, bq, bk, causal, bq)
         bspec_p, qsspec_p, ksspec_p = _extras_specs(
             h, nq, bq, nk, bk, bias_kind, nb, nh, has_seg,
             jt_from_args=lambda j, t: (j, t), hp=hp)
@@ -1125,6 +1381,8 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
     # single-pass fused backward while the full-(sk, d) dk/dv scratch
     # fits VMEM comfortably; two-kernel fallback for long context
     if fused_fits and not dbias_sk:
+        tile = _pick_tile(bq, backward=True)
+        _count_scores(bh, sq, sk, bq, bk, causal, tile)
         out_specs = [qspec, kspec, vspec]
         out_shape = [jax.ShapeDtypeStruct((bh, sq, d), dq_dt),
                      jax.ShapeDtypeStruct((bh, sk, d), dk_dt),
@@ -1134,8 +1392,12 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
                                           lambda i, j, t: (i, t, j)))
             out_shape.append(
                 jax.ShapeDtypeStruct((bh, sk, sq), jnp.float32))
-        outs = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, nq=nq, nk=nk,
+        outs = _one_call((
+            "bwd_fused", bh, h, sq, sk, d, dv, q.dtype, dq_dt, dk_dt, dv_dt,
+            bq, bk, tile, bias_kind, nb, nh, has_seg, dbias_full, scale,
+            causal, dropout_rate, pallas_interpret(),
+        ), lambda: pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, nq=nq, nk=nk, tile=tile,
                               want_dbias=dbias_full, **static),
             grid=(bh, nq, nk),
             in_specs=[qspec, kspec, vspec, dospec, r1, r1,
@@ -1151,7 +1413,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=pallas_interpret(),
             name="flash_bwd",
-        )(*args)
+        ))(*args)
         dq, dk, dv = outs[:3]
         dbias = None
         if dbias_full:
@@ -1160,6 +1422,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
         return (dq.reshape(q.shape), dk.reshape(k.shape),
                 dv.reshape(v.shape), dbias)
 
+    _count_scores(bh, sq, sk, bq, bk, causal, bq, passes=2)
     dq_specs = [qspec]
     dq_shape = [jax.ShapeDtypeStruct((bh, sq, d), dq_dt)]
     if dbias_full:
@@ -1235,8 +1498,7 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, dropout_rate=0.0,
 # ------------- the projection's own layout: host-side plumbing --------------
 
 @functools.lru_cache(maxsize=None)
-def _qkv_call(backward, b, s, nh, d, dtype, block_q, block_k, interpret,
-              **static):
+def _qkv_call(backward, b, s, nh, d, dtype, bq, bk, interpret, **static):
     """The forward or the backward pallas_call over the projection's
     layout, (B, S, 3*nh*d) batch-major.  One object a shape: every
     layer of a model calls the same one, so its kernel body is traced
@@ -1248,11 +1510,6 @@ def _qkv_call(backward, b, s, nh, d, dtype, block_q, block_k, interpret,
     k, v; the context, d(context) and dq take q's rows of a
     (B, S, nh*d) array, dk and dv take k's."""
     hp, g = _LANES // d, nh * d // _LANES
-    bq, bk = _resolve_blocks(s, s, block_q, block_k)
-    if block_q is None and dtype.itemsize > 2:
-        # a 128-lane fp32 block is twice a bf16 one: at (1024, 1024) the
-        # backward's blocks and score tiles pass 16 MiB of scoped VMEM
-        bq = _pick_block(s, cap=min(bq, 512))
     nq, nk = s // bq, s // bk
 
     def spec(rows, take, part):
@@ -1304,8 +1561,15 @@ def _run_qkv(backward, x, nh, block_q, block_k, seed, operands, **static):
     """x: the packed projection batch-major, (B, S, 3*nh*d); `operands`
     follow q, k and v."""
     b, s, width = x.shape
+    bq, bk = _resolve_blocks(s, s, block_q, block_k)
+    if block_q is None and x.dtype.itemsize > 2:
+        # a 128-lane fp32 block is twice a bf16 one: at (1024, 1024) the
+        # backward's blocks and score tiles pass 16 MiB of scoped VMEM
+        bq = _pick_block(s, cap=min(bq, 512))
+    tile = _pick_tile(bq, backward)
+    _count_scores(b * nh, s, s, bq, bk, static["causal"], tile)
     call = _qkv_call(backward, b, s, nh, width // (3 * nh), x.dtype,
-                     block_q, block_k, pallas_interpret(), **static)
+                     bq, bk, pallas_interpret(), tile=tile, **static)
     extras = _extras_arrays(1, 1, 1, 1, 1, 1, 1, 1, None, None, None)
     return call(x, x, x, *operands, *extras, _seed3(seed))
 
@@ -1395,24 +1659,6 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # --------------------------------- public API -------------------------------
-
-# flash attention calls traced since the last reset, by the layout their
-# kernels took: the packed projection's own, or head-major
-_calls = {"projection_layout": 0, "head_major": 0}
-
-
-def stats():
-    """{"projection_layout", "head_major"}: the flash attention calls
-    traced since the last reset whose kernels read the packed QKV
-    projection where it lay, and those that were handed (B, heads, S, d)
-    arrays (a call that took the jnp reference is neither)."""
-    return dict(_calls)
-
-
-def reset_stats():
-    for layout in _calls:
-        _calls[layout] = 0
-
 
 def _dropout_seed(dropout_rate, dropout_key):
     if dropout_rate > 0.0:

@@ -19,7 +19,11 @@ The v5e flash entries below pack 2 heads per grid step (heads_per_step)
 with 512-square blocks: the d=64 per-head score block is VPU-epilogue
 and grid-overhead bound (docs/PERF.md roofline: 29–44% of the 7-matmul
 mix ceiling), and packing fills the softmax-stat vregs across heads
-while keeping the (hp·bk·bq) fp32 score tile at 2 MB of VMEM.
+while keeping the (hp·bk·bq) fp32 score tile at 2 MB of VMEM.  Those
+are shapes no benchmark cell runs: the cells' shapes take the kernels'
+own blocks and, under a causal mask, the compute tiles the step bodies
+cut them into (`ops/flash_attention.py::_pick_tile`); the one entry a
+cell does hit is the latent-attention shape at the end.
 """
 
 from __future__ import annotations

@@ -2,6 +2,8 @@
 multihead_attn numerics tests: Pallas blockwise kernel vs plain softmax
 attention, fwd + grads, causal and full, multiple shapes."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -393,6 +395,11 @@ _QKV_SHAPES = {
 }
 
 
+def _layouts(stats):
+    """The calls by layout, without the score counters beside them."""
+    return {k: stats[k] for k in ("projection_layout", "head_major")}
+
+
 def _packed(s, b, nh, d, dtype, seed=0):
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     qkv = jax.random.normal(k1, (s, b, 3 * nh * d), jnp.float32)
@@ -430,7 +437,7 @@ def test_flash_qkv_matches_reference_and_head_major(shape, causal, dtype):
         lambda x: flash_attention_qkv(
             x, nh, causal=causal, block_q=bq, block_k=bk,
             use_pallas_override=True), qkv, weight)
-    assert flash_mod.stats() == {"projection_layout": 2, "head_major": 0}
+    assert _layouts(flash_mod.stats()) == {"projection_layout": 2, "head_major": 0}
     kernel = _loss_and_grad(
         lambda x: _split_then(flash_attention, x, nh, d, causal=causal,
                               block_q=bq, block_k=bk,
@@ -488,7 +495,7 @@ def test_flash_qkv_falls_back_to_head_major(s, nh, d, why, monkeypatch):
     flash_mod.reset_stats()
     got = flash_attention_qkv(qkv, nh, causal=True,
                               use_pallas_override=True)
-    assert flash_mod.stats() == {"projection_layout": 0, "head_major": 1}, why
+    assert _layouts(flash_mod.stats()) == {"projection_layout": 0, "head_major": 1}, why
     assert got.shape == (s, 1, nh * d)
     if s <= 1024:
         want = _split_then(attention_reference, qkv, nh, d, causal=True)
@@ -505,8 +512,211 @@ def test_flash_qkv_without_kernels_is_neither():
     want = _split_then(attention_reference, qkv, 2, 64, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-    assert flash_mod.stats() == {"projection_layout": 0, "head_major": 0}
+    assert _layouts(flash_mod.stats()) == {"projection_layout": 0, "head_major": 0}
     with pytest.raises(ValueError):
         flash_attention_qkv(qkv, 5)
     with pytest.raises(ValueError):
         flash_attention_qkv(qkv, 2, dropout_rate=0.1)
+
+
+# --------------- causal compute tiles inside a block ------------------------
+#
+# A causal call cuts the block a step is handed into static windows
+# (`_pick_tile`, `_tile_cases`, `_tile_dispatch`): a q window runs
+# against the k rows its queries see and no others, under the mask
+# where the diagonal crosses them.  The rule is patched to 128 rows
+# here so that several tiles run at S = 256-512.
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    monkeypatch.setattr(
+        flash_mod, "_pick_tile",
+        lambda bq, backward: 128 if bq % 128 == 0 else bq)
+
+
+def _fwd_and_grads(attn, *operands, seed=9):
+    out, vjp = jax.vjp(attn, *operands)
+    do = jax.random.normal(jax.random.PRNGKey(seed), out.shape, out.dtype)
+    return (out,) + vjp(do)
+
+
+def _two_widths(b, h, s, d, dv, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, h, s, d)),
+            jax.random.normal(ks[1], (b, h, s, d)),
+            jax.random.normal(ks[2], (b, h, s, dv)))
+
+
+# one block a head: the tiles are chosen while tracing; (256, 128) blocks
+# over 512: a block crosses the diagonal at delta 0 and at -128, lies
+# below it, or above it
+_TILED_BLOCKS = {"one_block": (None, None), "bq_2bk": (256, 128)}
+
+
+@pytest.mark.parametrize("blocks", list(_TILED_BLOCKS))
+@pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128)],
+                         ids=["d64", "d128", "k192_v128"])
+def test_causal_tiles_head_major(d, dv, blocks, small_tiles):
+    """Forward and all three gradients of the tiled kernels against the
+    dense reference."""
+    bq, bk = _TILED_BLOCKS[blocks]
+    q, k, v = _two_widths(1, 2, 512, d, dv)
+    got = _fwd_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk,
+        use_pallas_override=True), q, k, v)
+    want = _fwd_and_grads(lambda q, k, v: attention_reference(
+        q, k, v, causal=True), q, k, v)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", list(_TILED_BLOCKS))
+def test_causal_tiles_projection_layout(blocks, small_tiles):
+    """The same in the projection's layout: two heads of 64 a lane
+    block, the statistics and accumulators at a window of lanes, v
+    transposed once a step and read at the window's columns."""
+    bq, bk = _TILED_BLOCKS[blocks]
+    s, b, nh, d = 512, 2, 4, 64
+    qkv, weight = _packed(s, b, nh, d, jnp.float32, seed=7)
+    flash_mod.reset_stats()
+    got = _loss_and_grad(lambda x: flash_attention_qkv(
+        x, nh, causal=True, block_q=bq, block_k=bk,
+        use_pallas_override=True), qkv, weight)
+    want = _loss_and_grad(
+        lambda x: _split_then(attention_reference, x, nh, d, causal=True),
+        qkv, weight)
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=2e-5, atol=2e-5)
+    # three kernels traced (a forward, and a forward and a backward
+    # under grad), each over the 10 of a 512 square's 16 tiles of 128
+    # that lie on or under the diagonal, however the blocks cut it
+    took = flash_mod.stats()
+    assert took["scores_computed"] == 3 * b * nh * 10 * 128 * 128
+    assert took["scores_required"] == 3 * b * nh * 512 * 513 // 2
+
+
+@pytest.mark.parametrize("blocks", list(_TILED_BLOCKS))
+def test_causal_tiles_with_segment_ids(blocks, small_tiles):
+    """Packed sequences: the segment rows are read at the window."""
+    bq, bk = _TILED_BLOCKS[blocks]
+    q, k, v = _two_widths(2, 2, 512, 64, 64, seed=1)
+    seg = jnp.stack([jnp.repeat(jnp.arange(4), 128),
+                     (jnp.arange(512) >= 200).astype(jnp.int32)])
+    got = _fwd_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, segment_ids=seg, block_q=bq, block_k=bk,
+        use_pallas_override=True), q, k, v)
+    want = _fwd_and_grads(lambda q, k, v: attention_reference(
+        q, k, v, causal=True, q_segment_ids=seg, kv_segment_ids=seg),
+        q, k, v)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("bias_grad", [False, True])
+def test_causal_tiles_with_a_key_compact_bias(bias_grad, small_tiles):
+    """A (.., 1, sk) bias rides as a row and is read at the k window;
+    wanted, its gradient sends the backward to the two kernels, which
+    the tiles leave alone."""
+    q, k, v = _two_widths(1, 2, 256, 64, 64, seed=2)
+    bias = jax.random.normal(jax.random.PRNGKey(5), (1, 2, 1, 256))
+    got = _fwd_and_grads(lambda q, k, v, bias: flash_attention(
+        q, k, v, causal=True, bias=bias, bias_grad=bias_grad,
+        use_pallas_override=True), q, k, v, bias)
+    want = _fwd_and_grads(lambda q, k, v, bias: attention_reference(
+        q, k, v, causal=True, bias=bias), q, k, v, bias)
+    for g, w, name in zip(got[:4], want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+    np.testing.assert_allclose(got[4], want[4] if bias_grad else 0.0,
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_causal_tiles_with_a_full_bias_and_its_gradient(small_tiles):
+    """A (sq, sk) bias is read at the tile's window of its block, and
+    its gradient, ds itself, written there: a tile that never runs
+    leaves the zeros the step began with."""
+    q, k, v = _two_widths(1, 2, 256, 64, 64, seed=3)
+    bias = jax.random.normal(jax.random.PRNGKey(6), (1, 2, 256, 256))
+    got = _fwd_and_grads(lambda q, k, v, bias: flash_attention(
+        q, k, v, causal=True, bias=bias, use_pallas_override=True),
+        q, k, v, bias)
+    want = _fwd_and_grads(lambda q, k, v, bias: attention_reference(
+        q, k, v, causal=True, bias=bias), q, k, v, bias)
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv", "dbias")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("blocks", list(_TILED_BLOCKS))
+def test_causal_tiles_drop_what_the_dense_mask_drops(blocks, small_tiles):
+    """Dropout 0.1: a tile's keep-mask is its window of
+    `dropout_keep_dense`'s bits, forward and backward."""
+    bq, bk = _TILED_BLOCKS[blocks]
+    rate, key = 0.1, jax.random.PRNGKey(21)
+    q, k, v = _two_widths(1, 2, 512, 64, 64, seed=4)
+    keep = flash_mod.dropout_keep_dense(
+        flash_mod._dropout_seed(rate, key), 1, 2, 512, 512, rate)
+
+    def dense(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 8.0
+        s = jnp.where(jnp.triu(jnp.ones((512, 512), bool), k=1), -1e30, s)
+        p = jnp.where(keep, jax.nn.softmax(s, axis=-1), 0.0) / (1.0 - rate)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+    got = _fwd_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, dropout_rate=rate, dropout_key=key,
+        block_q=bq, block_k=bk, use_pallas_override=True), q, k, v)
+    want = _fwd_and_grads(dense, q, k, v)
+    assert 0.05 < 1.0 - float(jnp.mean(keep)) < 0.15
+    for g, w, name in zip(got, want, ("o", "dq", "dk", "dv")):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def _kernel_texts(fn, *operands):
+    """name -> text of each pallas_call's kernel jaxpr in fn's jaxpr."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = str(eqn.params["jaxpr"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*operands).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("layout", ["head_major", "projection_layout"])
+def test_no_tile_above_the_diagonal_is_emitted(layout, small_tiles):
+    """One block a head of 512 in q windows of 128: the kernels are a
+    straight line of 4 tiles, a window's 128 queries against the 128,
+    256, 384 and 512 keys they see, each under its mask; the k rows
+    above the diagonal (6 of the 16 tiles of 128) are not in the
+    program and nothing branches.  Without a mask the program is one
+    tile, as it was."""
+    def traced(causal):
+        if layout == "head_major":
+            q = jnp.zeros((1, 2, 512, 64), jnp.float32)
+            return _kernel_texts(lambda q: jax.vjp(
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=causal, use_pallas_override=True),
+                q, q, q)[1](q), q)
+        x = jnp.zeros((512, 1, 3 * 2 * 64), jnp.float32)
+        return _kernel_texts(lambda x: jax.vjp(
+            lambda x: flash_attention_qkv(
+                x, 2, causal=causal, use_pallas_override=True),
+            x)[1](x[..., :128]), x)
+
+    tiled, whole = traced(True), traced(False)
+    assert set(tiled) == set(whole) == {"flash_fwd", "flash_bwd"}
+    # matmuls a tile: QK^T and P.V forward; QK^T, dO.V^T, dV, dK, dQ back
+    for name, per_tile in (("flash_fwd", 2), ("flash_bwd", 5)):
+        assert whole[name].count("dot_general") == per_tile
+        assert tiled[name].count("dot_general") == 4 * per_tile
+        assert "f32[512,512]" in whole[name]
+        # score tiles, (k rows, q rows)
+        shapes = re.findall(r"f32\[(\d+),128\] = dot_general", tiled[name])
+        assert set(shapes) >= {"128", "256", "384", "512"}
+        assert "f32[512,512]" not in tiled[name]
+        # the mask: one `where` a tile, and no branch picks tiles
+        assert tiled[name].count("name=_where") == 4
+        assert whole[name].count("name=_where") == 0
+        assert tiled[name].count("cond[") == whole[name].count("cond[")

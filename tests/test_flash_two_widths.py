@@ -84,48 +84,67 @@ def test_no_operand_is_padded_to_anothers_width():
     assert text.count("bf16[2,384,128]") >= 4
 
 
-# sha256 of str(jax.make_jaxpr(...)) at the parent of the PR that let v
-# have a width of its own (e1fe135), forward and backward in one jaxpr,
-# taken with this installation's JAX.  One width must trace to the same
-# text: the dense cells compile to the program they had.
+# sha256 of str(jax.make_jaxpr(...)), forward and backward in one jaxpr,
+# taken with this installation's JAX.  `head_packed_d64` is the causal
+# text of e1fe135, the parent of the PR that let v have a width of its
+# own: one width must trace to the same text.  The others were taken at
+# cd387b4, the parent of the PR that cut causal blocks into compute
+# tiles inside `_fwd_kernel` and `_bwd_fused_kernel`: that PR changes
+# what a causal call through those two bodies traces to, on purpose, so
+# what is pinned is what it must not touch.  Without a mask the three
+# paths through them trace to the program they had (the bypass); the
+# causal two-kernel backward, traced alone because its forward is
+# `_fwd_kernel`'s, and both directions of the causal head-packed
+# kernels are the parent's too.
 PARENT_JAXPR = {
-    "head_major_d64": "e596976624fe4190dd94e68a9b68981f03efd2a8a87538dd64608b"
-                      "c38ee8dc0e",
-    "head_major_d128": "96e1f1340e9b75f91f82b84ebcf133d88ef949847de044fcaa9851"
-                       "934b9f5abc",
+    "head_major_d64": "012f2236b9b727aea33ea42187a0fe3b872c4a2334a02b48a03533"
+                      "435be9a40a",
+    "head_major_d128": "7cb76e2b4ab1e00e2d3ffb608f4b1cd4e040e5956761db8dfad619"
+                       "557143133d",
     "head_packed_d64": "0ef7505eb21ef7dd161ebf6d0d6cf7194b402e2ea2d00b06022c99"
                        "302728a215",
-    "two_kernel_s8192": "802149358ac273491ad92e311e4b1721b54362a066d937136b76c"
-                        "0d7b347d32b",
-    "projection_layout": "fb5ef76b3a5ae158afc1239a09fc2967fcfcfbaa9857f52c43e5"
-                         "888d6f27f747",
+    "two_kernel_s8192": "f1cac71d58a9005ca8b36b3444f0f977971141217ce9d6103b2b9"
+                        "318dc8e31ab",
+    "projection_layout": "3136c875f3a2c3ddc23dcad97d4d756ca01b0ecbdac9f411225f"
+                         "47f8c6b5e353",
 }
 
 
-def _head_major_text(shape, **kw):
+def _head_major_text(shape, causal, **kw):
     q = jnp.zeros(shape, jnp.bfloat16)
     return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
         lambda q, k, v: FA.flash_attention(
-            q, k, v, causal=True, use_pallas_override=True, **kw),
+            q, k, v, causal=causal, use_pallas_override=True, **kw),
         q, k, v)[1](q))(q, q, q))
+
+
+def _two_kernel_backward_text():
+    """The causal backward of a sequence past the fused kernel's cap,
+    `flash_bwd_dq` and `flash_bwd_dkv`, without its forward."""
+    q = jnp.zeros((1, 2, 8192, 64), jnp.bfloat16)
+    lse = jnp.zeros((1, 2, 8192), jnp.float32)
+    text = str(jax.make_jaxpr(lambda q, k, v, o, lse, do: FA._bwd_impl(
+        q, k, v, o, lse, do, 0.125, True)[:3])(q, q, q, q, lse, q))
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    return text
 
 
 def _projection_text():
     x = jnp.zeros((256, 2, 3 * 4 * 64), jnp.bfloat16)
     return str(jax.make_jaxpr(lambda x: jax.vjp(
         lambda x: FA.flash_attention_qkv(
-            x, 4, causal=True, use_pallas_override=True),
+            x, 4, causal=False, use_pallas_override=True),
         x)[1](jnp.zeros((256, 2, 256), jnp.bfloat16)))(x))
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
                     reason="the recorded texts are JAX 0.9.0's")
 @pytest.mark.parametrize("case,trace", [
-    ("head_major_d64", lambda: _head_major_text((2, 4, 256, 64))),
-    ("head_major_d128", lambda: _head_major_text((1, 2, 512, 128))),
-    ("head_packed_d64", lambda: _head_major_text((2, 4, 256, 64),
+    ("head_major_d64", lambda: _head_major_text((2, 4, 256, 64), False)),
+    ("head_major_d128", lambda: _head_major_text((1, 2, 512, 128), False)),
+    ("head_packed_d64", lambda: _head_major_text((2, 4, 256, 64), True,
                                                  heads_per_step=2)),
-    ("two_kernel_s8192", lambda: _head_major_text((1, 2, 8192, 64))),
+    ("two_kernel_s8192", _two_kernel_backward_text),
     ("projection_layout", _projection_text),
 ])
 def test_one_width_traces_to_the_parents_jaxpr(case, trace):
