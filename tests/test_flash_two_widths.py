@@ -107,6 +107,18 @@ PARENT_JAXPR = {
                         "318dc8e31ab",
     "projection_layout": "3136c875f3a2c3ddc23dcad97d4d756ca01b0ecbdac9f411225f"
                          "47f8c6b5e353",
+    # the flash call of each benchmark cell, causal, bf16, taken at
+    # bec3780, the parent of the PR that deleted the head-packed kernels
+    # and moved a call's kernel shape into one function: every cell
+    # traces to the program it traced to
+    "cell_gpt2_medium_b12s1024": "1bf746fc42a63adbcdb9d538b02cec32278e7f2d2d43"
+                                 "c465cc8a6736412401ad",
+    "cell_gpt_1p3b_b7s512": "20435f492a50074803701a879ac7450bb28d1cabe93d13a6b"
+                            "6db8b721bf7007d",
+    "cell_gpt_1p3b_tp2dp2_b8s1024": "2a071ad78b67813b8f12a7ac3308b81b061511f2b"
+                                    "d5c35d18a79cfc02acf00be",
+    "cell_joyai_b2s4096_192_128": "6bf1eab9a8716d7ddb9c2aa6ceade2129e020b995ee"
+                                  "bb8c6a03424881a13c807",
 }
 
 
@@ -129,12 +141,29 @@ def _two_kernel_backward_text():
     return text
 
 
-def _projection_text():
-    x = jnp.zeros((256, 2, 3 * 4 * 64), jnp.bfloat16)
+def _projection_text(s=256, b=2, nh=4, causal=False):
+    x = jax.ShapeDtypeStruct((s, b, 3 * nh * 64), jnp.bfloat16)
     return str(jax.make_jaxpr(lambda x: jax.vjp(
         lambda x: FA.flash_attention_qkv(
-            x, 4, causal=False, use_pallas_override=True),
-        x)[1](jnp.zeros((256, 2, 256), jnp.bfloat16)))(x))
+            x, nh, causal=causal, use_pallas_override=True),
+        x)[1](jnp.zeros((s, b, nh * 64), jnp.bfloat16)))(x))
+
+
+def _latent_text():
+    """Cell 4's call: keys 192 and values 128 wide, head-major, under
+    the committed v5e config, handed over as `test_chip_compile.py`
+    hands it (the tuner sees a CPU here)."""
+    from apex_tpu.tune import defaults
+    key = tune.make_key("flash_sdpa", tune.flash_attrs(
+        2, 32, 4096, 4096, D_QK, "bfloat16", True, dv=D_V))
+    config = dict(defaults.DEFAULTS["v5e"][key]["config"])
+    config["fused_backward"] = config.pop("fused_bwd")
+    q = jax.ShapeDtypeStruct((2, 32, 4096, D_QK), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 32, 4096, D_V), jnp.bfloat16)
+    return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, use_pallas_override=True, **config),
+        q, k, v)[1](jnp.zeros(v.shape, v.dtype)))(q, q, v))
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
@@ -146,6 +175,11 @@ def _projection_text():
                                                  heads_per_step=2)),
     ("two_kernel_s8192", _two_kernel_backward_text),
     ("projection_layout", _projection_text),
+    ("cell_gpt2_medium_b12s1024", lambda: _projection_text(1024, 12, 16, True)),
+    ("cell_gpt_1p3b_b7s512", lambda: _projection_text(512, 7, 32, True)),
+    ("cell_gpt_1p3b_tp2dp2_b8s1024",
+     lambda: _projection_text(1024, 8, 16, True)),
+    ("cell_joyai_b2s4096_192_128", _latent_text),
 ])
 def test_one_width_traces_to_the_parents_jaxpr(case, trace):
     assert hashlib.sha256(trace().encode()).hexdigest() == PARENT_JAXPR[case]
