@@ -80,14 +80,6 @@ class GPTConfig:
     logits_dtype: Any = None
     sequence_parallel: bool = False
     use_flash_attention: bool = False
-    # Explicit flash kernel-shape overrides for A/B sweeps.  None (the
-    # default) lets the flash kernel consult the apex_tpu.tune cache at
-    # trace time for a config tuned at this exact (shape, dtype,
-    # device-kind) key, falling back to the built-in heuristics on a
-    # miss — so an untuned machine runs exactly the pre-tuner kernels.
-    attn_block_q: Any = None
-    attn_block_k: Any = None
-    attn_heads_per_step: Any = None
     # Chunked compute/collective overlap depth for the TP layers
     # (parallel/overlap.py) and the MoE micro-chunk exchange.  None =
     # tuner-owned (`overlap_chunks` op, heuristic 1 — the monolithic
@@ -241,9 +233,7 @@ class GPT:
             return flash_attention_qkv(
                 qkv, nh_local, causal=True,
                 softmax_scale=1.0 / math.sqrt(c.head_dim),
-                dropout_rate=rate, dropout_key=key if rate > 0 else None,
-                block_q=c.attn_block_q, block_k=c.attn_block_k,
-                heads_per_step=c.attn_heads_per_step)
+                dropout_rate=rate, dropout_key=key if rate > 0 else None)
         # one transpose of the PACKED tensor instead of three strided
         # slice+transpose copies (ops/fused_dense.qkv_split_heads)
         from apex_tpu.ops.fused_dense import qkv_split_heads

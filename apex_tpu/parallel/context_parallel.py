@@ -62,6 +62,7 @@ from apex_tpu.ops.flash_attention import (
     _NEG_INF,
     _bwd_impl,
     _fwd_impl,
+    _kernel_shape,
     _pick_block,
     dropout_keep_dense,
 )
@@ -177,12 +178,22 @@ def _chunk_bwd_jnp(q, k, v, do, lse, delta, scale, causal, q_seg, kv_seg,
     return dq, unblock(dk_b), unblock(dv_b)
 
 
+def _chunk_shape(q, k, v, causal, block_q, block_k):
+    """The kernel shape of one chunk against one chunk: the single-chip
+    kernels' own decision at the ring's blocks, without a tuner lookup
+    (no sweep ran at a chunk's shape).  A function of the chunk's shape
+    alone, so a ring step's backward rebuilds its forward's."""
+    return _kernel_shape(q.shape[2], k.shape[2], q.shape[3], v.shape[3],
+                         q.dtype, causal, block_q=block_q, block_k=block_k)
+
+
 def _chunk_fwd(q, k, v, scale, causal, q_seg, kv_seg, block_q, block_k,
                pallas_path, dropout_rate=0.0, seed=None, q_off=0,
                k_off=0):
     if pallas_path:
-        return _fwd_impl(q, k, v, scale, causal, dropout_rate, seed,
-                         block_q, block_k, None, q_seg, kv_seg,
+        return _fwd_impl(q, k, v, scale, causal,
+                         _chunk_shape(q, k, v, causal, block_q, block_k),
+                         dropout_rate, seed, None, q_seg, kv_seg,
                          q_off=q_off, k_off=k_off)
     return _chunk_fwd_jnp(q, k, v, scale, causal, q_seg, kv_seg, block_k,
                           dropout_rate, seed, q_off, k_off)
@@ -196,11 +207,11 @@ def _chunk_bwd(q, k, v, o, lse, delta, do, scale, causal, q_seg, kv_seg,
         # accumulate across hops at full precision and round to the
         # input dtype ONCE at the end (ADVICE r4 — bf16-per-hop rounding
         # degraded with ring size)
-        dq, dk, dv, _ = _bwd_impl(q, k, v, o, lse, do, scale, causal,
-                                  dropout_rate, seed, block_q, block_k,
-                                  None, q_seg, kv_seg,
-                                  grad_dtype=jnp.float32,
-                                  q_off=q_off, k_off=k_off)
+        dq, dk, dv, _ = _bwd_impl(
+            q, k, v, o, lse, do, scale, causal,
+            _chunk_shape(q, k, v, causal, block_q, block_k),
+            dropout_rate, seed, None, q_seg, kv_seg,
+            grad_dtype=jnp.float32, q_off=q_off, k_off=k_off)
         return dq, dk, dv
     return _chunk_bwd_jnp(q, k, v, do, lse, delta, scale, causal,
                           q_seg, kv_seg, block_k, dropout_rate, seed,

@@ -16,11 +16,11 @@ Three pieces:
               step): times candidate configs wall-clock and records the
               winners.  `scripts/gpt_anatomy.py tune` is the CLI.
 
-Tunable surfaces wired in this round: flash attention block_q/block_k +
-heads_per_step head packing (ops/flash_attention.py), the softmax and
+Tunable surfaces wired in this round: flash attention block_q/block_k
+and fused_bwd (ops/flash_attention.py::_kernel_shape), the softmax and
 layer-norm row blocks (via ops._common.tuned_row_block), the flat
 optimizer kernels' rows-per-block (ops/optimizer_kernels.py), and the
-serving path (ISSUE 8): `flash_decode` heads_per_step (key:
+serving path (ISSUE 8): `flash_decode`'s kv-head packing factor (key:
 decode_attrs) and the paged KV cache's page size (`serve_page`, key:
 serve_page_attrs — the page IS the decode kernel's kv block, so the
 one knob tunes both the DMA unit and the pool granularity), and the

@@ -61,28 +61,16 @@ def forced(op: str, attrs: Dict[str, Any], config: Dict[str, Any]):
 
 # ------------------------------ flash attention -----------------------------
 
-def flash_candidates(h: int, sq: int, sk: int,
+def flash_candidates(sq: int, sk: int,
                      max_score_elems: int = 512 * 1024
                      ) -> List[Dict[str, int]]:
-    """Candidate (block_q, block_k, heads_per_step) grid: blocks divide
-    the sequence, packing divides the head count, and the packed fp32
-    score tile (hp·bk·bq) stays within ~2 MB of VMEM."""
-    blocks = [b for b in (128, 256, 512, 1024)]
-    out = []
-    for hp in (1, 2, 4, 8):
-        if h % hp:
-            continue
-        for bq in blocks:
-            if sq % bq:
-                continue
-            for bk in blocks:
-                if sk % bk:
-                    continue
-                if hp * bq * bk > max_score_elems:
-                    continue
-                out.append({"block_q": bq, "block_k": bk,
-                            "heads_per_step": hp})
-    return out
+    """Candidate (block_q, block_k) grid: blocks divide the sequence
+    and the fp32 score block (bk·bq) stays within ~2 MB of VMEM."""
+    blocks = (128, 256, 512, 1024)
+    return [{"block_q": bq, "block_k": bk}
+            for bq in blocks if sq % bq == 0
+            for bk in blocks if sk % bk == 0
+            and bq * bk <= max_score_elems]
 
 
 def flash_attrs(b, h, s, d, dtype, causal, bias="none", seg=False):
@@ -112,13 +100,12 @@ def tune_flash(b: int, h: int, s: int, d: int, *, dtype=None,
     seg_ids = (jnp.zeros((b, s), jnp.int32) if seg else None)
 
     results = []
-    for cand in flash_candidates(h, s, s):
+    for cand in flash_candidates(s, s):
         def fb(q, k, v, cand=cand):
             def f(q, k, v):
                 return flash_attention(
                     q, k, v, causal=causal, segment_ids=seg_ids,
                     block_q=cand["block_q"], block_k=cand["block_k"],
-                    heads_per_step=cand["heads_per_step"],
                     use_pallas_override=use_pallas_override)
             out, vjp = jax.vjp(f, q, k, v)
             return (out,) + vjp(out)

@@ -372,17 +372,6 @@ def decode_case(name, n_slots, heads, head_dim, page, pages_per_slot
         lambda *a: (paged_attention_reference(*a),), 5e-2, 5e-2)
 
 
-PACKED_FLASH_SHAPE = (8, 16, 2048, 64)   # a key of tune/defaults.py on v5e
-
-
-def packed_flash_attrs() -> dict:
-    """The tuner's lookup attrs for flash attention at PACKED_FLASH_SHAPE."""
-    from apex_tpu import tune
-
-    b, h, s, d = PACKED_FLASH_SHAPE
-    return tune.flash_attrs(b, h, s, s, d, "bfloat16", True)
-
-
 def flagship_config(**overrides):
     """GPT-350M as bench.py trains it."""
     import jax.numpy as jnp
@@ -405,7 +394,8 @@ def kernel_cases(device) -> list:
     return [
         flash_case("flash_350m", (BATCH, h, SEQ, d)),
         flash_qkv_case("flash_qkv_350m", SEQ, BATCH, h, d),
-        flash_case("flash_packed", PACKED_FLASH_SHAPE),
+        # head-major at 64 wide with several blocks on the causal grid
+        flash_case("flash_d64_s2048", (8, 16, 2048, 64)),
         # latent attention: keys 192 wide, values 128 (models/mla_moe.py)
         flash_case("flash_mla_192_128", (2, 32, 4096, 192), v_dim=128),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
@@ -470,17 +460,11 @@ def run_kernel_case(case: KernelCase, seed: int, require_chip: bool) -> dict:
 
 
 def phase_kernels(device, seed: int) -> None:
-    from apex_tpu import tune
     from apex_tpu.ops._common import pallas_interpret
 
     if pallas_interpret():
         raise RuntimeError("pallas_interpret() is true on the chip: the "
                            "kernels would run interpreted, not compiled")
-    packed = tune.tuned("flash_sdpa", packed_flash_attrs())
-    if not packed or packed.get("heads_per_step", 1) < 2:
-        raise RuntimeError(
-            f"the tuner gives {packed!r} at {PACKED_FLASH_SHAPE}, not the "
-            "head-packed config tune/defaults.py commits for v5e")
     for case in kernel_cases(device):
         emit(phase="kernels", **run_kernel_case(case, seed,
                                                 require_chip=True))

@@ -241,7 +241,7 @@ def _gemm_row(label, m, k, n, per_layer=1):
 
 
 def _flash_row(batch, heads, seq, d, causal, block_q=None, block_k=None,
-               heads_per_step=None, label="flash sdpa (7 mm)"):
+               label="flash sdpa (7 mm)"):
     """The attention kernel as a 7-matmul mix: fwd S=QKᵀ + O=PV, bwd
     recompute-S + dP=dO·Vᵀ + dQ + dK + dV.  Three of the seven contract
     over d; the single-block causal config at seq ≤ 1024 executes the
@@ -254,8 +254,7 @@ def _flash_row(batch, heads, seq, d, causal, block_q=None, block_k=None,
     q, k, v = (jax.random.normal(kk, (batch, heads, seq, d), jnp.bfloat16)
                for kk in keys)
     attn = functools.partial(flash_attention, causal=causal,
-                             block_q=block_q, block_k=block_k,
-                             heads_per_step=heads_per_step)
+                             block_q=block_q, block_k=block_k)
 
     def fb(q, k, v):
         out, vjp = jax.vjp(attn, q, k, v)
@@ -289,8 +288,7 @@ def gemm_roofline(name, hidden, layers, heads, batch, seq, vocab=50304,
                      tune.flash_attrs(batch, heads, seq, seq, d,
                                       "bfloat16", causal))
     flabel = ("flash sdpa (7 mm)" if not cfg else
-              f"flash tuned q{cfg.get('block_q')}k{cfg.get('block_k')}"
-              f"hp{cfg.get('heads_per_step', 1)}")
+              f"flash tuned q{cfg.get('block_q')}k{cfg.get('block_k')}")
     _flash_row(batch, heads, seq, d, causal, label=flabel)
     _gemm_row("attn_out (M,H)x(H,H)", m_rows, hidden, hidden)
     _gemm_row("mlp_up (M,H)x(H,4H)", m_rows, hidden, 4 * hidden)
@@ -323,21 +321,18 @@ def gemm_roofline(name, hidden, layers, heads, batch, seq, vocab=50304,
 
 
 def flash_block_sweep(batch=32, heads=16, seq=512, d=64, causal=False):
-    """Flash block+packing re-sweep at seq 512 (the BERT/1.3B shape; the
-    round-4 sweep only covered seq 1024 and predates head packing)."""
+    """Flash block re-sweep at seq 512 (the BERT/1.3B shape; the
+    round-4 sweep only covered seq 1024)."""
     print(f"--- flash blocks @ b{batch} H{heads} s{seq} d{d} "
           f"causal={causal}", flush=True)
-    for bq, bk, hp in ((None, None, None), (512, 512, 1), (256, 512, 1),
-                       (512, 256, 1), (256, 256, 1), (512, 512, 2),
-                       (256, 512, 2), (512, 256, 4), (256, 256, 4)):
+    for bq, bk in ((None, None), (512, 512), (256, 512), (512, 256),
+                   (256, 256)):
         try:
             t, _, _ = _flash_row(batch, heads, seq, d, causal,
                                  block_q=bq, block_k=bk,
-                                 heads_per_step=hp,
-                                 label=f"blocks ({bq},{bk})x{hp}")
+                                 label=f"blocks ({bq},{bk})")
         except Exception as e:
-            print(f"blocks ({bq},{bk})x{hp}: FAIL {repr(e)[:80]}",
-                  flush=True)
+            print(f"blocks ({bq},{bk}): FAIL {repr(e)[:80]}", flush=True)
 
 
 def _parse_key_attrs(key):
@@ -367,7 +362,8 @@ def _check_committed(committed):
         want = entry.get("config")
         try:
             if op == "flash_sdpa":
-                if a.get("bias", "none") != "none" or a["sq"] != a["sk"]:
+                if (a.get("bias", "none") != "none" or a["sq"] != a["sk"]
+                        or "dv" in a):
                     print(f"  --check: cannot sweep {key} (unsupported "
                           "key shape); skipping", flush=True)
                     continue

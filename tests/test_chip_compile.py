@@ -91,18 +91,10 @@ def _sds(shape, dtype):
 
 def _smoke_case(name, device):
     (case,) = [c for c in chip_smoke.kernel_cases(device) if c.name == name]
-    if name == "flash_packed":
-        # on the chip the tuner picks this; here it sees a CPU, so the
-        # test hands the committed v5e config over itself
-        from apex_tpu import tune
-        from apex_tpu.tune import defaults
-        key = tune.make_key("flash_sdpa", chip_smoke.packed_flash_attrs())
-        config = defaults.DEFAULTS["v5e"][key]["config"]
-        assert config["heads_per_step"] > 1
-        case = chip_smoke.flash_case(name, chip_smoke.PACKED_FLASH_SHAPE,
-                                     config)
     if name == "flash_mla_192_128":
-        # likewise: the single-pass backward at (1024, 512) blocks
+        # on the chip the tuner picks the single-pass backward at
+        # (1024, 512) blocks; here it sees a CPU, so the test hands the
+        # committed v5e config over itself
         from apex_tpu import tune
         from apex_tpu.tune import defaults
         key = tune.make_key("flash_sdpa", tune.flash_attrs(
@@ -116,7 +108,7 @@ def _smoke_case(name, device):
 
 
 @pytest.mark.parametrize("name,min_kernels", [
-    ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_packed", 2),
+    ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_d64_s2048", 2),
     ("flash_mla_192_128", 2), ("moe_held_experts", 6),
     ("adam_flat_fp32", 1),
     ("adam_flat_bf16", 1), ("xent_pallas", 2), ("xent_vocab_parallel", 0),

@@ -354,10 +354,10 @@ def test_flash_in_kernel_dropout_mask_consistency():
     vv = jax.random.normal(jax.random.PRNGKey(2), (B, H, S, D))
     cc = jax.random.normal(jax.random.PRNGKey(3), (B, H, S, D))
     seed = jnp.asarray([[777]], jnp.int32)
-    # (bias, q_seg, kv_seg, scale, causal, rate, block_q, block_k,
-    #  heads_per_step, bias_grad, seed)
-    args = (None, None, None, 0.18, True, 0.2, None, None, 1, False,
-            seed)
+    from apex_tpu.ops.flash_attention import _kernel_shape
+    shape = _kernel_shape(S, S, D, D, qq.dtype, True)
+    # (bias, q_seg, kv_seg, scale, causal, rate, shape, bias_grad, seed)
+    args = (None, None, None, 0.18, True, 0.2, shape, False, seed)
     o1 = np.asarray(_flash(qq, kk, vv, *args))
     o2 = np.asarray(_flash(qq, kk, vv, *args))
     np.testing.assert_array_equal(o1, o2)
@@ -373,8 +373,7 @@ def test_flash_in_kernel_dropout_mask_consistency():
 
     # keep-rate statistic ~ 1 - rate
     p_nodrop = np.asarray(_flash(
-        qq, kk, vv, None, None, None, 0.18, True, 0.0, None, None, 1,
-        False, seed))
+        qq, kk, vv, None, None, None, 0.18, True, 0.0, shape, False, seed))
     assert not np.allclose(o1, p_nodrop)
 
 
@@ -720,3 +719,93 @@ def test_no_tile_above_the_diagonal_is_emitted(layout, small_tiles):
         assert tiled[name].count("name=_where") == 4
         assert whole[name].count("name=_where") == 0
         assert tiled[name].count("cond[") == whole[name].count("cond[")
+
+
+# ------------------- the kernel shape of a call, decided once ---------------
+#
+# `_kernel_shape` is the one place the blocks, the causal compute tile of
+# each direction and the kind of backward are chosen; the forward and
+# the backward of a call are built from the one value it returns.  The
+# values below are what the kernels of each benchmark cell ran with
+# before the decision had one place (PERF.md, PR 29's lines), and the
+# two corners no cell reaches.
+
+# layout, its shape, dtype -> (bq, bk, tile_fwd, tile_bwd, fused_bwd);
+# "qkv": (S, B, heads, d) of the projection; "bhsd": (B, heads, S, d, dv)
+_DECISIONS = {
+    "gpt2_medium_b12s1024": ("qkv", (1024, 12, 16, 64), jnp.bfloat16,
+                             (1024, 1024, 512, 256, True)),
+    "gpt_1p3b_b7s512": ("qkv", (512, 7, 32, 64), jnp.bfloat16,
+                        (512, 512, 512, 256, True)),
+    "gpt_1p3b_tp2dp2_b8s1024": ("qkv", (1024, 8, 16, 64), jnp.bfloat16,
+                                (1024, 1024, 512, 256, True)),
+    # the committed v5e entry: (1024, 512) and the single pass, where
+    # the heuristics say (512, 1024) and the cap says two kernels
+    "joyai_b2s4096_192_128": ("bhsd", (2, 32, 4096, 192, 128), jnp.bfloat16,
+                              (1024, 512, 512, 256, True)),
+    # past the cap: two kernels, each computing a block in one piece
+    "past_the_cap_s8192": ("bhsd", (1, 2, 8192, 64, 64), jnp.bfloat16,
+                           (512, 1024, 512, 512, False)),
+    # a 128-lane fp32 block is twice a bf16 one: the q block is halved
+    "fp32_projection_s1024": ("qkv", (1024, 2, 4, 64), jnp.float32,
+                              (512, 1024, 512, 256, True)),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECISIONS))
+def test_the_kernel_shape_is_decided_once_a_call(case, monkeypatch,
+                                                 tmp_path):
+    from apex_tpu import tune
+
+    layout, dims, dtype, want = _DECISIONS[case]
+    # the tuner as a v5e sees it: the committed defaults, no user file
+    monkeypatch.setenv(tune.ENV_CACHE_PATH, str(tmp_path / "tune.json"))
+    monkeypatch.setattr(tune.cache, "device_kind", lambda: "v5e")
+    tune.invalidate()
+    tune.reset_stats()
+    decided, built = [], []
+    decide, count = flash_mod._kernel_shape, flash_mod._count_scores
+
+    def deciding(*a, **kw):
+        decided.append(decide(*a, **kw))
+        return decided[-1]
+
+    def counting(heads, sq, sk, bq, bk, causal, tile, passes=1):
+        built.append((bq, bk, tile, passes))
+        return count(heads, sq, sk, bq, bk, causal, tile, passes)
+
+    monkeypatch.setattr(flash_mod, "_kernel_shape", deciding)
+    monkeypatch.setattr(flash_mod, "_count_scores", counting)
+    if layout == "qkv":
+        s, b, nh, d = dims
+        operands = [jax.ShapeDtypeStruct((s, b, 3 * nh * d), dtype)]
+
+        def attn(x):
+            return flash_attention_qkv(x, nh, causal=True,
+                                       use_pallas_override=True)
+    else:
+        b, nh, s, d, dv = dims
+        qk = jax.ShapeDtypeStruct((b, nh, s, d), dtype)
+        operands = [qk, qk, jax.ShapeDtypeStruct((b, nh, s, dv), dtype)]
+
+        def attn(q, k, v):
+            return flash_attention(q, k, v, causal=True,
+                                   use_pallas_override=True)
+
+    def fwd_bwd(*xs):
+        out, vjp = jax.vjp(attn, *xs)
+        return vjp(out)
+
+    try:
+        jax.eval_shape(fwd_bwd, *operands)
+    finally:
+        tune.invalidate()
+    # one decision and one tuner lookup a public call, and the forward
+    # and the backward were both built from it
+    assert decided == [want]
+    lookups = tune.stats()
+    assert lookups["hits"] + lookups["misses"] == 1
+    assert lookups["hits"] == (case == "joyai_b2s4096_192_128")
+    bq, bk, tile_fwd, tile_bwd, fused = want
+    assert built == [(bq, bk, tile_fwd, 1),
+                     (bq, bk, tile_bwd, 1 if fused else 2)]

@@ -30,22 +30,17 @@ def _fwd_bwd(attn, q, k, v, do):
     return (out,) + vjp(do)
 
 
-# the fused single-pass backward, the two-kernel backward (the path
-# 4096 x 192 takes: a whole head's dk and dv sums pass the fused
-# kernel's VMEM cap) and the head-packed kernels
-@pytest.mark.parametrize("path,cap,kw", [
-    ("fused", None, {}),
-    ("two_kernel", 1, {}),
-    ("packed", None, {"heads_per_step": 2}),
-])
+# the fused single-pass backward and the two-kernel backward (the path
+# 4096 x 192 takes by the cap: a whole head's dk and dv sums pass it)
+@pytest.mark.parametrize("path,cap", [("fused", None), ("two_kernel", 1)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_two_widths_match_the_reference(monkeypatch, path, cap, kw, causal):
+def test_two_widths_match_the_reference(monkeypatch, path, cap, causal):
     if cap is not None:
         monkeypatch.setattr(FA, "_FUSED_BWD_CAP", cap)
     q, k, v, do = _qkvdo(1, 2, 256, jnp.float32)
     got = _fwd_bwd(lambda q, k, v: FA.flash_attention(
         q, k, v, causal=causal, use_pallas_override=True, block_q=128,
-        block_k=128, **kw), q, k, v, do)
+        block_k=128), q, k, v, do)
     want = _fwd_bwd(lambda q, k, v: FA.attention_reference(
         q, k, v, causal=causal), q, k, v, do)
     # o and dv have v's width, dq and dk the keys'
@@ -85,24 +80,19 @@ def test_no_operand_is_padded_to_anothers_width():
 
 
 # sha256 of str(jax.make_jaxpr(...)), forward and backward in one jaxpr,
-# taken with this installation's JAX.  `head_packed_d64` is the causal
-# text of e1fe135, the parent of the PR that let v have a width of its
-# own: one width must trace to the same text.  The others were taken at
+# taken with this installation's JAX.  The first four were taken at
 # cd387b4, the parent of the PR that cut causal blocks into compute
 # tiles inside `_fwd_kernel` and `_bwd_fused_kernel`: that PR changes
 # what a causal call through those two bodies traces to, on purpose, so
 # what is pinned is what it must not touch.  Without a mask the three
-# paths through them trace to the program they had (the bypass); the
-# causal two-kernel backward, traced alone because its forward is
-# `_fwd_kernel`'s, and both directions of the causal head-packed
-# kernels are the parent's too.
+# paths through them trace to the program they had (the bypass), and so
+# does the causal two-kernel backward, traced alone because its forward
+# is `_fwd_kernel`'s.
 PARENT_JAXPR = {
     "head_major_d64": "012f2236b9b727aea33ea42187a0fe3b872c4a2334a02b48a03533"
                       "435be9a40a",
     "head_major_d128": "7cb76e2b4ab1e00e2d3ffb608f4b1cd4e040e5956761db8dfad619"
                        "557143133d",
-    "head_packed_d64": "0ef7505eb21ef7dd161ebf6d0d6cf7194b402e2ea2d00b06022c99"
-                       "302728a215",
     "two_kernel_s8192": "f1cac71d58a9005ca8b36b3444f0f977971141217ce9d6103b2b9"
                         "318dc8e31ab",
     "projection_layout": "3136c875f3a2c3ddc23dcad97d4d756ca01b0ecbdac9f411225f"
@@ -135,8 +125,10 @@ def _two_kernel_backward_text():
     `flash_bwd_dq` and `flash_bwd_dkv`, without its forward."""
     q = jnp.zeros((1, 2, 8192, 64), jnp.bfloat16)
     lse = jnp.zeros((1, 2, 8192), jnp.float32)
+    shape = FA._kernel_shape(8192, 8192, 64, 64, q.dtype, True)
+    assert not shape.fused_bwd
     text = str(jax.make_jaxpr(lambda q, k, v, o, lse, do: FA._bwd_impl(
-        q, k, v, o, lse, do, 0.125, True)[:3])(q, q, q, q, lse, q))
+        q, k, v, o, lse, do, 0.125, True, shape)[:3])(q, q, q, q, lse, q))
     assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
     return text
 
@@ -171,8 +163,6 @@ def _latent_text():
 @pytest.mark.parametrize("case,trace", [
     ("head_major_d64", lambda: _head_major_text((2, 4, 256, 64), False)),
     ("head_major_d128", lambda: _head_major_text((1, 2, 512, 128), False)),
-    ("head_packed_d64", lambda: _head_major_text((2, 4, 256, 64), True,
-                                                 heads_per_step=2)),
     ("two_kernel_s8192", _two_kernel_backward_text),
     ("projection_layout", _projection_text),
     ("cell_gpt2_medium_b12s1024", lambda: _projection_text(1024, 12, 16, True)),
@@ -240,11 +230,14 @@ def test_the_committed_v5e_config_for_latent_attention(monkeypatch):
     config = defaults.DEFAULTS["v5e"][key]["config"]
     assert config == {"block_q": 1024, "block_k": 512, "fused_bwd": True}
     # FA's validation of a cache hit lets it through, and not a
-    # `fused_bwd` that is no bool
+    # `fused_bwd` that is no bool: then the heuristics' blocks and the
+    # two kernels the cap sends 4096 x 192 / 128 to
     monkeypatch.setattr(tune, "tuned", lambda op, attrs: config)
-    args = (2, 32, 4096, 4096, D_QK, jnp.bfloat16, True, "none", False)
-    assert FA._tuned_flash_config(*args, dv=D_V) == config
+    args = (4096, 4096, D_QK, D_V, jnp.bfloat16, True)
+    assert FA._kernel_shape(*args, tuner_key=(2, 32, False)) == (
+        1024, 512, 512, 256, True)
     monkeypatch.setattr(tune, "tuned",
                         lambda op, attrs: dict(config, fused_bwd="yes"))
     with pytest.warns(UserWarning, match="out-of-range tuned config"):
-        assert FA._tuned_flash_config(*args, dv=D_V) is None
+        assert FA._kernel_shape(*args, tuner_key=(2, 32, False)) == (
+            512, 1024, 512, 512, False)

@@ -189,7 +189,7 @@ def _with_kernels(monkeypatch, in_place):
     from apex_tpu.ops import flash_attention as flash_mod
     monkeypatch.setattr(flash_mod, "use_pallas", lambda override=None: True)
     if not in_place:
-        monkeypatch.setattr(flash_mod, "_FUSED_BWD_CAP_PACKED", 0)
+        monkeypatch.setattr(flash_mod, "_QKV_LAYOUT_SEQ_CAP", 0)
     flash_mod.reset_stats()
     return flash_mod
 

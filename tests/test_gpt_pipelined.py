@@ -225,7 +225,7 @@ def test_pipelined_flash_in_projection_layout(tp, sp, monkeypatch):
 
     (loss, grads), took = run()
     assert took["projection_layout"] > 0 and took["head_major"] == 0
-    monkeypatch.setattr(flash_mod, "_FUSED_BWD_CAP_PACKED", 0)
+    monkeypatch.setattr(flash_mod, "_QKV_LAYOUT_SEQ_CAP", 0)
     (want_loss, want), took = run()
     assert took["projection_layout"] == 0 and took["head_major"] > 0
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)

@@ -1,11 +1,12 @@
-"""Kernel autotuner (apex_tpu.tune) + head-packed flash attention.
+"""Kernel autotuner (apex_tpu.tune) and the flash kernels it tunes.
 
-ISSUE 3 coverage: cache round-trip, corrupt/missing cache → heuristic
-fallback (deterministically), device-kind isolation, empty-cache
-byte-identity, and head-packed flash parity vs the unpacked kernel
-(bitwise) and the fp64 oracle across causal × bias × segment ids."""
+Cache round-trip, corrupt/missing cache → heuristic fallback
+(deterministically), device-kind isolation, empty-cache byte-identity,
+an entry swept for a kernel that is gone, and the multi-block flash
+kernels against the fp64 oracle across causal × bias × segment ids."""
 
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,7 @@ def tmp_cache(tmp_path, monkeypatch):
 def test_cache_roundtrip(tmp_cache):
     attrs = dict(b=2, h=4, sq=64, sk=64, d=16, dtype="float32",
                  causal=True, bias="none", seg=False)
-    cfg = {"block_q": 32, "block_k": 32, "heads_per_step": 2}
+    cfg = {"block_q": 32, "block_k": 32, "fused_bwd": True}
     tune.record("flash_sdpa", attrs, cfg, meta={"ms": 1.0})
     # reload from disk (invalidate drops the memo)
     tune.invalidate()
@@ -110,44 +111,85 @@ def test_empty_cache_matches_explicit_heuristics(tmp_cache):
     auto = flash_attention(q, k, v, causal=True, use_pallas_override=True)
     explicit = flash_attention(q, k, v, causal=True,
                                use_pallas_override=True,
-                               block_q=64, block_k=64, heads_per_step=1)
+                               block_q=64, block_k=64)
     assert np.array_equal(np.asarray(auto), np.asarray(explicit))
+
+
+def _traced_blocks(fn, q, k, v):
+    """The (bq, bk) of the forward kernel `fn` traces, read off its
+    grid, (batch * heads, q blocks, k blocks)."""
+    (grid,) = re.findall(r"grid=\((\d+), (\d+), (\d+)\)",
+                         str(jax.make_jaxpr(fn)(q, k, v)))
+    return q.shape[2] // int(grid[1]), k.shape[2] // int(grid[2])
 
 
 def test_tuned_flash_entry_is_picked_up(tmp_cache):
     """A recorded entry for the current (cpu) kind drives the default
-    path — observable via the hit counter — and stays correct."""
+    path — observable via the hit counter and the traced kernel's
+    blocks — and stays correct."""
     b, h, s, d = 1, 4, 64, 16
     q, k, v = _qkv(b, h, s, d)
     attrs = dict(b=b, h=h, sq=s, sk=s, d=d, dtype="float32",
                  causal=True, bias="none", seg=False)
-    tune.record("flash_sdpa", attrs,
-                {"block_q": 32, "block_k": 32, "heads_per_step": 2})
+    tune.record("flash_sdpa", attrs, {"block_q": 32, "block_k": 32})
     tune.reset_stats()
-    out = flash_attention(q, k, v, causal=True, use_pallas_override=True)
-    assert tune.stats()["hits"] >= 1
+
+    def attn(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               use_pallas_override=True)
+
+    out = attn(q, k, v)
+    assert tune.stats()["hits"] == 1
+    assert _traced_blocks(attn, q, k, v) == (32, 32)
     want = attention_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_tuned_invalid_heads_per_step_degrades(tmp_cache):
-    """A stale tuned hp that doesn't divide the head count must degrade
-    to the unpacked kernel (warn once), not fail."""
-    b, h, s, d = 1, 3, 64, 16
+def test_an_entry_swept_for_the_head_packed_kernel_is_ignored(tmp_cache):
+    """A user's cache file outlives the kernels it was swept for: a
+    `flash_sdpa` entry that still carries a packing factor above 1 was
+    the optimum of the head-packed kernel, which is gone, so the whole
+    entry is ignored (one warning, no error) and the call takes the
+    heuristics' blocks; at a factor of 1 the sweep chose these kernels
+    and its blocks hold."""
+    import warnings
+
+    import apex_tpu.ops.flash_attention as fa
+
+    fa._BLOCK_FALLBACK_WARNED.clear()
+    b, h, s, d = 1, 4, 64, 16
     q, k, v = _qkv(b, h, s, d, seed=5)
     attrs = dict(b=b, h=h, sq=s, sk=s, d=d, dtype="float32",
                  causal=False, bias="none", seg=False)
-    tune.record("flash_sdpa", attrs,
-                {"block_q": 64, "block_k": 64, "heads_per_step": 4})
-    with pytest.warns(UserWarning, match="heads_per_step"):
-        out = flash_attention(q, k, v, use_pallas_override=True)
-    want = attention_reference(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+    tmp_cache.write_text(json.dumps({
+        "schema": tune.SCHEMA_VERSION,
+        "entries": {tune.device_kind(): {
+            tune.make_key("flash_sdpa", attrs): {"config": {
+                "block_q": 32, "block_k": 32, "heads_per_step": 2}},
+            tune.make_key("flash_sdpa", dict(attrs, causal=True)): {
+                "config": {"block_q": 32, "block_k": 16,
+                           "heads_per_step": 1}}}}}))
+    tune.invalidate()
+
+    def attn(q, k, v, causal=False):
+        return flash_attention(q, k, v, causal=causal,
+                               use_pallas_override=True)
+
+    with pytest.warns(UserWarning, match="out-of-range tuned config") as w:
+        out = attn(q, k, v)
+    assert len(w) == 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # warned once: now silent
+        assert _traced_blocks(attn, q, k, v) == (64, 64)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(attention_reference(q, k, v)),
                                rtol=1e-4, atol=1e-4)
+    assert _traced_blocks(
+        lambda *a: attn(*a, causal=True), q, k, v) == (32, 16)
 
 
-# ----------------------- head-packed flash attention ------------------------
+# ------------------- the multi-block kernels vs the oracle -------------------
 
 def _oracle64(q, k, v, **kw):
     """TRUE fp64 reference (the satellite's oracle) — the conftest
@@ -163,8 +205,11 @@ def _oracle64(q, k, v, **kw):
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("bias_kind", ["none", "sk", "full"])
 @pytest.mark.parametrize("seg", [False, True])
-def test_packed_matches_unpacked_and_oracle(causal, bias_kind, seg,
-                                            tmp_cache):
+def test_multi_block_matches_oracle(causal, bias_kind, seg, tmp_cache):
+    """Four (32, 32) blocks a head against the fp64 oracle: the
+    forward everywhere, the gradients on the simplest and the fullest
+    combination (the kernels' own parity on the rest lives in
+    test_flash_attention.py)."""
     b, h, s, d = 2, 4, 64, 16
     q, k, v = _qkv(b, h, s, d, seed=7)
     ks = jax.random.split(jax.random.PRNGKey(11), 2)
@@ -180,93 +225,44 @@ def test_packed_matches_unpacked_and_oracle(causal, bias_kind, seg,
 
     kw = dict(causal=causal, bias=bias, segment_ids=seg_ids,
               use_pallas_override=True, block_q=32, block_k=32)
-    un = flash_attention(q, k, v, heads_per_step=1, **kw)
-    pk = flash_attention(q, k, v, heads_per_step=2, **kw)
-    # bit parity at identical blocks (acceptance criterion)
-    assert np.array_equal(np.asarray(un), np.asarray(pk)), (
-        "packed forward is not bit-identical to unpacked")
+    got = flash_attention(q, k, v, **kw)
     want = _oracle64(q, k, v, causal=causal, bias=bias,
                      q_segment_ids=seg_ids, kv_segment_ids=seg_ids)
-    assert np.abs(np.asarray(pk, np.float64) - want).max() < 1e-5
+    assert np.abs(np.asarray(got, np.float64) - want).max() < 1e-5
 
-    # grads: packed vs unpacked bitwise, packed vs fp64 oracle loose
-    def loss(f, hp):
-        def inner(q, k, v):
-            return jnp.sum(jnp.sin(f(q, k, v, heads_per_step=hp, **kw)))
-        return inner
+    if (causal, bias_kind, seg) not in ((False, "none", False),
+                                        (True, "full", True)):
+        return
+    grads = jax.grad(
+        lambda q, k, v: jnp.sum(jnp.sin(flash_attention(q, k, v, **kw))),
+        argnums=(0, 1, 2))(q, k, v)
+    with jax.enable_x64():
+        def loss64(q, k, v):
+            out = attention_reference(q, k, v, causal=causal,
+                                      bias=None if bias is None
+                                      else bias.astype(jnp.float64),
+                                      q_segment_ids=seg_ids,
+                                      kv_segment_ids=seg_ids)
+            return jnp.sum(jnp.sin(out))
 
-    g_un = jax.grad(loss(flash_attention, 1), argnums=(0, 1, 2))(q, k, v)
-    g_pk = jax.grad(loss(flash_attention, 2), argnums=(0, 1, 2))(q, k, v)
-    for a, e, name in zip(g_pk, g_un, "qkv"):
-        assert np.array_equal(np.asarray(a), np.asarray(e)), (
-            f"packed d{name} not bit-identical to unpacked")
-
-    # oracle-grad cross-check on the simplest and the fullest combo
-    # only (the bitwise identity above covers the rest; the unpacked
-    # kernel's own oracle parity lives in test_flash_attention.py)
-    if (causal, bias_kind, seg) in ((False, "none", False),
-                                    (True, "full", True)):
-        with jax.enable_x64():
-            def loss64(q, k, v):
-                out = attention_reference(q, k, v, causal=causal,
-                                          bias=None if bias is None
-                                          else bias.astype(jnp.float64),
-                                          q_segment_ids=seg_ids,
-                                          kv_segment_ids=seg_ids)
-                return jnp.sum(jnp.sin(out))
-
-            g_or = jax.grad(loss64, argnums=(0, 1, 2))(
-                q.astype(jnp.float64), k.astype(jnp.float64),
-                v.astype(jnp.float64))
-            g_or = [np.asarray(g, np.float64) for g in g_or]
-        for a, e, name in zip(g_pk, g_or, "qkv"):
-            np.testing.assert_allclose(
-                np.asarray(a, np.float64), np.asarray(e),
-                rtol=1e-4, atol=1e-4,
-                err_msg=f"packed d{name} vs oracle")
+        g_or = jax.grad(loss64, argnums=(0, 1, 2))(
+            q.astype(jnp.float64), k.astype(jnp.float64),
+            v.astype(jnp.float64))
+        g_or = [np.asarray(g, np.float64) for g in g_or]
+    for a, e, name in zip(grads, g_or, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float64), np.asarray(e),
+            rtol=1e-4, atol=1e-4, err_msg=f"d{name} vs oracle")
 
 
-def test_packed_bf16_vs_oracle(tmp_cache):
-    """bf16 packed kernel ≤ 1e-2 max-abs vs the fp64 oracle (acceptance
-    criterion tolerance)."""
+def test_multi_block_bf16_vs_oracle(tmp_cache):
+    """The bf16 kernel at (64, 64) blocks ≤ 1e-2 max-abs vs the fp64
+    oracle."""
     q, k, v = _qkv(1, 4, 128, 32, dtype=jnp.bfloat16, seed=9)
-    pk = flash_attention(q, k, v, causal=True, use_pallas_override=True,
-                         heads_per_step=4, block_q=64, block_k=64)
+    got = flash_attention(q, k, v, causal=True, use_pallas_override=True,
+                          block_q=64, block_k=64)
     want = _oracle64(q, k, v, causal=True)
-    assert np.abs(np.asarray(pk, np.float64) - want).max() < 1e-2
-
-
-def test_packed_dropout_bitwise(tmp_cache):
-    """The in-kernel counter dropout hashes the FLAT batch*head index —
-    packing must regenerate the identical mask."""
-    q, k, v = _qkv(2, 4, 64, 16, seed=13)
-    key = jax.random.PRNGKey(42)
-    kw = dict(causal=True, dropout_rate=0.3, dropout_key=key,
-              use_pallas_override=True, block_q=32, block_k=32)
-    un = flash_attention(q, k, v, heads_per_step=1, **kw)
-    pk = flash_attention(q, k, v, heads_per_step=2, **kw)
-    assert np.array_equal(np.asarray(un), np.asarray(pk))
-
-
-def test_packed_long_context_bwd_fallback(monkeypatch, tmp_cache):
-    """When the packed (hp, sk, d) scratch exceeds the packed cap the
-    backward silently drops to the unpacked kernels — same grads."""
-    import apex_tpu.ops.flash_attention as fa
-
-    monkeypatch.setattr(fa, "_FUSED_BWD_CAP_PACKED", 16)  # force
-    q, k, v = _qkv(1, 2, 64, 16, seed=17)
-
-    def loss(hp):
-        def inner(q, k, v):
-            return jnp.sum(jnp.sin(fa.flash_attention(
-                q, k, v, causal=True, use_pallas_override=True,
-                heads_per_step=hp, block_q=32, block_k=32)))
-        return inner
-
-    g1 = jax.grad(loss(1), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss(2), argnums=(0, 1, 2))(q, k, v)
-    for a, e in zip(g2, g1):
-        assert np.array_equal(np.asarray(a), np.asarray(e))
+    assert np.abs(np.asarray(got, np.float64) - want).max() < 1e-2
 
 
 def test_block_fallback_warns_once_and_matches(tmp_cache):
