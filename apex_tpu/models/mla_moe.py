@@ -12,7 +12,9 @@ anywhere, RMSNorm, untied embedding and head, no position table):
   all heads share; causal softmax attention with keys of
   `qk_nope + qk_rope` and values of `v_head_dim`
   (`ops.flash_attention.flash_attention` takes the two widths apart);
-  the output projection;
+  the output projection.  The parameters keep the published column
+  order; how the projections' outputs reach the kernels is
+  `_attention`'s business (`ops.rope_stage`);
 * FFN of the leading `first_k_dense_replace` layers: a SwiGLU of
   `intermediate_size`; of the others: `moe.HeldExpertsMLP`, which
   routes over all `n_routed_experts` and computes the part of the
@@ -48,7 +50,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.moe.layer import HeldExpertsMLP
+from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_rms_norm
+from apex_tpu.ops.rope_stage import (
+    halves,
+    rope_tables,
+    stage_heads,
+    turn_halves,
+)
 from apex_tpu.parallel.mesh import TP_AXIS
 from apex_tpu.transformer.tensor_parallel.cross_entropy import (
     vocab_parallel_cross_entropy,
@@ -88,10 +97,11 @@ class MLAMoEConfig:
     router_bias_range: float = 0.05  # the seeded, fixed e_score_correction_bias
     dtype: Any = jnp.float32
     logits_dtype: Any = None         # None keeps fp32 logits
-    # the flash kernels' dispatch, as `flash_attention` takes it: None
-    # lets the backend decide (the kernels on a TPU, the jnp reference
+    # the dispatch of the attention's kernels (flash and the staging
+    # pass in front of it), as `flash_attention` takes it: None lets the
+    # backend decide (the kernels on a TPU, the jnp references
     # elsewhere), True forces the kernels (interpreted off the chip),
-    # False the jnp reference
+    # False the jnp references
     flash_override: Any = None
     fused_xent: Any = None
     axis_name: str = TP_AXIS
@@ -103,23 +113,6 @@ class MLAMoEConfig:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-
-def rope_interleaved(x, positions, theta: float):
-    """Rotary embedding over the last axis of x (..., S, n, d), pairs
-    (2i, 2i+1) turned by `positions * theta**(-2i/d)`
-    (`rope_interleave`).  Computed in fp32; the pair's partner comes
-    by a roll along the lanes, not by a reshape to (d/2, 2)."""
-    d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = positions.astype(jnp.float32)[:, None] * inv_freq   # (S, d/2)
-    cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None, :]    # (S, 1, d)
-    sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None, :]
-    x32 = x.astype(jnp.float32)
-    even = (jnp.arange(d) % 2) == 0
-    partner = jnp.where(even, -jnp.roll(x32, -1, axis=-1),
-                        jnp.roll(x32, 1, axis=-1))
-    return (x32 * cos + partner * sin).astype(x.dtype)
 
 
 class MLAMoE:
@@ -208,9 +201,31 @@ class MLAMoE:
         return jnp.dot(x, w, preferred_element_type=jnp.float32
                        ).astype(x.dtype)
 
-    def _attention(self, p, a):
-        """a: (B, S, H), normed.  The latent attention's output, before
-        the residual add."""
+    def _tables(self, i, seq):
+        """The rotary tables (cos, sin), each (seq, qk_rope / 2) fp32.
+        Whoever runs the blocks computes them once and hands them to
+        every block; the time is filed under block i, the first it
+        runs."""
+        with jax.named_scope(f"block{i}"), jax.named_scope("attn"), \
+                jax.named_scope("rope"):
+            return rope_tables(seq, self.c.qk_rope_head_dim,
+                               self.c.rope_theta)
+
+    def _attention(self, p, a, tables):
+        """a: (B, S, H), normed; tables: `_tables`.  The latent
+        attention's output, before the residual add.
+
+        The weights pair rotary lanes (2i, 2i + 1); here the rotary
+        columns of `q_b` and `kv_a` are taken in halves order (a gather
+        on megabytes of weight, whose transpose carries the gradient
+        back), so that q's and k's rotary lanes share an order and a
+        rotation is two multiplies and an add a half.  `q_b` and `kv_b`
+        each run as two GEMMs over their column groups, so that every
+        output lies as its one reader takes it: `stage_heads` turns q's
+        rotary lanes and writes q and k head-major, k's shared rotary
+        row once a head, in one pass each; v and the context cross
+        between the token-major GEMMs and the head-major kernels in one
+        copy each."""
         c = self.c
         b, s, _ = a.shape
         nh, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim,
@@ -218,29 +233,28 @@ class MLAMoE:
         with jax.named_scope("q_a"):
             c_q = self._norm(p["q_a_norm"], self._dot(a, p["q_a"]))
         with jax.named_scope("q_b"):
-            q = self._dot(c_q, p["q_b"]).reshape(b, s, nh, dn + dr)
+            w = p["q_b"].reshape(-1, nh, dn + dr)
+            q_n = self._dot(c_q, w[..., :dn].reshape(-1, nh * dn))
+            q_r = self._dot(c_q, halves(w[..., dn:]).reshape(-1, nh * dr))
         with jax.named_scope("kv_a"):
-            ckv = self._dot(a, p["kv_a"])
+            ckv = self._dot(a, halves(p["kv_a"], c.kv_lora_rank))
             k_r = ckv[..., c.kv_lora_rank:]
             c_kv = self._norm(p["kv_a_norm"], ckv[..., :c.kv_lora_rank])
         with jax.named_scope("kv_b"):
-            kv = self._dot(c_kv, p["kv_b"]).reshape(b, s, nh, dn + dv)
+            w = p["kv_b"].reshape(-1, nh, dn + dv)
+            k_n = self._dot(c_kv, w[..., :dn].reshape(-1, nh * dn))
+            v = self._dot(c_kv, w[..., dn:].reshape(-1, nh * dv))
         with jax.named_scope("rope"):
-            pos = jnp.arange(s)
-            q_r = rope_interleaved(q[..., dn:], pos, c.rope_theta)
-            k_r = rope_interleaved(k_r[:, :, None, :], pos, c.rope_theta)
-            q = jnp.concatenate([q[..., :dn], q_r], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(k_r, (b, s, nh, dr))],
-                axis=-1)
-        # the head-major copies of q, k, v and of the context are the
+            q = stage_heads(q_n, q_r, nh, tables,
+                            use_pallas_override=c.flash_override)
+            k = stage_heads(k_n, turn_halves(k_r, *tables), nh,
+                            use_pallas_override=c.flash_override)
+        # the head-major copies of v and of the context are the
         # kernels' price, as in the GPT block: they carry their scope
         with jax.named_scope("flash"):
-            from apex_tpu.ops.flash_attention import flash_attention
             ctx = flash_attention(
-                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                kv[..., dn:].transpose(0, 2, 1, 3), causal=True,
-                softmax_scale=1.0 / math.sqrt(c.qk_head_dim),
+                q, k, v.reshape(b, s, nh, dv).transpose(0, 2, 1, 3),
+                causal=True, softmax_scale=1.0 / math.sqrt(c.qk_head_dim),
                 use_pallas_override=c.flash_override)
             ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * dv)
         with jax.named_scope("proj"):
@@ -257,12 +271,12 @@ class MLAMoE:
                 return self._dot(act, p["down"]), None
         return self.experts.apply(p, m)
 
-    def _block(self, i, p, x):
+    def _block(self, i, p, x, tables):
         with jax.named_scope(f"block{i}"):
             with jax.named_scope("ln1"):
                 a = self._norm(p["ln1"], x)
             with jax.named_scope("attn"):
-                x = x + self._attention(p["attn"], a)
+                x = x + self._attention(p["attn"], a, tables)
             with jax.named_scope("ln2"):
                 m = self._norm(p["ln2"], x)
             with jax.named_scope("mlp"):
@@ -273,14 +287,17 @@ class MLAMoE:
         with jax.named_scope("embed"):
             return self.embed.apply(params["embed"], ids)
 
-    def trunk(self, params, tokens):
+    def trunk(self, params, tokens, tables=None):
         """tokens (B, S) -> (the residual stream after the last held
         layer, (B, S, H), before the final norm; the expert layers'
-        HeldExpertsStats in layer order)."""
+        HeldExpertsStats in layer order).  `tables`: `_tables`, where
+        the caller has them."""
         h = self._embed(params, tokens)
+        if tables is None:
+            tables = self._tables(0, tokens.shape[1])
         stats = []
         for i in range(self.c.num_layers):
-            h, st = self._block(i, params[f"block{i}"], h)
+            h, st = self._block(i, params[f"block{i}"], h, tables)
             if st is not None:
                 stats.append(st)
         return h, stats
@@ -296,10 +313,11 @@ class MLAMoE:
         with jax.named_scope("final_ln"):
             return self._norm(params["final_ln"], h)
 
-    def mtp_hidden(self, params, h, next_tokens):
+    def mtp_hidden(self, params, h, next_tokens, tables=None):
         """The MTP module up to its own final norm: `h` the trunk's
         residual stream (B, S, H), `next_tokens` (B, S) the token after
-        each position.  Returns (hidden the shared head reads, the MTP
+        each position, `tables` the trunk's `_tables` where the caller
+        has them.  Returns (hidden the shared head reads, the MTP
         block's HeldExpertsStats)."""
         p = params["mtp"]
         with jax.named_scope("mtp"):
@@ -312,7 +330,9 @@ class MLAMoE:
                     axis=-1)
                 x = self._dot(both, p["proj"])
         i = self.c.num_layers
-        x, stats = self._block(i, params[f"block{i}"], x)
+        if tables is None:
+            tables = self._tables(i, h.shape[1])
+        x, stats = self._block(i, params[f"block{i}"], x, tables)
         with jax.named_scope("mtp"):
             return self._norm(p["final_ln"], x), stats
 
@@ -339,13 +359,14 @@ class MLAMoE:
         last position is given its first label, as the benchmark's
         seeded batches give the main head), or None without the
         module; and every expert layer's HeldExpertsStats."""
-        h, stats = self.trunk(params, tokens)
+        tables = self._tables(0, tokens.shape[1])
+        h, stats = self.trunk(params, tokens, tables)
         logits = self.logits_local(params, self._final_ln(params, h))
         with jax.named_scope("loss"):
             main = self._xent(logits, labels)
         if not self.c.mtp:
             return main, None, stats
-        hm, st = self.mtp_hidden(params, h, labels)
+        hm, st = self.mtp_hidden(params, h, labels, tables)
         with jax.named_scope("mtp"), jax.named_scope("head"):
             mtp = self._xent(self._head(params, hm),
                              jnp.roll(labels, -1, axis=1))
@@ -366,8 +387,9 @@ class MLAMoE:
         experts_count) int32, overflow (layers,) int32) of every expert
         layer held, the MTP block's last: what a router-bias update
         reads, and whether the grouped buffers' bound held."""
-        h, stats = self.trunk(params, tokens)
+        tables = self._tables(0, tokens.shape[1])
+        h, stats = self.trunk(params, tokens, tables)
         if self.c.mtp:
-            stats = stats + [self.mtp_hidden(params, h, labels)[1]]
+            stats = stats + [self.mtp_hidden(params, h, labels, tables)[1]]
         return (jnp.stack([s.counts for s in stats]),
                 jnp.stack([s.overflow for s in stats]))

@@ -81,6 +81,9 @@ class KernelCase:
     rtol: float
     atol: float
     mosaic: bool = True        # a tpu_custom_call must be in the program
+    # errors and `want` counted in units of each reference output's root
+    # mean square: for outputs of unlike sizes (an activation, gradients)
+    in_rms: bool = False
 
 
 def flash_case(name, shape, config: Optional[dict] = None,
@@ -232,6 +235,92 @@ def held_experts_case(name, tokens, hidden, ffn, n_experts, count,
     # gradient was off by 3.7: a grouped GEMM on the chip leaves them
     # as it finds them, which no CPU run shows
     return KernelCase(name, make_args, kernel, reference, 5e-2, 2.5e-2)
+
+
+def mla_attention_case(name, batch, seq) -> KernelCase:
+    """`MLAMoE._attention` at JoyAI-LLM-Flash's widths in bf16 (the
+    staging pass `rope_stage` in front of the flash kernels at keys 192
+    / values 128), forward and the gradients of `q_b`, `kv_a`, `kv_b`
+    and `proj`, against the published-order formulation in float32 on
+    the same weights: interleaved pairs turned by a roll, k's rotary
+    row broadcast to the heads, one GEMM a projection, dense attention
+    a batch row at a time.  The benchmark's `correct` compares losses
+    and cannot see a wrong backward; this can."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
+    from apex_tpu.ops.flash_attention import attention_reference
+
+    model = MLAMoE(MLAMoEConfig(dtype=jnp.bfloat16))
+    c = model.c
+    nh, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                      c.v_head_dim)
+    trained = ("q_b", "kv_a", "kv_b", "proj")
+
+    def make_args(key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        return (model._init_block(k1, 0)["attn"],
+                jax.random.normal(k2, (batch, seq, c.hidden), jnp.bfloat16),
+                jax.random.normal(k3, (batch, seq, c.hidden), jnp.bfloat16))
+
+    def fwd_bwd(attention, p, a, dy):
+        rest = {k: v for k, v in p.items() if k not in trained}
+        y, vjp = jax.vjp(lambda w: attention({**rest, **w}, a),
+                         {k: p[k] for k in trained})
+        (dw,) = vjp(dy.astype(y.dtype))
+        return (y,) + tuple(dw[k] for k in trained)
+
+    def kernel(p, a, dy):
+        return fwd_bwd(lambda p, a: model._attention(
+            p, a, model._tables(0, seq)), p, a, dy)
+
+    def turn_pairs(x):
+        """(..., S, n, dr), pairs (2i, 2i+1) turned by position."""
+        inv_freq = c.rope_theta ** (-jnp.arange(0, dr, 2) / dr)
+        angle = jnp.arange(seq)[:, None] * inv_freq
+        cos = jnp.repeat(jnp.cos(angle), 2, axis=-1)[:, None]
+        sin = jnp.repeat(jnp.sin(angle), 2, axis=-1)[:, None]
+        partner = jnp.where(jnp.arange(dr) % 2 == 0,
+                            -jnp.roll(x, -1, axis=-1),
+                            jnp.roll(x, 1, axis=-1))
+        return x * cos + partner * sin
+
+    def published(p, a):
+        def norm(x, w):
+            return x * jax.lax.rsqrt(jnp.mean(
+                x * x, -1, keepdims=True) + c.rms_norm_eps) * w["weight"]
+        q = (norm(a @ p["q_a"], p["q_a_norm"]) @ p["q_b"]).reshape(
+            batch, seq, nh, dn + dr)
+        ckv = a @ p["kv_a"]
+        kv = (norm(ckv[..., :c.kv_lora_rank], p["kv_a_norm"])
+              @ p["kv_b"]).reshape(batch, seq, nh, dn + dv)
+        k_r = turn_pairs(ckv[:, :, None, c.kv_lora_rank:])
+        q = jnp.concatenate([q[..., :dn], turn_pairs(q[..., dn:])], -1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_r, (batch, seq, nh, dr))], -1)
+
+        def one(row):       # (nh, S, d) each: one batch row
+            return attention_reference(
+                *(x[None] for x in row), causal=True,
+                softmax_scale=1.0 / math.sqrt(dn + dr))[0]
+        ctx = jax.lax.map(one, tuple(
+            x.transpose(0, 2, 1, 3) for x in (q, k, kv[..., dn:])))
+        return ctx.transpose(0, 2, 1, 3).reshape(batch, seq, nh * dv) \
+            @ p["proj"]
+
+    def reference(p, a, dy):
+        f32 = jax.tree.map(lambda x: x.astype(jnp.float32), (p, a, dy))
+        with jax.default_matmul_precision("highest"):
+            return fwd_bwd(published, *f32)
+
+    # bf16 against float32, each output in units of its own root mean
+    # square (the output's is 0.03, a weight gradient's a few hundred):
+    # the band is set from the chip's readings, given beside the case in
+    # `kernel_cases`; a rotary turned the wrong way, or a head left out
+    # of `kv_a`'s sum, is an error of about 1
+    return KernelCase(name, make_args, kernel, reference, 5e-2, 0.3,
+                      in_rms=True)
 
 
 def adam_case(name, n, state_dtype) -> KernelCase:
@@ -398,6 +487,10 @@ def kernel_cases(device) -> list:
         flash_case("flash_d64_s2048", (8, 16, 2048, 64)),
         # latent attention: keys 192 wide, values 128 (models/mla_moe.py)
         flash_case("flash_mla_192_128", (2, 32, 4096, 192), v_dim=128),
+        # the whole latent attention around them, gradients and all: past
+        # rtol the output reads 0.11-0.14 of its rms and the gradients of
+        # q_b, kv_a, kv_b, proj 0.03, 0.02, 0.03, 0.02 (three seeds, PR 31)
+        mla_attention_case("mla_attention_grads", 2, 4096),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
         held_experts_case("moe_held_experts", 8192, 2048, 768, 256, 16, 8),
         adam_case("adam_flat_fp32", n_params, jnp.float32),
@@ -423,6 +516,9 @@ def kernel_check(case: KernelCase):
         for got, want in zip(case.kernel(*args), case.reference(*args),
                              strict=True):
             got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+            if case.in_rms:
+                unit = jnp.sqrt(jnp.mean(want * want))
+                got, want = got / unit, want / unit
             err = jnp.abs(got - want)
             rows.append(jnp.stack([
                 jnp.max(err - case.rtol * jnp.abs(want)), jnp.max(err),

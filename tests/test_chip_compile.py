@@ -109,7 +109,8 @@ def _smoke_case(name, device):
 
 @pytest.mark.parametrize("name,min_kernels", [
     ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_d64_s2048", 2),
-    ("flash_mla_192_128", 2), ("moe_held_experts", 6),
+    ("flash_mla_192_128", 2), ("mla_attention_grads", 6),
+    ("moe_held_experts", 6),
     ("adam_flat_fp32", 1),
     ("adam_flat_bf16", 1), ("xent_pallas", 2), ("xent_vocab_parallel", 0),
     ("flash_decode", 1)])
@@ -448,10 +449,12 @@ def test_mla_moe_step_compiles_fits_and_is_named(topo, on_chip):
     for name in kernels:
         by_name[name.split(".")[0]] = by_name.get(name.split(".")[0], 0) + 1
     # 6 blocks (5 layers and the MTP module's); off the chip the tuner
-    # has no v5e entry, so the backward is the two-kernel one
+    # has no v5e entry, so the backward is the two-kernel one; the
+    # staging pass runs once for q and once for k a block and direction
     assert {k: v for k, v in by_name.items() if k.startswith(
-        ("flash", "adam"))} == {"flash_fwd": 6, "flash_bwd_dq": 6,
-                                "flash_bwd_dkv": 6, "adam_flat": 1}
+        ("flash", "adam", "rope"))} == {
+        "flash_fwd": 6, "flash_bwd_dq": 6, "flash_bwd_dkv": 6,
+        "rope_stage": 12, "rope_unstage": 12, "adam_flat": 1}
     # 5 expert layers x 2 grouped GEMMs x (forward, dgrad, wgrad)
     assert by_name["ragged-dot-none"] == 30
     m = compiled.memory_analysis()

@@ -16,11 +16,11 @@ from jax.sharding import PartitionSpec as P
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from apex_tpu.models.mla_moe import (  # noqa: E402
-    MLAMoE, MLAMoEConfig, rope_interleaved)
+from apex_tpu.models.mla_moe import MLAMoE, MLAMoEConfig  # noqa: E402
 from apex_tpu.moe import HeldExpertsMLP, dispatch as D  # noqa: E402
 from apex_tpu.moe.layer import swiglu  # noqa: E402
 from apex_tpu.moe.router import sigmoid_topk_gates  # noqa: E402
+from apex_tpu.ops import rope_stage  # noqa: E402
 from apex_tpu.parallel import mesh as M  # noqa: E402
 from benchmarks.reference import joyai_llm_flash as ref  # noqa: E402
 
@@ -364,15 +364,193 @@ def test_the_row_bound_is_twice_the_uniform_expectation():
 
 @pytest.mark.parametrize("d,heads", [(64, 32), (4, 2), (64, 1)])
 def test_interleaved_rope_is_the_complex_rotation(d, heads):
+    """The model turns rotary lanes in halves order (`rope_stage`: the
+    partners of a pair d/2 lanes apart).  On lanes taken from the
+    published pairs (2i, 2i+1) by `halves` that is the published
+    rotation, the complex one, of the same lanes."""
     x = jax.random.normal(jax.random.PRNGKey(9), (2, 16, heads, d))
-    got = rope_interleaved(x, jnp.arange(16), 32e6)
+    cos, sin = rope_stage.rope_tables(16, d, 32e6)
+    got = rope_stage.turn_halves(rope_stage.halves(x), cos[:, None],
+                                 sin[:, None])
     pairs = np.asarray(x, np.float64).reshape(2, 16, heads, d // 2, 2)
     z = pairs[..., 0] + 1j * pairs[..., 1]
     angle = (np.arange(16)[:, None]
              * 32e6 ** (-np.arange(0, d, 2) / d))[None, :, None, :]
     z = z * np.exp(1j * angle)
-    want = np.stack([z.real, z.imag], -1).reshape(x.shape)
+    want = np.concatenate([z.real, z.imag], -1)     # halves of the pairs
     np.testing.assert_allclose(got, want, atol=2e-5)
-    np.testing.assert_allclose(got, ref._rope(x, 32e6), atol=2e-5)
+    np.testing.assert_allclose(got, rope_stage.halves(ref._rope(x, 32e6)),
+                               atol=2e-5)
     # position 0 is left as it is
-    np.testing.assert_array_equal(got[:, 0], x[:, 0])
+    np.testing.assert_array_equal(got[:, 0], rope_stage.halves(x)[:, 0])
+
+
+# ------------------------------ the attention alone ------------------------------
+
+# `_attention` against the published-order formulation (the reference's:
+# interleaved pairs turned as complex numbers, k's rotary row broadcast
+# to the heads, one GEMM a projection), float32, on the same `params`:
+# the model re-orders rotary columns and splits GEMMs inside the step,
+# and every gradient must come back in the published order.  "toy" is
+# the file's small model; "cell4" has the benchmark cell's head
+# geometry (32 heads, keys 128 + 64, values 128) over a short sequence,
+# where the staging pass runs in blocks (interpreted) under "kernels"
+GEOMETRY = {
+    "toy": dict(num_heads=2, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                v_head_dim=8, seq=32, batch=2),
+    "cell4": dict(num_heads=32, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                  v_head_dim=128, seq=128, batch=1),
+}
+ATTN_LEAVES = ("q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm", "kv_b", "proj")
+
+
+def _attention_pair(geometry, flash):
+    g = dict(GEOMETRY[geometry])
+    seq, batch = g.pop("seq"), g.pop("batch")
+    model = MLAMoE(MLAMoEConfig(
+        vocab_size=64, hidden=32, q_lora_rank=24, kv_lora_rank=16,
+        rope_theta=1e4, init_std=0.3, flash_override=flash, **g))
+    p = model._init_block(jax.random.PRNGKey(2), 0)["attn"]
+    a, dy = jax.random.normal(jax.random.PRNGKey(3), (2, batch, seq, 32))
+    arch = ref._Arch.of(dict(
+        ARCH, num_attention_heads=g["num_heads"],
+        qk_nope_head_dim=g["qk_nope_head_dim"],
+        qk_rope_head_dim=g["qk_rope_head_dim"], v_head_dim=g["v_head_dim"]))
+
+    def both(fn):
+        out, vjp = jax.vjp(fn, p, a)
+        return (out,) + vjp(dy)
+
+    with jax.default_matmul_precision("highest"):
+        got = both(lambda p, a: model._attention(
+            p, a, model._tables(0, seq)))
+        want = both(lambda p, a: ref._attention(p, a, arch, None))
+    return model, got, want
+
+
+@pytest.fixture(scope="module")
+def attention_pairs():
+    cache = {}
+
+    def get(geometry, flash):
+        if (geometry, flash) not in cache:
+            cache[geometry, flash] = _attention_pair(geometry, flash)
+        return cache[geometry, flash]
+    return get
+
+
+def _rotary_columns(model, leaf, g):
+    """The rotary columns of a weight gradient in the published order:
+    `kv_a`'s last qk_rope columns (the sum over heads of the keys'
+    gradient reaches them), `q_b`'s last qk_rope columns of every head
+    (carried back through the halves order)."""
+    c = model.c
+    if leaf == "kv_a":
+        return g[:, c.kv_lora_rank:]
+    return g.reshape(-1, c.num_heads, c.qk_head_dim)[..., c.qk_nope_head_dim:]
+
+
+@pytest.mark.parametrize("what", [
+    "output", "input", *ATTN_LEAVES, "kv_a-rotary-columns",
+    "q_b-rotary-columns"])
+@pytest.mark.parametrize("geometry,flash", [
+    ("toy", None), ("toy", True), ("cell4", None), ("cell4", True)],
+    ids=["toy", "toy-kernels", "cell4", "cell4-kernels"])
+def test_attention_matches_the_published_order_formulation(
+        attention_pairs, geometry, flash, what):
+    model, (out, dp, da), (w_out, w_dp, w_da) = attention_pairs(geometry,
+                                                                flash)
+    if what == "output":
+        got, want = out, w_out
+    elif what == "input":
+        got, want = da, w_da
+    elif what.endswith("-rotary-columns"):
+        leaf = what.split("-")[0]
+        got, want = (_rotary_columns(model, leaf, g[leaf])
+                     for g in (dp, w_dp))
+        assert got.shape[-1] == model.c.qk_rope_head_dim
+    else:
+        got, want = (g[what]["weight"] if what.endswith("norm") else g[what]
+                     for g in (dp, w_dp))
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    scale = float(jnp.abs(want).max())
+    assert scale > 0
+    assert float(jnp.abs(got - want).max()) <= 1e-4 * scale, what
+
+
+def test_weights_loaded_in_published_order_match_the_reference(mesh):
+    """A checkpoint's weights, laid out as `config.json`'s family lays
+    them out (a head's columns together, nope | rope, rotary pairs
+    interleaved) and put into `params` as they are, at the cell's head
+    geometry: the per-token losses are the reference's on the same
+    arrays."""
+    g = {k: v for k, v in GEOMETRY["cell4"].items()
+         if k not in ("seq", "batch")}
+    model = MLAMoE(MLAMoEConfig(
+        vocab_size=64, hidden=32, q_lora_rank=24, kv_lora_rank=16,
+        intermediate_size=48, moe_intermediate_size=8, n_routed_experts=16,
+        num_experts_per_tok=3, first_k_dense_replace=1, num_expert_layers=1,
+        experts_first=4, experts_count=8, rope_theta=1e4, init_std=0.3, **g))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    params = jax.tree.map(           # nothing of `init` but the shapes
+        lambda sh: jnp.asarray(rng.normal(0, 0.3, sh.shape), jnp.float32),
+        shapes)
+    arch = dict(ARCH, num_attention_heads=32, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=2)
+    tokens = jnp.asarray(rng.integers(0, 64, (2, 32)), jnp.int32)
+    labels = jnp.roll(tokens, -1, axis=1)
+    with jax.default_matmul_precision("highest"):
+        got = on_mesh(model, mesh,
+                      lambda p, t, l: model.token_losses(p, t, l)[:2],
+                      (P(), P()))(params, tokens, labels)
+    want = ref.token_losses(params, tokens, labels, arch=arch)
+    for g_, w_ in zip(got, want):
+        np.testing.assert_allclose(g_, w_, atol=5e-5, rtol=0)
+
+
+# ------------------------------ what the staging promises ------------------------------
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_attention_stages_q_and_k_in_one_pass_each():
+    """Guards the mechanism at the level a jaxpr shows it, at the
+    benchmark cell's shapes (2 x 4096, 32 heads, 128 + 64 / 128, bf16,
+    the kernels forced): between the GEMMs and `flash_attention` no
+    full-size q or k is transposed or concatenated (the head-major
+    order is the staging pass's output layout, not a copy after it),
+    k's rotary row is never broadcast to the heads, and the pass is one
+    `rope_stage` call for q and one for k a block, `rope_unstage` the
+    same in the backward.  v and the context, 128 wide, may be
+    transposed once each way."""
+    model = MLAMoE(MLAMoEConfig(dtype=jnp.bfloat16, flash_override=True))
+    p = jax.eval_shape(lambda k: model._init_block(k, 1),
+                       jax.random.PRNGKey(0))["attn"]
+    a = jax.ShapeDtypeStruct((2, 4096, 2048), jnp.bfloat16)
+
+    def fwd_bwd(p, a):
+        out, vjp = jax.vjp(lambda p, a: model._attention(
+            p, a, model._tables(0, 4096)), p, a)
+        return vjp(out)
+
+    eqns = list(_eqns(jax.make_jaxpr(fwd_bwd)(p, a).jaxpr))
+    calls = [e.params["name"] for e in eqns
+             if e.primitive.name == "pallas_call"]
+    assert calls.count("rope_stage") == 2
+    assert calls.count("rope_unstage") == 2
+    assert sum(c.startswith("flash_fwd") for c in calls) == 1
+    big = 2 * 4096 * 32 * 64          # a rotary slice of every head
+    for e in eqns:
+        shapes = [tuple(v.aval.shape) for v in e.outvars]
+        if e.primitive.name in ("transpose", "concatenate"):
+            # only 128-wide v, context and their gradients; and weights,
+            # which are smaller than any activation here
+            assert all(s[-1] != 192 and (np.prod(s) < big or s[-1] == 128)
+                       for s in shapes), (e.primitive.name, shapes)
+        if e.primitive.name == "broadcast_in_dim":
+            assert all(np.prod(s) < big for s in shapes), shapes
