@@ -1,0 +1,302 @@
+"""HybridMoE — a decoder whose layers mix two kinds of token mixer in
+one stack, every layer an expert layer, as one chip of an
+expert-parallel group holds it: gated grouped-query softmax attention
+without positions on the layers `attention_layers` names, Kimi Delta
+Attention (a gated delta rule with a per-channel decay,
+arXiv:2510.26692) on the others.
+
+The layers (no bias but KDA's `dt_bias`, RMSNorm, untied embedding and
+head, no rotary embedding and no position table anywhere):
+
+* block: `h = x + Mixer_i(RMSNorm(x))`, `y = h + MoE(RMSNorm(h))`;
+* gated attention: `q = a W_q` (heads x d), `k, v = a W_k, a W_v`
+  (kv_heads x d), causal `softmax(q k^T / sqrt(d)) v` with kv head j
+  serving query heads `j * group ...` (`ops.flash_attention` takes the
+  fewer kv heads as they are); `out = W_o [attn * sigmoid(a W_gate)]`,
+  the gate elementwise, a column a head and channel
+  (arXiv:2505.06708);
+* KDA, n heads of d_k = d_v = d: `q, k, v = SiLU(conv(a W_.))`, a
+  causal depthwise convolution over time, `conv_kernel` taps, a weight
+  a channel and tap; a head's q and k scaled to unit length, q by
+  d^-1/2 more; the log-decay a channel `g = -exp(A_h) softplus(W_f2
+  (W_f1 a) + dt_bias)` in float32; `beta = sigmoid(W_beta a)`, twice
+  that where `allow_neg_eigval`; the state and the outputs by
+  `ops.delta_rule.gated_delta_rule`; `out = W_o [RMSNorm_d(o) *
+  sigmoid(W_g2 (W_g1 a))]`, the norm's weight of d shared by the heads;
+* the expert layer: `moe.HeldExpertsMLP`, which routes over all
+  `n_routed_experts` by the bias-corrected sigmoid gate and computes
+  the part of the result that experts `[experts_first, experts_first +
+  experts_count)` give, plus the shared expert.
+
+The config says what is held here: how many layers and which of them
+attend, which experts, how many rows of the vocabulary.  The layers
+left out lie on other chips as pipeline stages, the experts left out
+on the other chips of the expert-parallel group; this module has no
+code that stands in for either.
+
+Runs shard-local inside `shard_map` over the (pp, dp, tp) mesh with the
+surface `models.mla_moe.MLAMoE` gives the step builder (`init`,
+`partition_specs`, `trunk`, `token_losses`, `loss`, `routing_counts`;
+the two share `models.held_experts_lm.HeldExpertsLM`), tensor
+parallelism 1 only.  Activations are (B, S, H); the two kernels' side
+is head-major, (B, heads, S, d), and the copies between the two carry
+the scope of what they feed.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.models.held_experts_lm import HeldExpertsLM
+from apex_tpu.ops.delta_rule import gated_delta_rule
+from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.parallel.mesh import TP_AXIS
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridMoEConfig:
+    vocab_size: int = 24576          # rows of embedding and head held here
+    hidden: int = 4096
+    num_layers: int = 4              # layers held here
+    attention_layers: Tuple[int, ...] = (0,)   # which of them attend
+    num_heads: int = 64
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    kda_heads: int = 64
+    kda_head_dim: int = 128
+    conv_kernel: int = 4
+    kda_rank: int = 128              # inner width of the f and g pairs
+    allow_neg_eigval: bool = True
+    moe_intermediate_size: int = 1280
+    n_routed_experts: int = 320      # the router's width, as published
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-5
+    experts_first: int = 0           # experts [first, first + count)
+    experts_count: int = 8
+    # rows of the grouped GEMMs' buffer over what uniform routing sends
+    # the held experts (`HeldExpertsMLP.rows_bound`)
+    expert_rows_factor: float = 2.0
+    init_std: float = 0.02
+    router_bias_range: float = 0.05  # the seeded, fixed selection bias
+    dtype: Any = jnp.float32
+    logits_dtype: Any = None         # None keeps fp32 logits
+    # True recomputes a mixer's inner activations in the backward
+    # (`jax.checkpoint` around the mixer): what a step keeps of it is
+    # its normed input
+    recompute_mixers: bool = False
+    scan_chunk: Optional[int] = None   # None: the tuner's, or the op's
+    # the dispatch of the flash kernels, as `flash_attention` takes it
+    flash_override: Any = None
+    fused_xent: Any = None
+    axis_name: str = TP_AXIS
+
+
+class HybridMoE(HeldExpertsLM):
+    def _attends(self, i: int) -> bool:
+        return i in self.c.attention_layers
+
+    # ------------------------------ params --------------------------------
+    def _init_block(self, key, i: int) -> dict:
+        c = self.c
+        ks = jax.random.split(key, 16)
+        h = c.hidden
+
+        def normal(k, *shape):
+            return jax.random.normal(k, shape, c.dtype) * c.init_std
+
+        def ones(n):
+            return {"weight": jnp.ones((n,), c.dtype)}
+
+        if self._attends(i):
+            wide, kv = c.num_heads * c.head_dim, c.num_kv_heads * c.head_dim
+            attn = {"q": normal(ks[0], h, wide), "k": normal(ks[1], h, kv),
+                    "v": normal(ks[2], h, kv), "gate": normal(ks[3], h, wide),
+                    "proj": normal(ks[4], wide, h)}
+        else:
+            n, d, r = c.kda_heads, c.kda_head_dim, c.kda_rank
+            wide = n * d
+            # A_h and dt_bias as the paper's code starts them: A uniform
+            # in (1, 16), a time step log-uniform in (1e-3, 1e-1) and the
+            # bias its inverse softplus
+            dt = jnp.exp(jax.random.uniform(
+                ks[12], (wide,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            attn = {
+                "q": normal(ks[0], h, wide), "k": normal(ks[1], h, wide),
+                "v": normal(ks[2], h, wide),
+                # a tap's weight a channel, uniform in +-1/sqrt(taps) as
+                # a depthwise convolution is started
+                **{f"conv_{x}": jax.random.uniform(
+                    k, (c.conv_kernel, wide), c.dtype, -1.0, 1.0)
+                   / math.sqrt(c.conv_kernel)
+                   for x, k in zip("qkv", ks[5:8])},
+                "f_a": normal(ks[8], h, r), "f_b": normal(ks[9], r, wide),
+                "a_log": jnp.log(jax.random.uniform(
+                    ks[11], (n,), jnp.float32, 1.0, 16.0)).astype(c.dtype),
+                "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(c.dtype),
+                "beta": normal(ks[13], h, n),
+                "g_a": normal(ks[14], h, r), "g_b": normal(ks[15], r, wide),
+                "o_norm": ones(d),
+                "proj": normal(ks[4], wide, h),
+            }
+        return {"ln1": ones(h), "attn": attn, "ln2": ones(h),
+                "mlp": self.experts.init(ks[10], c.dtype)}
+
+    def init(self, key):
+        keys = jax.random.split(key, 2 + self.c.num_layers)
+        params = self._init_ends(keys[0], keys[1])
+        for i in range(self.c.num_layers):
+            params[f"block{i}"] = self._init_block(keys[2 + i], i)
+        return params
+
+    # ------------------------------ forward -------------------------------
+    @staticmethod
+    def _heads(x, n):
+        """(B, S, n * d) -> (B, n, S, d)."""
+        b, s, w = x.shape
+        return x.reshape(b, s, n, w // n).transpose(0, 2, 1, 3)
+
+    def _attention(self, p, a):
+        """a: (B, S, H), normed.  The gated grouped-query attention's
+        output, before the residual add."""
+        c = self.c
+        b, s, _ = a.shape
+        with jax.named_scope("qkv"):
+            q, k, v = (self._dot(a, p[x]) for x in "qkv")
+        with jax.named_scope("flash"):
+            ctx = flash_attention(
+                self._heads(q, c.num_heads), self._heads(k, c.num_kv_heads),
+                self._heads(v, c.num_kv_heads), causal=True,
+                softmax_scale=1.0 / math.sqrt(c.head_dim),
+                use_pallas_override=c.flash_override)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
+        with jax.named_scope("gate"):
+            ctx = ctx * jax.nn.sigmoid(self._dot(a, p["gate"]))
+        with jax.named_scope("proj"):
+            return self._dot(ctx, p["proj"])
+
+    def _conv(self, x, w):
+        """SiLU of the causal depthwise convolution over time: x (B, S,
+        C), w (taps, C); tap j weighs the token taps - 1 - j back."""
+        taps = w.shape[0]
+        s = x.shape[1]
+        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+        y = sum(padded[:, j:j + s].astype(jnp.float32)
+                * w[j].astype(jnp.float32) for j in range(taps))
+        # rounded where the backward keeps it: the sum, not its terms
+        return jax.nn.silu(y.astype(x.dtype))
+
+    def scan_inputs(self, p, a):
+        """a: (B, S, H), normed.  What Kimi Delta Attention hands
+        `gated_delta_rule`, head-major: q, k, v (B, n, S, d) in the
+        model's dtype, the log-decay g (B, n, S, d) and beta (B, n, S)
+        in float32."""
+        c = self.c
+        b, s, _ = a.shape
+        n, d = c.kda_heads, c.kda_head_dim
+        f32 = jnp.float32
+        with jax.named_scope("qkv"):
+            q, k, v = (self._dot(a, p[x]) for x in "qkv")
+        with jax.named_scope("conv"):
+            def unit(x, scale=1.0):
+                x = x.reshape(b, s, n, d).astype(f32)
+                inv = jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                    + 1e-12)
+                return (x * (scale * inv)).astype(c.dtype).transpose(
+                    0, 2, 1, 3)
+            q = unit(self._conv(q, p["conv_q"]), d ** -0.5)
+            k = unit(self._conv(k, p["conv_k"]))
+            v = self._heads(self._conv(v, p["conv_v"]), n)
+        with jax.named_scope("decay"):
+            f = self._dot(self._dot(a, p["f_a"]), p["f_b"])
+            rate = jnp.exp(p["a_log"].astype(f32))[:, None]
+            g = -rate * jax.nn.softplus(
+                f.astype(f32) + p["dt_bias"].astype(f32)).reshape(b, s, n, d)
+            g = g.transpose(0, 2, 1, 3)
+            beta = jax.nn.sigmoid(jnp.dot(
+                a, p["beta"], preferred_element_type=f32))
+            if c.allow_neg_eigval:
+                beta = 2.0 * beta
+            beta = beta.transpose(0, 2, 1)
+        return q, k, v, g, beta
+
+    def _kda(self, p, a):
+        """a: (B, S, H), normed.  Kimi Delta Attention's output, before
+        the residual add."""
+        c = self.c
+        b, s, _ = a.shape
+        n, d = c.kda_heads, c.kda_head_dim
+        f32 = jnp.float32
+        q, k, v, g, beta = self.scan_inputs(p, a)
+        with jax.named_scope("scan"):
+            o = gated_delta_rule(q, k, v, g, beta, chunk=c.scan_chunk)
+        with jax.named_scope("onorm"):
+            o = o.transpose(0, 2, 1, 3).astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + c.rms_norm_eps)
+            o = (o * p["o_norm"]["weight"].astype(f32)).reshape(b, s, n * d)
+            gate = self._dot(self._dot(a, p["g_a"]), p["g_b"])
+            o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(c.dtype)
+        with jax.named_scope("proj"):
+            return self._dot(o, p["proj"])
+
+    def _block(self, i, p, x):
+        mixer = self._attention if self._attends(i) else self._kda
+        if self.c.recompute_mixers:
+            mixer = jax.checkpoint(mixer)
+        with jax.named_scope(f"block{i}"):
+            with jax.named_scope("ln1"):
+                a = self._norm(p["ln1"], x)
+            with jax.named_scope("attn"):
+                x = x + mixer(p["attn"], a)
+            with jax.named_scope("ln2"):
+                m = self._norm(p["ln2"], x)
+            with jax.named_scope("mlp"):
+                y, stats = self.experts.apply(p["mlp"], m)
+                return x + y, stats
+
+    def trunk(self, params, tokens):
+        """tokens (B, S) -> (the residual stream after the last held
+        layer, (B, S, H), before the final norm; the expert layers'
+        HeldExpertsStats in layer order)."""
+        h = self._embed(params, tokens)
+        stats = []
+        for i in range(self.c.num_layers):
+            h, st = self._block(i, params[f"block{i}"], h)
+            stats.append(st)
+        return h, stats
+
+    def apply(self, params, tokens, key=None):
+        """tokens: (B, S) ids within the held rows.  The hidden states
+        the head reads, (B, S, H).  Shard-local: call inside
+        shard_map."""
+        h, _ = self.trunk(params, tokens)
+        return self._final_ln(params, h)
+
+    def token_losses(self, params, tokens, labels):
+        """(main, None, stats): per-token cross entropies (B, S) fp32
+        against `labels`, no second head (`MLAMoE.token_losses` has
+        one), and every expert layer's HeldExpertsStats."""
+        h, stats = self.trunk(params, tokens)
+        logits = self.logits_local(params, self._final_ln(params, h))
+        with jax.named_scope("loss"):
+            return self._xent(logits, labels), None, stats
+
+    def loss(self, params, tokens, labels, key=None):
+        """The mean over tokens.  tokens/labels: (B, S)."""
+        main, _, _ = self.token_losses(params, tokens, labels)
+        with jax.named_scope("loss"):
+            return jnp.mean(main)
+
+    def routing_counts(self, params, tokens, labels=None):
+        """Forward only, without the head: (counts (layers,
+        experts_count) int32, overflow (layers,) int32) of every expert
+        layer held."""
+        return self._counts(self.trunk(params, tokens)[1])

@@ -47,11 +47,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from apex_tpu.moe.layer import HeldExpertsMLP
+from apex_tpu.models.held_experts_lm import HeldExpertsLM
 from apex_tpu.ops.flash_attention import flash_attention
-from apex_tpu.ops.layer_norm import fused_rms_norm
 from apex_tpu.ops.rope_stage import (
     halves,
     rope_tables,
@@ -59,12 +57,6 @@ from apex_tpu.ops.rope_stage import (
     turn_halves,
 )
 from apex_tpu.parallel.mesh import TP_AXIS
-from apex_tpu.transformer.tensor_parallel.cross_entropy import (
-    vocab_parallel_cross_entropy,
-)
-from apex_tpu.transformer.tensor_parallel.layers import (
-    VocabParallelEmbedding,
-)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -115,20 +107,14 @@ class MLAMoEConfig:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
-class MLAMoE:
+class MLAMoE(HeldExpertsLM):
+    """The ends of the network, the expert layer and the small pieces
+    of a block are `HeldExpertsLM`'s, shared with `models.hybrid_moe`."""
+
     def __init__(self, config: MLAMoEConfig):
-        self.c = c = config
-        self.embed = VocabParallelEmbedding(
-            c.vocab_size, c.hidden, init_std=c.init_std,
-            axis_name=c.axis_name)
-        self.experts = HeldExpertsMLP(
-            c.hidden, c.moe_intermediate_size, c.n_routed_experts,
-            first=c.experts_first, count=c.experts_count,
-            top_k=c.num_experts_per_tok, n_shared=c.n_shared_experts,
-            scale=c.routed_scaling_factor, renormalize=c.norm_topk_prob,
-            init_std=c.init_std, bias_range=c.router_bias_range)
+        super().__init__(config)
         # block indices: the layers held, then the MTP module's own
-        self.n_blocks = c.num_layers + int(c.mtp)
+        self.n_blocks = config.num_layers + int(config.mtp)
 
     def _is_dense(self, i: int) -> bool:
         return i < self.c.first_k_dense_replace
@@ -165,12 +151,7 @@ class MLAMoE:
     def init(self, key):
         c = self.c
         keys = jax.random.split(key, 3 + self.n_blocks)
-        params = {
-            "embed": self.embed.init(keys[0], c.dtype),
-            "head": {"weight": jax.random.normal(
-                keys[1], (c.vocab_size, c.hidden), c.dtype) * c.init_std},
-            "final_ln": {"weight": jnp.ones((c.hidden,), c.dtype)},
-        }
+        params = self._init_ends(keys[0], keys[1])
         for i in range(self.n_blocks):
             params[f"block{i}"] = self._init_block(keys[3 + i], i)
         if c.mtp:
@@ -183,24 +164,7 @@ class MLAMoE:
             }
         return params
 
-    def partition_specs(self):
-        """PartitionSpec pytree matching init(): the vocabulary's rows
-        over the tp axis (of size 1), everything else replicated."""
-        c = self.c
-        shapes = jax.eval_shape(self.init, jax.random.PRNGKey(0))
-        specs = jax.tree.map(lambda _: P(), shapes)
-        specs["embed"] = {"weight": P(c.axis_name, None)}
-        specs["head"] = {"weight": P(c.axis_name, None)}
-        return specs
-
     # ------------------------------ forward -------------------------------
-    def _norm(self, p, x):
-        return fused_rms_norm(x, p["weight"], eps=self.c.rms_norm_eps)
-
-    def _dot(self, x, w):
-        return jnp.dot(x, w, preferred_element_type=jnp.float32
-                       ).astype(x.dtype)
-
     def _tables(self, i, seq):
         """The rotary tables (cos, sin), each (seq, qk_rope / 2) fp32.
         Whoever runs the blocks computes them once and hands them to
@@ -283,10 +247,6 @@ class MLAMoE:
                 y, stats = self._mlp(i, p["mlp"], m)
                 return x + y, stats
 
-    def _embed(self, params, ids):
-        with jax.named_scope("embed"):
-            return self.embed.apply(params["embed"], ids)
-
     def trunk(self, params, tokens, tables=None):
         """tokens (B, S) -> (the residual stream after the last held
         layer, (B, S, H), before the final norm; the expert layers'
@@ -308,10 +268,6 @@ class MLAMoE:
         shard_map."""
         h, _ = self.trunk(params, tokens)
         return self._final_ln(params, h)
-
-    def _final_ln(self, params, h):
-        with jax.named_scope("final_ln"):
-            return self._norm(params["final_ln"], h)
 
     def mtp_hidden(self, params, h, next_tokens, tables=None):
         """The MTP module up to its own final norm: `h` the trunk's
@@ -335,22 +291,6 @@ class MLAMoE:
         x, stats = self._block(i, params[f"block{i}"], x, tables)
         with jax.named_scope("mtp"):
             return self._norm(p["final_ln"], x), stats
-
-    def logits_local(self, params, h):
-        """The untied head over the held rows: (B, S, V/tp)."""
-        with jax.named_scope("head"):
-            return self._head(params, h)
-
-    def _head(self, params, h):
-        out_dtype = self.c.logits_dtype or jnp.float32
-        return jnp.einsum("bsh,vh->bsv", h, params["head"]["weight"],
-                          preferred_element_type=jnp.float32
-                          ).astype(out_dtype)
-
-    def _xent(self, logits, labels):
-        return vocab_parallel_cross_entropy(
-            logits, labels, axis_name=self.c.axis_name,
-            fused=self.c.fused_xent)
 
     def token_losses(self, params, tokens, labels):
         """(main, mtp, stats): per-token cross entropies (B, S) fp32 of
@@ -391,5 +331,4 @@ class MLAMoE:
         h, stats = self.trunk(params, tokens, tables)
         if self.c.mtp:
             stats = stats + [self.mtp_hidden(params, h, labels, tables)[1]]
-        return (jnp.stack([s.counts for s in stats]),
-                jnp.stack([s.overflow for s in stats]))
+        return self._counts(stats)

@@ -333,9 +333,9 @@ class HeldExpertsMLP:
     run of rows as a grouped GEMM (`jax.lax.ragged_dot`, which the TPU
     compiler turns into a grouped-matmul kernel of its own).  There is
     no per-expert capacity and no dropped token: `rows` is a static
-    bound on the total, twice what uniform routing sends
-    (`rows_bound`), and `HeldExpertsStats.overflow` counts what would
-    not fit.
+    bound on the total, `rows_factor` times (twice, by default) what
+    uniform routing sends (`rows_bound`), and
+    `HeldExpertsStats.overflow` counts what would not fit.
 
     Experts are SwiGLUs without biases, gate and up projection side by
     side in one tensor: `experts_gate_up` (count, H, 2F),
@@ -347,7 +347,8 @@ class HeldExpertsMLP:
     def __init__(self, hidden: int, ffn_hidden: int, n_experts: int, *,
                  first: int, count: int, top_k: int, n_shared: int = 1,
                  scale: float = 1.0, renormalize: bool = True,
-                 init_std: float = 0.02, bias_range: float = 0.0):
+                 init_std: float = 0.02, bias_range: float = 0.0,
+                 rows_factor: float = 2.0):
         if not 0 <= first <= first + count <= n_experts:
             raise ValueError(
                 f"held experts [{first}, {first + count}) are not "
@@ -359,13 +360,16 @@ class HeldExpertsMLP:
         self.top_k, self.n_shared = top_k, n_shared
         self.scale, self.renormalize = scale, renormalize
         self.init_std, self.bias_range = init_std, bias_range
+        self.rows_factor = rows_factor
 
     def rows_bound(self, tokens: int) -> int:
-        """Rows of the grouped buffer: twice the assignments uniform
-        routing sends to the held experts, rounded up to the sublane
-        tile, and never more than every assignment."""
+        """Rows of the grouped buffer: `rows_factor` times (twice,
+        unless the caller knows its share swings wider) the assignments
+        uniform routing sends to the held experts, rounded up to the
+        sublane tile, and never more than every assignment."""
         expected = tokens * self.top_k * self.count / self.n_experts
-        return min(tokens * self.top_k, -(-int(2 * expected) // 8) * 8)
+        return min(tokens * self.top_k,
+                   -(-int(self.rows_factor * expected) // 8) * 8)
 
     def init(self, key, dtype=jnp.float32) -> dict:
         ks = jax.random.split(key, 6)

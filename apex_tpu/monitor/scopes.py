@@ -26,7 +26,14 @@ _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
               "attn/q_a", "attn/q_b", "attn/kv_a", "attn/kv_b", "attn/rope",
               # ... its dense SwiGLU, and the held-experts layer
               "mlp/gate_up", "mlp/down", "mlp/router", "mlp/dispatch",
-              "mlp/experts", "mlp/shared", "mlp/combine")
+              "mlp/experts", "mlp/shared", "mlp/combine",
+              # the hybrid block (models/hybrid_moe.py): the attention's
+              # output gate; the delta-rule mixer's convolution (with
+              # SiLU and the unit scaling of q and k), its decay pair
+              # and beta, the chunked scan (ops/delta_rule.py), and its
+              # output norm and gate
+              "attn/gate", "attn/conv", "attn/decay", "attn/scan",
+              "attn/onorm")
 # every scope path the program may open; `block{i}` is a layer by index
 # (`_tap` spells it the same way), `block` a layer of a scanned stack
 OWNERS = (
@@ -58,16 +65,28 @@ _VOCABULARY = "|".join(          # longest first: the first match is the longest
     for p in sorted(OWNERS, key=len, reverse=True))
 _PATH = re.compile(f"(?:^|/)({_VOCABULARY})(?=/|$)")
 _WHOLE_PATH = re.compile(_VOCABULARY)
+# `jax.checkpoint` puts these two segments between the scope it was
+# called in and the scopes opened inside it, and spells the former once
+# more for the transform around it:
+# "transpose(jvp(block3))/attn/jvp(block3)/attn/checkpoint/
+# rematted_computation/scan/dot_general"
+_REMAT = re.compile(r"/(?:checkpoint|rematted_computation)(?=/|$)")
 
 
 def owner_of(op_name: str):
     """(owner, direction) an `op_name` states itself: the longest
     vocabulary path in it, or None; "bwd" under `transpose(jvp(`, "fwd"
-    under `jvp(`, else "step"."""
+    under `jvp(`, else "step".  Where a path is followed by a longer
+    spelling of itself (what `jax.checkpoint` leaves: the enclosing
+    scopes twice, then the scopes inside), the longer one is the
+    owner; a recomputed forward runs in the backward and is "bwd"."""
     direction = ("bwd" if "transpose(jvp(" in op_name
                  else "fwd" if "jvp(" in op_name else "step")
-    m = _PATH.search(_WRAPPER.sub("", op_name))
-    return (m.group(1) if m else None), direction
+    owner = None
+    for m in _PATH.finditer(_REMAT.sub("", _WRAPPER.sub("", op_name))):
+        if owner is None or m.group(1).startswith(owner):
+            owner = m.group(1)
+    return owner, direction
 
 
 def _shared(found):
@@ -86,6 +105,18 @@ def _shared(found):
     return ("/".join(paths[0][:n]), found[0][1]) if n else None
 
 
+def _first_of_one_sublayer(found):
+    """The first (owner, direction) pair where all are the same
+    sublayer of different layers (`block1/attn/scan`,
+    `block3/attn/scan`), else None."""
+    found = [f for f in found if f is not None]
+    paths = [owner.split("/") for owner, _ in found]
+    if found and len({tuple(p[1:]) for p in paths}) == 1 and all(
+            re.fullmatch(r"block\d+", p[0]) for p in paths):
+        return found[0]
+    return None
+
+
 def owners(hlo_text: str) -> dict:
     """{instruction: (owner, direction, opcode)} for a compiled
     program's text (`compiled.as_text()`).
@@ -93,9 +124,19 @@ def owners(hlo_text: str) -> dict:
     An instruction's owner is the longest vocabulary path in its own
     `op_name`; failing that, what its users share (followed through
     users that state none), failing that, what its operands' producers
-    share; else `UNOWNED`.  The direction comes with the owner."""
+    share; failing that, for a fusion that reads nothing a scope made
+    (a buffer of zeros, or of a constant, that the compiler fills once
+    for every layer's scan) and whose users are one sublayer of several
+    layers, the first of them: a reader sums a sublayer over the
+    layers; failing that, inside a loop's body or condition, the owner
+    of the `while` instruction that runs it (a loop the compiler made
+    of one instruction, a gather say, names the loop and nothing
+    inside); else `UNOWNED`.  The direction comes with the owner."""
     out = {}
-    for comp in parse_module(hlo_text):
+    inherited = {}      # computation -> what the loop running it states
+    # printed order puts a computation before the ones that call it:
+    # backwards, a loop is resolved before its body
+    for comp in reversed(parse_module(hlo_text)):
         instrs = comp.instructions
         own = {i.name: owner_of(i.op_name) for i in instrs}
         found = {n: (o if o[0] else None) for n, o in own.items()}
@@ -113,6 +154,17 @@ def owners(hlo_text: str) -> dict:
             if found[i.name] is None:
                 found[i.name] = _shared(
                     found.get(p) for p in i.operand_names)
+        for i in instrs:
+            if (found[i.name] is None and i.opcode == "fusion"
+                    and not any(found.get(p) for p in i.operand_names)):
+                found[i.name] = _first_of_one_sublayer(
+                    found[u] for u in users[i.name])
+        for i in instrs:
+            if found[i.name] is None:
+                found[i.name] = inherited.get(comp.name)
+            if i.opcode == "while" and found[i.name] is not None:
+                for called in i.called:
+                    inherited.setdefault(called, found[i.name])
             owner, direction = found[i.name] or (UNOWNED, own[i.name][1])
             out[i.name] = (owner, direction, i.opcode)
     return out

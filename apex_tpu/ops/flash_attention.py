@@ -739,7 +739,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       seed_ref, dq_ref, dk_ref, dv_ref, *rest,
                       scale, causal, bq, bk,
                       nq, nk, tile, dropout_rate, bias_kind, has_seg,
-                      want_dbias=False, lanes=0):
+                      want_dbias=False, lanes=0, group=1):
     """Single-pass backward: dq, dk, dv from ONE score/exp recompute.
 
     The two-kernel split recomputes st/p twice (7 matmuls + 2 exp
@@ -751,7 +751,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     `lanes`: the blocks' layout (_Heads).  In the projection's layout
     the three accumulators are 128 lanes wide and every head of the
-    block adds into its own lanes of them."""
+    block adds into its own lanes of them.
+
+    `group`: query heads a kv head (grouped-query attention,
+    head-major).  The `group` query heads of a kv head are consecutive
+    values of i and share the dk and dv blocks: the sums are zeroed on
+    the group's first head and go on through its last, whose last q
+    pass leaves them complete, as a head's last q pass does."""
     if want_dbias:          # "full"-bias grad: ds IS the dbias block
         db_ref, dq_scr, dk_scr, dv_scr = rest
     else:
@@ -769,7 +775,11 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     if want_dbias:
         db_ref[0] = jnp.zeros_like(db_ref[0])
 
-    @pl.when(hd.first((j == 0) & (t == 0)))
+    first_pass = (j == 0) & (t == 0)
+    if group > 1:
+        first_pass = first_pass & (lax.rem(i, group) == 0)
+
+    @pl.when(hd.first(first_pass))
     def _init_dkv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -997,9 +1007,12 @@ def _kernel_shape(sq, sk, d, dv, dtype, causal, *, bias_kind="none",
 
     The tuner is consulted where the caller chose nothing (`block_q`,
     `block_k` and `fused_backward` all None) at a self-attention shape,
-    under `tuner_key` = (batch, heads, has segment ids), the rest of
-    its key: a public entry passes it, ring attention, whose chunks no
-    sweep ran at, does not.  A miss keeps the heuristics below.
+    under `tuner_key` = (batch, heads, has segment ids[, kv heads,
+    where they are fewer]), the rest of its key: a public entry passes
+    it, ring attention, whose chunks no sweep ran at, does not.  A miss
+    keeps the heuristics below.  Fewer kv heads change nothing else
+    here: a grid step is one query head's (bq, bk) scores either way,
+    and the single pass's dk and dv sums are one kv head's.
 
     Blocks, swept on v5e (docs/PERF.md): one block an axis while the
     sequence fits (<= 1024), else (512, 1024) to keep the k-side
@@ -1030,13 +1043,13 @@ def _kernel_shape(sq, sk, d, dv, dtype, causal, *, bias_kind="none",
             and block_k is None and fused_backward is None):
         from apex_tpu import tune
 
-        b, h, has_seg = tuner_key
+        b, h, has_seg, *kv_heads = tuner_key
         # a pure host-side dict access at trace time: no collectives,
         # no host syncs; swept at self-attention shapes only
         cfg = tune.tuned("flash_sdpa",
                          tune.flash_attrs(b, h, sq, sk, d, dtype, causal,
                                           bias=bias_kind, seg=has_seg,
-                                          dv=dv))
+                                          dv=dv, hkv=(kv_heads or [h])[0]))
         cfg = _checked_tuned_config(cfg, sq, sk, d) if cfg else None
         if cfg:
             block_q, block_k = cfg.get("block_q"), cfg.get("block_k")
@@ -1068,7 +1081,7 @@ def _flatten_bh(x):
 @functools.lru_cache(maxsize=None)
 def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
                      bias_kind, nb, nh, has_seg, want_dbias, interpret,
-                     **static):
+                     group=1, **static):
     """The pallas_call of one head-major kernel: `kind` "fwd", "bwd"
     (the single-pass backward), "dq" or "dkv" (the two kernels), over
     (batch*heads, seq, d) arrays.  One object a shape and set of
@@ -1077,7 +1090,14 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
     a tile) is traced and lowered once a program, not once a layer.
     `dtypes`: the results', (o,) or (dq, dk, dv); `want_dbias`: the
     kernel also writes the gradient of a "full" bias ("bwd", "dq") or
-    of a key-compact one ("dkv")."""
+    of a key-compact one ("dkv").
+
+    `group`: query heads a kv head.  k and v are then
+    (batch*heads/group, seq, .) arrays and a query head's k and v
+    blocks are those of kv head `i // group`: nothing is repeated.  The
+    single pass sums dk and dv over the group inside its grid
+    (_bwd_fused_kernel); the dkv kernel writes a query head's own
+    partial, (batch*heads, seq, .), for its caller to sum."""
     nq, nk = sq // bq, sk // bk
     # the grid is (i, j, t), q blocks outside k blocks; the dkv
     # kernel's is (i, t, j), q blocks inner and sequential
@@ -1088,11 +1108,18 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
         return pl.BlockSpec((1, rows, width),
                             lambda i, *at: (i, jt(*at)[take], 0))
 
-    def out(rows, width, dtype):
-        return jax.ShapeDtypeStruct((bh, rows, width), dtype)
+    def out(rows, width, dtype, heads=bh):
+        return jax.ShapeDtypeStruct((heads, rows, width), dtype)
 
     q, k, v, do = (spec(bq, d, 0), spec(bk, d, 1), spec(bk, dv, 1),
                    spec(bq, dv, 0))
+    dk, dv_ = k, v          # a query head's own blocks of dk and dv
+    if group > 1:
+        def of_group(rows, width):
+            return pl.BlockSpec(
+                (1, rows, width),
+                lambda i, *at: (lax.div(i, group), jt(*at)[1], 0))
+        k, v = of_group(bk, d), of_group(bk, dv)
     # lse and delta as (bh, nq, bq), one whole-head block resident
     # across the block loops (a (bh, sq, 1) fp32 array would tile-pad
     # to 128x its size; 2-D (1, bq) blocks violate the (8, 128) tile
@@ -1113,6 +1140,12 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
         dimension_semantics=("parallel", "arbitrary", "arbitrary"))
     split = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
+    if group > 1 and kind == "bwd":
+        # dk and dv are summed across the heads of a group: no axis of
+        # the grid may be split between cores
+        static.update(group=group)
+        shared = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"))
     # "full"-bias grad: ds IS the dbias block, written per (j, t)
     ds_spec = [pl.BlockSpec((1, bk, bq), lambda i, j, t: (i, t, j))]
     ds_out = [out(sk, sq, f32)]
@@ -1134,8 +1167,9 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
                               want_dbias=want_dbias, **static),
             grid=(bh, nq, nk), in_specs=ins,
             out_specs=[q, k, v] + ds_spec * want_dbias,
-            out_shape=[out(sq, d, dq_dt), out(sk, d, dk_dt),
-                       out(sk, dv, dv_dt)] + ds_out * want_dbias,
+            out_shape=[out(sq, d, dq_dt), out(sk, d, dk_dt, bh // group),
+                       out(sk, dv, dv_dt, bh // group)]
+            + ds_out * want_dbias,
             scratch_shapes=[pltpu.VMEM((bq, d), f32),
                             pltpu.VMEM((sk, d), f32),
                             pltpu.VMEM((sk, dv), f32)],
@@ -1156,7 +1190,7 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
         functools.partial(_bwd_dkv_kernel, nq=nq, want_dbias=want_dbias,
                           **static),
         grid=(bh, nk, nq), in_specs=ins,
-        out_specs=[k, v] + [pl.BlockSpec(
+        out_specs=[dk, dv_] + [pl.BlockSpec(
             (1, nk, bk), lambda i, t, j: (i, 0, 0))] * want_dbias,
         out_shape=[out(sk, d, dk_dt), out(sk, dv, dv_dt)]
         + [out(nk, bk, f32)] * want_dbias,
@@ -1164,6 +1198,14 @@ def _head_major_call(kind, bh, h, sq, sk, d, dv, dtypes, bq, bk, tile,
         + [pltpu.VMEM((1, bk), f32)] * want_dbias,
         compiler_params=shared if want_dbias else split,
         interpret=interpret, name="flash_bwd_dkv")
+
+
+def _group_of(q, k):
+    """{"group": query heads a kv head} of head-major q and k, or {}
+    where every query head has a kv head of its own: a call without
+    groups is built as it always was."""
+    group = q.shape[1] // k.shape[1]
+    return {"group": group} if group > 1 else {}
 
 
 def _fwd_impl(q, k, v, scale, causal, shape, dropout_rate=0.0, seed=None,
@@ -1186,7 +1228,7 @@ def _fwd_impl(q, k, v, scale, causal, shape, dropout_rate=0.0, seed=None,
         "fwd", bh, h, sq, sk, d, dv, (q.dtype,), bq, bk, shape.tile_fwd,
         bias_kind, nb, nh, q_seg is not None, False, pallas_interpret(),
         scale=scale, causal=causal, dropout_rate=dropout_rate,
-    )(qf, kf, vf, bias_t, qs, ks, seed)
+        **_group_of(q, k))(qf, kf, vf, bias_t, qs, ks, seed)
     return o.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
@@ -1218,13 +1260,19 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, shape, dropout_rate=0.0,
             _flatten_bh(do), lse.reshape(bh, nq, bq),
             delta.reshape(bh, nq, bq), bias_t, qsegs, ksegs, seed]
 
+    grouped = _group_of(q, k)
+    # the two kernels' dk and dv of a group are a partial a query head,
+    # summed below: kept in fp32 until then
+    partials = jnp.float32 if grouped and not shape.fused_bwd else None
+
     def run(kind, dbias):
         return _head_major_call(
             kind, bh, h, sq, sk, d, dv,
-            (grad_dtype or q.dtype, grad_dtype or k.dtype,
-             grad_dtype or v.dtype), bq, bk, shape.tile_bwd, bias_kind,
-            nb, nh, q_seg is not None, dbias, pallas_interpret(),
-            scale=scale, causal=causal, dropout_rate=dropout_rate)(*args)
+            (grad_dtype or q.dtype, partials or grad_dtype or k.dtype,
+             partials or grad_dtype or v.dtype), bq, bk, shape.tile_bwd,
+            bias_kind, nb, nh, q_seg is not None, dbias, pallas_interpret(),
+            scale=scale, causal=causal, dropout_rate=dropout_rate,
+            **grouped)(*args)
 
     def dbias_of(db_full):
         """(b, h, ...) per-head dbias partials → the caller's broadcast
@@ -1247,6 +1295,11 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, shape, dropout_rate=0.0,
     else:
         dq, *db_t = run("dq", dbias_full)
         dk, dv, *db_sk = run("dkv", dbias_sk)
+        if grouped:
+            dk, dv = (
+                part.reshape(b, k.shape[1], -1, sk, part.shape[-1]).sum(2)
+                .astype(grad_dtype or to.dtype)
+                for part, to in ((dk, k), (dv, v)))
     dbias = None
     if dbias_full:
         dbias = jnp.swapaxes(dbias_of(db_t[0].reshape(b, h, sk, sq)), 2, 3)
@@ -1441,6 +1494,13 @@ def flash_attention(q, k, v, *, causal: bool = False,
     width and P.V, dV, dP over v's, and no operand is padded to
     another's.  The default `softmax_scale` is 1/sqrt(q's width).
 
+    k and v may have fewer heads than q, a divisor of q's
+    (grouped-query attention): kv head j serves query heads
+    `j * group ... (j + 1) * group - 1`.  The kernels read a kv head's
+    blocks where they lie for each of its query heads (no repeated copy
+    of k or v exists) and dk, dv come summed over the group: inside the
+    single pass's grid, or from the two kernels' partials a query head.
+
     ≡ apex.contrib.fmha.FMHAFun (apex/contrib/fmha/fmha.py:33-72) with
     the seq≤512/head-64 restriction removed, and the core of the
     fast_multihead_attn variants (self/encdec attention cores).
@@ -1508,6 +1568,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
         raise ValueError("q_segment_ids and kv_segment_ids go together")
     b, h = q.shape[0], q.shape[1]
     sq, sk = q.shape[2], k.shape[2]
+    hkv = k.shape[1]
+    if h % hkv or v.shape[1] != hkv:
+        raise ValueError(
+            f"{hkv} k heads and {v.shape[1]} v heads do not serve "
+            f"{h} query heads in equal groups")
     if bias is not None:
         eb, eh = bias.shape[0], bias.shape[1]
         if (bias.ndim != 4 or eb not in (1, b) or eh not in (1, h)
@@ -1533,7 +1598,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
             bias_kind=_bias_kind(bias, sk),
             dbias=_wants_dbias(bias, bias_grad), block_q=block_q,
             block_k=block_k, fused_backward=fused_backward,
-            tuner_key=(b, h, q_segment_ids is not None))
+            tuner_key=(b, h, q_segment_ids is not None)
+            + ((hkv,) if hkv != h else ()))
         seed = _dropout_seed(dropout_rate, dropout_key)
         _calls["head_major"] += 1
         return _flash(q, k, v, bias, q_segment_ids, kv_segment_ids,
@@ -1542,6 +1608,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
     # fallback keeps the same dbias semantics: AD through the dense
     # path yields the (broadcast-reduced) dbias when bias_grad, and a
     # stop_gradient reproduces the constant-bias contract otherwise
+    if hkv != h:     # the dense oracle takes a kv head a query head
+        k, v = (jnp.repeat(x, h // hkv, axis=1) for x in (k, v))
     return attention_reference(q, k, v, causal=causal, softmax_scale=scale,
                                bias=(bias if bias is None or bias_grad
                                      else lax.stop_gradient(bias)),

@@ -62,14 +62,16 @@ def pow2_bucket(n: int) -> int:
 
 
 def flash_attrs(b, h, sq, sk, d, dtype, causal, bias="none", seg=False,
-                dv=None):
+                dv=None, hkv=None):
     """The ONE definition of the flash_sdpa lookup-key attrs — shared
     by the runtime lookup (ops/flash_attention.py), the sweep driver
     (tune/search.py), and the committed defaults (tune/defaults.py).
     A key-schema change here reaches all three or none.  dtype None
     means the bench dtype, bfloat16.  `dv`, v's width, is part of the
     key only where it differs from `d`: every key made before the two
-    could differ reads as it did."""
+    could differ reads as it did.  So with `hkv`, the kv heads of a
+    grouped-query call: in the key only where they are fewer than
+    `h`."""
     import jax.numpy as jnp
 
     dtype = jnp.bfloat16 if dtype is None else dtype
@@ -78,7 +80,24 @@ def flash_attrs(b, h, sq, sk, d, dtype, causal, bias="none", seg=False,
                  bias=bias, seg=bool(seg))
     if dv is not None and int(dv) != int(d):
         attrs["dv"] = int(dv)
+    if hkv is not None and int(hkv) != int(h):
+        attrs["hkv"] = int(hkv)
     return attrs
+
+
+def delta_rule_attrs(b, n, s, dk, dv, dtype):
+    """The ONE definition of the `delta_rule` lookup-key attrs — shared
+    by the runtime lookup (ops/delta_rule.py) and the committed
+    defaults.  The config carries `chunk`, the tokens a chunk of the
+    chunkwise gated delta rule: the sequential part runs S / chunk
+    steps and keeps as many states a head, the parallel part's work a
+    token grows with the chunk.  dtype None means the bench dtype,
+    bfloat16."""
+    import jax.numpy as jnp
+
+    dtype = jnp.bfloat16 if dtype is None else dtype
+    return dict(b=int(b), n=int(n), s=int(s), dk=int(dk), dv=int(dv),
+                dtype=jnp.dtype(dtype).name)
 
 
 def decode_attrs(n_slots, q_len, hq, hkv, d, page_size, dtype):

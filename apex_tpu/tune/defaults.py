@@ -25,12 +25,12 @@ from __future__ import annotations
 
 
 def _flash(b, h, sq, sk, d, dtype, causal, bias="none", seg=False,
-           dv=None):
+           dv=None, hkv=None):
     from apex_tpu import tune
     from apex_tpu.tune.cache import make_key
     return make_key("flash_sdpa",
                     tune.flash_attrs(b, h, sq, sk, d, dtype, causal,
-                                     bias=bias, seg=seg, dv=dv))
+                                     bias=bias, seg=seg, dv=dv, hkv=hkv))
 
 
 def _mk(config, note):
@@ -41,6 +41,9 @@ def _v5e_entries():
     """Entries measured on a v5e, each with the run it came from: a
     headline metric must never gamble on an unmeasured config.  Promote
     cache winners here per docs/tuning.md once measured."""
+    from apex_tpu import tune
+    from apex_tpu.tune.cache import make_key
+
     e = {}
     # latent attention, keys 192 and values 128, 2 x 32 heads x 4096
     # (models/mla_moe.py at the benchmark's cell): the single-pass
@@ -50,12 +53,33 @@ def _v5e_entries():
         {"block_q": 1024, "block_k": 512, "fused_bwd": True},
         "v5e, PR 28 chip run, forward + backward a layer: 15.2 ms; "
         "fused at the heuristics' blocks 16.7, two-kernel 19.0")
+    # grouped-query attention, 64 query heads on 8 kv heads of 128,
+    # 1 x 4096 (models/hybrid_moe.py at the benchmark's cell)
+    e[_flash(1, 64, 4096, 4096, 128, "bfloat16", True, hkv=8)] = _mk(
+        {"block_q": 2048, "block_k": 512, "fused_bwd": True},
+        "v5e, PR 32 chip run, forward + backward a layer: 7.53 ms; "
+        "(1024, 1024) 8.00, (1024, 512) 8.44, the heuristics' (512, "
+        "1024) fused 9.50 and two-kernel 12.50; (4096, 256) does not "
+        "fit VMEM")
+    # the chunked gated delta rule, 64 heads of 128 x 128, 1 x 4096
+    # (ops/delta_rule.py at the same cell)
+    e[make_key("delta_rule", tune.delta_rule_attrs(
+        1, 64, 4096, 128, 128, "bfloat16"))] = _mk(
+        {"chunk": 64, "heads": 32},
+        "v5e, PR 32 chip run, forward + backward a layer in one call of "
+        "64 heads: chunk 64 62.3 ms, 32 57.2 (twice the states kept), "
+        "128 69.4; two calls of 32 heads for the step's memory")
     # flat-optimizer block rows at the 1B Adam bench point: the swept
     # heuristic value, committed so the fingerprint records it
-    from apex_tpu.tune.cache import make_key
     e[make_key("opt_flat", dict(kernel="adam", rows=8388608))] = _mk(
         {"block_rows": 512},
         "v5e 1B-param sweep: 512 rows = 721 GB/s (docs/PERF.md)")
+    # the same kernel over the hybrid cell's 1.295B bf16 parameters, the
+    # next bucket of rows: the default read the same share of the HBM peak
+    e[make_key("opt_flat", dict(kernel="adam", rows=16777216))] = _mk(
+        {"block_rows": 512},
+        "v5e, PR 32 chip run: adam_hbm_pct 76.9 at 512 rows, as the 1B "
+        "point's 77.0")
     return e
 
 

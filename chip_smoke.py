@@ -86,6 +86,14 @@ class KernelCase:
     in_rms: bool = False
 
 
+def _attention_fwd_bwd(attn, q, k, v, do):
+    """(out, dq, dk, dv) of `attn(q, k, v)` under the cotangent `do`."""
+    import jax
+
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out,) + vjp(do)
+
+
 def flash_case(name, shape, config: Optional[dict] = None,
                v_dim: Optional[int] = None) -> KernelCase:
     """Causal bf16 flash attention, forward and backward, against the
@@ -109,9 +117,7 @@ def flash_case(name, shape, config: Optional[dict] = None,
         return tuple(jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(
             ks, (shape, shape, v_shape, v_shape)))
 
-    def fwd_bwd(attn, q, k, v, do):
-        out, vjp = jax.vjp(attn, q, k, v)
-        return (out,) + vjp(do)
+    fwd_bwd = _attention_fwd_bwd
 
     def kernel(q, k, v, do):
         return fwd_bwd(lambda q, k, v: flash_attention(
@@ -127,6 +133,117 @@ def flash_case(name, shape, config: Optional[dict] = None,
     # bf16 in and out: a few bf16 ulps at the O(1) magnitudes attention
     # produces, the bound tests/test_flash_attention.py holds bf16 to
     return KernelCase(name, make_args, kernel, reference, 5e-2, 5e-2)
+
+
+def gqa_flash_case(name, batch, heads, kv_heads, seq, head_dim,
+                   config: Optional[dict] = None) -> KernelCase:
+    """Causal bf16 grouped-query flash attention (`kv_heads` serving
+    `heads` query heads, no bias, no rotary: models/hybrid_moe.py),
+    forward and dq, dk, dv, against the dense reference on k and v
+    repeated a query head.  The reference walks the kv heads one at a
+    time so its (group, S, S) scores stay small, and its dk, dv are
+    the sums over a group that the repeat's transpose makes.  config
+    None leaves the kernel shape to the tuner, as the model does."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.flash_attention import (
+        attention_reference,
+        flash_attention,
+    )
+
+    group = heads // kv_heads
+
+    def make_args(key):
+        ks = jax.random.split(key, 4)      # q, k, v, do
+        return tuple(jax.random.normal(k, (batch, h, seq, head_dim),
+                                       jnp.bfloat16)
+                     for k, h in zip(ks, (heads, kv_heads, kv_heads, heads)))
+
+    fwd_bwd = _attention_fwd_bwd
+
+    def kernel(q, k, v, do):
+        return fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, **(config or {})), q, k, v, do)
+
+    def reference(q, k, v, do):
+        def one(x):       # a kv head and its query heads: (B, group|1, S, d)
+            return fwd_bwd(lambda q, k, v: attention_reference(
+                q, jnp.repeat(k, group, 1), jnp.repeat(v, group, 1),
+                causal=True), *x)
+
+        def by_kv_head(x):
+            b, h, s, d = x.shape
+            return x.reshape(b, kv_heads, h // kv_heads, s, d).swapaxes(0, 1)
+
+        outs = jax.lax.map(one, tuple(map(by_kv_head, (q, k, v, do))))
+        return tuple(o.swapaxes(0, 1).reshape(a.shape)
+                     for o, a in zip(outs, (q, q, k, v)))
+
+    # dk and dv sum eight query heads' parts, so they are larger than a
+    # query head's and are held to the same relative bound
+    return KernelCase(name, make_args, kernel, reference, 5e-2, 5e-2)
+
+
+def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4) -> KernelCase:
+    """The chunked gated delta rule (ops/delta_rule.py) in bf16 at the
+    benchmark's shape, forward and all five gradients, against the
+    recurrence a token at a time in float32.  Inputs as KDA makes them:
+    q and k of unit length (q by d^-1/2 more), the log-decay
+    -A softplus(.) with A in (1, 16) and a time step in (1e-3, 1e-1),
+    beta in (0, 2).  The reference walks the heads a few at a time: its
+    backward keeps a state a token."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.delta_rule import (
+        gated_delta_rule,
+        gated_delta_rule_reference,
+    )
+
+    shape = (batch, heads, seq, dim)
+
+    def make_args(key):
+        ks = jax.random.split(key, 8)
+
+        def unit(k, scale=1.0):
+            x = jax.random.normal(k, shape, jnp.float32)
+            return (x * scale / jnp.linalg.norm(x, axis=-1, keepdims=True)
+                    ).astype(jnp.bfloat16)
+        rate = jax.random.uniform(ks[3], (1, heads, 1, 1), jnp.float32, 1, 16)
+        dt = jnp.exp(jax.random.uniform(
+            ks[4], (1, heads, 1, dim), jnp.float32, math.log(1e-3),
+            math.log(1e-1)))
+        g = -rate * jax.nn.softplus(
+            jnp.log(jnp.expm1(dt)) + 0.3 * jax.random.normal(ks[5], shape))
+        beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[6], shape[:3]))
+        return (unit(ks[0], dim ** -0.5), unit(ks[1]),
+                jax.random.normal(ks[2], shape, jnp.bfloat16), g, beta,
+                jax.random.normal(ks[7], shape, jnp.bfloat16))
+
+    def fwd_bwd(rule, q, k, v, g, beta, do):
+        out, vjp = jax.vjp(rule, q, k, v, g, beta)
+        return (out,) + vjp(do.astype(out.dtype))
+
+    def kernel(*args):
+        return fwd_bwd(gated_delta_rule, *args)
+
+    def reference(*args):
+        def some(x):
+            return fwd_bwd(gated_delta_rule_reference, *x)
+
+        def by_pass(x):    # (B, n, ...) -> (n / pass, B, pass, ...)
+            return x.reshape(batch, heads // heads_a_pass, heads_a_pass,
+                             *x.shape[2:]).swapaxes(0, 1)
+
+        outs = jax.lax.map(some, tuple(map(by_pass, args)))
+        return tuple(o.swapaxes(0, 1).reshape(a.shape)
+                     for o, a in zip(outs, args[2:3] + args[:5]))
+
+    # in units of each output's rms: bf16 operands in the chunk-local
+    # products against float32 throughout
+    return KernelCase(name, make_args, kernel, reference, 5e-2, 0.3,
+                      mosaic=False, in_rms=True)
 
 
 def flash_qkv_case(name, seq, batch, heads, head_dim) -> KernelCase:
@@ -172,13 +289,14 @@ def flash_qkv_case(name, seq, batch, heads, head_dim) -> KernelCase:
 
 
 def held_experts_case(name, tokens, hidden, ffn, n_experts, count,
-                      top_k) -> KernelCase:
+                      top_k, **layer_options) -> KernelCase:
     """`moe.HeldExpertsMLP` (sigmoid router over `n_experts`, the
     sort-by-expert grouping, grouped GEMMs over the `count` experts
     held, the shared expert) in bf16, forward and the gradients of the
     input and of the experts' tensors, against the same sum written
     densely: every held expert over every token, times the weight the
-    same router gave it (0 where it was not chosen)."""
+    same router gave it (0 where it was not chosen).  `layer_options`
+    go to the layer: the buffer's size."""
     import jax
     import jax.numpy as jnp
 
@@ -186,7 +304,8 @@ def held_experts_case(name, tokens, hidden, ffn, n_experts, count,
     from apex_tpu.moe.layer import swiglu
 
     layer = HeldExpertsMLP(hidden, ffn, n_experts, first=0, count=count,
-                           top_k=top_k, scale=2.5, bias_range=0.05)
+                           top_k=top_k, scale=2.5, bias_range=0.05,
+                           **layer_options)
     trained = ("experts_gate_up", "experts_down")
     # a weight gradient is a sum over an expert's rows, tokens * top_k /
     # n_experts of them on average: divided by the root of that, it has
@@ -491,8 +610,18 @@ def kernel_cases(device) -> list:
         # rtol the output reads 0.11-0.14 of its rms and the gradients of
         # q_b, kv_a, kv_b, proj 0.03, 0.02, 0.03, 0.02 (three seeds, PR 31)
         mla_attention_case("mla_attention_grads", 2, 4096),
+        # grouped-query attention, 64 query heads on 8 kv heads, and the
+        # chunked gated delta rule, both at the hybrid cell's shape
+        # (models/hybrid_moe.py): `correct` cannot see a wrong backward
+        gqa_flash_case("gqa_flash_grads", 1, 64, 8, 4096, 128),
+        delta_rule_case("delta_rule_grads", 1, 64, 4096, 128),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
         held_experts_case("moe_held_experts", 8192, 2048, 768, 256, 16, 8),
+        # one chip's 8 of 320 experts of 1280 over 4,096 tokens, a
+        # buffer of eight times the expectation (the hybrid cell's
+        # expert layer)
+        held_experts_case("moe_held_experts_320", 4096, 4096, 1280, 320, 8, 8,
+                          rows_factor=8.0),
         adam_case("adam_flat_fp32", n_params, jnp.float32),
         adam_case("adam_flat_bf16", n_params, jnp.bfloat16),
         xent_case("xent_pallas", BATCH * SEQ, FLAGSHIP["vocab_size"]),

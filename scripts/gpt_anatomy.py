@@ -363,7 +363,7 @@ def _check_committed(committed):
         try:
             if op == "flash_sdpa":
                 if (a.get("bias", "none") != "none" or a["sq"] != a["sk"]
-                        or "dv" in a):
+                        or "dv" in a or "hkv" in a):
                     print(f"  --check: cannot sweep {key} (unsupported "
                           "key shape); skipping", flush=True)
                     continue

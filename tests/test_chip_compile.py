@@ -104,13 +104,25 @@ def _smoke_case(name, device):
         assert config["fused_backward"] is True
         case = chip_smoke.flash_case(name, (2, 32, 4096, 192), config,
                                      v_dim=128)
+    if name == "gqa_flash_grads":
+        # the same for the grouped-query call: the single pass, which
+        # sums dk and dv over a group inside its grid
+        from apex_tpu import tune
+        from apex_tpu.tune import defaults
+        key = tune.make_key("flash_sdpa", tune.flash_attrs(
+            1, 64, 4096, 4096, 128, "bfloat16", True, hkv=8))
+        config = dict(defaults.DEFAULTS["v5e"][key]["config"])
+        config["fused_backward"] = config.pop("fused_bwd")
+        assert config["fused_backward"] is True
+        case = chip_smoke.gqa_flash_case(name, 1, 64, 8, 4096, 128, config)
     return case
 
 
 @pytest.mark.parametrize("name,min_kernels", [
+    ("gqa_flash_grads", 2),
     ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_d64_s2048", 2),
     ("flash_mla_192_128", 2), ("mla_attention_grads", 6),
-    ("moe_held_experts", 6),
+    ("moe_held_experts", 6), ("moe_held_experts_320", 6),
     ("adam_flat_fp32", 1),
     ("adam_flat_bf16", 1), ("xent_pallas", 2), ("xent_vocab_parallel", 0),
     ("flash_decode", 1)])

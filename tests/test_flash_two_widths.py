@@ -109,6 +109,10 @@ PARENT_JAXPR = {
                                     "d5c35d18a79cfc02acf00be",
     "cell_joyai_b2s4096_192_128": "6bf1eab9a8716d7ddb9c2aa6ceade2129e020b995ee"
                                   "bb8c6a03424881a13c807",
+    # the grouped-query call of the fifth cell, taken at the PR that
+    # taught the kernels groups (PR 32): eight query heads a kv head
+    "cell_solar_open2_b1s4096_64_on_8": "df29d241d5b863a1535b50d0e5c6203424dc9"
+                                        "08ad816c09ec7e7620fd8564998",
 }
 
 
@@ -158,6 +162,22 @@ def _latent_text():
         q, k, v)[1](jnp.zeros(v.shape, v.dtype)))(q, q, v))
 
 
+def _grouped_text():
+    """Cell 5's call: 64 query heads on 8 kv heads of 128, 1 x 4096,
+    head-major, under the committed v5e config."""
+    from apex_tpu.tune import defaults
+    key = tune.make_key("flash_sdpa", tune.flash_attrs(
+        1, 64, 4096, 4096, 128, "bfloat16", True, hkv=8))
+    config = dict(defaults.DEFAULTS["v5e"][key]["config"])
+    config["fused_backward"] = config.pop("fused_bwd")
+    q = jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 8, 4096, 128), jnp.bfloat16)
+    return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+        lambda q, k, v: FA.flash_attention(
+            q, k, v, causal=True, use_pallas_override=True, **config),
+        q, k, v)[1](jnp.zeros(q.shape, q.dtype)))(q, k, k))
+
+
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
                     reason="the recorded texts are JAX 0.9.0's")
 @pytest.mark.parametrize("case,trace", [
@@ -170,6 +190,7 @@ def _latent_text():
     ("cell_gpt_1p3b_tp2dp2_b8s1024",
      lambda: _projection_text(1024, 8, 16, True)),
     ("cell_joyai_b2s4096_192_128", _latent_text),
+    ("cell_solar_open2_b1s4096_64_on_8", _grouped_text),
 ])
 def test_one_width_traces_to_the_parents_jaxpr(case, trace):
     assert hashlib.sha256(trace().encode()).hexdigest() == PARENT_JAXPR[case]

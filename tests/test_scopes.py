@@ -48,6 +48,18 @@ FLASH0 = "block0/attn/flash"
     ("jit(local_step)/optimizer/convert_element_type", ("optimizer", "step")),
     ("jit(local_step)/jvp(block2)/mlp/gelu/jit(gelu)/tanh",
      ("block2/mlp/gelu", "fwd")),
+    # under `jax.checkpoint` the enclosing scopes come twice and the
+    # scopes inside follow two segments of its own: the longer spelling
+    # owns, and the recomputed forward is the backward's
+    ("jit(local_step)/transpose(jvp(block3))/attn/jvp(block3)/attn/"
+     "checkpoint/rematted_computation/scan/dot_general",
+     ("block3/attn/scan", "bwd")),
+    ("jit(local_step)/transpose(jvp(block0))/attn/jvp(block0)/attn/"
+     "checkpoint/gate/transpose(jvp())/mul", ("block0/attn/gate", "bwd")),
+    ("jit(local_step)/jvp(block1)/attn/scan/checkpoint/jit(_where)/select_n",
+     ("block1/attn/scan", "fwd")),
+    # a later path that does not spell the first one on is not its owner
+    ("jit(local_step)/jvp(mtp)/proj/embed/gather", ("mtp/proj", "fwd")),
     # direction without an owner; a jitted function is not a scope; a
     # block alone, or a sublayer outside a block, is no vocabulary path
     ("jit(local_step)/jvp()/dot_general", (None, "fwd")),
@@ -117,6 +129,92 @@ ENTRY %main () -> () {
     found = scopes.owners(text)
     assert found["copy.1"] == ("block1/attn", "fwd", "copy")
     assert found["copy.2"] == ("block1/ln1", "fwd", "copy")
+
+
+def test_a_buffer_filled_once_for_every_layers_scan_goes_to_the_first():
+    """Not recorded: the compiler fills one buffer of zeros for the
+    scans of three layers.  A fusion that reads nothing a scope made
+    and whose users are one sublayer of several layers is the first
+    one's; one that reads a scope's array, or with users of two
+    sublayers, stays as the other rules leave it."""
+    text = """HloModule m
+
+ENTRY %main () -> () {
+  %zeros = f32[8] fusion(), kind=kLoop, calls=%fused
+  %a = f32[8] negate(%zeros), metadata={op_name="jit(f)/jvp(block1)/attn/scan/neg"}
+  %b = f32[8] negate(%zeros), metadata={op_name="jit(f)/transpose(jvp(block3))/attn/scan/neg"}
+  %mixed = f32[8] fusion(), kind=kLoop, calls=%fused.1
+  %c = f32[8] negate(%mixed), metadata={op_name="jit(f)/jvp(block1)/attn/scan/neg"}
+  %d = f32[8] negate(%mixed), metadata={op_name="jit(f)/jvp(block2)/attn/conv/neg"}
+  %one = f32[] constant(1)
+  %ones = f32[8] fusion(%one), kind=kLoop, calls=%fused.3
+  %g = f32[8] negate(%ones), metadata={op_name="jit(f)/jvp(block2)/mlp/dispatch/neg"}
+  %h = f32[8] negate(%ones), metadata={op_name="jit(f)/jvp(block3)/mlp/dispatch/neg"}
+  %p = f32[8] parameter(0), metadata={op_name="jit(f)/jvp(block1)/ln2/mul"}
+  %q = f32[8] parameter(1), metadata={op_name="jit(f)/jvp(block2)/ln2/mul"}
+  %fed = f32[8] fusion(%p, %q), kind=kLoop, calls=%fused.2
+  %e = f32[8] negate(%fed), metadata={op_name="jit(f)/jvp(block1)/mlp/router/neg"}
+  %f = f32[8] negate(%fed), metadata={op_name="jit(f)/jvp(block2)/mlp/router/neg"}
+}
+"""
+    found = scopes.owners(text)
+    assert found["zeros"] == ("block1/attn/scan", "fwd", "fusion")
+    assert found["ones"] == ("block2/mlp/dispatch", "fwd", "fusion")
+    assert found["mixed"][0] == scopes.UNOWNED
+    assert found["fed"][0] == scopes.UNOWNED
+
+
+def test_a_loop_the_compiler_made_belongs_to_the_instruction_it_was():
+    """Not recorded either: the TPU compiler turns one gather of the
+    delta rule's triangular inverse into a loop, names the `while` and
+    nothing in its body; a body's instructions take the loop's owner,
+    nested loops down, where no rule inside the body finds one."""
+    text = """HloModule m
+
+%inner_body (p: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %p = (s32[], f32[8]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %x = f32[8] get-tuple-element(%p), index=1
+  %fusion.7 = f32[8] fusion(%x), kind=kLoop, calls=%fused
+  ROOT %t = (s32[], f32[8]) tuple(%i, %fusion.7)
+}
+
+%cond (p: (s32[], f32[8])) -> pred[] {
+  %p.1 = (s32[], f32[8]) parameter(0)
+  %i.1 = s32[] get-tuple-element(%p.1), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i.1, %n), direction=LT
+}
+
+%body (q: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %q = (s32[], f32[8]) parameter(0)
+  %z = f32[8] constant(0)
+  %named = f32[8] negate(%z), metadata={op_name="jit(f)/jvp(block2)/attn/conv/neg"}
+  %y = f32[8] get-tuple-element(%q), index=1
+  %copy.9 = f32[8] copy(%y)
+  %t.2 = (s32[], f32[8]) tuple(%j, %copy.9)
+  %j = s32[] get-tuple-element(%q), index=0
+  ROOT %while.2 = (s32[], f32[8]) while(%t.2), condition=%cond, body=%inner_body
+}
+
+ENTRY %main () -> () {
+  %init = (s32[], f32[8]) tuple()
+  %while.1 = (s32[], f32[8]) while(%init), condition=%cond, body=%body, metadata={op_name="jit(f)/jvp(block1)/attn/scan/jit(diagonal)/gather"}
+  %init.2 = (s32[], f32[8]) tuple()
+  %other = (s32[], f32[8]) while(%init.2), condition=%cond, body=%body
+}
+"""
+    found = scopes.owners(text)
+    scan = ("block1/attn/scan", "fwd")
+    assert found["while.1"] == (*scan, "while")
+    assert found["copy.9"] == (*scan, "copy")
+    assert found["while.2"] == (*scan, "while")          # and on down
+    assert found["fusion.7"] == (*scan, "fusion")
+    assert found["lt"] == (*scan, "compare")             # the condition too
+    # what a body states itself stays, and a loop that states nothing
+    # hands nothing down
+    assert found["named"] == ("block2/attn/conv", "fwd", "negate")
+    assert found["other"][0] == scopes.UNOWNED
 
 
 # --------------------------- the vocabulary ---------------------------
@@ -303,7 +401,10 @@ def test_step_owners_names_the_latent_attention_block():
     added = {p.replace("block{i}/", "") for p in scopes.OWNERS
              if p.startswith("block{i}/")} - {
         "ln1", "attn", "attn/qkv", "attn/flash", "attn/proj", "ln2", "mlp",
-        "mlp/fc1", "mlp/gelu", "mlp/fc2"}
+        "mlp/fc1", "mlp/gelu", "mlp/fc2"} - {
+        # the hybrid block's, which its own step opens
+        # (test_hybrid_moe.py::test_every_instruction_of_the_step_is_owned)
+        "attn/gate", "attn/conv", "attn/decay", "attn/scan", "attn/onorm"}
     assert added == {s.split("/", 1)[1] for s in new} - {
         "attn/flash", "attn/proj"} | {"mlp/gate_up", "mlp/down"}
 

@@ -398,7 +398,9 @@ def test_check_key_roundtrip_covers_all_committed_defaults():
                 assert attrs["sq"] == attrs["sk"]  # sweepable shape
                 assert attrs["bias"] == "none"
             else:
-                assert op == "opt_flat"
+                # `delta_rule` has no sweep driver: --check names it and
+                # goes on
+                assert op in ("opt_flat", "delta_rule")
                 assert tune.make_key(op, attrs) == key
 
 
