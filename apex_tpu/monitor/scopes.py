@@ -53,7 +53,8 @@ KERNELS = (
     "adagrad_flat", "lamb_phase1", "lamb_phase1_seg", "lamb_phase2",
     "lamb_phase2_seg", "per_tensor_sumsq", "xent_fwd", "xent_bwd",
     "ln_fwd", "ln_bwd", "softmax_fwd", "softmax_bwd", "fused_dense",
-    "welford", "rope_stage", "rope_unstage")
+    "welford", "rope_stage", "rope_unstage", "kda_locals_fwd",
+    "kda_locals_bwd")
 UNOWNED = "unowned"
 
 # jvp( transpose( vmap( ... and every ")": a transform wraps the first

@@ -12,7 +12,8 @@ with `g_t` <= 0 a log-decay a channel of k and `beta_t` a scalar in
 time; nobody trains on it.  `gated_delta_rule` computes the same in
 chunks of `chunk` tokens:
 
-* inside a chunk (`_locals`, every chunk of every head at once): with
+* inside a chunk (`_locals`, or on the chip the Pallas pair
+  `kda_locals_fwd` / `kda_locals_bwd`; every chunk of every head): with
   `G_t` the running sum of g from the chunk's first token, a token's
   update is `S_t = Diag(exp(g_t)) S_{t-1} + k_t u_t^T` with the
   pseudo-value `u_t = beta_t (v_t - (Diag(exp(g_t)) S_{t-1})^T k_t)`,
@@ -43,11 +44,20 @@ transpose over the chunks in reverse, and pulls the sum back through
 `_locals`: each piece is the `jax.vjp` of the forward's own function,
 so the two cannot drift apart.
 
-Everything here is `jax.numpy`: the compiled form is what runs on the
-chip, under the caller's `attn/scan` scope.  Matrix products take
-their operands in the dtype of q (bf16 in a bf16 model) and accumulate
-in float32; running sums of g, decays, the triangular inverse and the
-state between chunks are float32 whatever q is.
+What runs where, all of it under the caller's `attn/scan` scope.  What
+is local to a chunk is two Pallas kernels on the chip, where the heads
+are whole lane tiles wide (d_k and d_v multiples of 128, a chunk of 32,
+64 or 128: `_kernels_take`): `kda_locals_fwd` reads q, k, v, g, beta
+once and writes the six arrays of `_Locals` once, `kda_locals_bwd`
+reads them and the six cotangents and writes the five gradients, and
+nothing between goes through HBM.  Any other call, and every call off
+the chip, takes `_locals`, compiled `jax.numpy` and the kernels'
+oracle.  The scans over chunks and `_outputs` are `jax.numpy` on either
+path.  Matrix products take their operands in the dtype of q (bf16 in
+a bf16 model) and accumulate in float32; running sums of g, decays,
+the sums over channels inside a sub-block, the triangular inverse and
+the state between chunks are float32 whatever q is, in the kernels as
+in `_locals`.
 """
 
 from __future__ import annotations
@@ -58,13 +68,18 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 _SUB = 16            # tokens a sub-block: pairs inside one are explicit
 DEFAULT_CHUNK = 64
 
 # calls traced since the last reset, the chunk of the last of them and
 # the bytes of chunk-start states their forwards keep for the backward
-_calls = {"calls": 0, "chunk": 0, "saved_state_bytes": 0}
+_calls = {"calls": 0, "chunk": 0, "saved_state_bytes": 0,
+          "kernel_calls": 0}
 
 
 def stats():
@@ -72,7 +87,8 @@ def stats():
     (a call that was differentiated counts once), "chunk": the chunk of
     the last of them, "saved_state_bytes": the bytes of chunk-start
     states the forwards of those calls keep for their backwards, summed
-    over the calls}."""
+    over the calls, "kernel_calls": those of "calls" whose chunk-local
+    stage took the Pallas pair}."""
     return dict(_calls)
 
 
@@ -108,11 +124,12 @@ def gated_delta_rule_reference(q, k, v, g, beta):
 # ------------------------------- inside a chunk ------------------------------
 
 class _Locals(NamedTuple):
-    """What `_states` and `_outputs` need of a chunk, (B, n, N, ...):
-    qp = q exp(G) (C, d_k); kd = k exp(G_C - G) (C, d_k); decay =
-    exp(G_C) (d_k,); w (C, d_k) and u (C, d_v), the chunk's pseudo-
-    values as `u - w S_0`; qk (C, C), lower triangle with its
-    diagonal.  decay and u are float32, the others q's dtype."""
+    """What `_states` and `_outputs` need of a chunk, (N, B, n, ...),
+    the chunk first as the scans over chunks read them: qp = q exp(G)
+    (C, d_k); kd = k exp(G_C - G) (C, d_k); decay = exp(G_C) (d_k,); w
+    (C, d_k) and u (C, d_v), the chunk's pseudo-values as `u - w S_0`;
+    qk (C, C), lower triangle with its diagonal.  decay and u are
+    float32, the others q's dtype."""
     qp: jnp.ndarray
     kd: jnp.ndarray
     decay: jnp.ndarray
@@ -222,10 +239,304 @@ def _locals(q, k, v, g, beta, chunk):
     u = _mm("...ti,...id->...td", T, beta[..., None] * v, dtype)
     # what is only ever a matmul's operand is kept as the matmul takes
     # it: the same numbers in half the bytes where q is bf16
-    return _Locals(qp=(q * fade).astype(dtype),
-                   kd=(k * jnp.exp(end - G)).astype(dtype),
-                   decay=jnp.exp(end[..., 0, :]), w=w.astype(dtype), u=u,
-                   qk=qk.astype(dtype))
+    loc = _Locals(qp=(q * fade).astype(dtype),
+                  kd=(k * jnp.exp(end - G)).astype(dtype),
+                  decay=jnp.exp(end[..., 0, :]), w=w.astype(dtype), u=u,
+                  qk=qk.astype(dtype))
+    return _Locals(*(jnp.moveaxis(x, 2, 0) for x in loc))
+
+
+# ------------------------- inside a chunk, in VMEM --------------------------
+#
+# `_locals` once more, for the chip: a step of the grid holds `_CHUNKS`
+# chunks of one head in VMEM as (chunks * C, d) tiles.  What `_locals`
+# spells with cumsum, reshape, concatenate and diagonal is here a shift
+# along the sublanes, a select under a mask of the tile's indices or a
+# matrix product batched over the chunks, so that every step lowers and
+# `jax.vjp` of the same function, inside the second kernel's body, is
+# the pullback: the two cannot drift apart.
+#
+# * Sums of g (`_block_sums`): for h = 1, 2, 4, ... C, the sum from the
+#   start of a token's block of h tokens to the token, and over its
+#   whole block, by doubling; float32.  Every decay is exp of one of
+#   them or of a difference of two, never positive while g is not.
+# * The pairs of one sub-block, float32: by halves, as `_tri_inv` goes.
+#   At a level of h tokens a pair (t, i) with t in the upper and i in
+#   the lower half of one block of 2h splits at the upper half's start
+#   into `exp(sum of g over [start, t])` and `exp(sum of g over (i,
+#   start))`, both <= 1, and the sum over channels is a float32 matrix
+#   product at HIGHEST masked to those pairs; log2(16) levels cover
+#   every pair below the diagonal.  The difference is still taken
+#   before the exp; nothing is divided by a decay.
+# * The pairs of two sub-blocks: as `_pair_products` splits them, at
+#   the later sub-block's start, operands in q's dtype.
+# * `(I + A)^-1` (`_unit_lower_inverse`): `_tri_inv`'s rounds on (C, C)
+#   tiles, D <- D - D (A * corner_h) D with corner_h the lower-left
+#   corners of the diagonal blocks of 2h, the level's own mask.
+
+_CHUNKS = 8          # chunks a grid step, as far as they divide S / chunk
+_KERNEL_CHUNKS = (32, 64, 128)
+_HI = lax.Precision.HIGHEST
+
+
+def _doublings(top):
+    """1, 2, 4, ... below top."""
+    return [1 << i for i in range(top.bit_length() - 1)]
+
+
+def _corner(C, h):
+    """(C, C) bool: t in the upper and i in the lower half of one block
+    of 2h tokens."""
+    t = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    i = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return ((t ^ i) < 2 * h) & ((t & h) != 0) & ((i & h) == 0)
+
+
+def _across(C, a):
+    """(C, C) bool: t in sub-block a, i in a sub-block before it."""
+    t = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    i = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return (t >= a * _SUB) & (t < (a + 1) * _SUB) & (i < a * _SUB)
+
+
+def _eye(C):
+    return (lax.broadcasted_iota(jnp.int32, (C, C), 0)
+            == lax.broadcasted_iota(jnp.int32, (C, C), 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rows_down(x, shift):
+    """x[r - shift] at row r, around the end."""
+    return pltpu.roll(x, shift, 0)
+
+
+def _rows_down_fwd(x, shift):
+    return _rows_down(x, shift), None
+
+
+def _rows_down_bwd(shift, _, ct):
+    return (pltpu.roll(ct, ct.shape[0] - shift, 0),)
+
+
+_rows_down.defvjp(_rows_down_fwd, _rows_down_bwd)
+
+
+def _block_sums(g, C):
+    """{h: (P, B)} for h = 1, 2, ... C over g (chunks * C, d_k): P the
+    sum of g from the start of a token's block of h tokens to the
+    token, B over its whole block.  A doubling joins neighbours: the
+    rows a shift brings in from another chunk are never kept."""
+    rows = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    P = B = g
+    out = {1: (P, B)}
+    for h in _doublings(C):
+        upper = (rows & h) != 0
+        below = _rows_down(B, h)
+        above = _rows_down(B, g.shape[0] - h)
+        P = P + jnp.where(upper, below, 0.)
+        B = B + jnp.where(upper, below, above)
+        out[2 * h] = (P, B)
+    return out
+
+
+def _bmm(a, b, **kw):
+    """(n, i, j) x (n, j, k) -> (n, i, k), accumulated in float32."""
+    return lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
+                           preferred_element_type=jnp.float32, **kw)
+
+
+def _pairs(a, b, **kw):
+    """(n, t, d) x (n, i, d) -> (n, t, i): the sum over channels."""
+    return lax.dot_general(a, b, (((2,), (2,)), ((0,), (0,))),
+                           preferred_element_type=jnp.float32, **kw)
+
+
+def _inverse_rounds(A):
+    C = A.shape[-1]
+    T = jnp.where(_eye(C), 1., 0.) - jnp.where(_corner(C, 1), A, 0.)
+    for h in _doublings(C)[1:]:
+        T = T - _bmm(_bmm(T, jnp.where(_corner(C, h), A, 0.), precision=_HI),
+                     T, precision=_HI)
+    return T
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(A):
+    """(I + tril(A, -1))^-1 of (n, C, C) float32 tiles."""
+    return _inverse_rounds(A)
+
+
+def _unit_lower_inverse_fwd(A):
+    T = _inverse_rounds(A)
+    return T, T
+
+
+def _unit_lower_inverse_bwd(T, dT):
+    # d(X^-1) = -X^-1 dX X^-1, transposed: dA = -T^t dT T^t
+    left = lax.dot_general(T, dT, (((1,), (1,)), ((0,), (0,))),
+                           precision=_HI, preferred_element_type=jnp.float32)
+    return (-_pairs(left, T, precision=_HI),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _chunks_locals(q, k, v, g, beta, C):
+    """`_locals` of n chunks side by side: q, k (n C, d_k), v (n C,
+    d_v) in q's dtype, g (n C, d_k) and beta (n C, 1) float32 -> the
+    six of `_Locals`, in its order and dtypes, (n, C, .) each and decay
+    (n, 1, d_k)."""
+    dtype = q.dtype
+    f32 = jnp.float32
+    n = g.shape[0] // C
+    q, k, v = (x.astype(f32) for x in (q, k, v))
+    by_chunk = lambda x: x.reshape(n, C, x.shape[-1])
+    sums = _block_sums(g, C)
+    G, whole = sums[C]
+    fade = jnp.exp(G)
+    # inside a sub-block, float32, by halves; the diagonal is no decay
+    kk = jnp.zeros((n, C, C), f32)
+    qk = jnp.where(_eye(C),
+                   by_chunk(jnp.sum(q * k, axis=-1, keepdims=True)), 0.)
+
+    def add(keep, left, right, **kw):
+        # k's and q's rows against the same columns in one product
+        both = _pairs(jnp.concatenate(left, axis=1), right, **kw)
+        return (kk + jnp.where(keep, both[:, :C], 0.),
+                qk + jnp.where(keep, both[:, C:], 0.))
+
+    for h in _doublings(_SUB):
+        turn = jnp.exp(sums[h][0])
+        right = k * jnp.exp(sums[h][1] - sums[h][0]) if h > 1 else k
+        kk, qk = add(_corner(C, h), [by_chunk(k * turn), by_chunk(q * turn)],
+                     by_chunk(right), precision=_HI)
+    # between sub-blocks, split at the later one's start
+    turn = jnp.exp(sums[_SUB][0])
+    left = [by_chunk((k * turn).astype(dtype)),
+            by_chunk((q * turn).astype(dtype))]
+    rows = lax.broadcasted_iota(jnp.int32, (1, C, 1), 1)
+    for a in range(1, C // _SUB):
+        start = jnp.sum(jnp.where(rows == a * _SUB - 1, by_chunk(G), 0.),
+                        axis=1, keepdims=True)
+        right = by_chunk(k) * jnp.exp(jnp.minimum(start - by_chunk(G), 0.))
+        kk, qk = add(_across(C, a), left, right.astype(dtype))
+    T = _unit_lower_inverse(by_chunk(beta) * kk).astype(dtype)
+    w = _bmm(T, by_chunk((beta * (k * fade)).astype(dtype)))
+    u = _bmm(T, by_chunk((beta * v).astype(dtype)))
+    return _Locals(
+        qp=by_chunk((q * fade).astype(dtype)),
+        kd=by_chunk((k * jnp.exp(whole - G)).astype(dtype)),
+        decay=jnp.exp(jnp.sum(by_chunk(g), axis=1, keepdims=True)),
+        w=w.astype(dtype), u=u, qk=qk.astype(dtype))
+
+
+# a grid step's blocks: the inputs and their gradients head-major, (1,
+# n, C, .) of (B n, N, C, .); `_Locals` and its cotangents chunk-major,
+# (n, 1, C, .) of (N, B n, C, .), as the scans over chunks read them
+
+def _rows(ref):
+    """A head-major block with its chunks' rows in one axis."""
+    return ref[0].reshape(-1, ref.shape[-1])
+
+
+def _locals_fwd_kernel(*refs, C):
+    ins, outs = refs[:5], refs[5:]
+    for ref, x in zip(outs, _chunks_locals(*map(_rows, ins), C)):
+        ref[:, 0] = x
+
+
+def _locals_bwd_kernel(*refs, C):
+    ins, cots, outs = refs[:5], refs[5:11], refs[11:]
+    _, pull = jax.vjp(lambda *x: _chunks_locals(*x, C), *map(_rows, ins))
+    for ref, x in zip(outs, pull(_Locals(*(r[:, 0] for r in cots)))):
+        ref[0] = x.reshape(ref.shape[1:])
+
+
+def _locals_call(backward, q, v, chunk):
+    """The `pl.pallas_call` of a direction at q's and v's shapes: from
+    q, k, v, g, beta (then `_Locals`' six cotangents) to `_Locals`' six
+    (or the five gradients)."""
+    b, n, s, dk = q.shape
+    bn, n_chunks, dv = b * n, s // chunk, v.shape[-1]
+    nc = next(c for c in (_CHUNKS, 4, 2, 1) if n_chunks % c == 0)
+    dtype, f32 = jnp.dtype(q.dtype), jnp.float32
+
+    def head_major(minor, dt):
+        return (pl.BlockSpec((1, nc, *minor), lambda i, j: (i, j, 0, 0)),
+                jax.ShapeDtypeStruct((bn, n_chunks, *minor), dt))
+
+    def chunk_major(minor, dt):
+        return (pl.BlockSpec((nc, 1, *minor), lambda i, j: (j, i, 0, 0)),
+                jax.ShapeDtypeStruct((n_chunks, bn, *minor), dt))
+
+    ins = [head_major(*x) for x in (
+        ((chunk, dk), dtype), ((chunk, dk), dtype), ((chunk, dv), dtype),
+        ((chunk, dk), f32), ((chunk, 1), f32))]
+    locs = _Locals(*(chunk_major(*x) for x in (
+        ((chunk, dk), dtype), ((chunk, dk), dtype), ((1, dk), f32),
+        ((chunk, dk), dtype), ((chunk, dv), f32), ((chunk, chunk), dtype))))
+    reads, writes = (ins + list(locs), ins) if backward else (ins, locs)
+    how = dict(
+        grid=(bn, n_chunks // nc),
+        in_specs=[spec for spec, _ in reads],
+        out_specs=[spec for spec, _ in writes],
+        out_shape=[shape for _, shape in writes],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=96 * 2 ** 20),
+        interpret=pallas_interpret())
+    if backward:
+        return pl.pallas_call(
+            functools.partial(_locals_bwd_kernel, C=chunk),
+            name="kda_locals_bwd", **how)
+    return pl.pallas_call(functools.partial(_locals_fwd_kernel, C=chunk),
+                          name="kda_locals_fwd", **how)
+
+
+def _cut(q, k, v, g, beta, chunk):
+    """The kernels' inputs: (B, n, S, ...) -> (B n, S / chunk, chunk,
+    ...), g and beta float32, beta a column."""
+    b, n, s, _ = q.shape
+    f32 = jnp.float32
+    return tuple(x.reshape(b * n, s // chunk, chunk, -1)
+                 for x in (q, k, v, g.astype(f32), beta.astype(f32)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _locals_kernels(q, k, v, g, beta, chunk):
+    """`_locals` by `kda_locals_fwd`, its pullback by `kda_locals_bwd`."""
+    return _locals_kernels_fwd(q, k, v, g, beta, chunk)[0]
+
+
+def _locals_kernels_fwd(q, k, v, g, beta, chunk):
+    b, n = q.shape[:2]
+    loc = _Locals(*(
+        x.reshape(x.shape[0], b, n, *x.shape[2:])
+        for x in _locals_call(False, q, v, chunk)(
+            *_cut(q, k, v, g, beta, chunk))))
+    return loc._replace(decay=loc.decay[..., 0, :]), (q, k, v, g, beta)
+
+
+def _locals_kernels_bwd(chunk, res, d_loc):
+    q, _, v = res[:3]
+    d_loc = d_loc._replace(decay=d_loc.decay[..., None, :])
+    grads = _locals_call(True, q, v, chunk)(
+        *_cut(*res, chunk),
+        *(x.reshape(x.shape[0], -1, *x.shape[3:]) for x in d_loc))
+    return tuple(dx.reshape(x.shape).astype(x.dtype)
+                 for dx, x in zip(grads, res))
+
+
+_locals_kernels.defvjp(_locals_kernels_fwd, _locals_kernels_bwd)
+
+
+def _kernels_take(q, v, chunk, override):
+    """Whether a call's chunk-local stage takes the Pallas pair: on the
+    chip (or where a test says so), heads whose widths fill whole lane
+    tiles and a chunk the kernels are written for."""
+    return (use_pallas(override) and q.shape[-1] % 128 == 0
+            and v.shape[-1] % 128 == 0 and v.dtype == q.dtype
+            and chunk in _KERNEL_CHUNKS)
 
 
 # ------------------------------ between chunks ------------------------------
@@ -238,27 +549,20 @@ def _advance(state, decay, kd, w, u, dtype):
                                           dtype)
 
 
-def _per_chunk(loc):
-    """(decay, kd, w, u) with the chunk axis first, as a scan reads."""
-    return tuple(jnp.moveaxis(x, 2, 0)
-                 for x in (loc.decay, loc.kd, loc.w, loc.u))
-
-
 def _states(loc, dtype):
-    """The state at every chunk's start, (B, n, N, d_k, d_v) float32:
+    """The state at every chunk's start, (N, B, n, d_k, d_v) float32:
     zero at the first."""
-    b, n, _, _, dk = loc.kd.shape
+    _, b, n, _, dk = loc.kd.shape
     first = jnp.zeros((b, n, dk, loc.u.shape[-1]), jnp.float32)
 
     def chunk(state, x):
         return _advance(state, *x, dtype), state
 
-    _, starts = lax.scan(chunk, first, _per_chunk(loc))
-    return jnp.moveaxis(starts, 0, 2)
+    return lax.scan(chunk, first, (loc.decay, loc.kd, loc.w, loc.u))[1]
 
 
 def _outputs(loc, starts, dtype):
-    """o of every token, (B, n, N, C, d_v) float32."""
+    """o of every token, (N, B, n, C, d_v) float32."""
     new = loc.u - _mm("...td,...dv->...tv", loc.w, starts, dtype)
     return (_mm("...td,...dv->...tv", loc.qp, starts, dtype)
             + _mm("...ti,...iv->...tv", loc.qk, new, dtype))
@@ -266,30 +570,39 @@ def _outputs(loc, starts, dtype):
 
 # ---------------------------------- the op ----------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _delta_rule(q, k, v, g, beta, chunk):
-    return _delta_rule_fwd(q, k, v, g, beta, chunk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _delta_rule(q, k, v, g, beta, chunk, kernels):
+    return _delta_rule_fwd(q, k, v, g, beta, chunk, kernels)[0]
 
 
-def _delta_rule_fwd(q, k, v, g, beta, chunk):
-    loc = _locals(q, k, v, g, beta, chunk)
+def _locals_and_pullback(q, k, v, g, beta, chunk, kernels):
+    """(`_Locals`, its pullback), as `jax.vjp` of the chunk-local stage
+    gives them.  The Pallas pair's two rules are called as they are: a
+    `jax.vjp` in here would wrap the kernels' names in the trace
+    (`transpose(jvp(kda_locals_bwd))`) and hide them from its readers."""
+    if not kernels:
+        return jax.vjp(lambda *x: _locals(*x, chunk), q, k, v, g, beta)
+    loc, res = _locals_kernels_fwd(q, k, v, g, beta, chunk)
+    return loc, lambda d_loc: _locals_kernels_bwd(chunk, res, d_loc)
+
+
+def _delta_rule_fwd(q, k, v, g, beta, chunk, kernels=False):
+    loc = (_locals_kernels if kernels else _locals)(q, k, v, g, beta, chunk)
     starts = _states(loc, q.dtype)
-    o = _outputs(loc, starts, q.dtype)
+    o = jnp.moveaxis(_outputs(loc, starts, q.dtype).astype(v.dtype), 0, 2)
     b, n, s, _ = q.shape
-    return (o.reshape(b, n, s, -1).astype(v.dtype),
-            (q, k, v, g, beta, starts))
+    return o.reshape(b, n, s, -1), (q, k, v, g, beta, starts)
 
 
-def _delta_rule_bwd(chunk, res, do):
+def _delta_rule_bwd(chunk, kernels, res, do):
     q, k, v, g, beta, starts = res
     dtype = q.dtype
     b, n, s, _ = q.shape
-    loc, pull_locals = jax.vjp(
-        lambda *x: _locals(*x, chunk), q, k, v, g, beta)
-    do = do.astype(jnp.float32).reshape(b, n, s // chunk, chunk, -1)
+    loc, pull_locals = _locals_and_pullback(q, k, v, g, beta, chunk, kernels)
+    do = jnp.moveaxis(do.reshape(b, n, s // chunk, chunk, -1), 2, 0)
     _, pull_outputs = jax.vjp(
         lambda loc, starts: _outputs(loc, starts, dtype), loc, starts)
-    d_loc, d_starts = pull_outputs(do)
+    d_loc, d_starts = pull_outputs(do.astype(jnp.float32))
 
     # the recurrence's transpose: from the last chunk to the first,
     # carrying the cotangent of the state a chunk leaves
@@ -300,11 +613,9 @@ def _delta_rule_bwd(chunk, res, do):
         d_state, *d_locals = pull(d_left)
         return d_state + d_start, tuple(d_locals)
 
-    _, d_scan = lax.scan(
-        chunk_back, jnp.zeros_like(starts[:, :, 0]),
-        (jnp.moveaxis(starts, 2, 0), jnp.moveaxis(d_starts, 2, 0),
-         *_per_chunk(loc)), reverse=True)
-    d_decay, d_kd, d_w, d_u = (jnp.moveaxis(x, 0, 2) for x in d_scan)
+    _, (d_decay, d_kd, d_w, d_u) = lax.scan(
+        chunk_back, jnp.zeros_like(starts[0]),
+        (starts, d_starts, loc.decay, loc.kd, loc.w, loc.u), reverse=True)
     d_loc = d_loc._replace(decay=d_loc.decay + d_decay, kd=d_loc.kd + d_kd,
                            w=d_loc.w + d_w, u=d_loc.u + d_u)
     return pull_locals(d_loc)
@@ -314,7 +625,8 @@ _delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
-                     heads_a_pass: Optional[int] = None):
+                     heads_a_pass: Optional[int] = None,
+                     use_pallas_override: Optional[bool] = None):
     """o (B, n, S, d_v) of the gated delta rule over head-major q, k
     (B, n, S, d_k), v (B, n, S, d_v), the log-decay g (B, n, S, d_k),
     <= 0, and beta (B, n, S); every head starts from a zero state.  o
@@ -331,8 +643,14 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
     `heads_a_pass`: heads computed at a time, a divisor of n: so many
     calls of the op side by side, each with the float32 temporaries of
     its own heads' recompute only, which the compiler's scheduler then
-    need not hold together (a tuned config's `heads`; None is every
-    head in one call)."""
+    need not hold together (a tuned config's `heads`, if it has one;
+    None is every head in one call).
+
+    On the chip the chunk-local stage of a call with 128-wide heads is
+    the Pallas pair `kda_locals_fwd` / `kda_locals_bwd`, elsewhere
+    compiled `jax.numpy` (`stats()["kernel_calls"]` counts the former);
+    `use_pallas_override` is for the tests: True runs the kernels in
+    interpret mode off the chip, False keeps `jax.numpy` on it."""
     b, n, s, dk = q.shape
     if k.shape != q.shape or g.shape != q.shape or v.shape[:3] != (b, n, s) \
             or beta.shape != (b, n, s):
@@ -353,11 +671,13 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
     if chunk < 1 or chunk & (chunk - 1) or s % chunk:
         raise ValueError(f"chunk {chunk} is not a power of two that "
                          f"divides the sequence, {s}")
+    kernels = _kernels_take(q, v, chunk, use_pallas_override)
     _calls["calls"] += 1
     _calls["chunk"] = chunk
     _calls["saved_state_bytes"] += 4 * b * n * (s // chunk) * dk * v.shape[-1]
+    _calls["kernel_calls"] += kernels
     if heads_a_pass in (None, n):
-        return _delta_rule(q, k, v, g, beta, chunk)
+        return _delta_rule(q, k, v, g, beta, chunk, kernels)
     if heads_a_pass < 1 or n % heads_a_pass:
         raise ValueError(f"heads_a_pass {heads_a_pass} does not divide the "
                          f"{n} heads")
@@ -367,5 +687,5 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
     # operands through copies
     return jnp.concatenate([
         _delta_rule(*(x[:, h:h + heads_a_pass] for x in (q, k, v, g, beta)),
-                    chunk)
+                    chunk, kernels)
         for h in range(0, n, heads_a_pass)], axis=1)

@@ -62,13 +62,15 @@ def _v5e_entries():
         "1024) fused 9.50 and two-kernel 12.50; (4096, 256) does not "
         "fit VMEM")
     # the chunked gated delta rule, 64 heads of 128 x 128, 1 x 4096
-    # (ops/delta_rule.py at the same cell)
+    # (ops/delta_rule.py at the same cell), its chunk-local stage in the
+    # Pallas pair: the heads need no passes any more
     e[make_key("delta_rule", tune.delta_rule_attrs(
         1, 64, 4096, 128, 128, "bfloat16"))] = _mk(
-        {"chunk": 64, "heads": 32},
-        "v5e, PR 32 chip run, forward + backward a layer in one call of "
-        "64 heads: chunk 64 62.3 ms, 32 57.2 (twice the states kept), "
-        "128 69.4; two calls of 32 heads for the step's memory")
+        {"chunk": 64},
+        "v5e, PR 33 chip run, forward + backward a layer: chunk 64 "
+        "32.5 ms in one call of 64 heads (34.1 in two of 32), chunk 32 "
+        "35.4 (38.3), chunk 128 37.2 (39.2); the compiled stage of PR "
+        "32 at chunk 64 in two calls of 32 heads 75.5")
     # flat-optimizer block rows at the 1B Adam bench point: the swept
     # heuristic value, committed so the fingerprint records it
     e[make_key("opt_flat", dict(kernel="adam", rows=8388608))] = _mk(

@@ -2,6 +2,8 @@
 recurrence a token at a time, forward and all five gradients, at tiny
 sizes on the CPU."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,7 +106,7 @@ def test_the_forward_keeps_a_state_a_chunk_not_a_token():
     args, _ = _inputs(1)
     _, res = jax.eval_shape(lambda *a: DR._delta_rule_fwd(*a, 32), *args)
     assert [r.shape for r in res[:5]] == [a.shape for a in args]
-    assert res[5].shape == (2, 3, 128 // 32, 16, 8)
+    assert res[5].shape == (128 // 32, 2, 3, 16, 8)     # the chunk first
     assert res[5].dtype == jnp.float32 and len(res) == 6
 
 
@@ -117,9 +119,12 @@ def test_stats_count_calls_chunk_and_saved_states():
     jax.eval_shape(lambda *a: DR.gated_delta_rule(*a, chunk=64), *args)
     assert DR.stats() == {
         "calls": 2, "chunk": 64,
-        "saved_state_bytes": 4 * 2 * 3 * 16 * 8 * (128 // 16 + 128 // 64)}
+        "saved_state_bytes": 4 * 2 * 3 * 16 * 8 * (128 // 16 + 128 // 64),
+        "kernel_calls": 0}
+    assert list(DR.stats())[:2] == ["calls", "chunk"]
     DR.reset_stats()
-    assert DR.stats() == {"calls": 0, "chunk": 0, "saved_state_bytes": 0}
+    assert DR.stats() == {"calls": 0, "chunk": 0, "saved_state_bytes": 0,
+                          "kernel_calls": 0}
 
 
 def test_the_chunk_comes_from_the_tuner_then_the_default(monkeypatch):
@@ -150,8 +155,13 @@ def test_the_committed_v5e_chunk_is_the_benchmark_shapes():
     key = tune.make_key("delta_rule", tune.delta_rule_attrs(
         1, 64, 4096, 128, 128, "bfloat16"))
     config = defaults.DEFAULTS["v5e"][key]["config"]
-    assert set(config) == {"chunk", "heads"}
-    assert 4096 % config["chunk"] == 0 and 64 % config["heads"] == 0
+    # re-measured on the kernels (PR 33): every head in one call
+    assert set(config) == {"chunk"}
+    assert 4096 % config["chunk"] == 0
+    assert DR._kernels_take(
+        jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16),
+        config["chunk"], True)
 
 
 @pytest.mark.parametrize("chunk", [0, 24, 256])
@@ -187,3 +197,149 @@ def test_no_token_by_token_loop_in_the_chunked_program():
     lengths = [int(part.split()[0].rstrip(",")) for part in
                text.split("length=")[1:]]
     assert lengths and set(lengths) == {256 // 64}
+
+
+# ------------------- the chunk-local stage as Pallas kernels -------------------
+# interpret mode here; tests/test_chip_compile.py compiles the pair for
+# the chip and chip_smoke.py::delta_rule_grads runs it there
+
+LOCALS = DR._Locals._fields
+GRADS = ("dq", "dk", "dv", "dg", "dbeta")
+WIDE = dict(b=1, n=2, s=128, dk=128, dv=128)
+
+
+def _close(got, want, rel):
+    """Within `rel` of the largest entry: bf16 outputs may land on the
+    neighbouring bf16."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= rel * max(np.abs(want).max(), 1e-30)
+
+
+@pytest.fixture(scope="module")
+def stages():
+    """{(chunk, dtype name): (kernels, jax.numpy)}, each (the six of
+    `_Locals`, the five gradients under one seeded cotangent)."""
+    out = {}
+    for chunk in (32, 64):
+        for dtype in (jnp.float32, jnp.bfloat16):
+            args, _ = _inputs(chunk, dtype=dtype, **WIDE)
+
+            def run(stage, args):
+                loc, pull = jax.vjp(lambda *a: stage(*a, chunk), *args)
+                keys = jax.random.split(jax.random.PRNGKey(9), len(loc))
+                cot = DR._Locals(*(
+                    jax.random.normal(k, x.shape).astype(x.dtype)
+                    for k, x in zip(keys, loc)))
+                return tuple(loc), pull(cot)
+            out[chunk, dtype.__name__] = (
+                jax.jit(functools.partial(run, DR._locals_kernels))(args),
+                jax.jit(functools.partial(run, DR._locals))(args))
+    return out
+
+
+@pytest.mark.parametrize("what", range(6), ids=LOCALS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_kda_locals_fwd_matches_the_compiled_stage(stages, chunk, dtype, what):
+    got, want = (x[0][what] for x in stages[chunk, dtype])
+    assert got.dtype == want.dtype
+    _close(got, want, 2e-5 if dtype == "float32" else 8e-3)
+
+
+@pytest.mark.parametrize("what", range(5), ids=GRADS)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_kda_locals_bwd_matches_the_compiled_stages_pullback(stages, chunk,
+                                                             dtype, what):
+    got, want = (x[1][what] for x in stages[chunk, dtype])
+    assert got.dtype == want.dtype
+    _close(got, want, 2e-5 if dtype == "float32" else 8e-3)
+
+
+def _forced(chunk):
+    return lambda *a: DR.gated_delta_rule(*a, chunk=chunk,
+                                          use_pallas_override=True)
+
+
+@pytest.fixture(scope="module")
+def forced_pairs():
+    """{neg: (the op with the kernels forced, the recurrence)} at 128-
+    wide heads, chunk 64."""
+    out = {}
+    for neg in (True, False):
+        args, do = _inputs(70 + neg, neg=neg, **WIDE)
+        out[neg] = (_fwd_bwd(_forced(64), args, do),
+                    _fwd_bwd(DR.gated_delta_rule_reference, args, do))
+    return out
+
+
+@pytest.mark.parametrize("what", range(6), ids=NAMES)
+@pytest.mark.parametrize("neg", [True, False], ids=["beta<2", "beta<1"])
+def test_the_op_through_the_kernels_matches_the_token_recurrence(
+        forced_pairs, neg, what):
+    got, want = forced_pairs[neg]
+    scale = float(jnp.max(jnp.abs(want[what])))
+    np.testing.assert_allclose(got[what], want[what], rtol=1e-4,
+                               atol=2e-5 * max(scale, 1.0))
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+def test_a_channel_that_forgets_everything_through_the_kernels(chunk):
+    args, do = _inputs(5, decay=40.0, **WIDE)
+    assert float(args[3].min()) < -40
+    got = _fwd_bwd(_forced(chunk), args, do)
+    want = _fwd_bwd(DR.gated_delta_rule_reference, args, do)
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("shape,chunk,kernels", [
+    (dict(dk=128, dv=128), 64, 1), (dict(dk=128, dv=128), 32, 1),
+    (dict(dk=16, dv=8), 64, 0), (dict(dk=8, dv=8), 32, 0),
+    (dict(dk=128, dv=64), 64, 0), (dict(dk=128, dv=128), 16, 0)],
+    ids=["128", "128-chunk32", "16x8", "8x8", "128x64", "128-chunk16"])
+def test_only_whole_lane_tiles_and_a_written_chunk_take_the_kernels(
+        shape, chunk, kernels):
+    """Whatever the override says, a head narrower than a lane tile or
+    a chunk the kernels are not written for takes the compiled stage
+    and counts no kernel call."""
+    args, _ = _inputs(2, b=1, n=2, s=128, **shape)
+    DR.reset_stats()
+    text = str(jax.make_jaxpr(_forced(chunk))(*args))
+    assert DR.stats()["calls"] == 1
+    assert DR.stats()["kernel_calls"] == kernels
+    assert ("kda_locals_fwd" in text) == bool(kernels)
+    DR.reset_stats()
+
+
+def test_off_the_chip_a_call_takes_the_compiled_stage():
+    args, _ = _inputs(2, **WIDE)
+    DR.reset_stats()
+    text = str(jax.make_jaxpr(
+        lambda *a: DR.gated_delta_rule(*a, chunk=64))(*args))
+    assert "pallas_call" not in text and DR.stats()["kernel_calls"] == 0
+    DR.reset_stats()
+
+
+@pytest.mark.parametrize("heads,passes", [(None, 1), (1, 2)])
+def test_a_differentiated_call_runs_the_stage_once_forward_twice_back(
+        heads, passes):
+    """By name: the forward holds one `kda_locals_fwd` a pass of heads;
+    the backward recomputes the stage with one more and pulls back
+    through one `kda_locals_bwd`."""
+    args, do = _inputs(3, **WIDE)
+    rule = lambda *a: DR.gated_delta_rule(
+        *a, chunk=64, heads_a_pass=heads, use_pallas_override=True)
+
+    def names(fn, *a):
+        text = str(jax.make_jaxpr(fn)(*a))
+        return (text.count("name=kda_locals_fwd"),
+                text.count("name=kda_locals_bwd"))
+
+    assert names(rule, *args) == (passes, 0)
+    _, pull = jax.vjp(rule, *args)
+    assert names(pull, do) == (passes, passes)
+    assert names(lambda a, do: _fwd_bwd(rule, a, do), args, do) == (
+        2 * passes, passes)
