@@ -3,24 +3,36 @@
 of the network — a vocabulary-parallel embedding, a final RMSNorm, an
 untied head over the held rows of the vocabulary and the fused cross
 entropy — the expert layer one chip of an expert-parallel group holds,
-built from the config's keys, and the small pieces every block uses.
+built from the config's keys, the small pieces every block uses, the
+dense SwiGLU of a leading layer, and the part of latent attention that
+does not depend on how a model makes its queries or whether it turns
+anything: the compressed key-value projection with its norm, its
+expansion as two GEMMs, the flash call and the output projection
+(`MLAMoE` compresses q and rotates; `HybridMoE`'s latent layers do
+neither).
 
 A config gives: vocab_size, hidden, init_std, rms_norm_eps, dtype,
 logits_dtype, fused_xent, axis_name, and of the expert layer
 moe_intermediate_size, n_routed_experts, experts_first, experts_count,
 num_experts_per_tok, n_shared_experts, routed_scaling_factor,
 norm_topk_prob, router_bias_range and, where it has one,
-expert_rows_factor (`HeldExpertsMLP`'s `rows_factor`).
+expert_rows_factor (`HeldExpertsMLP`'s `rows_factor`); where it has
+latent attention, num_heads, kv_lora_rank, qk_nope_head_dim,
+qk_rope_head_dim, v_head_dim and flash_override.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.moe.layer import HeldExpertsMLP
+from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.layer_norm import fused_rms_norm
+from apex_tpu.ops.rope_stage import halves
 from apex_tpu.transformer.tensor_parallel.cross_entropy import (
     vocab_parallel_cross_entropy,
 )
@@ -71,6 +83,56 @@ class HeldExpertsLM:
     def _dot(self, x, w):
         return jnp.dot(x, w, preferred_element_type=jnp.float32
                        ).astype(x.dtype)
+
+    def _swiglu(self, p, m):
+        """A dense layer's FFN: `down(silu(gate) * up)` of p's packed
+        `gate_up` (H, 2f) and `down` (f, H)."""
+        with jax.named_scope("gate_up"):
+            gate, up = jnp.split(self._dot(m, p["gate_up"]), 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        with jax.named_scope("down"):
+            return self._dot(act, p["down"])
+
+    def _latent_kv(self, p, a, rotary: bool):
+        """a: (B, S, H), normed.  Latent attention's key-value side,
+        token-major: (k_n (B, S, heads * nope), the heads' shared row
+        k_s (B, S, rope), v (B, S, heads * v)).  `kv_b` runs as two
+        GEMMs over its column groups, so that each output lies as its
+        one reader takes it.  With `rotary` the shared row's columns
+        are taken in halves order (`ops.rope_stage.halves`), as the
+        rotation that follows wants them."""
+        c = self.c
+        nh, dn, dv = c.num_heads, c.qk_nope_head_dim, c.v_head_dim
+        with jax.named_scope("kv_a"):
+            w = halves(p["kv_a"], c.kv_lora_rank) if rotary else p["kv_a"]
+            ckv = self._dot(a, w)
+            k_s = ckv[..., c.kv_lora_rank:]
+            c_kv = self._norm(p["kv_a_norm"], ckv[..., :c.kv_lora_rank])
+        with jax.named_scope("kv_b"):
+            w = p["kv_b"].reshape(-1, nh, dn + dv)
+            k_n = self._dot(c_kv, w[..., :dn].reshape(-1, nh * dn))
+            v = self._dot(c_kv, w[..., dn:].reshape(-1, nh * dv))
+        return k_n, k_s, v
+
+    def _latent_attend(self, p, q, k, v, segment_ids=None):
+        """Causal attention of head-major q, k (B, heads, S, nope +
+        rope) over the token-major v of `_latent_kv`, within a row's
+        documents where `segment_ids` (B, S) names them, and the output
+        projection.  The head-major copies of v and of the context are
+        the kernels' price, as in the GPT block: they carry the flash
+        scope."""
+        c = self.c
+        b, _, s, width = q.shape
+        nh, dv = c.num_heads, c.v_head_dim
+        with jax.named_scope("flash"):
+            ctx = flash_attention(
+                q, k, v.reshape(b, s, nh, dv).transpose(0, 2, 1, 3),
+                causal=True, softmax_scale=1.0 / math.sqrt(width),
+                segment_ids=segment_ids,
+                use_pallas_override=c.flash_override)
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * dv)
+        with jax.named_scope("proj"):
+            return self._dot(ctx, p["proj"])
 
     def _embed(self, params, ids):
         with jax.named_scope("embed"):

@@ -1,20 +1,30 @@
 """HybridMoE — a decoder whose layers mix two kinds of token mixer in
-one stack, every layer an expert layer, as one chip of an
-expert-parallel group holds it: gated grouped-query softmax attention
-without positions on the layers `attention_layers` names, Kimi Delta
-Attention (a gated delta rule with a per-channel decay,
-arXiv:2510.26692) on the others.
+one stack over expert layers, as one chip of an expert-parallel group
+holds it: softmax attention without positions on the layers
+`attention_layers` names (`attention_kind`: gated grouped-query
+attention, or latent attention), Kimi Delta Attention (a gated delta
+rule with a per-channel decay, arXiv:2510.26692) on the others.
 
 The layers (no bias but KDA's `dt_bias`, RMSNorm, untied embedding and
 head, no rotary embedding and no position table anywhere):
 
-* block: `h = x + Mixer_i(RMSNorm(x))`, `y = h + MoE(RMSNorm(h))`;
+* block: `h = x + Mixer_i(RMSNorm(x))`, `y = h + FFN_i(RMSNorm(h))`,
+  the FFN of the leading `first_k_dense_replace` layers a SwiGLU of
+  `intermediate_size`, of the others the expert layer;
 * gated attention: `q = a W_q` (heads x d), `k, v = a W_k, a W_v`
   (kv_heads x d), causal `softmax(q k^T / sqrt(d)) v` with kv head j
   serving query heads `j * group ...` (`ops.flash_attention` takes the
   fewer kv heads as they are); `out = W_o [attn * sigmoid(a W_gate)]`,
   the gate elementwise, a column a head and channel
   (arXiv:2505.06708);
+* latent attention without positions and without a query compression
+  (`attention_kind` "latent"): `q = a W_q` (heads x (nope + rope));
+  `[c_kv | k_s] = a W_kva`, `c_kv = RMSNorm(c_kv)`, `[k_n | v] = c_kv
+  W_kvb` a head; head h's key is `[k_n,h | k_s]`, the `rope`-wide row
+  `k_s` shared by the heads and, like q, never rotated; causal
+  `softmax(q k^T / sqrt(nope + rope)) v`, `out = W_o ctx`.  The
+  key-value side, the flash call and the projection are
+  `HeldExpertsLM`'s, which `models.mla_moe` rotates around;
 * KDA, n heads of d_k = d_v = d: `q, k, v = SiLU(conv(a W_.))`, a
   causal depthwise convolution over time, `conv_kernel` taps, a weight
   a channel and tap; a head's q and k scaled to unit length, q by
@@ -27,6 +37,18 @@ head, no rotary embedding and no position table anywhere):
   `n_routed_experts` by the bias-corrected sigmoid gate and computes
   the part of the result that experts `[experts_first, experts_first +
   experts_count)` give, plus the shared expert.
+
+**Documents.**  With `eod_token_id` a row is documents packed end to
+end, each closed by that id: a token's document is the number of EODs
+before it, and a token starts one where it is the row's first or
+follows an EOD.  Nothing a mixer computes for a token reads a token of
+another document: the flash call takes the documents as segment ids, a
+convolution tap that would reach into the document before reads 0, and
+the delta rule's state is zero before a document's first token
+(`gated_delta_rule(resets=)`).  The model derives all of it from its
+`tokens`, once a step, under the scope `block0/attn/segments`; norms,
+FFNs, the router, the head and the loss are a token at a time and know
+nothing of it.  None, the default: a row is one document.
 
 The config says what is held here: how many layers and which of them
 attend, which experts, how many rows of the vocabulary.  The layers
@@ -47,7 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +77,7 @@ import jax.numpy as jnp
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
 from apex_tpu.ops.delta_rule import gated_delta_rule
 from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.rope_stage import stage_heads
 from apex_tpu.parallel.mesh import TP_AXIS
 
 
@@ -64,14 +87,22 @@ class HybridMoEConfig:
     hidden: int = 4096
     num_layers: int = 4              # layers held here
     attention_layers: Tuple[int, ...] = (0,)   # which of them attend
+    attention_kind: str = "gqa"      # "gqa" (gated, grouped) or "latent"
     num_heads: int = 64
     num_kv_heads: int = 8
     head_dim: int = 128
+    # the latent kind's widths (it reads none of the two above)
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64       # unrotated: the width is all it keeps
+    v_head_dim: int = 128
     kda_heads: int = 64
     kda_head_dim: int = 128
     conv_kernel: int = 4
     kda_rank: int = 128              # inner width of the f and g pairs
     allow_neg_eigval: bool = True
+    first_k_dense_replace: int = 0   # leading layers with a dense SwiGLU
+    intermediate_size: int = 0       # its width
     moe_intermediate_size: int = 1280
     n_routed_experts: int = 320      # the router's width, as published
     num_experts_per_tok: int = 8
@@ -93,15 +124,36 @@ class HybridMoEConfig:
     # its normed input
     recompute_mixers: bool = False
     scan_chunk: Optional[int] = None   # None: the tuner's, or the op's
+    # the id that closes a document of a packed row; None: a row is one
+    eod_token_id: Optional[int] = None
     # the dispatch of the flash kernels, as `flash_attention` takes it
     flash_override: Any = None
     fused_xent: Any = None
     axis_name: str = TP_AXIS
 
 
+class Documents(NamedTuple):
+    """What the mixers know of a packed row: `ids` (B, S) int32, a
+    token's document; `first` (B, S) bool, the tokens that start one;
+    `taps[r - 1]` (B, S, 1) float32, 1 where the token r back is of the
+    same document and 0 where it is not, or lies before the row."""
+    ids: jnp.ndarray
+    first: jnp.ndarray
+    taps: Tuple[jnp.ndarray, ...]
+
+
 class HybridMoE(HeldExpertsLM):
+    def __init__(self, config: HybridMoEConfig):
+        if config.attention_kind not in ("gqa", "latent"):
+            raise ValueError(f"attention_kind {config.attention_kind!r} "
+                             "is neither 'gqa' nor 'latent'")
+        super().__init__(config)
+
     def _attends(self, i: int) -> bool:
         return i in self.c.attention_layers
+
+    def _is_dense(self, i: int) -> bool:
+        return i < self.c.first_k_dense_replace
 
     # ------------------------------ params --------------------------------
     def _init_block(self, key, i: int) -> dict:
@@ -115,7 +167,15 @@ class HybridMoE(HeldExpertsLM):
         def ones(n):
             return {"weight": jnp.ones((n,), c.dtype)}
 
-        if self._attends(i):
+        if self._attends(i) and c.attention_kind == "latent":
+            nh, dn, dv = c.num_heads, c.qk_nope_head_dim, c.v_head_dim
+            attn = {
+                "q": normal(ks[0], h, nh * (dn + c.qk_rope_head_dim)),
+                "kv_a": normal(ks[1], h, c.kv_lora_rank + c.qk_rope_head_dim),
+                "kv_a_norm": ones(c.kv_lora_rank),
+                "kv_b": normal(ks[2], c.kv_lora_rank, nh * (dn + dv)),
+                "proj": normal(ks[4], nh * dv, h)}
+        elif self._attends(i):
             wide, kv = c.num_heads * c.head_dim, c.num_kv_heads * c.head_dim
             attn = {"q": normal(ks[0], h, wide), "k": normal(ks[1], h, kv),
                     "v": normal(ks[2], h, kv), "gate": normal(ks[3], h, wide),
@@ -146,8 +206,13 @@ class HybridMoE(HeldExpertsLM):
                 "o_norm": ones(d),
                 "proj": normal(ks[4], wide, h),
             }
-        return {"ln1": ones(h), "attn": attn, "ln2": ones(h),
-                "mlp": self.experts.init(ks[10], c.dtype)}
+        if self._is_dense(i):
+            k_up, k_down = jax.random.split(ks[10])
+            mlp = {"gate_up": normal(k_up, h, 2 * c.intermediate_size),
+                   "down": normal(k_down, c.intermediate_size, h)}
+        else:
+            mlp = self.experts.init(ks[10], c.dtype)
+        return {"ln1": ones(h), "attn": attn, "ln2": ones(h), "mlp": mlp}
 
     def init(self, key):
         keys = jax.random.split(key, 2 + self.c.num_layers)
@@ -163,7 +228,28 @@ class HybridMoE(HeldExpertsLM):
         b, s, w = x.shape
         return x.reshape(b, s, n, w // n).transpose(0, 2, 1, 3)
 
-    def _attention(self, p, a):
+    def documents(self, tokens, i: int = 0) -> Optional[Documents]:
+        """The documents of `tokens` (B, S), for every mixer of the
+        step; None where the config names no `eod_token_id`.  Whoever
+        runs the blocks derives them once; the time is filed under
+        block i, the first it runs."""
+        c = self.c
+        if c.eod_token_id is None:
+            return None
+        with jax.named_scope(f"block{i}"), jax.named_scope("attn"), \
+                jax.named_scope("segments"):
+            # the EOD belongs to the document it closes
+            after_eod = jnp.pad(tokens[:, :-1] == c.eod_token_id,
+                                ((0, 0), (1, 0)))
+            ids = jnp.cumsum(after_eod, axis=1, dtype=jnp.int32)
+            back = lambda r: jnp.pad(ids[:, :-r], ((0, 0), (r, 0)),
+                                     constant_values=-1)
+            return Documents(
+                ids=ids, first=back(1) != ids,
+                taps=tuple((back(r) == ids)[..., None].astype(jnp.float32)
+                           for r in range(1, c.conv_kernel)))
+
+    def _attention(self, p, a, docs=None):
         """a: (B, S, H), normed.  The gated grouped-query attention's
         output, before the residual add."""
         c = self.c
@@ -175,6 +261,7 @@ class HybridMoE(HeldExpertsLM):
                 self._heads(q, c.num_heads), self._heads(k, c.num_kv_heads),
                 self._heads(v, c.num_kv_heads), causal=True,
                 softmax_scale=1.0 / math.sqrt(c.head_dim),
+                segment_ids=None if docs is None else docs.ids,
                 use_pallas_override=c.flash_override)
             ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
         with jax.named_scope("gate"):
@@ -182,22 +269,53 @@ class HybridMoE(HeldExpertsLM):
         with jax.named_scope("proj"):
             return self._dot(ctx, p["proj"])
 
-    def _conv(self, x, w):
+    def _latent(self, p, a, docs=None):
+        """a: (B, S, H), normed.  The latent attention's output, before
+        the residual add: nothing is rotated and q is not compressed.
+        `q` runs as two GEMMs over its column groups, as `MLAMoE`'s
+        `q_b` does, so that `stage_heads` reads each where it lies and
+        writes q and k head-major in one pass each, k's shared row once
+        a head."""
+        c = self.c
+        nh, dn, dr = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
+        with jax.named_scope("q"):
+            w = p["q"].reshape(-1, nh, dn + dr)
+            q_n = self._dot(a, w[..., :dn].reshape(-1, nh * dn))
+            q_s = self._dot(a, w[..., dn:].reshape(-1, nh * dr))
+        k_n, k_s, v = self._latent_kv(p, a, rotary=False)
+        with jax.named_scope("stage"):
+            q = stage_heads(q_n, q_s, nh, per_head=True,
+                            use_pallas_override=c.flash_override)
+            k = stage_heads(k_n, k_s, nh,
+                            use_pallas_override=c.flash_override)
+        return self._latent_attend(
+            p, q, k, v, segment_ids=None if docs is None else docs.ids)
+
+    def _conv(self, x, w, docs=None):
         """SiLU of the causal depthwise convolution over time: x (B, S,
-        C), w (taps, C); tap j weighs the token taps - 1 - j back."""
+        C), w (taps, C); tap j weighs the token taps - 1 - j back, or 0
+        where that token is of the document before."""
         taps = w.shape[0]
         s = x.shape[1]
         padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-        y = sum(padded[:, j:j + s].astype(jnp.float32)
-                * w[j].astype(jnp.float32) for j in range(taps))
+
+        def tap(j):
+            term = padded[:, j:j + s].astype(jnp.float32) \
+                * w[j].astype(jnp.float32)
+            back = taps - 1 - j
+            if docs is None or not back:
+                return term
+            return term * docs.taps[back - 1]
+
+        y = sum(tap(j) for j in range(taps))
         # rounded where the backward keeps it: the sum, not its terms
         return jax.nn.silu(y.astype(x.dtype))
 
-    def scan_inputs(self, p, a):
+    def scan_inputs(self, p, a, docs=None):
         """a: (B, S, H), normed.  What Kimi Delta Attention hands
         `gated_delta_rule`, head-major: q, k, v (B, n, S, d) in the
         model's dtype, the log-decay g (B, n, S, d) and beta (B, n, S)
-        in float32."""
+        in float32.  `docs`: the row's `documents`, for the taps."""
         c = self.c
         b, s, _ = a.shape
         n, d = c.kda_heads, c.kda_head_dim
@@ -211,9 +329,9 @@ class HybridMoE(HeldExpertsLM):
                                     + 1e-12)
                 return (x * (scale * inv)).astype(c.dtype).transpose(
                     0, 2, 1, 3)
-            q = unit(self._conv(q, p["conv_q"]), d ** -0.5)
-            k = unit(self._conv(k, p["conv_k"]))
-            v = self._heads(self._conv(v, p["conv_v"]), n)
+            q = unit(self._conv(q, p["conv_q"], docs), d ** -0.5)
+            k = unit(self._conv(k, p["conv_k"], docs))
+            v = self._heads(self._conv(v, p["conv_v"], docs), n)
         with jax.named_scope("decay"):
             f = self._dot(self._dot(a, p["f_a"]), p["f_b"])
             rate = jnp.exp(p["a_log"].astype(f32))[:, None]
@@ -227,16 +345,18 @@ class HybridMoE(HeldExpertsLM):
             beta = beta.transpose(0, 2, 1)
         return q, k, v, g, beta
 
-    def _kda(self, p, a):
+    def _kda(self, p, a, docs=None):
         """a: (B, S, H), normed.  Kimi Delta Attention's output, before
         the residual add."""
         c = self.c
         b, s, _ = a.shape
         n, d = c.kda_heads, c.kda_head_dim
         f32 = jnp.float32
-        q, k, v, g, beta = self.scan_inputs(p, a)
+        q, k, v, g, beta = self.scan_inputs(p, a, docs)
         with jax.named_scope("scan"):
-            o = gated_delta_rule(q, k, v, g, beta, chunk=c.scan_chunk)
+            o = gated_delta_rule(
+                q, k, v, g, beta, chunk=c.scan_chunk,
+                resets=None if docs is None else docs.first)
         with jax.named_scope("onorm"):
             o = o.transpose(0, 2, 1, 3).astype(f32)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
@@ -247,18 +367,27 @@ class HybridMoE(HeldExpertsLM):
         with jax.named_scope("proj"):
             return self._dot(o, p["proj"])
 
-    def _block(self, i, p, x):
-        mixer = self._attention if self._attends(i) else self._kda
+    def _block(self, i, p, x, docs=None):
+        """`docs`: the row's `documents`; an input of the checkpointed
+        mixer under `recompute_mixers`, not recomputed work."""
+        if not self._attends(i):
+            mixer = self._kda
+        elif self.c.attention_kind == "latent":
+            mixer = self._latent
+        else:
+            mixer = self._attention
         if self.c.recompute_mixers:
             mixer = jax.checkpoint(mixer)
         with jax.named_scope(f"block{i}"):
             with jax.named_scope("ln1"):
                 a = self._norm(p["ln1"], x)
             with jax.named_scope("attn"):
-                x = x + mixer(p["attn"], a)
+                x = x + mixer(p["attn"], a, docs)
             with jax.named_scope("ln2"):
                 m = self._norm(p["ln2"], x)
             with jax.named_scope("mlp"):
+                if self._is_dense(i):
+                    return x + self._swiglu(p["mlp"], m), None
                 y, stats = self.experts.apply(p["mlp"], m)
                 return x + y, stats
 
@@ -267,10 +396,12 @@ class HybridMoE(HeldExpertsLM):
         layer, (B, S, H), before the final norm; the expert layers'
         HeldExpertsStats in layer order)."""
         h = self._embed(params, tokens)
+        docs = self.documents(tokens)
         stats = []
         for i in range(self.c.num_layers):
-            h, st = self._block(i, params[f"block{i}"], h)
-            stats.append(st)
+            h, st = self._block(i, params[f"block{i}"], h, docs)
+            if st is not None:
+                stats.append(st)
         return h, stats
 
     def apply(self, params, tokens, key=None):

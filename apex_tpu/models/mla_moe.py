@@ -42,14 +42,12 @@ Activations are (B, S, H).
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
-from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.rope_stage import (
     halves,
     rope_tables,
@@ -189,50 +187,29 @@ class MLAMoE(HeldExpertsLM):
         rotary lanes and writes q and k head-major, k's shared rotary
         row once a head, in one pass each; v and the context cross
         between the token-major GEMMs and the head-major kernels in one
-        copy each."""
+        copy each.  The key-value side, the flash call and the output
+        projection are `HeldExpertsLM`'s, shared with the latent layers
+        of `models.hybrid_moe`."""
         c = self.c
-        b, s, _ = a.shape
-        nh, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim,
-                          c.qk_rope_head_dim, c.v_head_dim)
+        nh, dn, dr = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
         with jax.named_scope("q_a"):
             c_q = self._norm(p["q_a_norm"], self._dot(a, p["q_a"]))
         with jax.named_scope("q_b"):
             w = p["q_b"].reshape(-1, nh, dn + dr)
             q_n = self._dot(c_q, w[..., :dn].reshape(-1, nh * dn))
             q_r = self._dot(c_q, halves(w[..., dn:]).reshape(-1, nh * dr))
-        with jax.named_scope("kv_a"):
-            ckv = self._dot(a, halves(p["kv_a"], c.kv_lora_rank))
-            k_r = ckv[..., c.kv_lora_rank:]
-            c_kv = self._norm(p["kv_a_norm"], ckv[..., :c.kv_lora_rank])
-        with jax.named_scope("kv_b"):
-            w = p["kv_b"].reshape(-1, nh, dn + dv)
-            k_n = self._dot(c_kv, w[..., :dn].reshape(-1, nh * dn))
-            v = self._dot(c_kv, w[..., dn:].reshape(-1, nh * dv))
+        k_n, k_r, v = self._latent_kv(p, a, rotary=True)
         with jax.named_scope("rope"):
             q = stage_heads(q_n, q_r, nh, tables,
                             use_pallas_override=c.flash_override)
             k = stage_heads(k_n, turn_halves(k_r, *tables), nh,
                             use_pallas_override=c.flash_override)
-        # the head-major copies of v and of the context are the
-        # kernels' price, as in the GPT block: they carry their scope
-        with jax.named_scope("flash"):
-            ctx = flash_attention(
-                q, k, v.reshape(b, s, nh, dv).transpose(0, 2, 1, 3),
-                causal=True, softmax_scale=1.0 / math.sqrt(c.qk_head_dim),
-                use_pallas_override=c.flash_override)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * dv)
-        with jax.named_scope("proj"):
-            return self._dot(ctx, p["proj"])
+        return self._latent_attend(p, q, k, v)
 
     def _mlp(self, i, p, m):
         """(the FFN's output, HeldExpertsStats or None)."""
         if self._is_dense(i):
-            with jax.named_scope("gate_up"):
-                gu = self._dot(m, p["gate_up"])
-                gate, up = jnp.split(gu, 2, axis=-1)
-                act = jax.nn.silu(gate) * up
-            with jax.named_scope("down"):
-                return self._dot(act, p["down"]), None
+            return self._swiglu(p, m), None
         return self.experts.apply(p, m)
 
     def _block(self, i, p, x, tables):

@@ -33,7 +33,13 @@ _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
               # and beta, the chunked scan (ops/delta_rule.py), and its
               # output norm and gate
               "attn/gate", "attn/conv", "attn/decay", "attn/scan",
-              "attn/onorm")
+              "attn/onorm",
+              # its latent attention without positions: the uncompressed
+              # query projection and the head-major staging of q and k
+              # with nothing turned (`attn/rope` stays the rotating
+              # block's); and what a step of packed rows spends on
+              # knowing its documents, filed under block0
+              "attn/q", "attn/stage", "attn/segments")
 # every scope path the program may open; `block{i}` is a layer by index
 # (`_tap` spells it the same way), `block` a layer of a scanned stack
 OWNERS = (

@@ -44,6 +44,26 @@ transpose over the chunks in reverse, and pulls the sum back through
 `_locals`: each piece is the `jax.vjp` of the forward's own function,
 so the two cannot drift apart.
 
+Documents packed into one row (`resets`, a (B, S) mask of the tokens
+that start one): a head's state is zero before such a token, as before
+the row's first.  The op has no second mechanism for that: the token's
+log-decay is pinned to `RESET_LOG_DECAY` on every channel, so what the
+state held reaches the token multiplied by `exp(-30)`, 9e-14, which is
+nothing beside anything a float32 sum holds, and every factor that
+carries a pair, a chunk's state or a cotangent across the boundary has
+that decay in its exponent: the chunk-local stage, the Pallas pair, the
+scans over chunks and their pullbacks run as they are.  The decay a
+first token came with is never read (`where`), so its gradient is 0,
+as under an exact reset, where nothing is left to decay.  The price is
+resolution: a chunk's running sums of g are 30 larger a boundary, and
+a float32 near 60 (two boundaries in a chunk) resolves 4e-6, which the
+decay between two tokens of one document past a boundary then carries
+as a relative error; the decays of a channel that forgets within a
+chunk sum as large without any boundary.  A lower pin would buy
+nothing and cost resolution; one above -25 would leave a state a
+hundred times its successor visible in the seventh digit.
+`gated_delta_rule_reference` resets exactly.
+
 What runs where, all of it under the caller's `attn/scan` scope.  What
 is local to a chunk is two Pallas kernels on the chip, where the heads
 are whole lane tiles wide (d_k and d_v multiples of 128, a chunk of 32,
@@ -75,6 +95,8 @@ from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 _SUB = 16            # tokens a sub-block: pairs inside one are explicit
 DEFAULT_CHUNK = 64
+# the log-decay of a token that starts a document, every channel
+RESET_LOG_DECAY = -30.0
 
 # calls traced since the last reset, the chunk of the last of them and
 # the bytes of chunk-start states their forwards keep for the backward
@@ -99,15 +121,18 @@ def reset_stats():
 
 # ------------------------------ the recurrence ------------------------------
 
-def gated_delta_rule_reference(q, k, v, g, beta):
+def gated_delta_rule_reference(q, k, v, g, beta, resets=None):
     """The recurrence itself, a token at a time, in float32: the parity
-    oracle.  Shapes as `gated_delta_rule`."""
+    oracle.  Shapes as `gated_delta_rule`; the state is set to zero,
+    exactly, before a token `resets` (B, S) marks."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
+    keep = (jnp.ones_like(beta) if resets is None else
+            jnp.broadcast_to(~resets[:, None], beta.shape).astype(f32))
 
     def token(state, x):
-        qt, kt, vt, gt, bt = x                  # (B, n, d) ... (B, n)
-        state = state * jnp.exp(gt)[..., None]
+        qt, kt, vt, gt, bt, keep_t = x          # (B, n, d) ... (B, n)
+        state = state * (keep_t[..., None] * jnp.exp(gt))[..., None]
         seen = jnp.einsum("bnkv,bnk->bnv", state, kt)
         state = state + jnp.einsum(
             "bnk,bnv->bnkv", kt, bt[..., None] * (vt - seen))
@@ -115,7 +140,7 @@ def gated_delta_rule_reference(q, k, v, g, beta):
 
     b, n, _, dk = q.shape
     first = jnp.zeros((b, n, dk, v.shape[-1]), f32)
-    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta, keep))
     with jax.default_matmul_precision("highest"):
         _, o = lax.scan(token, first, xs)
     return jnp.moveaxis(o, 0, 2)
@@ -624,13 +649,19 @@ def _delta_rule_bwd(chunk, kernels, res, do):
 _delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
+def gated_delta_rule(q, k, v, g, beta, *, resets=None,
+                     chunk: Optional[int] = None,
                      heads_a_pass: Optional[int] = None,
                      use_pallas_override: Optional[bool] = None):
     """o (B, n, S, d_v) of the gated delta rule over head-major q, k
     (B, n, S, d_k), v (B, n, S, d_v), the log-decay g (B, n, S, d_k),
     <= 0, and beta (B, n, S); every head starts from a zero state.  o
     has v's dtype; g and beta are best handed over in float32.
+
+    `resets`: (B, S) bool, true at the tokens that start a document of
+    a packed row: a head's state is zero before each, and g gets no
+    gradient there (the module's docstring says how).  None is one
+    document a row, and the op as it is without the argument.
 
     `chunk`: tokens a chunk, a power of two that divides S; None asks
     the `apex_tpu.tune` cache for one measured at this shape (op
@@ -658,6 +689,12 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: Optional[int] = None,
             f"shapes q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, "
             f"beta {beta.shape} are not (B, n, S, d_k) x 2, (B, n, S, "
             "d_v), (B, n, S, d_k), (B, n, S)")
+    if resets is not None:
+        if resets.shape != (b, s):
+            raise ValueError(f"resets {resets.shape} is not (B, S) = "
+                             f"{(b, s)}")
+        g = jnp.where(resets[:, None, :, None],
+                      jnp.asarray(RESET_LOG_DECAY, g.dtype), g)
     if chunk is None:
         from apex_tpu import tune
 

@@ -23,7 +23,11 @@ rotary lanes then share an order, which is all a dot product sees.
 one row a token that every head shares, `(B, S, dr)`, already turned
 (it is a 64-wide row: its rotation is no pass to save): then the pass
 writes it once a head and the backward sums its gradient over the
-heads, so no `(B, S, heads, dr)` array exists in either direction.
+heads, so no `(B, S, heads, dr)` array exists in either direction.  A
+model without positions (`models/hybrid_moe.py`'s latent attention)
+keeps the two widths and turns nothing: its keys' shared row goes in
+as it is, and its queries' per-head lanes with `per_head` and no
+tables, the same pass without the rotation.
 """
 
 from __future__ import annotations
@@ -71,9 +75,12 @@ def turn_halves(x, cos, sin):
 
 # --------------------------- reference (jnp) path ---------------------------
 
-def stage_heads_reference(nope, rope, num_heads: int, tables=None):
+def stage_heads_reference(nope, rope, num_heads: int, tables=None,
+                          per_head: bool = False):
     b, s, _ = nope.shape
-    if tables is None:
+    if tables is None and per_head:
+        r = rope.reshape(b, s, num_heads, -1)
+    elif tables is None:
         r = jnp.broadcast_to(rope[:, :, None], (b, s, num_heads,
                                                 rope.shape[-1]))
     else:
@@ -97,27 +104,31 @@ def _turn_block(r, cos, sin, dr):
     return r * cos + partner * sin
 
 
-def _stage_kernel(*refs, g, dn, dr, shared):
-    if shared:
-        nope_ref, rope_ref, o_ref = refs
-        turned = rope_ref[0]
-    else:
+def _stage_kernel(*refs, g, dn, dr, shared, turn):
+    if turn:
         nope_ref, rope_ref, cos_ref, sin_ref, o_ref = refs
         turned = _turn_block(rope_ref[0].astype(jnp.float32), cos_ref[...],
                              sin_ref[...], dr).astype(o_ref.dtype)
+    else:
+        nope_ref, rope_ref, o_ref = refs
+        turned = rope_ref[0]
     for j in range(g):
         o_ref[0, j, :, :dn] = nope_ref[0, :, j * dn:(j + 1) * dn]
         o_ref[0, j, :, dn:] = (turned if shared
                                else turned[:, j * dr:(j + 1) * dr])
 
 
-def _unstage_kernel(*refs, g, dn, dr, shared):
-    if shared:
-        g_ref, dnope_ref, drope_ref = refs
-    else:
+def _unstage_kernel(*refs, g, dn, dr, shared, turn):
+    if turn:
         g_ref, cos_ref, sin_ref, dnope_ref, drope_ref = refs
+    else:
+        g_ref, dnope_ref, drope_ref = refs
     for j in range(g):
         dnope_ref[0, :, j * dn:(j + 1) * dn] = g_ref[0, j, :, :dn]
+    if not (shared or turn):
+        for j in range(g):
+            drope_ref[0, :, j * dr:(j + 1) * dr] = g_ref[0, j, :, dn:]
+        return
     pieces = [g_ref[0, j, :, dn:].astype(jnp.float32) for j in range(g)]
     if shared:
         # one row a token for every head: the sum over this block's
@@ -151,20 +162,21 @@ def _lane_tables(cos, sin, g):
 
 
 @functools.lru_cache(maxsize=None)
-def _call(backward, b, s, nh, dn, dr, shared, rows, g, dtype, interpret):
+def _call(backward, b, s, nh, dn, dr, shared, turn, rows, g, dtype,
+          interpret):
     grid = (b, s // rows, nh // g)
     flat = lambda w: pl.BlockSpec((1, rows, g * w), lambda i, j, k: (i, j, k))
     heads = pl.BlockSpec((1, g, rows, dn + dr), lambda i, j, k: (i, k, j, 0))
     table = pl.BlockSpec((rows, g * dr), lambda i, j, k: (j, 0))
     row = pl.BlockSpec((1, rows, dr), lambda i, j, k: (i, j, 0))
-    tables = [] if shared else [table, table]
+    tables = [table, table] if turn else []
     params = pltpu.CompilerParams(dimension_semantics=(
         "parallel", "parallel", "arbitrary" if backward and shared
         else "parallel"))
     if backward:
         return pl.pallas_call(
             functools.partial(_unstage_kernel, g=g, dn=dn, dr=dr,
-                              shared=shared),
+                              shared=shared, turn=turn),
             grid=grid, in_specs=[heads] + tables,
             out_specs=[flat(dn), row if shared else flat(dr)],
             out_shape=[
@@ -174,29 +186,30 @@ def _call(backward, b, s, nh, dn, dr, shared, rows, g, dtype, interpret):
             compiler_params=params, interpret=interpret,
             name="rope_unstage")
     return pl.pallas_call(
-        functools.partial(_stage_kernel, g=g, dn=dn, dr=dr, shared=shared),
+        functools.partial(_stage_kernel, g=g, dn=dn, dr=dr, shared=shared,
+                          turn=turn),
         grid=grid, in_specs=[flat(dn), row if shared else flat(dr)] + tables,
         out_specs=heads,
         out_shape=jax.ShapeDtypeStruct((b, nh, s, dn + dr), dtype),
         compiler_params=params, interpret=interpret, name="rope_stage")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _stage(nope, rope, tables, dims, blocks):
-    return _stage_fwd(nope, rope, tables, dims, blocks)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _stage(nope, rope, tables, dims, blocks, shared):
+    return _stage_fwd(nope, rope, tables, dims, blocks, shared)[0]
 
 
-def _stage_fwd(nope, rope, tables, dims, blocks):
+def _stage_fwd(nope, rope, tables, dims, blocks, shared):
     b, s, _ = nope.shape
-    call = _call(False, b, s, *dims, tables is None, *blocks,
+    call = _call(False, b, s, *dims, shared, tables is not None, *blocks,
                  jnp.dtype(nope.dtype), pallas_interpret())
     lanes = () if tables is None else _lane_tables(*tables, blocks[1])
     return call(nope, rope, *lanes), tables
 
 
-def _stage_bwd(dims, blocks, tables, grad):
+def _stage_bwd(dims, blocks, shared, tables, grad):
     b, _, s, _ = grad.shape
-    call = _call(True, b, s, *dims, tables is None, *blocks,
+    call = _call(True, b, s, *dims, shared, tables is not None, *blocks,
                  jnp.dtype(grad.dtype), pallas_interpret())
     if tables is None:
         d_nope, d_rope = call(grad)
@@ -211,19 +224,21 @@ _stage.defvjp(_stage_fwd, _stage_bwd)
 
 
 def stage_heads(nope, rope, num_heads: int, tables=None, *,
-                use_pallas_override=None):
+                per_head: bool = False, use_pallas_override=None):
     """The flash kernels' operand `(B, heads, S, dn + dr)` from a
     projection's output `nope` (B, S, heads * dn) and `rope`: with
     `tables` = (cos, sin), each (S, dr / 2), `rope` is (B, S, heads *
     dr) in halves order and is turned here; without, it is (B, S, dr),
-    one turned row a token that every head gets.  Differentiable in
-    `nope` and `rope`."""
+    one row a token that every head gets as it is, or with `per_head`
+    (B, S, heads * dr), a head's own lanes as they are.  Differentiable
+    in `nope` and `rope`."""
     b, s, width = nope.shape
     assert width % num_heads == 0
-    dr = rope.shape[-1] // (1 if tables is None else num_heads)
+    per_head = per_head or tables is not None
+    dr = rope.shape[-1] // (num_heads if per_head else 1)
     assert tables is None or tables[0].shape == (s, dr // 2)
     dims = (num_heads, width // num_heads, dr)
     blocks = _blocks(s, *dims) if use_pallas(use_pallas_override) else None
     if blocks is None:
-        return stage_heads_reference(nope, rope, num_heads, tables)
-    return _stage(nope, rope, tables, dims, blocks)
+        return stage_heads_reference(nope, rope, num_heads, tables, per_head)
+    return _stage(nope, rope, tables, dims, blocks, not per_head)

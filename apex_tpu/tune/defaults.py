@@ -17,8 +17,8 @@ committed entries drift from fresh measurements.
 
 The dense cells' flash shapes carry no entry: they take the kernels' own
 blocks and, under a causal mask, the compute tiles the step bodies cut
-them into (`ops/flash_attention.py::_kernel_shape`).  The one flash
-entry, the latent-attention shape, is the one a benchmark cell hits.
+them into (`ops/flash_attention.py::_kernel_shape`).  Each flash entry
+is a shape a benchmark cell hits.
 """
 
 from __future__ import annotations
@@ -71,6 +71,28 @@ def _v5e_entries():
         "32.5 ms in one call of 64 heads (34.1 in two of 32), chunk 32 "
         "35.4 (38.3), chunk 128 37.2 (39.2); the compiled stage of PR "
         "32 at chunk 64 in two calls of 32 heads 75.5")
+    # a row of 8,192 tokens of packed documents (models/hybrid_moe.py's
+    # latent kind at the benchmark's sixth cell): latent attention's two
+    # widths under the segment mask, where only the (512, 512) single
+    # pass fits VMEM and two kernels at square blocks read the same;
+    # and the delta rule at half the heads and twice the row of the
+    # entry above, with its resets
+    e[_flash(1, 32, 8192, 8192, 192, "bfloat16", True, seg=True,
+             dv=128)] = _mk(
+        {"block_q": 1024, "block_k": 1024, "fused_bwd": False},
+        "v5e, PR 34 chip run, forward + backward a layer under the "
+        "segment mask: 29.81 ms; fused at (512, 512) 29.82, two-kernel "
+        "(2048, 512) 31.06, (1024, 512) 31.36, the heuristics' (512, "
+        "1024) 33.18 (33.02 without segment ids), (512, 512) 35.44; a "
+        "single pass at any larger block and (2048, 1024) do not fit "
+        "VMEM")
+    e[make_key("delta_rule", tune.delta_rule_attrs(
+        1, 32, 8192, 128, 128, "bfloat16"))] = _mk(
+        {"chunk": 64},
+        "v5e, PR 34 chip run, forward + backward a layer with a row's "
+        "resets: chunk 64 33.77 ms in one call of 32 heads (34.98 in "
+        "two of 16), chunk 32 37.39 (39.77), chunk 128 38.45 (39.89); "
+        "chunk 64 without resets 32.97")
     # flat-optimizer block rows at the 1B Adam bench point: the swept
     # heuristic value, committed so the fingerprint records it
     e[make_key("opt_flat", dict(kernel="adam", rows=8388608))] = _mk(

@@ -94,14 +94,28 @@ def _attention_fwd_bwd(attn, q, k, v, do):
     return (out,) + vjp(do)
 
 
+def _seeded_documents(key, batch, seq, mean=845):
+    """(ids (B, S) int32, first (B, S) bool): documents of a packed
+    row, a boundary after a token with probability 1 / mean."""
+    import jax
+    import jax.numpy as jnp
+
+    after = jax.random.bernoulli(key, 1.0 / mean, (batch, seq))
+    first = jnp.pad(after[:, :-1], ((0, 0), (1, 0)), constant_values=True)
+    return jnp.cumsum(first, axis=1, dtype=jnp.int32), first
+
+
 def flash_case(name, shape, config: Optional[dict] = None,
-               v_dim: Optional[int] = None) -> KernelCase:
+               v_dim: Optional[int] = None,
+               documents: bool = False) -> KernelCase:
     """Causal bf16 flash attention, forward and backward, against the
     dense reference.  config None leaves the kernel shape to the tuner
     and the heuristics, as the models do.  `v_dim` gives v (and the
-    output) a width of its own, as latent attention has it.  The
-    reference walks the batch one row at a time so its (S, S) scores
-    stay small."""
+    output) a width of its own, as latent attention has it; with
+    `documents` a row is packed documents and the call carries their
+    segment ids.  The reference walks the batch (and, a row of
+    documents being long, the heads) one at a time so its (S, S)
+    scores stay small."""
     import jax
     import jax.numpy as jnp
 
@@ -113,22 +127,37 @@ def flash_case(name, shape, config: Optional[dict] = None,
     v_shape = tuple(shape[:-1]) + (v_dim or shape[-1],)
 
     def make_args(key):
-        ks = jax.random.split(key, 4)      # q, k at `shape`; v, do at v's
-        return tuple(jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(
+        ks = jax.random.split(key, 5)      # q, k at `shape`; v, do at v's
+        args = tuple(jax.random.normal(k, s, jnp.bfloat16) for k, s in zip(
             ks, (shape, shape, v_shape, v_shape)))
+        if documents:
+            args += (_seeded_documents(ks[4], shape[0], shape[2])[0],)
+        return args
 
     fwd_bwd = _attention_fwd_bwd
 
-    def kernel(q, k, v, do):
+    def kernel(q, k, v, do, ids=None):
         return fwd_bwd(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, **(config or {})), q, k, v, do)
+            q, k, v, causal=True, segment_ids=ids, **(config or {})),
+            q, k, v, do)
 
-    def reference(q, k, v, do):
-        def one(row):
+    def reference(q, k, v, do, ids=None):
+        if ids is None:
+            def one(row):
+                return fwd_bwd(lambda q, k, v: attention_reference(
+                    q, k, v, causal=True), *(a[None] for a in row))
+            outs = jax.lax.map(one, (q, k, v, do))
+            return tuple(o[:, 0] for o in outs)
+
+        def head(x):                       # a head of a row at a time
+            *row, ids = x
             return fwd_bwd(lambda q, k, v: attention_reference(
-                q, k, v, causal=True), *(a[None] for a in row))
-        outs = jax.lax.map(one, (q, k, v, do))
-        return tuple(o[:, 0] for o in outs)
+                q, k, v, causal=True, q_segment_ids=ids[None],
+                kv_segment_ids=ids[None]), *(a[None, None] for a in row))
+        b, h = shape[:2]
+        flat = [a.reshape(b * h, *a.shape[2:]) for a in (q, k, v, do)]
+        outs = jax.lax.map(head, (*flat, jnp.repeat(ids, h, axis=0)))
+        return tuple(o.reshape(b, h, *o.shape[3:]) for o in outs)
 
     # bf16 in and out: a few bf16 ulps at the O(1) magnitudes attention
     # produces, the bound tests/test_flash_attention.py holds bf16 to
@@ -185,14 +214,17 @@ def gqa_flash_case(name, batch, heads, kv_heads, seq, head_dim,
     return KernelCase(name, make_args, kernel, reference, 5e-2, 5e-2)
 
 
-def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4) -> KernelCase:
+def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
+                    documents: bool = False) -> KernelCase:
     """The chunked gated delta rule (ops/delta_rule.py) in bf16 at the
     benchmark's shape, forward and all five gradients, against the
     recurrence a token at a time in float32.  Inputs as KDA makes them:
     q and k of unit length (q by d^-1/2 more), the log-decay
     -A softplus(.) with A in (1, 16) and a time step in (1e-3, 1e-1),
-    beta in (0, 2).  The reference walks the heads a few at a time: its
-    backward keeps a state a token."""
+    beta in (0, 2), or in (0, 1) with `documents`: then a row is packed
+    documents, the op is handed their first tokens as `resets` and the
+    recurrence sets its state to 0 there, exactly.  The reference walks
+    the heads a few at a time: its backward keeps a state a token."""
     import jax
     import jax.numpy as jnp
 
@@ -216,21 +248,32 @@ def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4) -> KernelCase:
             math.log(1e-1)))
         g = -rate * jax.nn.softplus(
             jnp.log(jnp.expm1(dt)) + 0.3 * jax.random.normal(ks[5], shape))
-        beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[6], shape[:3]))
-        return (unit(ks[0], dim ** -0.5), unit(ks[1]),
-                jax.random.normal(ks[2], shape, jnp.bfloat16), g, beta,
+        beta = jax.nn.sigmoid(jax.random.normal(ks[6], shape[:3]))
+        args = (unit(ks[0], dim ** -0.5), unit(ks[1]),
+                jax.random.normal(ks[2], shape, jnp.bfloat16), g,
+                beta if documents else 2 * beta,
                 jax.random.normal(ks[7], shape, jnp.bfloat16))
+        if documents:
+            args += (_seeded_documents(jax.random.fold_in(ks[5], 1), batch,
+                                       seq)[1],)
+        return args
 
     def fwd_bwd(rule, q, k, v, g, beta, do):
         out, vjp = jax.vjp(rule, q, k, v, g, beta)
         return (out,) + vjp(do.astype(out.dtype))
 
     def kernel(*args):
-        return fwd_bwd(gated_delta_rule, *args)
+        first = args[6] if documents else None
+        return fwd_bwd(lambda *a: gated_delta_rule(*a, resets=first),
+                       *args[:6])
 
     def reference(*args):
+        first = args[6] if documents else None
+        args = args[:6]
+
         def some(x):
-            return fwd_bwd(gated_delta_rule_reference, *x)
+            return fwd_bwd(lambda *a: gated_delta_rule_reference(
+                *a, resets=first), *x)
 
         def by_pass(x):    # (B, n, ...) -> (n / pass, B, pass, ...)
             return x.reshape(batch, heads // heads_a_pass, heads_a_pass,
@@ -615,6 +658,14 @@ def kernel_cases(device) -> list:
         # (models/hybrid_moe.py): `correct` cannot see a wrong backward
         gqa_flash_case("gqa_flash_grads", 1, 64, 8, 4096, 128),
         delta_rule_case("delta_rule_grads", 1, 64, 4096, 128),
+        # a row of 8,192 tokens of packed documents at the Kimi-Linear
+        # cell's shapes: latent attention's two widths under the segment
+        # mask, and the delta rule with its state reset at every
+        # document's first token, against the exact reset
+        flash_case("flash_nope_192_128_docs", (1, 32, 8192, 192), v_dim=128,
+                   documents=True),
+        delta_rule_case("delta_rule_docs_grads", 1, 32, 8192, 128,
+                        documents=True),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
         held_experts_case("moe_held_experts", 8192, 2048, 768, 256, 16, 8),
         # one chip's 8 of 320 experts of 1280 over 4,096 tokens, a

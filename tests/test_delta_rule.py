@@ -149,18 +149,19 @@ def test_the_chunk_comes_from_the_tuner_then_the_default(monkeypatch):
     assert DR.stats()["chunk"] == 16
 
 
-def test_the_committed_v5e_chunk_is_the_benchmark_shapes():
+@pytest.mark.parametrize("heads,seq", [(64, 4096), (32, 8192)])
+def test_the_committed_v5e_chunk_is_the_benchmark_shapes(heads, seq):
     from apex_tpu.tune import defaults
 
     key = tune.make_key("delta_rule", tune.delta_rule_attrs(
-        1, 64, 4096, 128, 128, "bfloat16"))
+        1, heads, seq, 128, 128, "bfloat16"))
     config = defaults.DEFAULTS["v5e"][key]["config"]
-    # re-measured on the kernels (PR 33): every head in one call
+    # re-measured on the kernels (PRs 33, 34): every head in one call
     assert set(config) == {"chunk"}
-    assert 4096 % config["chunk"] == 0
+    assert seq % config["chunk"] == 0
     assert DR._kernels_take(
-        jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16),
-        jax.ShapeDtypeStruct((1, 64, 4096, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16),
         config["chunk"], True)
 
 
@@ -343,3 +344,123 @@ def test_a_differentiated_call_runs_the_stage_once_forward_twice_back(
     assert names(pull, do) == (passes, passes)
     assert names(lambda a, do: _fwd_bwd(rule, a, do), args, do) == (
         2 * passes, passes)
+
+
+# --------------------- documents packed into one row ---------------------
+# `resets` marks the tokens that start a document: the packed row has to
+# give, document by document, what each document gives in a row of its
+# own (the recurrence a token at a time, over that document's slice)
+
+# a boundary at every position class of a chunk of 32 and of 64: on the
+# chunk's edge (64, 128), one past it (129), one before it (191), on and
+# beside a sub-block's edge (16, 17), in a sub-block (40), documents of
+# 1 and 2 tokens (40-41, 41-43), and one that outlasts two chunks
+STARTS = (0, 16, 17, 40, 41, 43, 64, 128, 129, 191, 250, 255)
+PACKED = 256
+
+
+def _resets(b, s=PACKED, starts=STARTS):
+    first = np.zeros((b, s), bool)
+    first[:, list(starts)] = True
+    return jnp.asarray(first)
+
+
+def _document_by_document(args, do, starts=STARTS):
+    """(o, dq, dk, dv, dg, dbeta) of the recurrence over each document
+    alone, laid side by side along the row."""
+    s = do.shape[2]
+    edges = list(starts) + [s]
+    parts = []
+    for a, z in zip(edges[:-1], edges[1:]):
+        cut = lambda x: x[:, :, a:z]
+        parts.append(_fwd_bwd(DR.gated_delta_rule_reference,
+                              tuple(cut(x) for x in args), cut(do)))
+    return tuple(jnp.concatenate(x, axis=2) for x in zip(*parts))
+
+
+@pytest.fixture(scope="module")
+def packed():
+    """{(path, chunk): (the packed row through the op, document by
+    document)}; the `jax.numpy` stage at 16 x 8 heads, the kernels
+    interpreted at 128 x 128."""
+    out = {}
+    for path, shape, override in (
+            ("jnp", dict(b=2, n=3, s=PACKED, dk=16, dv=8), None),
+            ("kernels", dict(b=1, n=2, s=PACKED, dk=128, dv=128), True)):
+        for chunk in (32, 64):
+            args, do = _inputs(chunk + 11, neg=False, **shape)
+            rule = lambda *a: DR.gated_delta_rule(
+                *a, resets=_resets(shape["b"]), chunk=chunk,
+                use_pallas_override=override)
+            out[path, chunk] = (_fwd_bwd(rule, args, do),
+                                _document_by_document(args, do))
+    return out
+
+
+@pytest.mark.parametrize("what", range(6), ids=NAMES)
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("path", ["jnp", "kernels"])
+def test_a_packed_row_is_its_documents_one_by_one(packed, path, chunk, what):
+    got, want = packed[path, chunk]
+    scale = float(jnp.max(jnp.abs(want[what])))
+    np.testing.assert_allclose(got[what], want[what], rtol=1e-4,
+                               atol=2e-5 * max(scale, 1.0))
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernels"])
+def test_the_decay_of_a_first_token_gets_no_gradient(packed, path):
+    dg = np.asarray(packed[path, 64][0][4])
+    assert not dg[:, :, list(STARTS)].any()
+    assert dg[:, :, 1].any()
+
+
+def test_the_recurrence_with_exact_resets_is_document_by_document():
+    args, do = _inputs(3, b=1, n=2, s=PACKED, neg=False)
+    got = _fwd_bwd(lambda *a: DR.gated_delta_rule_reference(
+        *a, resets=_resets(1)), args, do)
+    for g, w in zip(got, _document_by_document(args, do)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("path", ["jnp", "kernels"])
+def test_a_state_that_lives_a_thousand_tokens_is_gone_past_a_boundary(path):
+    """g = -1e-3 on every channel: without the boundary the second
+    document would read a state that has kept nine tenths of the
+    first; with it, what each document reads alone."""
+    shape, override = ((dict(b=1, n=2, s=128, dk=16, dv=8), None)
+                       if path == "jnp" else (WIDE, True))
+    args, do = _inputs(13, neg=False, **shape)
+    args = args[:3] + (jnp.full_like(args[3], -1e-3), args[4])
+    starts = (0, 70)
+    rule = lambda *a: DR.gated_delta_rule(
+        *a, resets=_resets(shape["b"], 128, starts), chunk=64,
+        use_pallas_override=override)
+    got = _fwd_bwd(rule, args, do)
+    want = _document_by_document(args, do, starts)
+    unbounded = DR.gated_delta_rule_reference(*args)
+    assert float(jnp.max(jnp.abs(unbounded - want[0])[:, :, 71])) > 1e-2
+    for g, w in zip(got, want):
+        scale = float(jnp.max(jnp.abs(w)))
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=2e-5 * max(scale, 1.0))
+
+
+def test_without_resets_the_op_traces_as_it_did():
+    """None is no argument at all, and a mask is one `select` on g in
+    front of the same program: the op has no second path for packed
+    rows."""
+    args, _ = _inputs(2, b=1, n=2, s=128)
+    resets = _resets(1, 128, (0, 50))
+    plain = lambda *a: DR.gated_delta_rule(*a, chunk=32)
+    assert str(jax.make_jaxpr(plain)(*args)) == str(jax.make_jaxpr(
+        lambda *a: DR.gated_delta_rule(*a, resets=None, chunk=32))(*args))
+    pinned = lambda q, k, v, g, beta: plain(q, k, v, jnp.where(
+        resets[:, None, :, None], jnp.float32(DR.RESET_LOG_DECAY), g), beta)
+    assert str(jax.make_jaxpr(pinned)(*args)) == str(jax.make_jaxpr(
+        lambda *a: DR.gated_delta_rule(*a, resets=resets, chunk=32))(*args))
+
+
+def test_resets_of_another_shape_are_refused():
+    args, _ = _inputs(4, s=64)
+    with pytest.raises(ValueError, match="resets"):
+        DR.gated_delta_rule(*args, resets=jnp.zeros((2, 32), bool), chunk=32)
