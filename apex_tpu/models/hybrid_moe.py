@@ -75,6 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
+from apex_tpu.ops.conv_stage import stage_conv_heads
 from apex_tpu.ops.delta_rule import gated_delta_rule
 from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.rope_stage import stage_heads
@@ -291,26 +292,6 @@ class HybridMoE(HeldExpertsLM):
         return self._latent_attend(
             p, q, k, v, segment_ids=None if docs is None else docs.ids)
 
-    def _conv(self, x, w, docs=None):
-        """SiLU of the causal depthwise convolution over time: x (B, S,
-        C), w (taps, C); tap j weighs the token taps - 1 - j back, or 0
-        where that token is of the document before."""
-        taps = w.shape[0]
-        s = x.shape[1]
-        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-
-        def tap(j):
-            term = padded[:, j:j + s].astype(jnp.float32) \
-                * w[j].astype(jnp.float32)
-            back = taps - 1 - j
-            if docs is None or not back:
-                return term
-            return term * docs.taps[back - 1]
-
-        y = sum(tap(j) for j in range(taps))
-        # rounded where the backward keeps it: the sum, not its terms
-        return jax.nn.silu(y.astype(x.dtype))
-
     def scan_inputs(self, p, a, docs=None):
         """a: (B, S, H), normed.  What Kimi Delta Attention hands
         `gated_delta_rule`, head-major: q, k, v (B, n, S, d) in the
@@ -323,15 +304,13 @@ class HybridMoE(HeldExpertsLM):
         with jax.named_scope("qkv"):
             q, k, v = (self._dot(a, p[x]) for x in "qkv")
         with jax.named_scope("conv"):
-            def unit(x, scale=1.0):
-                x = x.reshape(b, s, n, d).astype(f32)
-                inv = jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
-                                    + 1e-12)
-                return (x * (scale * inv)).astype(c.dtype).transpose(
-                    0, 2, 1, 3)
-            q = unit(self._conv(q, p["conv_q"], docs), d ** -0.5)
-            k = unit(self._conv(k, p["conv_k"], docs))
-            v = self._heads(self._conv(v, p["conv_v"], docs), n)
+            # q's and k's heads of unit length, q by d^-1/2 more
+            q, k, v = stage_conv_heads(
+                (q, k, v), [p[f"conv_{x}"] for x in "qkv"], n,
+                (d ** -0.5, 1.0, None),
+                ids=None if docs is None else docs.ids,
+                masks=None if docs is None else docs.taps,
+                use_pallas_override=c.flash_override)
         with jax.named_scope("decay"):
             f = self._dot(self._dot(a, p["f_a"]), p["f_b"])
             rate = jnp.exp(p["a_log"].astype(f32))[:, None]

@@ -60,7 +60,7 @@ KERNELS = (
     "lamb_phase2_seg", "per_tensor_sumsq", "xent_fwd", "xent_bwd",
     "ln_fwd", "ln_bwd", "softmax_fwd", "softmax_bwd", "fused_dense",
     "welford", "rope_stage", "rope_unstage", "kda_locals_fwd",
-    "kda_locals_bwd")
+    "kda_locals_bwd", "conv_stage", "conv_unstage")
 UNOWNED = "unowned"
 
 # jvp( transpose( vmap( ... and every ")": a transform wraps the first

@@ -289,6 +289,58 @@ def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
                       in_rms=True)
 
 
+def conv_stage_case(name, shapes) -> KernelCase:
+    """KDA's staging of q, k, v for the scan (ops/conv_stage.py) in
+    bf16, the Pallas pair against the `jax.numpy` body in float32:
+    the three head-major outputs and the gradients of the streams and
+    of the taps, at each of `shapes` = (batch, heads, seq, dim,
+    documents), the two hybrid cells'; with `documents` a row is packed
+    documents and the taps stop at their first tokens."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.conv_stage import (
+        stage_conv_heads,
+        stage_conv_heads_reference,
+    )
+
+    def make_args(key):
+        args = []
+        for i, (batch, heads, seq, dim, documents) in enumerate(shapes):
+            ks = jax.random.split(jax.random.fold_in(key, i), 10)
+            wide = heads * dim
+            xs = tuple(jax.random.normal(k, (batch, seq, wide), jnp.bfloat16)
+                       for k in ks[:3])
+            ws = tuple((jax.random.uniform(k, (4, wide), minval=-1.0) / 2
+                        ).astype(jnp.bfloat16) for k in ks[3:6])
+            cots = tuple(jax.random.normal(
+                k, (batch, heads, seq, dim), jnp.bfloat16) for k in ks[6:9])
+            ids = _seeded_documents(ks[9], batch, seq)[0] if documents \
+                else None
+            args.append((xs, ws, cots, ids))
+        return tuple(args)
+
+    def fwd_bwd(stage, dtype, xs, ws, cots, ids, heads, dim):
+        cast = lambda t: tuple(x.astype(dtype) for x in t)
+        outs, pull = jax.vjp(
+            lambda xs, ws: stage(xs, ws, heads, (dim ** -0.5, 1.0, None),
+                                 ids=ids), cast(xs), cast(ws))
+        dxs, dws = pull(cast(cots))
+        return (*outs, *dxs, *dws)
+
+    def run(stage, dtype, *args):
+        return tuple(x for arg, (_, heads, _, dim, _) in zip(args, shapes)
+                     for x in fwd_bwd(stage, dtype, *arg, heads, dim))
+
+    # in units of each output's rms, the band of `delta_rule_grads`:
+    # bf16's rounding of the taps' sum and of the results against
+    # float32 throughout
+    return KernelCase(
+        name, make_args, lambda *a: run(stage_conv_heads, jnp.bfloat16, *a),
+        lambda *a: run(stage_conv_heads_reference, jnp.float32, *a),
+        5e-2, 0.2, in_rms=True)
+
+
 def flash_qkv_case(name, seq, batch, heads, head_dim) -> KernelCase:
     """Causal bf16 flash attention from the packed (S, B, 3*H)
     projection to the (S, B, H) context, forward and backward, as
@@ -666,6 +718,10 @@ def kernel_cases(device) -> list:
                    documents=True),
         delta_rule_case("delta_rule_docs_grads", 1, 32, 8192, 128,
                         documents=True),
+        # the way from KDA's projections to those two calls: taps, SiLU,
+        # unit scaling and the head-major order in one pass a direction
+        conv_stage_case("conv_stage_grads", [(1, 64, 4096, 128, False),
+                                             (1, 32, 8192, 128, True)]),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
         held_experts_case("moe_held_experts", 8192, 2048, 768, 256, 16, 8),
         # one chip's 8 of 320 experts of 1280 over 4,096 tokens, a

@@ -121,6 +121,7 @@ def _smoke_case(name, device):
 @pytest.mark.parametrize("name,min_kernels", [
     ("gqa_flash_grads", 2), ("delta_rule_grads", 3),
     ("flash_nope_192_128_docs", 3), ("delta_rule_docs_grads", 3),
+    ("conv_stage_grads", 4),
     ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_d64_s2048", 2),
     ("flash_mla_192_128", 2), ("mla_attention_grads", 6),
     ("moe_held_experts", 6), ("moe_held_experts_320", 6),

@@ -395,13 +395,17 @@ def test_the_owners_vocabulary_has_the_new_sublayers():
 
 # sha256 of str(jax.make_jaxpr(...)) of loss and gradients at the older
 # cells' published widths and shapes, addresses scrubbed and the sets a
-# jaxpr prints put in order, as the parent commit (2da4316) traced them:
-# this PR moved the key-value side of latent attention, the flash call,
-# the output projection and the dense SwiGLU into `HeldExpertsLM`, gave
-# `stage_heads` a third mode and the hybrid block its documents, and the
-# steps of `joyai-llm-flash` and `solar-open2-250b` are to compile to
-# what they did.  "kernels" traces
-# the Pallas bodies too (interpret mode), "jnp" the references.
+# jaxpr prints put in order, as the commit before PR 34 (2da4316) traced
+# them: PR 34 moved the key-value side of latent attention, the flash
+# call, the output projection and the dense SwiGLU into `HeldExpertsLM`,
+# gave `stage_heads` a third mode and the hybrid block its documents,
+# and the steps of `joyai-llm-flash` and `solar-open2-250b` were to
+# compile to what they did.  "kernels" traces the Pallas bodies too
+# (interpret mode), "jnp" the references.  PR 35 made the body of the
+# hybrid stack's `attn/conv` an op with a Pallas pair of its own
+# (`ops/conv_stage.py`): its `jax.numpy` body is the parent's, so
+# ("hybrid", "jnp") stands, and ("hybrid", "kernels") stands with that
+# one op on its body and differs, by the pair's two names, without.
 PARENT_JAXPR = {
     ("mla", "jnp"):
         "1a1b2d245e5547abf64ebd8d221234b344b506f3865bdfbfbc60cda68b0110bd",
@@ -415,10 +419,12 @@ PARENT_JAXPR = {
 
 
 @pytest.mark.parametrize("which,path", sorted(PARENT_JAXPR))
-def test_the_older_cells_models_trace_to_the_parents_jaxpr(mesh, which, path):
+def test_the_older_cells_models_trace_to_the_parents_jaxpr(mesh, which, path,
+                                                           monkeypatch):
     import hashlib
     import re
 
+    from apex_tpu.models import hybrid_moe
     from apex_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
 
     common = dict(dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
@@ -434,14 +440,30 @@ def test_the_older_cells_models_trace_to_the_parents_jaxpr(mesh, which, path):
     fn = shard_map(jax.value_and_grad(model.loss), mesh=mesh,
                    in_specs=(model.partition_specs(), P(), P()),
                    out_specs=(P(), model.partition_specs()), check_vma=False)
-    text = re.sub(r"0x[0-9a-f]+", "0x",
-                  str(jax.make_jaxpr(fn)(params, tok, tok)))
-    # a frozenset prints in the order of this process's string hashes
-    text = re.sub(
-        r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})" % ", ".join(
-            sorted(x.strip() for x in m.group(1).split(","))), text)
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_JAXPR[
-        which, path]
+
+    def digest():
+        text = re.sub(r"0x[0-9a-f]+", "0x",
+                      str(jax.make_jaxpr(fn)(params, tok, tok)))
+        # a frozenset prints in the order of this process's string hashes
+        text = re.sub(
+            r"frozenset\(\{([^}]*)\}\)",
+            lambda m: "frozenset({%s})" % ", ".join(
+                sorted(x.strip() for x in m.group(1).split(","))), text)
+        return hashlib.sha256(text.encode()).hexdigest(), text
+
+    if (which, path) == ("hybrid", "kernels"):
+        # three KDA layers: one call of the pair a layer and direction,
+        # and once more for the recomputed forward
+        text = digest()[1]
+        assert text.count("name=conv_stage") == 6
+        assert text.count("name=conv_unstage") == 3
+        staged = hybrid_moe.stage_conv_heads
+        monkeypatch.setattr(
+            hybrid_moe, "stage_conv_heads",
+            lambda *a, use_pallas_override=None, **kw: staged(
+                *a, use_pallas_override=False, **kw))
+        jax.clear_caches()      # a checkpointed mixer's trace is kept
+    assert digest()[0] == PARENT_JAXPR[which, path]
 
 
 def test_the_committed_v5e_config_for_packed_latent_attention(monkeypatch):
