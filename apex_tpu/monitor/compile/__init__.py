@@ -2,7 +2,7 @@
 
 The monitor stack's third axis, after "how fast" (metrics/MFU, ISSUE
 2) and "where did numerics break" (trace, ISSUE 4): the compiled
-program itself.  Three cooperating pieces:
+program itself.  Four cooperating pieces:
 
   * report     — `analyze_step(step_fn, args) -> CompileReport`: AOT
                  lower+compile WITHOUT executing; per-program
@@ -22,8 +22,19 @@ program itself.  Three cooperating pieces:
                  flight-recorder guard can attach the last
                  CompileReport + memory snapshot to a
                  RESOURCE_EXHAUSTED crash dump.
+  * startup    — the set-up ledger (ISSUE 36): every program the
+                 process traces, lowers, compiles or reads from the
+                 persistent cache, by name, stage and seconds (from
+                 `jax.monitoring`), the seconds each Pallas kernel's
+                 body took to trace (`kernel_span`, at the ops'
+                 `pallas_call` sites), the program's own spans of
+                 set-up work (`span`, also `apex.setup/<name>` in a
+                 profile) and the bound on what came before it was
+                 armed; `mark_steady` (the sentry's) files a later
+                 compile under `steady`, by name and seconds.
 
-See docs/observability.md ("HBM budget & recompile debugging").
+See docs/observability.md ("HBM budget & recompile debugging", "Why
+did start-up take N seconds").
 """
 
 from apex_tpu.monitor.compile.report import (  # noqa: F401
@@ -33,6 +44,7 @@ from apex_tpu.monitor.compile.report import (  # noqa: F401
     tree_bytes,
 )
 from apex_tpu.monitor.compile.sentry import RecompileSentry  # noqa: F401
+from apex_tpu.monitor.compile import startup  # noqa: F401
 # NOTE: the module itself is deliberately NOT shadowed — the function
 # export is named hbm_watermarks so `compile.watermarks` stays the
 # submodule (recorder/logger import it by module path)

@@ -31,6 +31,8 @@ from __future__ import annotations
 import warnings
 from typing import Callable, Optional
 
+from apex_tpu.monitor.compile import startup
+
 _MAX_EVENTS = 64
 
 
@@ -73,6 +75,7 @@ class RecompileSentry:
         self._signatures = {}     # sig -> first-seen call index
         self._steady = False
         self._warned = False
+        self._programs_seen = startup.n_programs()
         # poll the jit cache when reachable: the builders attach the
         # underlying jitted fn as `step.jitted`; a bare jitted step IS
         # its own cache owner
@@ -125,6 +128,13 @@ class RecompileSentry:
                      "steady_state": self._steady,
                      "signature": sig if len(sig) <= 512 else
                      sig[:509] + "..."}
+            # the program this call just compiled, by the set-up
+            # ledger's account (where it is armed): its name and seconds
+            program = startup.heaviest_since(self._programs_seen)
+            self._programs_seen = startup.n_programs()
+            if program is not None:
+                event["fun_name"] = program["fun_name"]
+                event["seconds"] = startup.seconds_of(program)
             if len(self.events) < _MAX_EVENTS:
                 self.events.append(event)
             if self.recorder is not None:
@@ -147,8 +157,11 @@ class RecompileSentry:
 
     def mark_steady(self) -> None:
         """End of warmup: compiles were expected until now; from here
-        every compile is a steady-state recompile (warned + counted)."""
+        every compile is a steady-state recompile (warned + counted),
+        and the set-up ledger files it under `steady`."""
         self._steady = True
+        self._programs_seen = startup.n_programs()
+        startup.mark_steady()
 
     @property
     def n_signatures(self) -> int:

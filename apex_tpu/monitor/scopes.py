@@ -18,6 +18,7 @@ from __future__ import annotations
 import re
 
 from apex_tpu.monitor.comms.hlo import parse_module
+from apex_tpu.monitor.compile import startup
 
 _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
               "ln2", "mlp", "mlp/fc1", "mlp/gelu", "mlp/fc2",
@@ -189,6 +190,7 @@ def register(name: str, jitted, args) -> bool:
     another transformation, not on a device."""
     import jax
 
+    startup.arm()
     if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
         return False
 
@@ -203,14 +205,67 @@ def register(name: str, jitted, args) -> bool:
     return True
 
 
+def registered() -> tuple:
+    """The names of the programs this process registered."""
+    return tuple(_programs)
+
+
 def step_text(name: str = "local_step") -> str:
     """The compiled text of the registered program `name`.  After the
     program has run this lowers and compiles nothing anew: tracing,
     lowering and the executable come from JAX's in-process caches."""
     jitted, args = _programs[name]
-    return jitted.lower(*args).compile().as_text()
+    with startup.span("scopes.step_text"):
+        return jitted.lower(*args).compile().as_text()
 
 
 def step_owners(name: str = "local_step") -> dict:
     """`owners` of the registered program `name`."""
     return owners(step_text(name))
+
+
+def _jaxprs_in(eqn):
+    """The jaxprs an equation carries in its parameters: the body of a
+    `pjit`, `custom_vjp_call`, `checkpoint`, `scan`, `while`,
+    `shard_map`, the branches of a `cond`."""
+    from jax.extend import core
+
+    for value in eqn.params.values():
+        for inner in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(inner, "jaxpr", inner)     # a ClosedJaxpr
+            if isinstance(inner, core.Jaxpr):
+                yield inner
+
+
+def count_eqns(jaxpr) -> int:
+    """The equations of a jaxpr and of every jaxpr nested in it."""
+    return sum(1 + sum(count_eqns(inner) for inner in _jaxprs_in(eqn))
+               for eqn in jaxpr.eqns)
+
+
+def kernels_in(jaxpr, found=None) -> dict:
+    """{kernel: {"call_sites": n, "body_eqns": m}} for the Pallas calls
+    of a jaxpr, every nested body walked: `n` the `pallas_call`
+    equations that name the kernel, `m` the equations of their bodies
+    together (`count_eqns`, so a body's own branches and loops count).
+    A call site is what Mosaic lowers: one in a scanned body is one,
+    however often it runs."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            cell = found.setdefault(eqn.params.get("name") or "pallas_call",
+                                    {"call_sites": 0, "body_eqns": 0})
+            cell["call_sites"] += 1
+            cell["body_eqns"] += count_eqns(eqn.params["jaxpr"])
+        else:
+            for inner in _jaxprs_in(eqn):
+                kernels_in(inner, found)
+    return found
+
+
+def step_kernels(name: str = "local_step") -> dict:
+    """`kernels_in` the traced jaxpr of the registered program `name`:
+    the static twin of the ledger's kernel spans.  After the program
+    has run, the jaxpr comes from JAX's cache (as `step_text`'s does)."""
+    jitted, args = _programs[name]
+    return kernels_in(jitted.trace(*args).jaxpr.jaxpr)

@@ -54,6 +54,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 LANES = 128
@@ -353,7 +354,8 @@ def _stage_fwd(xs, ws, ids, how):
     args = [] if ids is None else [ids[..., None]] * 2
     for x, w in zip(xs, ws):
         args += [x, x, w.astype(jnp.float32)]
-    outs = _call_for(False, xs, ws, ids, how)(*args)
+    with kernel_span("conv_stage"):
+        outs = _call_for(False, xs, ws, ids, how)(*args)
     return tuple(outs), (xs, ws, ids)
 
 
@@ -362,7 +364,8 @@ def _stage_bwd(how, res, grads):
     args = [] if ids is None else [ids[..., None]] * 3
     for x, w, grad in zip(xs, ws, grads):
         args += [grad, x, x, w.astype(jnp.float32)]
-    outs = _call_for(True, xs, ws, ids, how)(*args)
+    with kernel_span("conv_unstage"):
+        outs = _call_for(True, xs, ws, ids, how)(*args)
     # a batch row's and a sublane's partial sums of dw
     dws = tuple(part.sum(axis=(0, 2)).astype(w.dtype)
                 for part, w in zip(outs[1::2], ws))

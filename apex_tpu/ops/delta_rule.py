@@ -91,6 +91,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 _SUB = 16            # tokens a sub-block: pairs inside one are explicit
@@ -514,8 +515,9 @@ def _locals_call(backward, q, v, chunk):
         return pl.pallas_call(
             functools.partial(_locals_bwd_kernel, C=chunk),
             name="kda_locals_bwd", **how)
-    return pl.pallas_call(functools.partial(_locals_fwd_kernel, C=chunk),
-                          name="kda_locals_fwd", **how)
+    return pl.pallas_call(
+        functools.partial(_locals_fwd_kernel, C=chunk),
+        name="kda_locals_fwd", **how)
 
 
 def _cut(q, k, v, g, beta, chunk):
@@ -535,19 +537,21 @@ def _locals_kernels(q, k, v, g, beta, chunk):
 
 def _locals_kernels_fwd(q, k, v, g, beta, chunk):
     b, n = q.shape[:2]
+    with kernel_span("kda_locals_fwd"):
+        locs = _locals_call(False, q, v, chunk)(
+            *_cut(q, k, v, g, beta, chunk))
     loc = _Locals(*(
-        x.reshape(x.shape[0], b, n, *x.shape[2:])
-        for x in _locals_call(False, q, v, chunk)(
-            *_cut(q, k, v, g, beta, chunk))))
+        x.reshape(x.shape[0], b, n, *x.shape[2:]) for x in locs))
     return loc._replace(decay=loc.decay[..., 0, :]), (q, k, v, g, beta)
 
 
 def _locals_kernels_bwd(chunk, res, d_loc):
     q, _, v = res[:3]
     d_loc = d_loc._replace(decay=d_loc.decay[..., None, :])
-    grads = _locals_call(True, q, v, chunk)(
-        *_cut(*res, chunk),
-        *(x.reshape(x.shape[0], -1, *x.shape[3:]) for x in d_loc))
+    with kernel_span("kda_locals_bwd"):
+        grads = _locals_call(True, q, v, chunk)(
+            *_cut(*res, chunk),
+            *(x.reshape(x.shape[0], -1, *x.shape[3:]) for x in d_loc))
     return tuple(dx.reshape(x.shape).astype(x.dtype)
                  for dx, x in zip(grads, res))
 

@@ -57,6 +57,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import dropout as _dense_dropout
 from apex_tpu.ops._common import pallas_interpret, use_pallas
 
@@ -1224,11 +1225,12 @@ def _fwd_impl(q, k, v, scale, causal, shape, dropout_rate=0.0, seed=None,
     nh = bias.shape[1] if bias is not None else 1
     bias_t, qs, ks = _extras_arrays(b, h, sq, sk, nq, bq, nk, bk,
                                     bias, q_seg, kv_seg, bias_kind)
-    o, lse = _head_major_call(
-        "fwd", bh, h, sq, sk, d, dv, (q.dtype,), bq, bk, shape.tile_fwd,
-        bias_kind, nb, nh, q_seg is not None, False, pallas_interpret(),
-        scale=scale, causal=causal, dropout_rate=dropout_rate,
-        **_group_of(q, k))(qf, kf, vf, bias_t, qs, ks, seed)
+    with kernel_span("flash_fwd"):
+        o, lse = _head_major_call(
+            "fwd", bh, h, sq, sk, d, dv, (q.dtype,), bq, bk, shape.tile_fwd,
+            bias_kind, nb, nh, q_seg is not None, False, pallas_interpret(),
+            scale=scale, causal=causal, dropout_rate=dropout_rate,
+            **_group_of(q, k))(qf, kf, vf, bias_t, qs, ks, seed)
     return o.reshape(b, h, sq, dv), lse.reshape(b, h, sq)
 
 
@@ -1266,13 +1268,15 @@ def _bwd_impl(q, k, v, o, lse, do, scale, causal, shape, dropout_rate=0.0,
     partials = jnp.float32 if grouped and not shape.fused_bwd else None
 
     def run(kind, dbias):
-        return _head_major_call(
-            kind, bh, h, sq, sk, d, dv,
-            (grad_dtype or q.dtype, partials or grad_dtype or k.dtype,
-             partials or grad_dtype or v.dtype), bq, bk, shape.tile_bwd,
-            bias_kind, nb, nh, q_seg is not None, dbias, pallas_interpret(),
-            scale=scale, causal=causal, dropout_rate=dropout_rate,
-            **grouped)(*args)
+        with kernel_span({"bwd": "flash_bwd", "dq": "flash_bwd_dq",
+                          "dkv": "flash_bwd_dkv"}[kind]):
+            return _head_major_call(
+                kind, bh, h, sq, sk, d, dv,
+                (grad_dtype or q.dtype, partials or grad_dtype or k.dtype,
+                 partials or grad_dtype or v.dtype), bq, bk,
+                shape.tile_bwd, bias_kind, nb, nh, q_seg is not None,
+                dbias, pallas_interpret(), scale=scale, causal=causal,
+                dropout_rate=dropout_rate, **grouped)(*args)
 
     def dbias_of(db_full):
         """(b, h, ...) per-head dbias partials → the caller's broadcast
@@ -1382,7 +1386,8 @@ def _run_qkv(backward, x, nh, shape, seed, operands, **static):
                      shape.bq, shape.bk, pallas_interpret(), tile=tile,
                      **static)
     extras = _extras_arrays(1, 1, 1, 1, 1, 1, 1, 1, None, None, None)
-    return call(x, x, x, *operands, *extras, _seed3(seed))
+    with kernel_span("flash_bwd" if backward else "flash_fwd"):
+        return call(x, x, x, *operands, *extras, _seed3(seed))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))

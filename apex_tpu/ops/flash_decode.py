@@ -64,6 +64,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 _NEG_INF = -1e30
@@ -287,20 +288,21 @@ def _decode_pallas(q, k_pages, v_pages, block_table, lengths, scale, hp):
                         pltpu.VMEM((hp, rows), jnp.float32),
                         pltpu.VMEM((hp, rows, d), jnp.float32)],
     )
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, page=page,
-                          rows=rows, q_len=q_len, hp=hp,
-                          n_blocks=max_pages),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots, hkv, rows, d), q.dtype),
-        # the table axis carries the online-softmax recurrence and must
-        # stay sequential; slot and head-group own disjoint outputs
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=pallas_interpret(),
-        name="flash_decode",
-    )(lengths.astype(jnp.int32), block_table.astype(jnp.int32),
-      qr, k_pages, v_pages)
+    with kernel_span("flash_decode"):
+        out = pl.pallas_call(
+            functools.partial(_decode_kernel, scale=scale, page=page,
+                              rows=rows, q_len=q_len, hp=hp,
+                              n_blocks=max_pages),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_slots, hkv, rows, d), q.dtype),
+            # the table axis carries the online-softmax recurrence and must
+            # stay sequential; slot and head-group own disjoint outputs
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=pallas_interpret(),
+            name="flash_decode",
+        )(lengths.astype(jnp.int32), block_table.astype(jnp.int32),
+          qr, k_pages, v_pages)
     return (out.reshape(n_slots, hkv, G, q_len, d)
             .transpose(0, 3, 1, 2, 4).reshape(n_slots, q_len, hq, d))
 

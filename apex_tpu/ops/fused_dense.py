@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, round_up, use_pallas
 
 
@@ -87,21 +88,22 @@ def _matmul_pallas(x2, w, b, activation, bm=256, bn=256, bk=512):
         b if has_bias else jnp.zeros((np_,), x2.dtype))
     bp = bp.reshape(1, np_)
     k_steps = kp // bk
-    out = pl.pallas_call(
-        functools.partial(_matmul_kernel, activation=activation,
-                          has_bias=has_bias, k_steps=k_steps),
-        grid=(mp // bm, np_ // bn, k_steps),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), x2.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=pallas_interpret(),
-        name="fused_dense",
-    )(xp, wp, bp)
+    with kernel_span("fused_dense"):
+        out = pl.pallas_call(
+            functools.partial(_matmul_kernel, activation=activation,
+                              has_bias=has_bias, k_steps=k_steps),
+            grid=(mp // bm, np_ // bn, k_steps),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+                pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((mp, np_), x2.dtype),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+            interpret=pallas_interpret(),
+            name="fused_dense",
+        )(xp, wp, bp)
     return out[:m, :n]
 
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import (pallas_interpret, tuned_row_block,
                                   use_pallas_fusable)
 
@@ -129,27 +130,28 @@ def _fwd_pallas(x2, weight, bias, eps, rms):
     b = bias if has_bias else jnp.zeros((hidden,), x2.dtype)
     kernel = functools.partial(_fwd_kernel, eps=eps, rms=rms, affine=affine,
                                has_bias=has_bias)
-    y, mean, rstd = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((hidden,), lambda i: (0,)),
-            pl.BlockSpec((hidden,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((prows, hidden), x2.dtype),
-            jax.ShapeDtypeStruct((prows, 1), jnp.float32),
-            jax.ShapeDtypeStruct((prows, 1), jnp.float32),
-        ],
-        interpret=pallas_interpret(),
-        name="ln_fwd",
-    )(x2p, w, b)
+    with kernel_span("ln_fwd"):
+        y, mean, rstd = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((hidden,), lambda i: (0,)),
+                pl.BlockSpec((hidden,), lambda i: (0,)),
+            ],
+            out_specs=[
+                pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((prows, hidden), x2.dtype),
+                jax.ShapeDtypeStruct((prows, 1), jnp.float32),
+                jax.ShapeDtypeStruct((prows, 1), jnp.float32),
+            ],
+            interpret=pallas_interpret(),
+            name="ln_fwd",
+        )(x2p, w, b)
     return y[:rows], mean[:rows], rstd[:rows]
 
 
@@ -165,29 +167,30 @@ def _bwd_pallas(g2, x2, mean, rstd, weight, rms):
     grid = prows // blk
     w = weight if affine else jnp.zeros((hidden,), x2.dtype)
     kernel = functools.partial(_bwd_kernel, rms=rms, affine=affine)
-    dx, dwp, dbp = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((hidden,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
-            pl.BlockSpec((1, hidden), lambda i: (0, 0)),
-            pl.BlockSpec((1, hidden), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((prows, hidden), x2.dtype),
-            jax.ShapeDtypeStruct((1, hidden), jnp.float32),
-            jax.ShapeDtypeStruct((1, hidden), jnp.float32),
-        ],
-        interpret=pallas_interpret(),
-        name="ln_bwd",
-    )(g2p, x2p, meanp, rstdp, w)
+    with kernel_span("ln_bwd"):
+        dx, dwp, dbp = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                pl.BlockSpec((hidden,), lambda i: (0,)),
+            ],
+            out_specs=[
+                pl.BlockSpec((blk, hidden), lambda i: (i, 0)),
+                pl.BlockSpec((1, hidden), lambda i: (0, 0)),
+                pl.BlockSpec((1, hidden), lambda i: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((prows, hidden), x2.dtype),
+                jax.ShapeDtypeStruct((1, hidden), jnp.float32),
+                jax.ShapeDtypeStruct((1, hidden), jnp.float32),
+            ],
+            interpret=pallas_interpret(),
+            name="ln_bwd",
+        )(g2p, x2p, meanp, rstdp, w)
     dw = dwp[0] if affine else None
     db = dbp[0] if affine else None
     return dx[:rows], dw, db

@@ -28,6 +28,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, use_pallas
 
 _LANES = 128
@@ -161,18 +162,19 @@ def adam_flat(p, m, v, g, lr, step, *, beta1=0.9, beta2=0.999, eps=1e-8,
     grid = rows // R
     spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((9, 1), lambda i: (0, 0))
-    pn, mn, vn = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[spec, spec, spec, spec, sspec],
-        out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-                   jax.ShapeDtypeStruct(m2.shape, m2.dtype),
-                   jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
-        input_output_aliases={0: 0, 1: 1, 2: 2},
-        interpret=pallas_interpret(),
-        name="adam_flat",
-    )(p2, m2, v2, g2, scalars)
+    with kernel_span("adam_flat"):
+        pn, mn, vn = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[spec, spec, spec, spec, sspec],
+            out_specs=[spec, spec, spec],
+            out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+                       jax.ShapeDtypeStruct(m2.shape, m2.dtype),
+                       jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
+            input_output_aliases={0: 0, 1: 1, 2: 2},
+            interpret=pallas_interpret(),
+            name="adam_flat",
+        )(p2, m2, v2, g2, scalars)
     return _from2d(pn, np_), _from2d(mn, np_), _from2d(vn, np_)
 
 
@@ -285,22 +287,23 @@ def adam_flat_seg(p, m, v, g, lr, step, *, wd_values, lr_scale_values,
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
     bspec = pl.BlockSpec((8, npad), lambda i: (0, 0))
     spec_b = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
-    pn, mn, vn = pl.pallas_call(
-        functools.partial(_adam_seg_kernel, eps=eps,
-                          adam_w_mode=adam_w_mode, npad=npad, R=R),
-        grid=(grid,),
-        in_specs=[spec_b, spec_b, spec_b, spec_b,
-                  pl.BlockSpec((9, 1), lambda i: (0, 0)),
-                  bspec, bspec, bspec,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=[spec_b, spec_b, spec_b],
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-                   jax.ShapeDtypeStruct(m2.shape, m2.dtype),
-                   jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
-        input_output_aliases={0: 0, 1: 1, 2: 2},
-        interpret=pallas_interpret(),
-        name="adam_flat_seg",
-    )(p2, m2, v2, g2, scalars, lo, hi, vals, off)
+    with kernel_span("adam_flat_seg"):
+        pn, mn, vn = pl.pallas_call(
+            functools.partial(_adam_seg_kernel, eps=eps,
+                              adam_w_mode=adam_w_mode, npad=npad, R=R),
+            grid=(grid,),
+            in_specs=[spec_b, spec_b, spec_b, spec_b,
+                      pl.BlockSpec((9, 1), lambda i: (0, 0)),
+                      bspec, bspec, bspec,
+                      pl.BlockSpec((1, 1), lambda i: (0, 0))],
+            out_specs=[spec_b, spec_b, spec_b],
+            out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+                       jax.ShapeDtypeStruct(m2.shape, m2.dtype),
+                       jax.ShapeDtypeStruct(v2.shape, v2.dtype)],
+            input_output_aliases={0: 0, 1: 1, 2: 2},
+            interpret=pallas_interpret(),
+            name="adam_flat_seg",
+        )(p2, m2, v2, g2, scalars, lo, hi, vals, off)
     return _from2d(pn, np_), _from2d(mn, np_), _from2d(vn, np_)
 
 
@@ -409,17 +412,18 @@ def sgd_flat(p, buf, g, lr, *, momentum=0.0, dampening=0.0, nesterov=False,
     grid = p2.shape[0] // R
     spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((4, 1), lambda i: (0, 0))
-    pn, bn = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[spec, spec, spec, sspec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-                   jax.ShapeDtypeStruct(b2.shape, b2.dtype)],
-        input_output_aliases={0: 0, 1: 1},
-        interpret=pallas_interpret(),
-        name="sgd_flat",
-    )(p2, b2, g2, scalars)
+    with kernel_span("sgd_flat"):
+        pn, bn = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[spec, spec, spec, sspec],
+            out_specs=[spec, spec],
+            out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+                       jax.ShapeDtypeStruct(b2.shape, b2.dtype)],
+            input_output_aliases={0: 0, 1: 1},
+            interpret=pallas_interpret(),
+            name="sgd_flat",
+        )(p2, b2, g2, scalars)
     return _from2d(pn, n), _from2d(bn, n)
 
 
@@ -465,17 +469,18 @@ def adagrad_flat(p, h, g, lr, *, eps=1e-10, weight_decay=0.0,
     grid = p2.shape[0] // R
     spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    pn, hn = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[spec, spec, spec, sspec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-                   jax.ShapeDtypeStruct(h2.shape, jnp.float32)],
-        input_output_aliases={0: 0, 1: 1},
-        interpret=pallas_interpret(),
-        name="adagrad_flat",
-    )(p2, h2, g2, scalars)
+    with kernel_span("adagrad_flat"):
+        pn, hn = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[spec, spec, spec, sspec],
+            out_specs=[spec, spec],
+            out_shape=[jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+                       jax.ShapeDtypeStruct(h2.shape, jnp.float32)],
+            input_output_aliases={0: 0, 1: 1},
+            interpret=pallas_interpret(),
+            name="adagrad_flat",
+        )(p2, h2, g2, scalars)
     return _from2d(pn, n), _from2d(hn, n)
 
 
@@ -590,21 +595,22 @@ def lamb_phase1_flat(m, v, g, p, clip_ratio, step, *, beta1, beta2, eps,
     grid = m2.shape[0] // R
     spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((8, 1), lambda i: (0, 0))
-    mn, vn, u = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[spec, spec, spec, spec, sspec],
-        out_specs=[spec, spec, spec],
-        # m/v aliased in place (dtypes preserved); u rides in the
-        # master dtype so a bf16-state LAMB halves the u write + the
-        # norm-pass and phase-2 reads (≡ the 1.3B Adam bf16-state point)
-        out_shape=[jax.ShapeDtypeStruct(m2.shape, m2.dtype),
-                   jax.ShapeDtypeStruct(v2.shape, v2.dtype),
-                   jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
-        input_output_aliases={0: 0, 1: 1},
-        interpret=pallas_interpret(),
-        name="lamb_phase1",
-    )(m2, v2, g2, p2, scalars)
+    with kernel_span("lamb_phase1"):
+        mn, vn, u = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[spec, spec, spec, spec, sspec],
+            out_specs=[spec, spec, spec],
+            # m/v aliased in place (dtypes preserved); u rides in the
+            # master dtype so a bf16-state LAMB halves the u write + the
+            # norm-pass and phase-2 reads (≡ the 1.3B Adam bf16-state point)
+            out_shape=[jax.ShapeDtypeStruct(m2.shape, m2.dtype),
+                       jax.ShapeDtypeStruct(v2.shape, v2.dtype),
+                       jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
+            input_output_aliases={0: 0, 1: 1},
+            interpret=pallas_interpret(),
+            name="lamb_phase1",
+        )(m2, v2, g2, p2, scalars)
     return _from2d(mn, n), _from2d(vn, n), _from2d(u, n)
 
 
@@ -649,22 +655,23 @@ def lamb_phase1_seg(m, v, g, p, clip_ratio, step, *, wd_values, spec,
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
     bspec = pl.BlockSpec((8, npad), lambda i: (0, 0))
     spec_b = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
-    mn, vn, u = pl.pallas_call(
-        functools.partial(_lamb_phase1_seg_kernel, eps=eps, npad=npad,
-                          R=R),
-        grid=(grid,),
-        in_specs=[spec_b, spec_b, spec_b, spec_b,
-                  pl.BlockSpec((8, 1), lambda i: (0, 0)),
-                  bspec, bspec, bspec,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=[spec_b, spec_b, spec_b],
-        out_shape=[jax.ShapeDtypeStruct(m2.shape, m2.dtype),
-                   jax.ShapeDtypeStruct(v2.shape, v2.dtype),
-                   jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
-        input_output_aliases={0: 0, 1: 1},
-        interpret=pallas_interpret(),
-        name="lamb_phase1_seg",
-    )(m2, v2, g2, p2, scalars, lo, hi, vals8, off)
+    with kernel_span("lamb_phase1_seg"):
+        mn, vn, u = pl.pallas_call(
+            functools.partial(_lamb_phase1_seg_kernel, eps=eps, npad=npad,
+                              R=R),
+            grid=(grid,),
+            in_specs=[spec_b, spec_b, spec_b, spec_b,
+                      pl.BlockSpec((8, 1), lambda i: (0, 0)),
+                      bspec, bspec, bspec,
+                      pl.BlockSpec((1, 1), lambda i: (0, 0))],
+            out_specs=[spec_b, spec_b, spec_b],
+            out_shape=[jax.ShapeDtypeStruct(m2.shape, m2.dtype),
+                       jax.ShapeDtypeStruct(v2.shape, v2.dtype),
+                       jax.ShapeDtypeStruct(p2.shape, p2.dtype)],
+            input_output_aliases={0: 0, 1: 1},
+            interpret=pallas_interpret(),
+            name="lamb_phase1_seg",
+        )(m2, v2, g2, p2, scalars, lo, hi, vals8, off)
     return _from2d(mn, n), _from2d(vn, n), _from2d(u, n)
 
 
@@ -722,18 +729,19 @@ def lamb_phase2_seg(p, u, ratio_values, spec, lr, *, row_offset=0,
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
     bspec = pl.BlockSpec((8, npad), lambda i: (0, 0))
     sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    pn = pl.pallas_call(
-        functools.partial(_lamb_phase2_seg_kernel, npad=npad, R=R),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
-                  pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
-                  bspec, bspec, bspec, sspec, sspec],
-        out_specs=pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-        input_output_aliases={0: 0},
-        interpret=pallas_interpret(),
-        name="lamb_phase2_seg",
-    )(p2, u2, lo, hi, vals8, scalars, off)
+    with kernel_span("lamb_phase2_seg"):
+        pn = pl.pallas_call(
+            functools.partial(_lamb_phase2_seg_kernel, npad=npad, R=R),
+            grid=(nb,),
+            in_specs=[pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
+                      pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
+                      bspec, bspec, bspec, sspec, sspec],
+            out_specs=pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+            input_output_aliases={0: 0},
+            interpret=pallas_interpret(),
+            name="lamb_phase2_seg",
+        )(p2, u2, lo, hi, vals8, scalars, off)
     return _from2d(pn, n)
 
 
@@ -749,16 +757,17 @@ def lamb_phase2_flat(p, u, ratio_elem, lr, use_pallas_override=None):
     grid = p2.shape[0] // R
     spec = pl.BlockSpec((R, _LANES), lambda i: (i, 0))
     sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    pn = pl.pallas_call(
-        _lamb_phase2_kernel,
-        grid=(grid,),
-        in_specs=[spec, spec, spec, sspec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
-        input_output_aliases={0: 0},
-        interpret=pallas_interpret(),
-        name="lamb_phase2",
-    )(p2, u2, r2, scalars)
+    with kernel_span("lamb_phase2"):
+        pn = pl.pallas_call(
+            _lamb_phase2_kernel,
+            grid=(grid,),
+            in_specs=[spec, spec, spec, sspec],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(p2.shape, p2.dtype),
+            input_output_aliases={0: 0},
+            interpret=pallas_interpret(),
+            name="lamb_phase2",
+        )(p2, u2, r2, scalars)
     return _from2d(pn, n)
 
 
@@ -896,18 +905,19 @@ def _per_tensor_sumsq_2d(x2, spec, n_seg, row_offset):
     lo, hi = _seg_row_bounds(spec, npad)
     off = jnp.asarray(row_offset, jnp.int32).reshape(1, 1)
     bspec = pl.BlockSpec((8, npad), lambda i: (0, 0))
-    out = pl.pallas_call(
-        functools.partial(_rows_sumsq_seg_kernel, nb=nb, npad=npad, R=R),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
-                  bspec, bspec,
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((8, npad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, npad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((8, npad), jnp.float32)],
-        interpret=pallas_interpret(),
-        name="per_tensor_sumsq",
-    )(x2, lo, hi, off)
+    with kernel_span("per_tensor_sumsq"):
+        out = pl.pallas_call(
+            functools.partial(_rows_sumsq_seg_kernel, nb=nb, npad=npad, R=R),
+            grid=(nb,),
+            in_specs=[pl.BlockSpec((R, _LANES), lambda i: (i, 0)),
+                      bspec, bspec,
+                      pl.BlockSpec((1, 1), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((8, npad), lambda i: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((8, npad), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((8, npad), jnp.float32)],
+            interpret=pallas_interpret(),
+            name="per_tensor_sumsq",
+        )(x2, lo, hi, off)
     return out[0, :n_seg]
 
 

@@ -40,6 +40,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import on_chip, pallas_interpret, use_pallas
 
 LANES = 128
@@ -204,7 +205,8 @@ def _stage_fwd(nope, rope, tables, dims, blocks, shared):
     call = _call(False, b, s, *dims, shared, tables is not None, *blocks,
                  jnp.dtype(nope.dtype), pallas_interpret())
     lanes = () if tables is None else _lane_tables(*tables, blocks[1])
-    return call(nope, rope, *lanes), tables
+    with kernel_span("rope_stage"):
+        return call(nope, rope, *lanes), tables
 
 
 def _stage_bwd(dims, blocks, shared, tables, grad):
@@ -212,11 +214,13 @@ def _stage_bwd(dims, blocks, shared, tables, grad):
     call = _call(True, b, s, *dims, shared, tables is not None, *blocks,
                  jnp.dtype(grad.dtype), pallas_interpret())
     if tables is None:
-        d_nope, d_rope = call(grad)
+        with kernel_span("rope_unstage"):
+            d_nope, d_rope = call(grad)
         return d_nope, d_rope.astype(grad.dtype), None
     cos, sin = tables
     # the transpose of a rotation is the rotation by the opposite angle
-    d_nope, d_rope = call(grad, *_lane_tables(cos, -sin, blocks[1]))
+    with kernel_span("rope_unstage"):
+        d_nope, d_rope = call(grad, *_lane_tables(cos, -sin, blocks[1]))
     return d_nope, d_rope, (jnp.zeros_like(cos), jnp.zeros_like(sin))
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import (pallas_interpret, row_block,
                                   tuned_row_block, use_pallas)
 
@@ -107,15 +108,16 @@ def _fwd_pallas(x2, mask2, scale, causal, sq):
             m_ref = None
         kernel(x_ref, m_ref, y_ref)
 
-    y = pl.pallas_call(
-        wrapped,
-        grid=(grid,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((prows, sk), x2.dtype),
-        interpret=pallas_interpret(),
-        name="softmax_fwd",
-    )(*inputs)
+    with kernel_span("softmax_fwd"):
+        y = pl.pallas_call(
+            wrapped,
+            grid=(grid,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((prows, sk), x2.dtype),
+            interpret=pallas_interpret(),
+            name="softmax_fwd",
+        )(*inputs)
     return y[:rows]
 
 
@@ -124,16 +126,17 @@ def _bwd_pallas(g2, y2, scale):
     blk = tuned_row_block("softmax_bwd", rows, sk)
     gp, yp = _pad_rows(g2, blk), _pad_rows(y2, blk)
     prows = gp.shape[0]
-    dx = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale),
-        grid=(prows // blk,),
-        in_specs=[pl.BlockSpec((blk, sk), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, sk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((prows, sk), g2.dtype),
-        interpret=pallas_interpret(),
-        name="softmax_bwd",
-    )(gp, yp)
+    with kernel_span("softmax_bwd"):
+        dx = pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale),
+            grid=(prows // blk,),
+            in_specs=[pl.BlockSpec((blk, sk), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, sk), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((blk, sk), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((prows, sk), g2.dtype),
+            interpret=pallas_interpret(),
+            name="softmax_bwd",
+        )(gp, yp)
     return dx[:rows]
 
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import (
     pallas_interpret,
     row_block,
@@ -59,17 +60,18 @@ def _channel_sums_impl(x2):
     blk = row_block(rows, c)
     pad = (-rows) % blk
     xp = jnp.pad(x2, ((0, pad), (0, 0))) if pad else x2
-    s, q = pl.pallas_call(
-        _stats_kernel,
-        grid=(xp.shape[0] // blk,),
-        in_specs=[pl.BlockSpec((blk, c), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, c), lambda i: (0, 0)),
-                   pl.BlockSpec((1, c), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
-                   jax.ShapeDtypeStruct((1, c), jnp.float32)],
-        interpret=pallas_interpret(),
-        name="welford",
-    )(xp)
+    with kernel_span("welford"):
+        s, q = pl.pallas_call(
+            _stats_kernel,
+            grid=(xp.shape[0] // blk,),
+            in_specs=[pl.BlockSpec((blk, c), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((1, c), lambda i: (0, 0)),
+                       pl.BlockSpec((1, c), lambda i: (0, 0))],
+            out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
+                       jax.ShapeDtypeStruct((1, c), jnp.float32)],
+            interpret=pallas_interpret(),
+            name="welford",
+        )(xp)
     return s[0], q[0]
 
 

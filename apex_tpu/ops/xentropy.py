@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from apex_tpu.monitor.compile.startup import kernel_span
 from apex_tpu.ops._common import pallas_interpret, row_block, use_pallas
 
 
@@ -75,18 +76,19 @@ def _fwd_pallas(x2, labels, smoothing):
     xp = _pad(x2, blk)
     lbl = _pad(labels.astype(jnp.int32).reshape(-1, 1), blk)
     prows = xp.shape[0]
-    loss, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, smoothing=smoothing),
-        grid=(prows // blk,),
-        in_specs=[pl.BlockSpec((blk, v), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((prows, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((prows, 1), jnp.float32)],
-        interpret=pallas_interpret(),
-        name="xent_fwd",
-    )(xp, lbl)
+    with kernel_span("xent_fwd"):
+        loss, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, smoothing=smoothing),
+            grid=(prows // blk,),
+            in_specs=[pl.BlockSpec((blk, v), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, 1), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                       pl.BlockSpec((blk, 1), lambda i: (i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((prows, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((prows, 1), jnp.float32)],
+            interpret=pallas_interpret(),
+            name="xent_fwd",
+        )(xp, lbl)
     return loss[:rows, 0], lse[:rows]
 
 
@@ -98,18 +100,19 @@ def _bwd_pallas(g, x2, labels, lse, smoothing):
     lbl = _pad(labels.astype(jnp.int32).reshape(-1, 1), blk)
     lsep = _pad(lse, blk)
     prows = xp.shape[0]
-    dx = pl.pallas_call(
-        functools.partial(_bwd_kernel, smoothing=smoothing),
-        grid=(prows // blk,),
-        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, v), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                  pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((blk, v), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((prows, v), x2.dtype),
-        interpret=pallas_interpret(),
-        name="xent_bwd",
-    )(gp, xp, lbl, lsep)
+    with kernel_span("xent_bwd"):
+        dx = pl.pallas_call(
+            functools.partial(_bwd_kernel, smoothing=smoothing),
+            grid=(prows // blk,),
+            in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, v), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, 1), lambda i: (i, 0)),
+                      pl.BlockSpec((blk, 1), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((blk, v), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((prows, v), x2.dtype),
+            interpret=pallas_interpret(),
+            name="xent_bwd",
+        )(gp, xp, lbl, lsep)
     return dx[:rows]
 
 

@@ -85,39 +85,45 @@ def initialize_model_parallel(
     dense steps are byte-identical to the pre-MoE framework.
     """
     global _GLOBAL_STATE
-    if devices is None:
-        devices = jax.devices()
-    world_size = len(devices)
-    tp, pp = tensor_model_parallel_size, pipeline_model_parallel_size
-    ep = expert_model_parallel_size
-    if ep < 1:
-        raise ValueError(f"expert_model_parallel_size must be >= 1, got {ep}")
-    if world_size % (tp * pp * ep) != 0:
-        raise ValueError(
-            f"world size {world_size} is not divisible by tp({tp}) x pp({pp})"
-            f" x ep({ep})"
+    # every job's first call into the program: the set-up ledger
+    # (monitor.compile.startup) listens from here on
+    from apex_tpu.monitor.compile import startup
+
+    startup.arm()
+    with startup.span("initialize_model_parallel"):
+        if devices is None:
+            devices = jax.devices()
+        world_size = len(devices)
+        tp, pp = tensor_model_parallel_size, pipeline_model_parallel_size
+        ep = expert_model_parallel_size
+        if ep < 1:
+            raise ValueError(f"expert_model_parallel_size must be >= 1, got {ep}")
+        if world_size % (tp * pp * ep) != 0:
+            raise ValueError(
+                f"world size {world_size} is not divisible by tp({tp}) x pp({pp})"
+                f" x ep({ep})"
+            )
+        dp = world_size // (tp * pp * ep)
+        if virtual_pipeline_model_parallel_size is not None and pp < 2:
+            raise ValueError(
+                "virtual pipeline parallelism requires pipeline_model_parallel_size >= 2"
+            )
+        if ep > 1:
+            dev_array = np.asarray(devices).reshape(pp, dp, ep, tp)
+            mesh = Mesh(dev_array, (PP_AXIS, DP_AXIS, EP_AXIS, TP_AXIS))
+        else:
+            dev_array = np.asarray(devices).reshape(pp, dp, tp)
+            mesh = Mesh(dev_array, (PP_AXIS, DP_AXIS, TP_AXIS))
+        _GLOBAL_STATE = _MeshState(
+            mesh=mesh,
+            tensor_model_parallel_size=tp,
+            pipeline_model_parallel_size=pp,
+            data_parallel_size=dp,
+            expert_model_parallel_size=ep,
+            virtual_pipeline_model_parallel_size=virtual_pipeline_model_parallel_size,
+            pipeline_model_parallel_split_rank=pipeline_model_parallel_split_rank,
+            use_fp8=use_fp8,
         )
-    dp = world_size // (tp * pp * ep)
-    if virtual_pipeline_model_parallel_size is not None and pp < 2:
-        raise ValueError(
-            "virtual pipeline parallelism requires pipeline_model_parallel_size >= 2"
-        )
-    if ep > 1:
-        dev_array = np.asarray(devices).reshape(pp, dp, ep, tp)
-        mesh = Mesh(dev_array, (PP_AXIS, DP_AXIS, EP_AXIS, TP_AXIS))
-    else:
-        dev_array = np.asarray(devices).reshape(pp, dp, tp)
-        mesh = Mesh(dev_array, (PP_AXIS, DP_AXIS, TP_AXIS))
-    _GLOBAL_STATE = _MeshState(
-        mesh=mesh,
-        tensor_model_parallel_size=tp,
-        pipeline_model_parallel_size=pp,
-        data_parallel_size=dp,
-        expert_model_parallel_size=ep,
-        virtual_pipeline_model_parallel_size=virtual_pipeline_model_parallel_size,
-        pipeline_model_parallel_split_rank=pipeline_model_parallel_split_rank,
-        use_fp8=use_fp8,
-    )
     return mesh
 
 

@@ -27,6 +27,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.monitor import scopes
+from apex_tpu.monitor.compile import startup
 from apex_tpu.optimizers import flat as F
 from apex_tpu.parallel.mesh import DP_AXIS, PP_AXIS
 
@@ -39,19 +40,21 @@ def init_sharded_optimizer(optimizer, model, params, mesh):
     """
     specs = model.partition_specs()
 
-    state_struct = jax.eval_shape(
-        lambda p: optimizer.init(p), params)  # sets optimizer.spec? no —
-    # eval_shape traces on GLOBAL shapes; re-derive the local spec by
-    # tracing inside shard_map below (optimizer.init sets .spec there).
-
     def local_init(p):
         return optimizer.init(p)
 
-    # buffers sharded over tp (dim 0), step replicated
-    out_specs = type(state_struct)(*([P()] + [P(("pp", "tp"))] * (len(state_struct) - 1)))
-    init_fn = jax.jit(shard_map(local_init, mesh=mesh, in_specs=(specs,),
-                                out_specs=out_specs, check_vma=False))
-    return init_fn(params)
+    with startup.span("init_sharded_optimizer"):
+        state_struct = jax.eval_shape(
+            lambda p: optimizer.init(p), params)  # sets optimizer.spec? no —
+        # eval_shape traces on GLOBAL shapes; re-derive the local spec by
+        # tracing inside shard_map below (optimizer.init sets .spec there).
+
+        # buffers sharded over tp (dim 0), step replicated
+        out_specs = type(state_struct)(
+            *([P()] + [P(("pp", "tp"))] * (len(state_struct) - 1)))
+        init_fn = jax.jit(shard_map(local_init, mesh=mesh, in_specs=(specs,),
+                                    out_specs=out_specs, check_vma=False))
+        return init_fn(params)
 
 
 def make_tp_dp_train_step(model, optimizer, mesh, *,
@@ -134,15 +137,17 @@ def make_tp_dp_train_step(model, optimizer, mesh, *,
         k = jax.tree_util.tree_structure(opt_state)
         fn = cache.get(k)
         if fn is None:
-            fn = build(opt_state)
-            # once a program, not a step: monitor.scopes can then say
-            # which scope owns each instruction of the step that ran.
-            # Under another transformation (the linter's make_jaxpr)
-            # the arguments are tracers and nothing will run: such a
-            # build is not kept, so the first real call registers
-            if scopes.register(local_step.__name__, fn,
-                               (opt_state, *batch)):
-                cache[k] = fn
+            with startup.span("make_tp_dp_train_step.build"):
+                fn = build(opt_state)
+                # once a program, not a step: monitor.scopes can then
+                # say which scope owns each instruction of the step that
+                # ran.  Under another transformation (the linter's
+                # make_jaxpr) the arguments are tracers and nothing will
+                # run: such a build is not kept, so the first real call
+                # registers
+                if scopes.register(local_step.__name__, fn,
+                                   (opt_state, *batch)):
+                    cache[k] = fn
         return fn
 
     def step(opt_state, tokens, labels):

@@ -148,7 +148,10 @@ def _ensure_loaded() -> Dict[str, Any]:
     kind = device_kind()
     with _lock:
         if _state["cache"] is None or _state["kind"] != kind:
-            _state["cache"] = _merged_for_kind(kind)
+            from apex_tpu.monitor.compile import startup
+
+            with startup.span("tune.load_tables"):
+                _state["cache"] = _merged_for_kind(kind)
             _state["kind"] = kind
             _state["fingerprint"] = None
         return _state["cache"]

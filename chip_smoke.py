@@ -44,26 +44,24 @@ def emit(**record) -> None:
     print(json.dumps(record), flush=True)
 
 
-class CacheEvents:
-    """Counts JAX's persistent-compilation-cache hits and misses."""
+def compile_account(earlier: dict) -> dict:
+    """What the set-up ledger (`apex_tpu.monitor.compile.startup`) has
+    counted in this process: the persistent cache's hits and misses so
+    far, and the seconds of tracing, lowering, compiling and reading
+    the cache since `earlier` (the totals of the call before, updated
+    in place)."""
+    from apex_tpu.monitor.compile import startup
 
-    _PREFIX = "/jax/compilation_cache/cache_"
-
-    def __init__(self):
-        import jax
-
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event, **_):
-        if event == self._PREFIX + "hits":
-            self.hits += 1
-        elif event == self._PREFIX + "misses":
-            self.misses += 1
-
-    def snapshot(self) -> dict:
-        return {"persistent_cache_hits": self.hits,
-                "persistent_cache_misses": self.misses}
+    phases = startup.ledger()["totals"].values()
+    now = {k: sum(t[k] for t in phases) for k in (
+        "programs", "cache_hits", "cache_misses", "trace_s", "lower_s",
+        "compile_s", "cache_read_s")}
+    since = {k: round(v - earlier.get(k, 0), 3) for k, v in now.items()
+             if k.endswith("_s") or k == "programs"}
+    earlier.update(now)
+    return {"persistent_cache_hits": now["cache_hits"],
+            "persistent_cache_misses": now["cache_misses"],
+            "compile_account": since}
 
 
 # ------------------------------- kernels -------------------------------
@@ -1068,14 +1066,14 @@ def _env_record(devices, cache_dir) -> dict:
     }
 
 
-def _after(phase: str, device, events: CacheEvents, t0: float, **rec) -> None:
+def _after(phase: str, device, earlier: dict, t0: float, **rec) -> None:
     from apex_tpu import tune
 
     stats = device.memory_stats() or {}
     emit(phase=phase, wall_s=round(time.perf_counter() - t0, 1),
          peak_bytes_in_use=stats.get("peak_bytes_in_use"),
          bytes_in_use=stats.get("bytes_in_use"),
-         tune=tune.stats(), **events.snapshot(), **rec)
+         tune=tune.stats(), **compile_account(earlier), **rec)
 
 
 def main(argv=None) -> int:
@@ -1107,10 +1105,12 @@ def main(argv=None) -> int:
 
 
 def _run(args, devices) -> None:
+    from apex_tpu.monitor.compile import startup
     from apex_tpu.ops._common import on_chip
     from apex_tpu.utils.compile_cache import enable_compile_cache
 
-    events = CacheEvents()
+    startup.arm()       # the kernels phase builds no mesh
+    events = {}         # the ledger's totals at the phase before
     cache_dir = enable_compile_cache()
     if not on_chip():
         raise RuntimeError("apex_tpu's on_chip() is false on a TPU")
