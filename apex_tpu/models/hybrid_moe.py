@@ -73,6 +73,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
 from apex_tpu.ops.conv_stage import stage_conv_heads
@@ -120,9 +121,10 @@ class HybridMoEConfig:
     router_bias_range: float = 0.05  # the seeded, fixed selection bias
     dtype: Any = jnp.float32
     logits_dtype: Any = None         # None keeps fp32 logits
-    # True recomputes a mixer's inner activations in the backward
-    # (`jax.checkpoint` around the mixer): what a step keeps of it is
-    # its normed input
+    # True puts a `jax.checkpoint` around every mixer.  Of a KDA mixer a
+    # step keeps its normed input and the activations `KEPT` names, and
+    # recomputes the rest in the backward; of a layer that attends it
+    # keeps everything and recomputes nothing
     recompute_mixers: bool = False
     scan_chunk: Optional[int] = None   # None: the tuner's, or the op's
     # the id that closes a document of a packed row; None: a row is one
@@ -141,6 +143,51 @@ class Documents(NamedTuple):
     ids: jnp.ndarray
     first: jnp.ndarray
     taps: Tuple[jnp.ndarray, ...]
+
+
+# What a checkpointed KDA mixer keeps besides its normed input, by the
+# `checkpoint_name` of each: the q, k, v projections' outputs in front
+# of the convolution (its backward reads them) and behind it, staged
+# for the scan (the scan's backward reads those); the rank-`kda_rank`
+# inner activations of the decay and gate pairs and beta's logits; o as
+# the scan returns it; the normed, gated o the output projection reads.
+# A (B, S, n * d) array each but the small three: dear to recompute (a
+# GEMM over H, a pass of the convolution, the scan's outputs, the norm's
+# turn) and small to hold.  Not kept: the float32 log-decay and
+# whatever the scan's own backward rebuilds from its inputs (the
+# chunk-local stage, the chunk-start states)
+KEPT = ("kda_q", "kda_k", "kda_v", "kda_staged", "kda_f", "kda_g",
+        "kda_beta", "kda_o", "kda_gated")
+
+_BY_NAME = jax.checkpoint_policies.save_only_these_names(*KEPT)
+_kept = {"kept_bytes": 0}
+
+
+def _keeps(prim, *avals, **params):
+    """A checkpointed KDA mixer's policy: `save_only_these_names(*KEPT)`,
+    which counts what it saves.  JAX asks it once an equation whose
+    inputs the forward knows, when the mixer is differentiated.  One
+    function for every layer, and not for the count's sake alone: with
+    a policy made anew a layer the TPU compiler wrote the decay's
+    recomputed GEMM in float32 and relaid it, 4.8 ms and 200 MB a step
+    in cell 5 (PERF.md section 6, PR 38)."""
+    saved = _BY_NAME(prim, *avals, **params)
+    if saved:
+        _kept["kept_bytes"] += sum(a.size * a.dtype.itemsize for a in avals)
+    return saved
+
+
+def stats():
+    """{"kept_bytes": the bytes the KDA mixers' checkpoints were told to
+    keep for the backward besides their inputs, shape x itemsize of every
+    activation the policy answered yes for} of the stacks differentiated
+    since the last `reset_stats()`.  0 where no policy was asked: the
+    flag false, a checkpoint without one, a name `KEPT` lacks."""
+    return dict(_kept)
+
+
+def reset_stats():
+    _kept["kept_bytes"] = 0
 
 
 class HybridMoE(HeldExpertsLM):
@@ -250,6 +297,11 @@ class HybridMoE(HeldExpertsLM):
                 taps=tuple((back(r) == ids)[..., None].astype(jnp.float32)
                            for r in range(1, c.conv_kernel)))
 
+    def _keep(self, x, name):
+        """`x` under the name `name`, for the checkpoint's policy to
+        know it by; `x` itself with `recompute_mixers` false."""
+        return checkpoint_name(x, name) if self.c.recompute_mixers else x
+
     def _attention(self, p, a, docs=None):
         """a: (B, S, H), normed.  The gated grouped-query attention's
         output, before the residual add."""
@@ -302,23 +354,25 @@ class HybridMoE(HeldExpertsLM):
         n, d = c.kda_heads, c.kda_head_dim
         f32 = jnp.float32
         with jax.named_scope("qkv"):
-            q, k, v = (self._dot(a, p[x]) for x in "qkv")
+            q, k, v = (self._keep(self._dot(a, p[x]), f"kda_{x}")
+                       for x in "qkv")
         with jax.named_scope("conv"):
             # q's and k's heads of unit length, q by d^-1/2 more
-            q, k, v = stage_conv_heads(
+            q, k, v = (self._keep(x, "kda_staged") for x in stage_conv_heads(
                 (q, k, v), [p[f"conv_{x}"] for x in "qkv"], n,
                 (d ** -0.5, 1.0, None),
                 ids=None if docs is None else docs.ids,
                 masks=None if docs is None else docs.taps,
-                use_pallas_override=c.flash_override)
+                use_pallas_override=c.flash_override))
         with jax.named_scope("decay"):
-            f = self._dot(self._dot(a, p["f_a"]), p["f_b"])
+            f = self._dot(self._keep(self._dot(a, p["f_a"]), "kda_f"),
+                          p["f_b"])
             rate = jnp.exp(p["a_log"].astype(f32))[:, None]
             g = -rate * jax.nn.softplus(
                 f.astype(f32) + p["dt_bias"].astype(f32)).reshape(b, s, n, d)
             g = g.transpose(0, 2, 1, 3)
-            beta = jax.nn.sigmoid(jnp.dot(
-                a, p["beta"], preferred_element_type=f32))
+            beta = jax.nn.sigmoid(self._keep(jnp.dot(
+                a, p["beta"], preferred_element_type=f32), "kda_beta"))
             if c.allow_neg_eigval:
                 beta = 2.0 * beta
             beta = beta.transpose(0, 2, 1)
@@ -333,30 +387,45 @@ class HybridMoE(HeldExpertsLM):
         f32 = jnp.float32
         q, k, v, g, beta = self.scan_inputs(p, a, docs)
         with jax.named_scope("scan"):
-            o = gated_delta_rule(
+            o = self._keep(gated_delta_rule(
                 q, k, v, g, beta, chunk=c.scan_chunk,
-                resets=None if docs is None else docs.first)
+                resets=None if docs is None else docs.first), "kda_o")
         with jax.named_scope("onorm"):
             o = o.transpose(0, 2, 1, 3).astype(f32)
             o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                                   + c.rms_norm_eps)
             o = (o * p["o_norm"]["weight"].astype(f32)).reshape(b, s, n * d)
-            gate = self._dot(self._dot(a, p["g_a"]), p["g_b"])
-            o = (o * jax.nn.sigmoid(gate.astype(f32))).astype(c.dtype)
+            gate = self._dot(self._keep(self._dot(a, p["g_a"]), "kda_g"),
+                             p["g_b"])
+            o = self._keep(
+                (o * jax.nn.sigmoid(gate.astype(f32))).astype(c.dtype),
+                "kda_gated")
         with jax.named_scope("proj"):
             return self._dot(o, p["proj"])
 
     def _block(self, i, p, x, docs=None):
         """`docs`: the row's `documents`; an input of the checkpointed
-        mixer under `recompute_mixers`, not recomputed work."""
-        if not self._attends(i):
+        mixer under `recompute_mixers`, not recomputed work.  Under the
+        flag a KDA mixer keeps its normed input and what `KEPT` names
+        and runs again, in the backward, the decay, the scan up to its
+        chunk-start states and the norm's elementwise part; a layer that
+        attends keeps everything and runs nothing twice.  Its checkpoint
+        is one in form alone, and is there for the set-up's sake: with
+        the layer under none the one binding of `flash_bwd` traced for
+        4.9 s and not 0.4 on the chip's host, cell 5's warm `setup_s`
+        56-59 s and not 51-53, for 0.4% more tokens/s and 0.1 GB less
+        (PERF.md section 6, PR 38's run of both forms)."""
+        attends = self._attends(i)
+        if not attends:
             mixer = self._kda
         elif self.c.attention_kind == "latent":
             mixer = self._latent
         else:
             mixer = self._attention
         if self.c.recompute_mixers:
-            mixer = jax.checkpoint(mixer)
+            mixer = jax.checkpoint(
+                mixer, policy=jax.checkpoint_policies.everything_saveable
+                if attends else _keeps)
         with jax.named_scope(f"block{i}"):
             with jax.named_scope("ln1"):
                 a = self._norm(p["ln1"], x)

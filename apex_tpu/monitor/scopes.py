@@ -97,6 +97,19 @@ def owner_of(op_name: str):
     return owner, direction
 
 
+def rematted(hlo_text: str) -> frozenset:
+    """The instructions of a compiled program's text whose own
+    `op_name` stands under `rematted_computation`: a forward that
+    `jax.checkpoint` runs again in the backward.  A lower bound on
+    what is recomputed: an instruction that states no `op_name` is not
+    in it, and a fusion states the `op_name` of one of its
+    instructions."""
+    return frozenset(
+        i.name for comp in parse_module(hlo_text)
+        for i in comp.instructions
+        if "/rematted_computation/" in i.op_name + "/")
+
+
 def _shared(found):
     """What (owner, direction) pairs agree on: the longest vocabulary
     path all the owners start with and the first pair's direction (an
@@ -222,6 +235,11 @@ def step_text(name: str = "local_step") -> str:
 def step_owners(name: str = "local_step") -> dict:
     """`owners` of the registered program `name`."""
     return owners(step_text(name))
+
+
+def step_rematted(name: str = "local_step") -> frozenset:
+    """`rematted` of the registered program `name`."""
+    return rematted(step_text(name))
 
 
 def _jaxprs_in(eqn):

@@ -492,3 +492,78 @@ def test_mla_moe_step_compiles_fits_and_is_named(topo, on_chip):
     assert set(grouped) == {f"block{i}/mlp/{s}" for i in range(1, 6)
                             for s in ("experts", "combine")}
     assert sum(g.endswith("combine") for g in grouped) == 5
+
+
+# ------------------- the hybrid step and what its checkpoint keeps -------------------
+
+def test_hybrid_step_fits_with_what_its_mixers_keep(topo, on_chip, monkeypatch):
+    """The benchmark's `solar-open2-250b` step (4 layers, 8 of 320
+    experts, 1 x 4096, bf16 Adam state, `recompute_mixers`) at the
+    committed v5e tuner entries: with the activations `hybrid_moe.KEPT`
+    names held and the attending layer's checkpoint keeping everything
+    it stays inside the chip's memory; the recomputation holds no projection over
+    the hidden width and nothing of the attending layer; the chunk-local
+    forward still runs twice a KDA layer, not three times."""
+    import json
+    import re
+
+    from apex_tpu.models import hybrid_moe
+    from apex_tpu.monitor import scopes
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.parallel import mesh as M
+    from apex_tpu.transformer.training import (
+        init_sharded_optimizer,
+        make_tp_dp_train_step,
+    )
+    from apex_tpu.tune import cache as tune_cache
+    from benchmarks.jobs.hybrid_moe_train import model_config
+
+    monkeypatch.setattr(tune_cache, "device_kind", lambda: "v5e")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        config = json.load(f)
+    M.destroy_model_parallel()
+    mesh = M.initialize_model_parallel(tensor_model_parallel_size=1,
+                                       devices=list(topo.devices[:1]))
+    model = hybrid_moe.HybridMoE(model_config(
+        config, dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
+        recompute_mixers=True))
+    opt = FusedAdam(lr=1e-5, master_dtype=jnp.bfloat16)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda p: init_sharded_optimizer(opt, model, p, mesh), params)
+
+    def placed(sds, spec):
+        return jax.ShapeDtypeStruct(sds.shape, sds.dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    state = type(state)(placed(state[0], P()), *(
+        placed(buf, P(("pp", "tp"))) for buf in state[1:]))
+    tokens = placed(_sds((1, 4096), jnp.int32), P("dp"))
+    step = make_tp_dp_train_step(model, opt, mesh, donate=True)
+    hybrid_moe.reset_stats()
+    compiled = step.lower(state, tokens, tokens).compile()
+    M.destroy_model_parallel()
+
+    # what the policy kept: three KDA layers x (q, k, v in front of the convolution and behind
+    # it, o, the gated o: 8 x 4096 x 8192 bf16; two rank-128 inner
+    # activations; beta's float32 logits)
+    assert hybrid_moe.stats() == {
+        "kept_bytes": 3 * (8 * 4096 * 8192 * 2 + 2 * 4096 * 128 * 2
+                           + 4096 * 64 * 4)}
+    assert _bytes(compiled) < HBM_BYTES
+    text = compiled.as_text()
+    by_name = {}
+    for name in re.findall(
+            r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text,
+            re.M):
+        by_name[name.split(".")[0]] = by_name.get(name.split(".")[0], 0) + 1
+    assert {k: by_name.get(k) for k in (
+        "kda_locals_fwd", "kda_locals_bwd", "conv_stage", "conv_unstage",
+        "flash_fwd")} == {"kda_locals_fwd": 6, "kda_locals_bwd": 3,
+                          "conv_stage": 3, "conv_unstage": 3, "flash_fwd": 1}
+    found = scopes.owners(text)
+    again = {found[name][0] for name in scopes.rematted(text)}
+    assert again and all(re.fullmatch(
+        r"block[123]/attn/(decay|scan|onorm)", owner) for owner in again)

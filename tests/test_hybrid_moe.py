@@ -5,6 +5,7 @@ at toy sizes; the share test; the router and the grouping at a width
 that is no multiple of 128; the step's owners."""
 
 import os
+import re
 import sys
 
 import jax
@@ -153,6 +154,107 @@ def test_recomputed_mixers_give_the_same_loss_and_gradients(mesh, batch,
         w = _leaves(want)[name]
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * max(
             float(jnp.max(jnp.abs(w))), 1e-6))
+
+
+# ----------------------- what the checkpoint keeps -----------------------
+
+def _eqns_in(jaxpr):
+    """Every equation of a jaxpr and of every jaxpr nested in it."""
+    from apex_tpu.monitor import scopes
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in scopes._jaxprs_in(eqn):
+            yield from _eqns_in(inner)
+
+
+def _remat_counts(model, mesh, batch):
+    """Of the gradient's jaxpr, nested bodies walked: the forward GEMMs
+    by the shapes of activation and weight, the `name` equations, the
+    checkpointed mixers, and what `hybrid_moe.stats()` counted meanwhile."""
+    from apex_tpu.models import hybrid_moe
+
+    hybrid_moe.reset_stats()
+    c = model.c
+    fn = shard_map(jax.value_and_grad(model.loss), mesh=mesh,
+                   in_specs=(model.partition_specs(), P(), P()),
+                   out_specs=(P(), model.partition_specs()), check_vma=False)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    eqns = list(_eqns_in(jax.make_jaxpr(fn)(params, *batch).jaxpr))
+    a = batch[0].shape + (c.hidden,)
+    gemms = [tuple(v.aval.shape for v in e.invars) for e in eqns
+             if e.primitive.name == "dot_general"]
+    return {"names": sum(e.primitive.name == "name" for e in eqns),
+            # the blocks whose `attn` scope itself holds a checkpoint
+            "checkpointed": sorted(
+                int(i) for e in eqns if "policy" in e.params
+                for i in re.findall(r"jvp\(block(\d+)\)/attn$",
+                                    str(e.source_info.name_stack))),
+            **hybrid_moe.stats(),
+            **{weight: gemms.count((a, (c.hidden, width)))
+               for weight, width in (
+                   ("kda_qkv", c.kda_heads * c.kda_head_dim),
+                   ("rank", c.kda_rank), ("beta", c.kda_heads),
+                   ("attn_kv", c.num_kv_heads * c.head_dim))}}
+
+
+@pytest.fixture(scope="module")
+def kept(mesh, batch):
+    """`_remat_counts` of the toy with `recompute_mixers` under the
+    model's policy, under `policy=None` (the parent's checkpoint) and
+    under a policy whose names lack `kda_staged`, and of the toy with
+    the flag false."""
+    from apex_tpu.models import hybrid_moe
+
+    def counts(**config):
+        return _remat_counts(toy(**config), mesh, batch)
+
+    found = {"policy": counts(recompute_mixers=True), "flag false": counts()}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid_moe, "_keeps", None)
+        found["no policy"] = counts(recompute_mixers=True)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid_moe, "_BY_NAME",
+                      jax.checkpoint_policies.save_only_these_names(
+                          *set(hybrid_moe.KEPT) - {"kda_staged"}))
+        found["a name short"] = counts(recompute_mixers=True)
+    return found
+
+
+# three KDA layers of 3 heads x 8, rank 8; (2, 64) tokens, float32
+@pytest.mark.parametrize("what,with_policy,without", [
+    # q, k, v of every KDA layer: the forward's alone, not the
+    # recomputation's too
+    ("kda_qkv", 3 * 3, 2 * 3 * 3),
+    # `f_a` and `g_a`; beta's logits
+    ("rank", 3 * 2, 2 * 3 * 2), ("beta", 3, 2 * 3),
+    # the eleven tags of a KDA layer, and once more where the
+    # recomputation runs them again
+    ("names", 3 * 11, 2 * 3 * 11),
+    # the layer that attends keeps everything: its k and v projections
+    # run once either way, under a checkpoint as every mixer's
+    ("attn_kv", 2, 2), ("checkpointed", [0, 1, 2, 3], [0, 1, 2, 3]),
+])
+def test_a_kept_activation_is_computed_once(kept, what, with_policy, without):
+    assert (kept["policy"][what], kept["no policy"][what]) == (
+        with_policy, without)
+
+
+# q, k, v in front of the convolution and behind it, o and the gated o;
+# the two inner activations; the logits: float32, (2, 64) tokens
+_WIDE = 4 * 2 * 64 * 3 * 8
+_A_LAYER = 8 * _WIDE + 4 * 2 * 64 * (2 * 8 + 3)
+
+
+@pytest.mark.parametrize("under,kept_bytes", [
+    ("policy", 3 * _A_LAYER),
+    # the staged q, k, v are then computed again and count for nothing
+    ("a name short", 3 * (_A_LAYER - 3 * _WIDE)),
+    # nothing asks the policy: the count says the list did not engage
+    ("no policy", 0), ("flag false", 0)])
+def test_stats_count_what_the_policy_keeps(kept, under, kept_bytes):
+    assert kept[under]["kept_bytes"] == kept_bytes
+    assert kept["flag false"]["names"] == 0
 
 
 def test_the_model_holds_two_kinds_of_layer_and_its_own_experts():
@@ -340,6 +442,34 @@ def test_every_instruction_of_the_step_is_owned(mesh):
     # and each is a path the vocabulary spells
     assert owners - {scopes.UNOWNED} <= {
         p.replace("{i}", str(i)) for p in scopes.OWNERS for i in range(4)}
+
+
+def test_the_steps_recomputation_is_the_kda_mixers_without_their_gemms(mesh):
+    """A step of the hybrid with `recompute_mixers` through the step
+    builder: what `scopes.step_rematted` names runs in the backward
+    under a KDA layer's convolution, decay, scan or output norm; no
+    projection over the hidden width and nothing of the layer that
+    attends is among it."""
+    from apex_tpu.monitor import scopes
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.transformer.training import (
+        init_sharded_optimizer,
+        make_tp_dp_train_step,
+    )
+
+    model = toy(init_std=0.06, recompute_mixers=True)
+    opt = FusedAdam(lr=3e-3, use_pallas=False)
+    state = init_sharded_optimizer(
+        opt, model, model.init(jax.random.PRNGKey(8)), mesh)
+    step = make_tp_dp_train_step(model, opt, mesh, donate=False)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0, 64)
+    step(state, tokens, jnp.roll(tokens, -1, axis=1))
+
+    found, again = scopes.step_owners(), scopes.step_rematted()
+    assert again <= set(found)
+    assert {found[n][:2] for n in again} == {
+        (f"block{i}/attn/{s}", "bwd") for i in (1, 2, 3)
+        for s in ("conv", "decay", "scan", "onorm")}
 
 
 def test_a_wider_row_bound_changes_the_buffer_and_not_the_numbers():

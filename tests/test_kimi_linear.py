@@ -8,6 +8,7 @@ weights at toy sizes; the share test; what the older cells' models
 trace to."""
 
 import os
+import re
 import sys
 
 import jax
@@ -210,6 +211,106 @@ def test_recomputed_mixers_give_the_same_loss_and_gradients(mesh, batch,
             float(jnp.max(jnp.abs(w))), 1e-6))
 
 
+# ----------------------- what the checkpoint keeps -----------------------
+
+def _eqns_in(jaxpr):
+    """Every equation of a jaxpr and of every jaxpr nested in it."""
+    from apex_tpu.monitor import scopes
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in scopes._jaxprs_in(eqn):
+            yield from _eqns_in(inner)
+
+
+def _remat_counts(model, mesh, batch):
+    """Of the gradient's jaxpr, nested bodies walked: the forward GEMMs
+    by the shapes of activation and weight, the `name` equations, the
+    checkpointed mixers, and what `hybrid_moe.stats()` counted meanwhile."""
+    from apex_tpu.models import hybrid_moe
+
+    hybrid_moe.reset_stats()
+    c = model.c
+    fn = shard_map(jax.value_and_grad(model.loss), mesh=mesh,
+                   in_specs=(model.partition_specs(), P(), P()),
+                   out_specs=(P(), model.partition_specs()), check_vma=False)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    eqns = list(_eqns_in(jax.make_jaxpr(fn)(params, *batch).jaxpr))
+    a = batch[0].shape + (c.hidden,)
+    gemms = [tuple(v.aval.shape for v in e.invars) for e in eqns
+             if e.primitive.name == "dot_general"]
+    return {"names": sum(e.primitive.name == "name" for e in eqns),
+            # the blocks whose `attn` scope itself holds a checkpoint
+            "checkpointed": sorted(
+                int(i) for e in eqns if "policy" in e.params
+                for i in re.findall(r"jvp\(block(\d+)\)/attn$",
+                                    str(e.source_info.name_stack))),
+            **hybrid_moe.stats(),
+            **{weight: gemms.count((a, (c.hidden, width)))
+               for weight, width in (
+                   ("kda_qkv", c.kda_heads * c.kda_head_dim),
+                   ("beta", c.kda_heads),
+                   ("latent_kv_a", c.kv_lora_rank + c.qk_rope_head_dim))}}
+
+
+@pytest.fixture(scope="module")
+def kept(mesh, batch):
+    """`_remat_counts` of the toy with `recompute_mixers` under the
+    model's policy, under `policy=None` (the parent's checkpoint) and
+    under a policy whose names lack `kda_staged`, and of the toy with
+    the flag false."""
+    from apex_tpu.models import hybrid_moe
+
+    def counts(**config):
+        return _remat_counts(toy(**config), mesh, batch)
+
+    found = {"policy": counts(recompute_mixers=True), "flag false": counts()}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid_moe, "_keeps", None)
+        found["no policy"] = counts(recompute_mixers=True)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid_moe, "_BY_NAME",
+                      jax.checkpoint_policies.save_only_these_names(
+                          *set(hybrid_moe.KEPT) - {"kda_staged"}))
+        found["a name short"] = counts(recompute_mixers=True)
+    return found
+
+
+# four KDA layers of 3 heads x 8; (2, 64) tokens, float32
+@pytest.mark.parametrize("what,with_policy,without", [
+    # q, k, v of every KDA layer: the forward's alone, not the
+    # recomputation's too; beta's logits
+    ("kda_qkv", 4 * 3, 2 * 4 * 3), ("beta", 4, 2 * 4),
+    # the eleven tags of a KDA layer, and once more where the
+    # recomputation runs them again
+    ("names", 4 * 11, 2 * 4 * 11),
+    # the latent layer keeps everything: `kv_a` runs once either way,
+    # under a checkpoint as every mixer's
+    ("latent_kv_a", 1, 1),
+    ("checkpointed", [0, 1, 2, 3, 4], [0, 1, 2, 3, 4]),
+])
+def test_a_kept_activation_is_computed_once(kept, what, with_policy, without):
+    assert (kept["policy"][what], kept["no policy"][what]) == (
+        with_policy, without)
+
+
+# q, k, v in front of the convolution and behind it, o and the gated o;
+# the two inner activations; the logits: float32, (2, SEQ) tokens
+_WIDE = 4 * 2 * SEQ * 3 * 8
+_A_LAYER = 8 * _WIDE + 4 * 2 * SEQ * (2 * 8 + 3)
+
+
+@pytest.mark.parametrize("under,kept_bytes", [
+    ("policy", 4 * _A_LAYER),
+    # the staged q, k, v are then computed again and count for nothing
+    ("a name short", 4 * (_A_LAYER - 3 * _WIDE)),
+    # nothing asks the policy: the count says the list did not engage
+    ("no policy", 0), ("flag false", 0)])
+def test_stats_count_what_the_policy_keeps(kept, under, kept_bytes):
+    assert kept[under]["kept_bytes"] == kept_bytes
+    assert kept["flag false"]["names"] == 0
+
+
 def test_the_model_holds_a_dense_layer_kda_and_latent_attention():
     model = toy()
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -406,15 +507,26 @@ def test_the_owners_vocabulary_has_the_new_sublayers():
 # (`ops/conv_stage.py`): its `jax.numpy` body is the parent's, so
 # ("hybrid", "jnp") stands, and ("hybrid", "kernels") stands with that
 # one op on its body and differs, by the pair's two names, without.
+# PR 38 gave the hybrid's checkpoint a policy and its mixers tags, which
+# is another jaxpr under `recompute_mixers` by design (the tests of what
+# the checkpoint keeps hold that one): the hybrid's two are traced with
+# the flag false since, and their digests are what PR 38's parent
+# (c29d385) traced so, where a tag is no equation at all.  To see that
+# they are the parent's and not this tree's, run this file's test on a
+# checkout of that commit (it reads nothing PR 38 added), 4 passed:
+#   mkdir /tmp/c29d385 && git archive c29d385 | tar -x -C /tmp/c29d385
+#   cp tests/test_kimi_linear.py /tmp/c29d385/tests/ && cd /tmp/c29d385
+#   JAX_PLATFORMS=cpu python -m pytest tests/test_kimi_linear.py \
+#       -k parents_jaxpr -p no:cacheprovider
 PARENT_JAXPR = {
     ("mla", "jnp"):
         "1a1b2d245e5547abf64ebd8d221234b344b506f3865bdfbfbc60cda68b0110bd",
     ("hybrid", "jnp"):
-        "bcec0ecf8e543ed5a4e53660506e271770ed197a854b53845c60921c4e66fd68",
+        "6dd176c3adee121de1d79886c50bed9a4387856473c5111afc2cc7b603bbbda0",
     ("mla", "kernels"):
         "f94e3eb93e0cd8093022f333ab976e27a60126144c20efcebfbb525ab000f824",
     ("hybrid", "kernels"):
-        "cc5c97b89ff2cb2a6e02fba2f7d878b378e1fd17c150f4b320cbea7799f6b339",
+        "883aaa0dc350403fee898b66135664f63fc0982cf2951a32992a0b7ac4946772",
 }
 
 
@@ -433,8 +545,7 @@ def test_the_older_cells_models_trace_to_the_parents_jaxpr(mesh, which, path,
         model, shape = MLAMoE(MLAMoEConfig(**common)), (2, 4096)
     else:
         model, shape = HybridMoE(HybridMoEConfig(
-            recompute_mixers=True, expert_rows_factor=8.0, **common)), (
-            1, 4096)
+            expert_rows_factor=8.0, **common)), (1, 4096)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     tok = jax.ShapeDtypeStruct(shape, jnp.int32)
     fn = shard_map(jax.value_and_grad(model.loss), mesh=mesh,
@@ -452,17 +563,16 @@ def test_the_older_cells_models_trace_to_the_parents_jaxpr(mesh, which, path,
         return hashlib.sha256(text.encode()).hexdigest(), text
 
     if (which, path) == ("hybrid", "kernels"):
-        # three KDA layers: one call of the pair a layer and direction,
-        # and once more for the recomputed forward
+        # three KDA layers: one call of the pair a layer and direction
         text = digest()[1]
-        assert text.count("name=conv_stage") == 6
+        assert text.count("name=conv_stage") == 3
         assert text.count("name=conv_unstage") == 3
         staged = hybrid_moe.stage_conv_heads
         monkeypatch.setattr(
             hybrid_moe, "stage_conv_heads",
             lambda *a, use_pallas_override=None, **kw: staged(
                 *a, use_pallas_override=False, **kw))
-        jax.clear_caches()      # a checkpointed mixer's trace is kept
+        jax.clear_caches()      # the first trace is kept
     assert digest()[0] == PARENT_JAXPR[which, path]
 
 

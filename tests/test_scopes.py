@@ -109,6 +109,46 @@ def test_ownership_rule_on_recorded_text(excerpt, instruction, want, why):
     assert excerpt[instruction] == want, why
 
 
+REMATTED = ("jit(local_step)/transpose(jvp(block3))/attn/jvp(block3)/attn/"
+            "checkpoint/rematted_computation/scan/dot_general")
+
+
+@pytest.mark.parametrize("op_name,again", [
+    # the docstring's own example: owned and directed as before, and in
+    # the set
+    (REMATTED, True),
+    # the checkpoint's first forward, and a backward that is no
+    # recomputation
+    ("jit(local_step)/jvp(block1)/attn/scan/checkpoint/jit(_where)/select_n",
+     False),
+    ("jit(local_step)/transpose(jvp(block0))/attn/jvp(block0)/attn/"
+     "checkpoint/gate/transpose(jvp())/mul", False),
+    # the segment is a whole one
+    ("jit(f)/transpose(jvp(block1))/attn/rematted_computation_of_mine/neg",
+     False),
+    ("", False),
+])
+def test_rematted_is_what_stands_under_the_recomputation(op_name, again):
+    """`rematted` reads an instruction's own `op_name` and nothing
+    else: what states none (`%copy`) is not in the set, whoever uses
+    it."""
+    meta = f', metadata={{op_name="{op_name}"}}' if op_name else ""
+    text = f"""HloModule m
+
+ENTRY %main () -> () {{
+  %p = f32[8] parameter(0)
+  %copy = f32[8] copy(%p)
+  %a = f32[8] negate(%copy){meta}
+  %b = f32[8] negate(%copy), metadata={{op_name="{REMATTED}"}}
+}}
+"""
+    assert scopes.rematted(text) == frozenset({"a", "b"} if again else {"b"})
+    if op_name == REMATTED:
+        assert scopes.owner_of(op_name) == ("block3/attn/scan", "bwd")
+        assert scopes.owners(text)["a"] == ("block3/attn/scan", "bwd",
+                                            "negate")
+
+
 def test_users_that_disagree_share_their_longest_path():
     """Not recorded (the flagship step has no such instruction): a
     copy used by the QKV GEMM and by the flash kernel of one block
