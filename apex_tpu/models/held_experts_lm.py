@@ -1,23 +1,34 @@
 """What the decoders built on `moe.HeldExpertsMLP` share
-(`models.mla_moe.MLAMoE`, `models.hybrid_moe.HybridMoE`): the two ends
-of the network — a vocabulary-parallel embedding, a final RMSNorm, an
-untied head over the held rows of the vocabulary and the fused cross
-entropy — the expert layer one chip of an expert-parallel group holds,
-built from the config's keys, the small pieces every block uses, the
-dense SwiGLU of a leading layer, and the part of latent attention that
-does not depend on how a model makes its queries or whether it turns
-anything: the compressed key-value projection with its norm, its
-expansion as two GEMMs, the flash call and the output projection
-(`MLAMoE` compresses q and rotates; `HybridMoE`'s latent layers do
-neither).
+(`models.mla_moe.MLAMoE`, `models.hybrid_moe.HybridMoE`,
+`models.shortconv_moe.ShortConvMoE`): the two ends of the network — a
+vocabulary-parallel embedding, a final RMSNorm, a head over the held
+rows of the vocabulary (a leaf of its own, or, where the config has
+`tie_word_embeddings`, the embedding's leaf read a second time) and
+the fused cross entropy — the expert layer one chip of an
+expert-parallel group holds, built from the config's keys, the small
+pieces every block uses, the dense SwiGLU of a leading layer, the part
+of grouped-query attention that does not depend on what a model does
+to q and k between the projections and the kernels (the three
+projections, the heads' reshape, the flash call with fewer kv heads:
+`HybridMoE` attends on them as they are and gates the context,
+`ShortConvMoE` norms and rotates q and k first), and the part of latent
+attention that does not depend on how a model makes its queries or
+whether it turns anything: the compressed key-value projection with its
+norm, its expansion as two GEMMs, the flash call and the output
+projection (`MLAMoE` compresses q and rotates; `HybridMoE`'s latent
+layers do neither); and, for a stack with one head, the surface the
+step builder takes (`init`, `apply`, `token_losses`, `loss`,
+`routing_counts`) over the subclass's `_init_block` and `trunk`.
 
 A config gives: vocab_size, hidden, init_std, rms_norm_eps, dtype,
 logits_dtype, fused_xent, axis_name, and of the expert layer
 moe_intermediate_size, n_routed_experts, experts_first, experts_count,
 num_experts_per_tok, n_shared_experts, routed_scaling_factor,
 norm_topk_prob, router_bias_range and, where it has one,
-expert_rows_factor (`HeldExpertsMLP`'s `rows_factor`); where it has
-latent attention, num_heads, kv_lora_rank, qk_nope_head_dim,
+expert_rows_factor (`HeldExpertsMLP`'s `rows_factor`) or
+tie_word_embeddings; where it has grouped-query attention, num_heads,
+num_kv_heads, head_dim and flash_override; where it has latent
+attention, num_heads, kv_lora_rank, qk_nope_head_dim,
 qk_rope_head_dim, v_head_dim and flash_override.
 """
 
@@ -56,15 +67,30 @@ class HeldExpertsLM:
             rows_factor=getattr(c, "expert_rows_factor", 2.0))
 
     # ------------------------------ params --------------------------------
+    @property
+    def tied(self) -> bool:
+        """The head reads the embedding's leaf: one (V, H) array, the
+        gradients of both uses summed, the optimizer over it once."""
+        return bool(getattr(self.c, "tie_word_embeddings", False))
+
     def _init_ends(self, k_embed, k_head) -> dict:
-        """embed, head and final_ln."""
+        """embed, final_ln and, where it is a leaf of its own, head."""
         c = self.c
-        return {
-            "embed": self.embed.init(k_embed, c.dtype),
-            "head": {"weight": jax.random.normal(
-                k_head, (c.vocab_size, c.hidden), c.dtype) * c.init_std},
-            "final_ln": {"weight": jnp.ones((c.hidden,), c.dtype)},
-        }
+        ends = {"embed": self.embed.init(k_embed, c.dtype),
+                "final_ln": {"weight": jnp.ones((c.hidden,), c.dtype)}}
+        if not self.tied:
+            ends["head"] = {"weight": jax.random.normal(
+                k_head, (c.vocab_size, c.hidden), c.dtype) * c.init_std}
+        return ends
+
+    def init(self, key):
+        """The ends and `num_layers` blocks of the subclass's
+        `_init_block(key, i)`."""
+        keys = jax.random.split(key, 2 + self.c.num_layers)
+        params = self._init_ends(keys[0], keys[1])
+        for i in range(self.c.num_layers):
+            params[f"block{i}"] = self._init_block(keys[2 + i], i)
+        return params
 
     def partition_specs(self):
         """PartitionSpec pytree matching init(): the vocabulary's rows
@@ -73,7 +99,8 @@ class HeldExpertsLM:
         shapes = jax.eval_shape(self.init, jax.random.PRNGKey(0))
         specs = jax.tree.map(lambda _: P(), shapes)
         specs["embed"] = {"weight": P(c.axis_name, None)}
-        specs["head"] = {"weight": P(c.axis_name, None)}
+        if not self.tied:
+            specs["head"] = {"weight": P(c.axis_name, None)}
         return specs
 
     # ------------------------------ forward -------------------------------
@@ -92,6 +119,32 @@ class HeldExpertsLM:
             act = jax.nn.silu(gate) * up
         with jax.named_scope("down"):
             return self._dot(act, p["down"])
+
+    @staticmethod
+    def _heads(x, n):
+        """(B, S, n * d) -> (B, n, S, d)."""
+        b, s, w = x.shape
+        return x.reshape(b, s, n, w // n).transpose(0, 2, 1, 3)
+
+    def _qkv(self, p, a):
+        """a: (B, S, H), normed.  Grouped-query attention's three
+        projections, token-major: q (B, S, heads * d), k and v (B, S,
+        kv_heads * d)."""
+        with jax.named_scope("qkv"):
+            return tuple(self._dot(a, p[x]) for x in "qkv")
+
+    def _attend(self, q, k, v, segment_ids=None):
+        """Causal attention of head-major q (B, heads, S, d) over k, v
+        (B, kv_heads, S, d), kv head j serving query heads `j * group
+        ...` (`ops.flash_attention` takes the fewer kv heads as they
+        are), within a row's documents where `segment_ids` (B, S) names
+        them: the context, token-major again, (B, S, heads * d)."""
+        c = self.c
+        b, _, s, _ = q.shape
+        ctx = flash_attention(
+            q, k, v, causal=True, softmax_scale=1.0 / math.sqrt(c.head_dim),
+            segment_ids=segment_ids, use_pallas_override=c.flash_override)
+        return ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
 
     def _latent_kv(self, p, a, rotary: bool):
         """a: (B, S, H), normed.  Latent attention's key-value side,
@@ -143,13 +196,14 @@ class HeldExpertsLM:
             return self._norm(params["final_ln"], h)
 
     def logits_local(self, params, h):
-        """The untied head over the held rows: (B, S, V/tp)."""
+        """The head over the held rows: (B, S, V/tp)."""
         with jax.named_scope("head"):
             return self._head(params, h)
 
     def _head(self, params, h):
         out_dtype = self.c.logits_dtype or jnp.float32
-        return jnp.einsum("bsh,vh->bsv", h, params["head"]["weight"],
+        weight = params["embed" if self.tied else "head"]["weight"]
+        return jnp.einsum("bsh,vh->bsv", h, weight,
                           preferred_element_type=jnp.float32
                           ).astype(out_dtype)
 
@@ -157,6 +211,38 @@ class HeldExpertsLM:
         return vocab_parallel_cross_entropy(
             logits, labels, axis_name=self.c.axis_name,
             fused=self.c.fused_xent)
+
+    # What follows is a stack's surface for the step builder, over the
+    # subclass's `trunk(params, tokens)` -> (the residual stream after
+    # the last held layer, the expert layers' HeldExpertsStats in layer
+    # order); `MLAMoE`, which has a second head, brings its own.
+    def apply(self, params, tokens, key=None):
+        """tokens: (B, S) ids within the held rows.  The hidden states
+        the head reads, (B, S, H).  Shard-local: call inside
+        shard_map."""
+        h, _ = self.trunk(params, tokens)
+        return self._final_ln(params, h)
+
+    def token_losses(self, params, tokens, labels):
+        """(main, None, stats): per-token cross entropies (B, S) fp32
+        against `labels`, no second head (`MLAMoE.token_losses` has
+        one), and every expert layer's HeldExpertsStats."""
+        h, stats = self.trunk(params, tokens)
+        logits = self.logits_local(params, self._final_ln(params, h))
+        with jax.named_scope("loss"):
+            return self._xent(logits, labels), None, stats
+
+    def loss(self, params, tokens, labels, key=None):
+        """The mean over tokens.  tokens/labels: (B, S)."""
+        main, _, _ = self.token_losses(params, tokens, labels)
+        with jax.named_scope("loss"):
+            return jnp.mean(main)
+
+    def routing_counts(self, params, tokens, labels=None):
+        """Forward only, without the head: (counts (layers,
+        experts_count) int32, overflow (layers,) int32) of every expert
+        layer held."""
+        return self._counts(self.trunk(params, tokens)[1])
 
     @staticmethod
     def _counts(stats):

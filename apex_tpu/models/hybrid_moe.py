@@ -78,7 +78,6 @@ from jax.ad_checkpoint import checkpoint_name
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
 from apex_tpu.ops.conv_stage import stage_conv_heads
 from apex_tpu.ops.delta_rule import gated_delta_rule
-from apex_tpu.ops.flash_attention import flash_attention
 from apex_tpu.ops.rope_stage import stage_heads
 from apex_tpu.parallel.mesh import TP_AXIS
 
@@ -262,20 +261,7 @@ class HybridMoE(HeldExpertsLM):
             mlp = self.experts.init(ks[10], c.dtype)
         return {"ln1": ones(h), "attn": attn, "ln2": ones(h), "mlp": mlp}
 
-    def init(self, key):
-        keys = jax.random.split(key, 2 + self.c.num_layers)
-        params = self._init_ends(keys[0], keys[1])
-        for i in range(self.c.num_layers):
-            params[f"block{i}"] = self._init_block(keys[2 + i], i)
-        return params
-
     # ------------------------------ forward -------------------------------
-    @staticmethod
-    def _heads(x, n):
-        """(B, S, n * d) -> (B, n, S, d)."""
-        b, s, w = x.shape
-        return x.reshape(b, s, n, w // n).transpose(0, 2, 1, 3)
-
     def documents(self, tokens, i: int = 0) -> Optional[Documents]:
         """The documents of `tokens` (B, S), for every mixer of the
         step; None where the config names no `eod_token_id`.  Whoever
@@ -306,17 +292,12 @@ class HybridMoE(HeldExpertsLM):
         """a: (B, S, H), normed.  The gated grouped-query attention's
         output, before the residual add."""
         c = self.c
-        b, s, _ = a.shape
-        with jax.named_scope("qkv"):
-            q, k, v = (self._dot(a, p[x]) for x in "qkv")
+        q, k, v = self._qkv(p, a)
         with jax.named_scope("flash"):
-            ctx = flash_attention(
+            ctx = self._attend(
                 self._heads(q, c.num_heads), self._heads(k, c.num_kv_heads),
-                self._heads(v, c.num_kv_heads), causal=True,
-                softmax_scale=1.0 / math.sqrt(c.head_dim),
-                segment_ids=None if docs is None else docs.ids,
-                use_pallas_override=c.flash_override)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, -1)
+                self._heads(v, c.num_kv_heads),
+                None if docs is None else docs.ids)
         with jax.named_scope("gate"):
             ctx = ctx * jax.nn.sigmoid(self._dot(a, p["gate"]))
         with jax.named_scope("proj"):
@@ -451,31 +432,3 @@ class HybridMoE(HeldExpertsLM):
             if st is not None:
                 stats.append(st)
         return h, stats
-
-    def apply(self, params, tokens, key=None):
-        """tokens: (B, S) ids within the held rows.  The hidden states
-        the head reads, (B, S, H).  Shard-local: call inside
-        shard_map."""
-        h, _ = self.trunk(params, tokens)
-        return self._final_ln(params, h)
-
-    def token_losses(self, params, tokens, labels):
-        """(main, None, stats): per-token cross entropies (B, S) fp32
-        against `labels`, no second head (`MLAMoE.token_losses` has
-        one), and every expert layer's HeldExpertsStats."""
-        h, stats = self.trunk(params, tokens)
-        logits = self.logits_local(params, self._final_ln(params, h))
-        with jax.named_scope("loss"):
-            return self._xent(logits, labels), None, stats
-
-    def loss(self, params, tokens, labels, key=None):
-        """The mean over tokens.  tokens/labels: (B, S)."""
-        main, _, _ = self.token_losses(params, tokens, labels)
-        with jax.named_scope("loss"):
-            return jnp.mean(main)
-
-    def routing_counts(self, params, tokens, labels=None):
-        """Forward only, without the head: (counts (layers,
-        experts_count) int32, overflow (layers,) int32) of every expert
-        layer held."""
-        return self._counts(self.trunk(params, tokens)[1])

@@ -40,7 +40,14 @@ _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
               # with nothing turned (`attn/rope` stays the rotating
               # block's); and what a step of packed rows spends on
               # knowing its documents, filed under block0
-              "attn/q", "attn/stage", "attn/segments")
+              "attn/q", "attn/stage", "attn/segments",
+              # the short-convolution block (models/shortconv_moe.py):
+              # the mixer's two projections and the gates and taps
+              # between them (`attn/conv` stays the delta-rule mixer's
+              # staging convolution); and, on its layers that attend,
+              # q's and k's norm and rotation on the way to the kernels
+              "attn/in_proj", "attn/shortconv", "attn/out_proj",
+              "attn/qknorm_rope")
 # every scope path the program may open; `block{i}` is a layer by index
 # (`_tap` spells it the same way), `block` a layer of a scanned stack
 OWNERS = (

@@ -93,6 +93,17 @@ def _v5e_entries():
         "resets: chunk 64 33.77 ms in one call of 32 heads (34.98 in "
         "two of 16), chunk 32 37.39 (39.77), chunk 128 38.45 (39.89); "
         "chunk 64 without resets 32.97")
+    # grouped-query attention at 64-wide heads, 32 query heads on 8 kv
+    # heads, one row of 8,192 (models/shortconv_moe.py at the
+    # benchmark's seventh cell): the single pass again, at the blocks of
+    # the 64-on-8 entry above
+    e[_flash(1, 32, 8192, 8192, 64, "bfloat16", True, hkv=8)] = _mk(
+        {"block_q": 2048, "block_k": 512, "fused_bwd": True},
+        "v5e, PR 39 chip run, forward + backward a layer: 14.24 ms "
+        "(forward 5.28); fused (1024, 1024) 14.31, (1024, 512) 15.13, "
+        "(512, 1024) 16.07, (512, 512) 17.24; two kernels (1024, 1024) "
+        "19.00, (1024, 512) 20.45, (2048, 512) 21.02, the heuristics' "
+        "(512, 1024) 20.53, (512, 512) 22.61")
     # flat-optimizer block rows at the 1B Adam bench point: the swept
     # heuristic value, committed so the fingerprint records it
     e[make_key("opt_flat", dict(kernel="adam", rows=8388608))] = _mk(

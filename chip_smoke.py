@@ -339,6 +339,37 @@ def conv_stage_case(name, shapes) -> KernelCase:
         5e-2, 0.2, in_rms=True)
 
 
+def short_conv_case(name, batch, seq, hidden, taps=3) -> KernelCase:
+    """The gated short convolution of a convolutional mixer
+    (ops/short_conv.py) in bf16, as the step compiles it, against the
+    same body in float32: the output and the gradients of the
+    projection's three thirds and of the taps."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.ops.short_conv import gated_short_conv
+
+    def make_args(key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        return (jax.random.normal(k1, (batch, seq, 3 * hidden), jnp.bfloat16),
+                (jax.random.uniform(k2, (hidden, taps), minval=-1.0)
+                 / math.sqrt(taps)).astype(jnp.bfloat16),
+                jax.random.normal(k3, (batch, seq, hidden), jnp.bfloat16))
+
+    def run(dtype, bcu, w, dy):
+        out, pull = jax.vjp(gated_short_conv, bcu.astype(dtype),
+                            w.astype(dtype))
+        return (out, *pull(dy.astype(dtype)))
+
+    # in units of each output's rms: one bf16 rounding of the output
+    # and of each gradient against float32 throughout (the taps'
+    # gradient sums 8,192 tokens in float32 on both sides)
+    return KernelCase(
+        name, make_args, lambda *a: run(jnp.bfloat16, *a),
+        lambda *a: run(jnp.float32, *a), 2e-2, 5e-2, mosaic=False,
+        in_rms=True)
+
+
 def flash_qkv_case(name, seq, batch, heads, head_dim) -> KernelCase:
     """Causal bf16 flash attention from the packed (S, B, 3*H)
     projection to the (S, B, H) context, forward and backward, as
@@ -435,7 +466,9 @@ def held_experts_case(name, tokens, hidden, ffn, n_experts, count,
 
         y, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
                             jnp.arange(count))
-        y = y + swiglu(x, params["shared_gate_up"], params["shared_down"])
+        if layer.n_shared:
+            y = y + swiglu(x, params["shared_gate_up"],
+                           params["shared_down"])
         return y.astype(x.dtype)
 
     def reference(params, x, dy):
@@ -727,6 +760,15 @@ def kernel_cases(device) -> list:
         # expert layer)
         held_experts_case("moe_held_experts_320", 4096, 4096, 1280, 320, 8, 8,
                           rows_factor=8.0),
+        # the short-convolution stack's (models/shortconv_moe.py): the
+        # gates and taps of one mixer over a row of 8,192; its one
+        # attention call, 32 query heads on 8 kv heads of 64; and one
+        # chip's 16 of 32 experts of 1792 with nothing shared, 4 a
+        # token, 1,024 rows an expert, the buffer every assignment
+        short_conv_case("short_conv_grads", 1, 8192, 2048),
+        gqa_flash_case("gqa_flash_d64_grads", 1, 32, 8, 8192, 64),
+        held_experts_case("moe_held_experts_half", 8192, 2048, 1792, 32, 16,
+                          4, n_shared=0),
         adam_case("adam_flat_fp32", n_params, jnp.float32),
         adam_case("adam_flat_bf16", n_params, jnp.bfloat16),
         xent_case("xent_pallas", BATCH * SEQ, FLAGSHIP["vocab_size"]),

@@ -125,6 +125,8 @@ def _smoke_case(name, device):
     ("flash_350m", 2), ("flash_qkv_350m", 2), ("flash_d64_s2048", 2),
     ("flash_mla_192_128", 2), ("mla_attention_grads", 6),
     ("moe_held_experts", 6), ("moe_held_experts_320", 6),
+    ("short_conv_grads", 0), ("gqa_flash_d64_grads", 2),
+    ("moe_held_experts_half", 6),
     ("adam_flat_fp32", 1),
     ("adam_flat_bf16", 1), ("xent_pallas", 2), ("xent_vocab_parallel", 0),
     ("flash_decode", 1)])
@@ -400,31 +402,30 @@ def test_flagship_flash_reads_the_projection_where_it_lies(flagship_compiled):
 
 # ---------------- the latent-attention, sparse-expert step ----------------
 
-def mla_moe_step(devices):
-    """(step, args): the benchmark's `joyai-llm-flash` step (one dense
-    and four expert layers and the MTP module, 16 of 256 experts, 16,256
-    vocabulary rows, bf16 Adam state, 2 x 4096, donated state) over
-    described `devices`, every argument a shape."""
+def _cell_step(devices, config_name, build, batch, seq):
+    """(model, step, args): the train step of one of the benchmark's
+    expert cells (bf16 Adam state, lr 1e-5, donated state, `batch` x
+    `seq` tokens, tensor parallelism 1) over described `devices`, every
+    argument a shape.  `build(config)` makes the model from the
+    configuration file `benchmarks/configs/<config_name>.json`, once
+    the mesh stands."""
     import json
 
-    from apex_tpu.models.mla_moe import MLAMoE
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import mesh as M
     from apex_tpu.transformer.training import (
         init_sharded_optimizer,
         make_tp_dp_train_step,
     )
-    from benchmarks.jobs.mla_moe_train import model_config
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(repo, "benchmarks", "configs",
-                           "joyai-llm-flash.json")) as f:
+                           config_name + ".json")) as f:
         config = json.load(f)
     M.destroy_model_parallel()
     mesh = M.initialize_model_parallel(tensor_model_parallel_size=1,
                                        devices=list(devices))
-    model = MLAMoE(model_config(config, dtype=jnp.bfloat16,
-                                logits_dtype=jnp.bfloat16))
+    model = build(config)
     opt = FusedAdam(lr=1e-5, master_dtype=jnp.bfloat16)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     state = jax.eval_shape(
@@ -436,9 +437,25 @@ def mla_moe_step(devices):
 
     state = type(state)(placed(state[0], P()), *(
         placed(buf, P(("pp", "tp"))) for buf in state[1:]))
-    tokens = placed(_sds((2, 4096), jnp.int32), P("dp"))
-    return make_tp_dp_train_step(model, opt, mesh, donate=True), (
+    tokens = placed(_sds((batch, seq), jnp.int32), P("dp"))
+    return model, make_tp_dp_train_step(model, opt, mesh, donate=True), (
         state, tokens, tokens)
+
+
+def _kernel_names(text):
+    """The `tpu_custom_call` instructions of a compiled step's text."""
+    import re
+
+    return re.findall(
+        r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+
+
+def _by_base_name(names):
+    """{a kernel's name without its `.N`: how many}."""
+    count = {}
+    for name in names:
+        count[name.split(".")[0]] = count.get(name.split(".")[0], 0) + 1
+    return count
 
 
 def test_mla_moe_step_compiles_fits_and_is_named(topo, on_chip):
@@ -447,21 +464,23 @@ def test_mla_moe_step_compiles_fits_and_is_named(topo, on_chip):
     192, values 128), the compiler's grouped-matmul kernels and the Adam
     pass in it, inside the chip's memory, and all but a few shared index
     fusions owned by a scope."""
-    import re
-
+    from apex_tpu.models.mla_moe import MLAMoE
     from apex_tpu.monitor import scopes
     from apex_tpu.monitor.comms.hlo import parse_module
+    from benchmarks.jobs.mla_moe_train import model_config
 
-    step, args = mla_moe_step(topo.devices[:1])
+    # one dense and four expert layers and the MTP module, 16 of 256
+    # experts, 16,256 vocabulary rows, 2 x 4096
+    _, step, args = _cell_step(
+        topo.devices[:1], "joyai-llm-flash",
+        lambda config: MLAMoE(model_config(
+            config, dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16)), 2, 4096)
     # the flat state: 680,834,304 parameters, each leaf tile-aligned
     assert 680_834_304 <= args[0][1].shape[0] < 680_834_304 * 1.001
     compiled = step.lower(*args).compile()
     text = compiled.as_text()
-    kernels = re.findall(
-        r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text, re.M)
-    by_name = {}
-    for name in kernels:
-        by_name[name.split(".")[0]] = by_name.get(name.split(".")[0], 0) + 1
+    kernels = _kernel_names(text)
+    by_name = _by_base_name(kernels)
     # 6 blocks (5 layers and the MTP module's); off the chip the tuner
     # has no v5e entry, so the backward is the two-kernel one; the
     # staging pass runs once for q and once for k a block and direction
@@ -504,46 +523,22 @@ def test_hybrid_step_fits_with_what_its_mixers_keep(topo, on_chip, monkeypatch):
     it stays inside the chip's memory; the recomputation holds no projection over
     the hidden width and nothing of the attending layer; the chunk-local
     forward still runs twice a KDA layer, not three times."""
-    import json
     import re
 
     from apex_tpu.models import hybrid_moe
     from apex_tpu.monitor import scopes
-    from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import mesh as M
-    from apex_tpu.transformer.training import (
-        init_sharded_optimizer,
-        make_tp_dp_train_step,
-    )
     from apex_tpu.tune import cache as tune_cache
     from benchmarks.jobs.hybrid_moe_train import model_config
 
     monkeypatch.setattr(tune_cache, "device_kind", lambda: "v5e")
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "benchmarks", "configs",
-                           "solar-open2-250b.json")) as f:
-        config = json.load(f)
-    M.destroy_model_parallel()
-    mesh = M.initialize_model_parallel(tensor_model_parallel_size=1,
-                                       devices=list(topo.devices[:1]))
-    model = hybrid_moe.HybridMoE(model_config(
-        config, dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
-        recompute_mixers=True))
-    opt = FusedAdam(lr=1e-5, master_dtype=jnp.bfloat16)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    state = jax.eval_shape(
-        lambda p: init_sharded_optimizer(opt, model, p, mesh), params)
-
-    def placed(sds, spec):
-        return jax.ShapeDtypeStruct(sds.shape, sds.dtype,
-                                    sharding=NamedSharding(mesh, spec))
-
-    state = type(state)(placed(state[0], P()), *(
-        placed(buf, P(("pp", "tp"))) for buf in state[1:]))
-    tokens = placed(_sds((1, 4096), jnp.int32), P("dp"))
-    step = make_tp_dp_train_step(model, opt, mesh, donate=True)
+    _, step, args = _cell_step(
+        topo.devices[:1], "solar-open2-250b",
+        lambda config: hybrid_moe.HybridMoE(model_config(
+            config, dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16,
+            recompute_mixers=True)), 1, 4096)
     hybrid_moe.reset_stats()
-    compiled = step.lower(state, tokens, tokens).compile()
+    compiled = step.lower(*args).compile()
     M.destroy_model_parallel()
 
     # what the policy kept: three KDA layers x (q, k, v in front of the convolution and behind
@@ -554,11 +549,7 @@ def test_hybrid_step_fits_with_what_its_mixers_keep(topo, on_chip, monkeypatch):
                            + 4096 * 64 * 4)}
     assert _bytes(compiled) < HBM_BYTES
     text = compiled.as_text()
-    by_name = {}
-    for name in re.findall(
-            r'^\s*%(\S+) = .*custom_call_target="tpu_custom_call"', text,
-            re.M):
-        by_name[name.split(".")[0]] = by_name.get(name.split(".")[0], 0) + 1
+    by_name = _by_base_name(_kernel_names(text))
     assert {k: by_name.get(k) for k in (
         "kda_locals_fwd", "kda_locals_bwd", "conv_stage", "conv_unstage",
         "flash_fwd")} == {"kda_locals_fwd": 6, "kda_locals_bwd": 3,
@@ -567,3 +558,61 @@ def test_hybrid_step_fits_with_what_its_mixers_keep(topo, on_chip, monkeypatch):
     again = {found[name][0] for name in scopes.rematted(text)}
     assert again and all(re.fullmatch(
         r"block[123]/attn/(decay|scan|onorm)", owner) for owner in again)
+
+
+# ------------------- the short-convolution, top-4 expert step -------------------
+
+def test_shortconv_moe_step_compiles_fits_and_is_named(topo, on_chip,
+                                                       monkeypatch):
+    """The benchmark's `lfm2-8b-a1b` step (6 layers, 16 of 32 experts,
+    32,768 vocabulary rows under a tied head, bf16 Adam state, 1 x
+    8192, no recompute, donated state) at the committed v5e tuner
+    entry: 954.5M parameters held, compiled for one v5e with the
+    single-pass flash kernels at 32 query heads on 8 kv heads of 64,
+    the compiler's grouped-matmul kernels and the Adam pass in it,
+    inside the chip's memory, and every scope the new readers name
+    opened."""
+    from apex_tpu.models.shortconv_moe import ShortConvMoE
+    from apex_tpu.monitor import scopes
+    from apex_tpu.parallel import mesh as M
+    from apex_tpu.tune import cache as tune_cache
+    from benchmarks.jobs.shortconv_moe_train import model_config
+
+    monkeypatch.setattr(tune_cache, "device_kind", lambda: "v5e")
+    model, step, args = _cell_step(
+        topo.devices[:1], "lfm2-8b-a1b",
+        lambda config: ShortConvMoE(model_config(
+            config, dtype=jnp.bfloat16, logits_dtype=jnp.bfloat16)), 1, 8192)
+    # one leaf for embedding and head
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 954_523_904
+    assert 954_523_904 <= args[0][1].shape[0] < 954_523_904 * 1.001
+    compiled = step.lower(*args).compile()
+    M.destroy_model_parallel()
+
+    text = compiled.as_text()
+    by_name = _by_base_name(_kernel_names(text))
+    assert {k: v for k, v in by_name.items()
+            if k.startswith(("flash", "adam"))} == {
+        "flash_fwd": 1, "flash_bwd": 1, "adam_flat": 1}
+    # 4 expert layers x 2 grouped GEMMs x (forward, dgrad, wgrad)
+    assert by_name["ragged-dot-none"] == 24
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes - m.alias_size_in_bytes < 2 ** 20
+    # the compiler's count: 5.33 GiB of arguments + 9.54 of temporaries
+    assert 14.0 * GIB < _bytes(compiled) < 15.3 * GIB
+    found = scopes.owners(text)
+    owners = {owner for owner, _, _ in found.values()}
+    assert {f"block{i}/attn/{s}" for i in (0, 1, 3, 4, 5)
+            for s in ("in_proj", "shortconv", "out_proj")} | {
+        f"block2/attn/{s}" for s in ("qkv", "qknorm_rope", "flash",
+                                     "proj")} | {
+        f"block{i}/mlp/{s}" for i in (2, 3, 4, 5)
+        for s in ("router", "dispatch", "experts", "combine")} | {
+        "block0/mlp/gate_up", "block1/mlp/down", "embed", "head",
+        "loss"} <= owners
+    # the grouped GEMMs: under the experts' scope, but for the forward
+    # down-projection, whose one user is the weighted scatter-add
+    grouped = [found[n][0] for n in found if n.startswith("ragged-dot-none")]
+    assert sum(g.endswith("mlp/combine") for g in grouped) == 4
+    assert sum(g.endswith("mlp/experts") for g in grouped) == 20

@@ -447,7 +447,11 @@ def test_step_owners_names_the_latent_attention_block():
         "attn/gate", "attn/conv", "attn/decay", "attn/scan", "attn/onorm",
         # and those of its latent kind over packed documents
         # (test_kimi_linear.py::test_every_instruction_of_the_step_is_owned)
-        "attn/q", "attn/stage", "attn/segments"}
+        "attn/q", "attn/stage", "attn/segments",
+        # the short-convolution block's, which its own step opens
+        # (test_shortconv_moe.py::test_every_instruction_of_the_step_is_owned)
+        "attn/in_proj", "attn/shortconv", "attn/out_proj",
+        "attn/qknorm_rope"}
     assert added == {s.split("/", 1)[1] for s in new} - {
         "attn/flash", "attn/proj"} | {"mlp/gate_up", "mlp/down"}
 
