@@ -5,7 +5,9 @@ vocabulary-parallel embedding, a final RMSNorm, a head over the held
 rows of the vocabulary (a leaf of its own, or, where the config has
 `tie_word_embeddings`, the embedding's leaf read a second time) and
 the fused cross entropy — the expert layer one chip of an
-expert-parallel group holds, built from the config's keys, the small
+expert-parallel group holds, built from the config's keys (none where
+the config routes to no expert: `models.olmo_hybrid.OlmoHybrid`, whose
+every FFN is the dense SwiGLU), the documents of a packed row, the small
 pieces every block uses, the dense SwiGLU of a leading layer, the part
 of grouped-query attention that does not depend on what a model does
 to q and k between the projections and the kernels (the three
@@ -21,7 +23,8 @@ step builder takes (`init`, `apply`, `token_losses`, `loss`,
 `routing_counts`) over the subclass's `_init_block` and `trunk`.
 
 A config gives: vocab_size, hidden, init_std, rms_norm_eps, dtype,
-logits_dtype, fused_xent, axis_name, and of the expert layer
+logits_dtype, fused_xent, axis_name, and, where it routes (a config
+without n_routed_experts routes nowhere), of the expert layer
 moe_intermediate_size, n_routed_experts, experts_first, experts_count,
 num_experts_per_tok, n_shared_experts, routed_scaling_factor,
 norm_topk_prob, router_bias_range and, where it has one,
@@ -29,15 +32,18 @@ expert_rows_factor (`HeldExpertsMLP`'s `rows_factor`) or
 tie_word_embeddings; where it has grouped-query attention, num_heads,
 num_kv_heads, head_dim and flash_override; where it has latent
 attention, num_heads, kv_lora_rank, qk_nope_head_dim,
-qk_rope_head_dim, v_head_dim and flash_override.
+qk_rope_head_dim, v_head_dim and flash_override; where its rows are
+packed documents, eod_token_id and conv_kernel.
 """
 
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from apex_tpu.moe.layer import HeldExpertsMLP
@@ -52,19 +58,32 @@ from apex_tpu.transformer.tensor_parallel.layers import (
 )
 
 
+class Documents(NamedTuple):
+    """What the mixers know of a packed row: `ids` (B, S) int32, a
+    token's document; `first` (B, S) bool, the tokens that start one;
+    `taps[r - 1]` (B, S, 1) float32, 1 where the token r back is of the
+    same document and 0 where it is not, or lies before the row."""
+    ids: jnp.ndarray
+    first: jnp.ndarray
+    taps: Tuple[jnp.ndarray, ...]
+
+
 class HeldExpertsLM:
     def __init__(self, config):
         self.c = c = config
         self.embed = VocabParallelEmbedding(
             c.vocab_size, c.hidden, init_std=c.init_std,
             axis_name=c.axis_name)
-        self.experts = HeldExpertsMLP(
-            c.hidden, c.moe_intermediate_size, c.n_routed_experts,
-            first=c.experts_first, count=c.experts_count,
-            top_k=c.num_experts_per_tok, n_shared=c.n_shared_experts,
-            scale=c.routed_scaling_factor, renormalize=c.norm_topk_prob,
-            init_std=c.init_std, bias_range=c.router_bias_range,
-            rows_factor=getattr(c, "expert_rows_factor", 2.0))
+        self.experts = None         # a config that routes to no expert
+        if getattr(c, "n_routed_experts", 0):
+            self.experts = HeldExpertsMLP(
+                c.hidden, c.moe_intermediate_size, c.n_routed_experts,
+                first=c.experts_first, count=c.experts_count,
+                top_k=c.num_experts_per_tok, n_shared=c.n_shared_experts,
+                scale=c.routed_scaling_factor,
+                renormalize=c.norm_topk_prob, init_std=c.init_std,
+                bias_range=c.router_bias_range,
+                rows_factor=getattr(c, "expert_rows_factor", 2.0))
 
     # ------------------------------ params --------------------------------
     @property
@@ -104,6 +123,33 @@ class HeldExpertsLM:
         return specs
 
     # ------------------------------ forward -------------------------------
+    def documents(self, tokens, i: int = 0) -> Optional[Documents]:
+        """The documents of `tokens` (B, S), for every mixer of the
+        step; None where the config names no `eod_token_id`.  Whoever
+        runs the blocks derives them once; the time is filed under
+        block i, the first it runs."""
+        c = self.c
+        if c.eod_token_id is None:
+            return None
+        with jax.named_scope(f"block{i}"), jax.named_scope("attn"), \
+                jax.named_scope("segments"):
+            # the EOD belongs to the document it closes
+            after_eod = jnp.pad(tokens[:, :-1] == c.eod_token_id,
+                                ((0, 0), (1, 0)))
+            ids = jnp.cumsum(after_eod, axis=1, dtype=jnp.int32)
+            back = lambda r: jnp.pad(ids[:, :-r], ((0, 0), (r, 0)),
+                                     constant_values=-1)
+            return Documents(
+                ids=ids, first=back(1) != ids,
+                taps=tuple((back(r) == ids)[..., None].astype(jnp.float32)
+                           for r in range(1, c.conv_kernel)))
+
+    def _keep(self, x, name):
+        """`x` under the name `name`, for the checkpoint's policy of a
+        stack that has `recompute_mixers` to know it by; `x` itself
+        with the flag false."""
+        return checkpoint_name(x, name) if self.c.recompute_mixers else x
+
     def _norm(self, p, x):
         return fused_rms_norm(x, p["weight"], eps=self.c.rms_norm_eps)
 
@@ -241,12 +287,14 @@ class HeldExpertsLM:
     def routing_counts(self, params, tokens, labels=None):
         """Forward only, without the head: (counts (layers,
         experts_count) int32, overflow (layers,) int32) of every expert
-        layer held."""
+        layer held; (0, 0) and (0,) where none is."""
         return self._counts(self.trunk(params, tokens)[1])
 
     @staticmethod
     def _counts(stats):
         """(counts (layers, experts_count) int32, overflow (layers,)
         int32) of the expert layers' HeldExpertsStats in layer order."""
+        if not stats:
+            return (jnp.zeros((0, 0), jnp.int32), jnp.zeros((0,), jnp.int32))
         return (jnp.stack([s.counts for s in stats]),
                 jnp.stack([s.overflow for s in stats]))

@@ -69,11 +69,10 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.models.held_experts_lm import HeldExpertsLM
 from apex_tpu.ops.conv_stage import stage_conv_heads
@@ -132,16 +131,6 @@ class HybridMoEConfig:
     flash_override: Any = None
     fused_xent: Any = None
     axis_name: str = TP_AXIS
-
-
-class Documents(NamedTuple):
-    """What the mixers know of a packed row: `ids` (B, S) int32, a
-    token's document; `first` (B, S) bool, the tokens that start one;
-    `taps[r - 1]` (B, S, 1) float32, 1 where the token r back is of the
-    same document and 0 where it is not, or lies before the row."""
-    ids: jnp.ndarray
-    first: jnp.ndarray
-    taps: Tuple[jnp.ndarray, ...]
 
 
 # What a checkpointed KDA mixer keeps besides its normed input, by the
@@ -262,32 +251,6 @@ class HybridMoE(HeldExpertsLM):
         return {"ln1": ones(h), "attn": attn, "ln2": ones(h), "mlp": mlp}
 
     # ------------------------------ forward -------------------------------
-    def documents(self, tokens, i: int = 0) -> Optional[Documents]:
-        """The documents of `tokens` (B, S), for every mixer of the
-        step; None where the config names no `eod_token_id`.  Whoever
-        runs the blocks derives them once; the time is filed under
-        block i, the first it runs."""
-        c = self.c
-        if c.eod_token_id is None:
-            return None
-        with jax.named_scope(f"block{i}"), jax.named_scope("attn"), \
-                jax.named_scope("segments"):
-            # the EOD belongs to the document it closes
-            after_eod = jnp.pad(tokens[:, :-1] == c.eod_token_id,
-                                ((0, 0), (1, 0)))
-            ids = jnp.cumsum(after_eod, axis=1, dtype=jnp.int32)
-            back = lambda r: jnp.pad(ids[:, :-r], ((0, 0), (r, 0)),
-                                     constant_values=-1)
-            return Documents(
-                ids=ids, first=back(1) != ids,
-                taps=tuple((back(r) == ids)[..., None].astype(jnp.float32)
-                           for r in range(1, c.conv_kernel)))
-
-    def _keep(self, x, name):
-        """`x` under the name `name`, for the checkpoint's policy to
-        know it by; `x` itself with `recompute_mixers` false."""
-        return checkpoint_name(x, name) if self.c.recompute_mixers else x
-
     def _attention(self, p, a, docs=None):
         """a: (B, S, H), normed.  The gated grouped-query attention's
         output, before the residual add."""

@@ -47,7 +47,11 @@ _SUBLAYERS = ("ln1", "attn", "attn/qkv", "attn/flash", "attn/proj",
               # staging convolution); and, on its layers that attend,
               # q's and k's norm and rotation on the way to the kernels
               "attn/in_proj", "attn/shortconv", "attn/out_proj",
-              "attn/qknorm_rope")
+              "attn/qknorm_rope",
+              # the post-normed hybrid (models/olmo_hybrid.py): its full
+              # attention's norm of q and k over the whole projection,
+              # nothing turned
+              "attn/qknorm")
 # every scope path the program may open; `block{i}` is a layer by index
 # (`_tap` spells it the same way), `block` a layer of a scanned stack
 OWNERS = (

@@ -68,6 +68,18 @@ def round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+LANES = 128          # a vreg's lanes: the last dimension's tile
+
+
+def fills_lane_tiles(d: int) -> bool:
+    """Whether a head `d` wide goes to a kernel whose block's last
+    dimension is the head's whole width, which Mosaic pads to lane
+    tiles in VMEM: a multiple of 32 that fills at least three quarters
+    of its tiles.  96, 192 and the multiples of 128 are what the chip
+    has run; a 64-wide head would leave half of every tile empty."""
+    return d % 32 == 0 and 4 * d >= 3 * round_up(d, LANES)
+
+
 def row_block(rows: int, hidden: int, bytes_per_elt: int = 4,
               vmem_budget: int = 2 * 1024 * 1024, align: int = 8,
               cap: int = 1024) -> int:

@@ -1,26 +1,31 @@
 """Staging for the delta rule's scan: a projection's output becomes the
 scan's head-major operand in one pass, the short convolution included.
 
-Kimi Delta Attention (models/hybrid_moe.py) hands `gated_delta_rule`
-q, k and v that are each `SiLU(conv(a W))`: a causal depthwise
-convolution over time, `taps` weights a channel, a tap dropped where
-the token it reads is of another document of a packed row; q's and k's
-heads scaled to unit length besides, q by `d ** -0.5` more.  The GEMM
-leaves a stream token-major, `(B, S, heads * d)`; the scan reads
-`(B, heads, S, d)`.  Written with `jax.numpy` the way from one to the
-other is a pad, `taps` shifted float32 slices, their masks, a sum,
-SiLU, a reduction over a head's lanes, a scaling and a transpose, and
-the TPU compiler gives them several trips through HBM, 6-10 times the
-bytes' time.  `stage_conv_heads` is that way as one Pallas pass for all
-of a layer's streams: a block of `rows` tokens of `g` heads is read
-where the GEMM wrote it, with the sublane tile of rows before it for
-the taps that reach back, everything between is float32 in registers,
-and the block is written head-major (with 128-wide heads that is the
-block's index map, no transpose).  Its backward, `conv_unstage`, reads
-the cotangent head-major and x, recomputes the sum, and writes dx
-token-major and float32 partial sums of dw (eight rows a block of
-lanes, which a small compiled reduction finishes); it keeps x, w and
-the document ids, nothing float32 and nothing head-major.
+Kimi Delta Attention (models/hybrid_moe.py) and Gated DeltaNet
+(models/olmo_hybrid.py) hand `gated_delta_rule` q, k and v that are
+each `SiLU(conv(a W))`: a causal depthwise convolution over time,
+`taps` weights a channel, a tap dropped where the token it reads is of
+another document of a packed row; q's and k's heads scaled to unit
+length besides, q by `d ** -0.5` more.  The GEMM leaves a stream
+token-major, `(B, S, heads * d)`; the scan reads `(B, heads, S, d)`.
+Written with `jax.numpy` the way from one to the other is a pad,
+`taps` shifted float32 slices, their masks, a sum, SiLU, a reduction
+over a head's lanes, a scaling and a transpose, and the TPU compiler
+gives them several trips through HBM, 6-10 times the bytes' time.
+`stage_conv_heads` is that way as one Pallas pass for all of a layer's
+streams, each at its own head width (KDA's three at 128, Gated
+DeltaNet's q and k at 96 and v at 192): a block of `rows` tokens of
+`g` heads is read where the GEMM wrote it, with the sublane tile of
+rows before it for the taps that reach back, everything between is
+float32 in registers, and the block is written head-major, a head's
+lanes at a time.  A block's `g` heads fill whole lane tiles of every
+stream (four 128-wide heads), or, where no divisor of the heads does
+(30 heads of 96), are all of them: a block may always be as wide as
+the array.  Its backward, `conv_unstage`, reads the cotangent
+head-major and x, recomputes the sum, and writes dx token-major and
+float32 partial sums of dw (eight rows a block of lanes, which a small
+compiled reduction finishes); it keeps x, w and the document ids,
+nothing float32 and nothing head-major.
 
 The pullback of a tap needs the pre-activation's gradient of the
 `taps - 1` tokens *after* a token.  The backward walks a row's blocks
@@ -38,8 +43,9 @@ the result is rounded once, where the body rounds SiLU's output in
 between.
 
 Which path a call takes is read from its input: the kernels where
-`use_pallas` says so, the heads are whole lane tiles wide and S is a
-multiple of the dtype's sublane tile; `stage_conv_heads_reference`,
+`use_pallas` says so, S is a multiple of the dtype's sublane tile and
+every head's width fills its lane tiles (`_common.fills_lane_tiles`:
+96, 192, a multiple of 128); `stage_conv_heads_reference`,
 the `jax.numpy` body, anywhere else, and off the chip.  `stats()`
 counts both while tracing.
 """
@@ -55,11 +61,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.monitor.compile.startup import kernel_span
-from apex_tpu.ops._common import pallas_interpret, use_pallas
+from apex_tpu.ops._common import (
+    LANES,
+    fills_lane_tiles,
+    pallas_interpret,
+    use_pallas,
+)
 
-LANES = 128
 ROWS = 256           # tokens a block
 HEADS = 4            # heads a block
+# the most a stream's float32 block may hold: 256 rows of four
+# 128-wide heads hold an eighth of it, 128 rows of thirty 192-wide heads
+# most of it (on the chip 128 rows there took 5.43 ms a layer forward
+# and backward, 64 rows 6.67, 32 rows 9.13)
+BLOCK_BYTES = 4 * 2 ** 20
 EPS = 1e-12          # under the root of a head's squared length
 
 # calls traced since the last reset, and those that took the kernels
@@ -161,8 +176,18 @@ def _fill_masks(mask_ref, ids_refs, first, last, halo):
 
 def _masks(mask_ref, width):
     """The taps' masks over a block's lanes, by r - 1."""
-    return [jnp.concatenate([mask_ref[r]] * (width // LANES), axis=-1)
+    tiles = -(-width // LANES)
+    return [jnp.concatenate([mask_ref[r]] * tiles, axis=-1)[:, :width]
             for r in range(mask_ref.shape[0])]
+
+
+def _masks_by_width(mask_ref, g, ds):
+    """`_masks` of every stream's width, each built once."""
+    built = {}
+    for d in ds:
+        if g * d not in built:
+            built[g * d] = _masks(mask_ref, g * d)
+    return [built[g * d] for d in ds]
 
 
 # A stream's block is computed at its whole width, g heads side by
@@ -190,20 +215,20 @@ def _conv_silu(before_ref, x_ref, first, w_ref, masks):
     return shifted, y, 1.0 / (1.0 + jnp.exp(-y))
 
 
-def _stage_kernel(*refs, scales, g, d, docs):
+def _stage_kernel(*refs, scales, g, ds, docs):
     n = len(scales)
     refs = list(refs)
     ids = [refs.pop(0) for _ in range(2)] if docs else None
     ins, outs, scratch = refs[:3 * n], refs[3 * n:4 * n], refs[4 * n:]
     dtype = outs[0].dtype
     first = pl.program_id(1) == 0
-    masks = None
+    masks = [None] * n
     if docs:
         _fill_masks(scratch[0], ids, first, None, _halo(dtype))
-        masks = _masks(scratch[0], g * d)
-    for s, scale in enumerate(scales):
+        masks = _masks_by_width(scratch[0], g, ds)
+    for s, (scale, d) in enumerate(zip(scales, ds)):
         _, y, sig = _conv_silu(*ins[3 * s:3 * s + 2], first, ins[3 * s + 2],
-                               masks)
+                               masks[s])
         act = y * sig
         for h in range(g):
             a = act[:, h * d:(h + 1) * d]
@@ -218,33 +243,36 @@ def _fold(x):
     return x.reshape(x.shape[0] // 8, 8, x.shape[1]).sum(axis=0)
 
 
-def _unstage_kernel(*refs, scales, g, d, docs):
+def _unstage_kernel(*refs, scales, g, ds, docs):
     n = len(scales)
     refs = list(refs)
     ids = [refs.pop(0) for _ in range(3)] if docs else None
     ins, outs, scratch = refs[:4 * n], refs[4 * n:6 * n], refs[6 * n:]
-    carry_ref = scratch[0]
+    carry_refs = scratch[:n]       # a stream's, its own width
     rows, dtype = ins[0].shape[2], outs[0].dtype
     halo = _halo(dtype)
     # the grid's last axis walks a row's blocks from its end
     step, steps = pl.program_id(2), pl.num_programs(2)
     first, last = step == steps - 1, step == 0
-    masks = None
+    masks = [None] * n
     if docs:
-        _fill_masks(scratch[1], ids, first, last, halo)
-        masks = _masks(scratch[1], g * d)
+        _fill_masks(scratch[n], ids, first, last, halo)
+        masks = _masks_by_width(scratch[n], g, ds)
 
     @pl.when(last)
     def _():
-        carry_ref[...] = jnp.zeros(carry_ref.shape, carry_ref.dtype)
+        for carry_ref in carry_refs:
+            carry_ref[...] = jnp.zeros(carry_ref.shape, carry_ref.dtype)
         for dw_ref in outs[1::2]:
             dw_ref[...] = jnp.zeros(dw_ref.shape, dw_ref.dtype)
 
-    for s, scale in enumerate(scales):
+    for s, (scale, d) in enumerate(zip(scales, ds)):
         do_ref, before_ref, x_ref, w_ref = ins[4 * s:4 * s + 4]
         dx_ref, dw_ref = outs[2 * s:2 * s + 2]
+        carry_ref = carry_refs[s]
         taps = w_ref.shape[0]
-        shifted, y, sig = _conv_silu(before_ref, x_ref, first, w_ref, masks)
+        shifted, y, sig = _conv_silu(before_ref, x_ref, first, w_ref,
+                                     masks[s])
         grads = [do_ref[0, h].astype(jnp.float32) for h in range(g)]
         if scale is not None:
             act = y * sig
@@ -257,34 +285,41 @@ def _unstage_kernel(*refs, scales, g, d, docs):
             * (sig * (1.0 + y * (1.0 - sig)))
         # with the pre-activation's gradient of the halo rows behind
         # the block, which the step before this one left
-        behind = jnp.concatenate([dy, carry_ref[s]], axis=0)
-        carry_ref[s] = dy[:halo]
+        behind = jnp.concatenate([dy, carry_ref[...]], axis=0)
+        carry_ref[...] = dy[:halo]
         dx = dy * w_ref[taps - 1:taps, :]
         dw_ref[0, taps - 1] += _fold(dy * shifted[0])
         for r in range(1, taps):
             # what token t + r passes the token r before it
-            passed = behind * masks[r - 1] if docs else behind
+            passed = behind * masks[s][r - 1] if docs else behind
             dx = dx + pltpu.roll(passed, rows + halo - r, 0)[:rows] \
                 * w_ref[taps - 1 - r:taps - r, :]
             dw_ref[0, taps - 1 - r] += _fold(passed[:rows] * shifted[r])
         dx_ref[0] = dx.astype(dtype)
 
 
-def _blocks(s, nh, d, taps, dtype):
+def _blocks(s, nh, ds, taps, dtype):
     """(rows, heads) a block, or None where the kernels do not take
-    the shapes: heads that are not whole lane tiles, a row that is no
-    multiple of the dtype's sublane tile, or taps that reach further
-    back than one such tile."""
+    the shapes: a head's width that leaves more than a quarter of its
+    lane tiles empty, a row that is no multiple of the dtype's sublane tile,
+    or taps that reach further back than one such tile.  A block's
+    heads span whole lane tiles of every stream, or are all the row's
+    heads (a block may always be the array's whole width); its rows
+    keep the widest stream's float32 block within BLOCK_BYTES."""
     halo = _halo(dtype)
-    if d % LANES or s % halo or not 1 < taps <= halo + 1:
+    if (s % halo or not 1 < taps <= halo + 1
+            or not all(map(fills_lane_tiles, ds))):
         return None
+    g = next((g for g in (HEADS, 2, 1) if nh % g == 0
+              and all(g * d % LANES == 0 for d in ds)), nh)
     rows = next(r for r in (ROWS, 128, 64, 32, 16, 8)
-                if s % r == 0 and r % halo == 0)
-    return rows, next(g for g in (HEADS, 2, 1) if nh % g == 0)
+                if s % r == 0 and r % halo == 0
+                and (r == halo or 4 * r * g * max(ds) <= BLOCK_BYTES))
+    return rows, g
 
 
 @functools.lru_cache(maxsize=None)
-def _call(backward, b, s, nh, d, taps, scales, docs, rows, g, dtype,
+def _call(backward, b, s, nh, ds, taps, scales, docs, rows, g, dtype,
           interpret):
     halo, f32 = _halo(dtype), jnp.float32
     per, n_blocks, n = rows // halo, s // rows, len(scales)
@@ -296,28 +331,32 @@ def _call(backward, b, s, nh, d, taps, scales, docs, rows, g, dtype,
     def spec(shape, place):
         return pl.BlockSpec(shape, lambda *at: place(*where(*at)))
 
-    flat = spec((1, rows, g * d), lambda i, j, k: (i, j, k))
-    before = spec((1, halo, g * d),
-                  lambda i, j, k: (i, jnp.maximum(j * per - 1, 0), k))
-    heads = spec((1, g, rows, d), lambda i, j, k: (i, k, j, 0))
-    w = spec((taps, g * d), lambda i, j, k: (0, k))
+    flat = [spec((1, rows, g * d), lambda i, j, k: (i, j, k)) for d in ds]
+    before = [spec((1, halo, g * d),
+                   lambda i, j, k: (i, jnp.maximum(j * per - 1, 0), k))
+              for d in ds]
+    heads = [spec((1, g, rows, d), lambda i, j, k: (i, k, j, 0)) for d in ds]
+    w = [spec((taps, g * d), lambda i, j, k: (0, k)) for d in ds]
     ids = [spec((1, halo, 1),
                 lambda i, j, k: (i, jnp.maximum(j * per - 1, 0), 0)),
            spec((1, rows, 1), lambda i, j, k: (i, j, 0)),
            spec((1, halo, 1), lambda i, j, k: (
                i, jnp.minimum((j + 1) * per, s // halo - 1), 0))]
-    kind = dict(scales=scales, g=g, d=d, docs=docs)
+    kind = dict(scales=scales, g=g, ds=ds, docs=docs)
     limit = 64 * 2 ** 20
     if backward:
-        dw = spec((1, taps, 8, g * d), lambda i, j, k: (i, 0, 0, k))
+        dw = [spec((1, taps, 8, g * d), lambda i, j, k: (i, 0, 0, k))
+              for d in ds]
         return pl.pallas_call(
             functools.partial(_unstage_kernel, **kind),
             grid=(b, nh // g, n_blocks),
-            in_specs=(ids if docs else []) + [heads, before, flat, w] * n,
-            out_specs=[flat, dw] * n,
-            out_shape=[jax.ShapeDtypeStruct((b, s, nh * d), dtype),
-                       jax.ShapeDtypeStruct((b, taps, 8, nh * d), f32)] * n,
-            scratch_shapes=[pltpu.VMEM((n, halo, g * d), f32)] + (
+            in_specs=(ids if docs else []) + [
+                x for z in zip(heads, before, flat, w) for x in z],
+            out_specs=[x for z in zip(flat, dw) for x in z],
+            out_shape=[x for d in ds for x in (
+                jax.ShapeDtypeStruct((b, s, nh * d), dtype),
+                jax.ShapeDtypeStruct((b, taps, 8, nh * d), f32))],
+            scratch_shapes=[pltpu.VMEM((halo, g * d), f32) for d in ds] + (
                 [pltpu.VMEM((taps - 1, rows + halo, LANES), f32)]
                 if docs else []),
             compiler_params=pltpu.CompilerParams(
@@ -327,9 +366,10 @@ def _call(backward, b, s, nh, d, taps, scales, docs, rows, g, dtype,
     return pl.pallas_call(
         functools.partial(_stage_kernel, **kind),
         grid=(b, n_blocks, nh // g),
-        in_specs=(ids[:2] if docs else []) + [before, flat, w] * n,
-        out_specs=[heads] * n,
-        out_shape=[jax.ShapeDtypeStruct((b, nh, s, d), dtype)] * n,
+        in_specs=(ids[:2] if docs else []) + [
+            x for z in zip(before, flat, w) for x in z],
+        out_specs=heads,
+        out_shape=[jax.ShapeDtypeStruct((b, nh, s, d), dtype) for d in ds],
         scratch_shapes=[pltpu.VMEM((taps - 1, rows, LANES), f32)]
         if docs else [],
         compiler_params=pltpu.CompilerParams(
@@ -339,10 +379,15 @@ def _call(backward, b, s, nh, d, taps, scales, docs, rows, g, dtype,
 
 def _call_for(backward, xs, ws, ids, how):
     num_heads, scales, rows, g = how
-    b, s, width = xs[0].shape
-    return _call(backward, b, s, num_heads, width // num_heads,
+    b, s, _ = xs[0].shape
+    return _call(backward, b, s, num_heads, _widths(xs, num_heads),
                  ws[0].shape[0], scales, ids is not None, rows, g,
                  jnp.dtype(xs[0].dtype), pallas_interpret())
+
+
+def _widths(xs, num_heads):
+    """A head's width in each stream."""
+    return tuple(x.shape[-1] // num_heads for x in xs)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -378,9 +423,10 @@ _stage.defvjp(_stage_fwd, _stage_bwd)
 def stage_conv_heads(xs, ws, num_heads: int, scales, ids=None, masks=None,
                      *, use_pallas_override=None):
     """The scan's head-major operands `(B, heads, S, d)`, one a stream,
-    from projections' outputs `xs`, each (B, S, heads * d), all of one
-    shape and dtype: `SiLU` of the causal depthwise convolution of a
-    stream with its `ws` (taps, heads * d), tap j weighing the token
+    from projections' outputs `xs`, each (B, S, heads * d) with a d of
+    its own (q and k 96, v 192 in Gated DeltaNet), all of one dtype:
+    `SiLU` of the causal depthwise convolution of a stream with its
+    `ws` (taps, heads * d), tap j weighing the token
     taps - 1 - j back; then, where the stream's `scales` entry is a
     number and not None, a head's d lanes scaled to unit length times
     that number.  `ids` (B, S) int32: a token's document of a packed
@@ -389,13 +435,13 @@ def stage_conv_heads(xs, ws, num_heads: int, scales, ids=None, masks=None,
     `jax.numpy` body then reads; the kernels read `ids`.
     Differentiable in `xs` and `ws`."""
     xs, ws, scales = tuple(xs), tuple(ws), tuple(scales)
-    b, s, width = xs[0].shape
+    b, s, _ = xs[0].shape
     taps = ws[0].shape[0]
     if not len(xs) == len(ws) == len(scales) \
-            or any(x.shape != (b, s, width) or x.dtype != xs[0].dtype
+            or any(x.shape[:2] != (b, s) or x.ndim != 3
+                   or x.shape[-1] % num_heads or x.dtype != xs[0].dtype
                    for x in xs) \
-            or any(w.shape != (taps, width) for w in ws) \
-            or width % num_heads \
+            or any(w.shape != (taps, x.shape[-1]) for x, w in zip(xs, ws)) \
             or (ids is not None and ids.shape != (b, s)):
         raise ValueError(
             f"streams {[x.shape for x in xs]}, taps "
@@ -403,7 +449,8 @@ def stage_conv_heads(xs, ws, num_heads: int, scales, ids=None, masks=None,
             f"{None if ids is None else ids.shape}: not as many (B, S, "
             f"{num_heads} d) of one dtype, (taps, {num_heads} d), and "
             "(B, S)")
-    blocks = (_blocks(s, num_heads, width // num_heads, taps, xs[0].dtype)
+    blocks = (_blocks(s, num_heads, _widths(xs, num_heads), taps,
+                      xs[0].dtype)
               if use_pallas(use_pallas_override) else None)
     _calls["calls"] += 1
     _calls["kernel_calls"] += blocks is not None

@@ -1,5 +1,6 @@
 """The gated delta rule with a per-channel decay (Kimi Delta Attention,
-arXiv:2510.26692), chunkwise-parallel, forward and backward.
+arXiv:2510.26692) or one decay a head (Gated DeltaNet,
+arXiv:2412.06464), chunkwise-parallel, forward and backward.
 
 A head keeps a state `S` of (d_k, d_v), zero before the first token,
 and a token does
@@ -7,10 +8,11 @@ and a token does
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
 
-with `g_t` <= 0 a log-decay a channel of k and `beta_t` a scalar in
-(0, 2).  `gated_delta_rule_reference` is that recurrence, a token at a
-time; nobody trains on it.  `gated_delta_rule` computes the same in
-chunks of `chunk` tokens:
+with `g_t` <= 0 a log-decay a channel of k, or one for every channel
+(g of (B, n, S)), and `beta_t` a scalar in (0, 2).
+`gated_delta_rule_reference` is that recurrence, a token at a time;
+nobody trains on it.  `gated_delta_rule` computes the same in chunks
+of `chunk` tokens:
 
 * inside a chunk (`_locals`, or on the chip the Pallas pair
   `kda_locals_fwd` / `kda_locals_bwd`; every chunk of every head): with
@@ -32,9 +34,14 @@ Decays are only ever applied as `exp` of a difference that is <= 0:
 `exp(G_t - G_i)` of a pair more than a sub-block of 16 tokens apart is
 split at the later sub-block's start into two such factors and the sum
 over channels is a matmul; inside a sub-block the difference is taken
-explicitly, a pair and channel at a time (`_pair_products`).  Nothing
-is divided by a decay, so a channel that forgets everything within a
-chunk costs no accuracy and overflows nowhere.
+explicitly, a pair and channel at a time (`_pair_products`).  With one
+decay a head the op takes g as a channel's decay of one channel, which
+every product broadcasts over d_k, and a chunk's decay is one (C, C)
+matrix `exp(G_t - G_i)`, t >= i, shared by all channels, times the
+products over channels (`_scalar_pair_products`, in the kernels
+`_scalar_pairs`): nothing is split.  Nothing is divided by a decay, so
+a channel that forgets everything within a chunk costs no accuracy and
+overflows nowhere.
 
 The op is a `jax.custom_vjp`.  The forward keeps its inputs and the
 states at the chunks' starts, S / chunk states a head, not S; the
@@ -65,19 +72,22 @@ hundred times its successor visible in the seventh digit.
 `gated_delta_rule_reference` resets exactly.
 
 What runs where, all of it under the caller's `attn/scan` scope.  What
-is local to a chunk is two Pallas kernels on the chip, where the heads
-are whole lane tiles wide (d_k and d_v multiples of 128, a chunk of 32,
-64 or 128: `_kernels_take`): `kda_locals_fwd` reads q, k, v, g, beta
-once and writes the six arrays of `_Locals` once, `kda_locals_bwd`
+is local to a chunk is two Pallas kernels on the chip, at head widths
+that fill three quarters of their lane tiles or more (a block's last
+dimension is a head's whole width: 96-wide keys and 192-wide values go
+as they are, padded to lane tiles in VMEM alone; a 64-wide head does
+not go) and a chunk of 32, 64 or 128 (`_kernels_take`), for either kind
+of decay: `kda_locals_fwd` reads q, k, v, g, beta once and writes the
+six arrays of `_Locals` once, `kda_locals_bwd`
 reads them and the six cotangents and writes the five gradients, and
 nothing between goes through HBM.  Any other call, and every call off
 the chip, takes `_locals`, compiled `jax.numpy` and the kernels'
 oracle.  The scans over chunks and `_outputs` are `jax.numpy` on either
 path.  Matrix products take their operands in the dtype of q (bf16 in
 a bf16 model) and accumulate in float32; running sums of g, decays,
-the sums over channels inside a sub-block, the triangular inverse and
-the state between chunks are float32 whatever q is, in the kernels as
-in `_locals`.
+the sums over channels inside a sub-block (with a head's decay, all of
+them), the triangular inverse and the state between chunks are
+float32 whatever q is, in the kernels as in `_locals`.
 """
 
 from __future__ import annotations
@@ -92,7 +102,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.monitor.compile.startup import kernel_span
-from apex_tpu.ops._common import pallas_interpret, use_pallas
+from apex_tpu.ops._common import (
+    LANES,
+    fills_lane_tiles,
+    pallas_interpret,
+    round_up,
+    use_pallas,
+)
 
 _SUB = 16            # tokens a sub-block: pairs inside one are explicit
 DEFAULT_CHUNK = 64
@@ -102,7 +118,8 @@ RESET_LOG_DECAY = -30.0
 # calls traced since the last reset, the chunk of the last of them and
 # the bytes of chunk-start states their forwards keep for the backward
 _calls = {"calls": 0, "chunk": 0, "saved_state_bytes": 0,
-          "kernel_calls": 0}
+          "kernel_calls": 0, "scalar_calls": 0, "scalar_kernel_calls": 0,
+          "state_elems": 0, "state_lane_elems": 0}
 
 
 def stats():
@@ -111,7 +128,13 @@ def stats():
     the last of them, "saved_state_bytes": the bytes of chunk-start
     states the forwards of those calls keep for their backwards, summed
     over the calls, "kernel_calls": those of "calls" whose chunk-local
-    stage took the Pallas pair}."""
+    stage took the Pallas pair, "scalar_calls": those of "calls" with
+    one decay a head, "scalar_kernel_calls": those of them that took
+    the pair, "state_elems": a head's d_k x d_v state elements, summed
+    over the calls, "state_lane_elems": the elements the state's
+    (d_k, d_v) float32 holds in the (8, 128) tiles it lies in, summed
+    the same way (a head of 96 x 192 holds 96 x 256: 75% of them
+    carry)}."""
     return dict(_calls)
 
 
@@ -120,13 +143,17 @@ def reset_stats():
         _calls[key] = 0
 
 
+
 # ------------------------------ the recurrence ------------------------------
 
 def gated_delta_rule_reference(q, k, v, g, beta, resets=None):
     """The recurrence itself, a token at a time, in float32: the parity
-    oracle.  Shapes as `gated_delta_rule`; the state is set to zero,
-    exactly, before a token `resets` (B, S) marks."""
+    oracle.  Shapes as `gated_delta_rule`, g a channel's (B, n, S, d_k)
+    or a head's (B, n, S); the state is set to zero, exactly, before a
+    token `resets` (B, S) marks."""
     f32 = jnp.float32
+    if g.ndim == 3:
+        g = g[..., None]
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
     keep = (jnp.ones_like(beta) if resets is None else
             jnp.broadcast_to(~resets[:, None], beta.shape).astype(f32))
@@ -220,6 +247,19 @@ def _pair_products(q, k, G, dtype):
             jnp.concatenate(rows_qk, axis=-2))
 
 
+def _scalar_pair_products(q, k, G):
+    """`_pair_products` where the decay is a head's, G (..., C, 1): the
+    chunk's one (C, C) matrix `exp(G_t - G_i)`, t >= i, shared by every
+    channel, times the products over channels, float32."""
+    C = G.shape[-2]
+    lower = jnp.tril(jnp.ones((C, C), bool))
+    diff = G[..., :, :1] - jnp.swapaxes(G, -1, -2)
+    spread = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.)), 0.)
+    hi = lax.Precision.HIGHEST
+    return (jnp.einsum("...td,...id->...ti", k, k, precision=hi) * spread,
+            jnp.einsum("...td,...id->...ti", q, k, precision=hi) * spread)
+
+
 def _tri_inv(a):
     """(I + a)^-1 for a strictly lower triangular, (..., m, m) float32,
     m a power of two: by halves, [[P, 0], [R, Q]]^-1 = [[P^-1, 0],
@@ -258,7 +298,10 @@ def _locals(q, k, v, g, beta, chunk):
     G = jnp.cumsum(g, axis=3)
     fade = jnp.exp(G)
     end = G[..., -1:, :]
-    kk, qk = _pair_products(q, k, G, dtype)
+    if G.shape[-1] == 1:
+        kk, qk = _scalar_pair_products(q, k, G)
+    else:
+        kk, qk = _pair_products(q, k, G, dtype)
     A = beta[..., None] * jnp.tril(kk, -1)
     T = _tri_inv(A)
     w = _mm("...ti,...id->...td", T, beta[..., None] * (k * fade), dtype)
@@ -420,6 +463,44 @@ def _chunks_locals(q, k, v, g, beta, C):
     sums = _block_sums(g, C)
     G, whole = sums[C]
     fade = jnp.exp(G)
+    if g.shape[-1] == 1:
+        kk, qk = _scalar_pairs(by_chunk(q), by_chunk(k), by_chunk(G))
+    else:
+        kk, qk = _channel_pairs(q, k, sums, dtype, C)
+    T = _unit_lower_inverse(by_chunk(beta) * kk).astype(dtype)
+    w = _bmm(T, by_chunk((beta * (k * fade)).astype(dtype)))
+    u = _bmm(T, by_chunk((beta * v).astype(dtype)))
+    return _Locals(
+        qp=by_chunk((q * fade).astype(dtype)),
+        kd=by_chunk((k * jnp.exp(whole - G)).astype(dtype)),
+        decay=jnp.exp(jnp.sum(by_chunk(g), axis=1, keepdims=True)),
+        w=w.astype(dtype), u=u, qk=qk.astype(dtype))
+
+
+def _scalar_pairs(q, k, G):
+    """(kk, qk), (n, C, C), of a head's decay: G (n, C, 1), q and k (n,
+    C, d_k) float32.  The chunk's one matrix `exp(G_t - G_i)`, t >= i,
+    times the products over channels, both float32 at HIGHEST."""
+    n, C, _ = G.shape
+    # G_i along a row: the column across the lanes, turned
+    along = jnp.swapaxes(jnp.broadcast_to(G, (n, C, C)), 1, 2)
+    t = lax.broadcasted_iota(jnp.int32, (n, C, C), 1)
+    i = lax.broadcasted_iota(jnp.int32, (n, C, C), 2)
+    lower = t >= i
+    spread = jnp.where(lower, jnp.exp(jnp.where(lower, G - along, 0.)), 0.)
+    both = _pairs(jnp.concatenate([k, q], axis=1), k, precision=_HI)
+    # kk below the diagonal only, as the inverse reads it: its pullback
+    # returns a whole matrix
+    return jnp.where(t > i, both[:, :C] * spread, 0.), both[:, C:] * spread
+
+
+def _channel_pairs(q, k, sums, dtype, C):
+    """(kk, qk), (n, C, C), of a channel's decay: by halves inside a
+    sub-block, split at the later one's start between them."""
+    f32 = jnp.float32
+    n = q.shape[0] // C
+    by_chunk = lambda x: x.reshape(n, C, x.shape[-1])
+    G = sums[C][0]
     # inside a sub-block, float32, by halves; the diagonal is no decay
     kk = jnp.zeros((n, C, C), f32)
     qk = jnp.where(_eye(C),
@@ -446,14 +527,7 @@ def _chunks_locals(q, k, v, g, beta, C):
                         axis=1, keepdims=True)
         right = by_chunk(k) * jnp.exp(jnp.minimum(start - by_chunk(G), 0.))
         kk, qk = add(_across(C, a), left, right.astype(dtype))
-    T = _unit_lower_inverse(by_chunk(beta) * kk).astype(dtype)
-    w = _bmm(T, by_chunk((beta * (k * fade)).astype(dtype)))
-    u = _bmm(T, by_chunk((beta * v).astype(dtype)))
-    return _Locals(
-        qp=by_chunk((q * fade).astype(dtype)),
-        kd=by_chunk((k * jnp.exp(whole - G)).astype(dtype)),
-        decay=jnp.exp(jnp.sum(by_chunk(g), axis=1, keepdims=True)),
-        w=w.astype(dtype), u=u, qk=qk.astype(dtype))
+    return kk, qk
 
 
 # a grid step's blocks: the inputs and their gradients head-major, (1,
@@ -478,12 +552,14 @@ def _locals_bwd_kernel(*refs, C):
         ref[0] = x.reshape(ref.shape[1:])
 
 
-def _locals_call(backward, q, v, chunk):
-    """The `pl.pallas_call` of a direction at q's and v's shapes: from
-    q, k, v, g, beta (then `_Locals`' six cotangents) to `_Locals`' six
-    (or the five gradients)."""
+def _locals_call(backward, q, v, g, chunk):
+    """The `pl.pallas_call` of a direction at q's, v's and g's shapes:
+    from q, k, v, g, beta (then `_Locals`' six cotangents) to `_Locals`'
+    six (or the five gradients).  A block's last dimension is a head's
+    whole width, as `_kernels_take` admits it, and g's is d_k or, a
+    head's decay, 1."""
     b, n, s, dk = q.shape
-    bn, n_chunks, dv = b * n, s // chunk, v.shape[-1]
+    bn, n_chunks, dv, dg = b * n, s // chunk, v.shape[-1], g.shape[-1]
     nc = next(c for c in (_CHUNKS, 4, 2, 1) if n_chunks % c == 0)
     dtype, f32 = jnp.dtype(q.dtype), jnp.float32
 
@@ -497,9 +573,9 @@ def _locals_call(backward, q, v, chunk):
 
     ins = [head_major(*x) for x in (
         ((chunk, dk), dtype), ((chunk, dk), dtype), ((chunk, dv), dtype),
-        ((chunk, dk), f32), ((chunk, 1), f32))]
+        ((chunk, dg), f32), ((chunk, 1), f32))]
     locs = _Locals(*(chunk_major(*x) for x in (
-        ((chunk, dk), dtype), ((chunk, dk), dtype), ((1, dk), f32),
+        ((chunk, dk), dtype), ((chunk, dk), dtype), ((1, dg), f32),
         ((chunk, dk), dtype), ((chunk, dv), f32), ((chunk, chunk), dtype))))
     reads, writes = (ins + list(locs), ins) if backward else (ins, locs)
     how = dict(
@@ -538,7 +614,7 @@ def _locals_kernels(q, k, v, g, beta, chunk):
 def _locals_kernels_fwd(q, k, v, g, beta, chunk):
     b, n = q.shape[:2]
     with kernel_span("kda_locals_fwd"):
-        locs = _locals_call(False, q, v, chunk)(
+        locs = _locals_call(False, q, v, g, chunk)(
             *_cut(q, k, v, g, beta, chunk))
     loc = _Locals(*(
         x.reshape(x.shape[0], b, n, *x.shape[2:]) for x in locs))
@@ -546,10 +622,10 @@ def _locals_kernels_fwd(q, k, v, g, beta, chunk):
 
 
 def _locals_kernels_bwd(chunk, res, d_loc):
-    q, _, v = res[:3]
+    q, _, v, g = res[:4]
     d_loc = d_loc._replace(decay=d_loc.decay[..., None, :])
     with kernel_span("kda_locals_bwd"):
-        grads = _locals_call(True, q, v, chunk)(
+        grads = _locals_call(True, q, v, g, chunk)(
             *_cut(*res, chunk),
             *(x.reshape(x.shape[0], -1, *x.shape[3:]) for x in d_loc))
     return tuple(dx.reshape(x.shape).astype(x.dtype)
@@ -561,11 +637,14 @@ _locals_kernels.defvjp(_locals_kernels_fwd, _locals_kernels_bwd)
 
 def _kernels_take(q, v, chunk, override):
     """Whether a call's chunk-local stage takes the Pallas pair: on the
-    chip (or where a test says so), heads whose widths fill whole lane
-    tiles and a chunk the kernels are written for."""
-    return (use_pallas(override) and q.shape[-1] % 128 == 0
-            and v.shape[-1] % 128 == 0 and v.dtype == q.dtype
-            and chunk in _KERNEL_CHUNKS)
+    chip (or where a test says so), q and v of one dtype, a chunk the
+    kernels are written for and heads whose widths fill their lane
+    tiles (`fills_lane_tiles`: a block's last dimension is a head's
+    whole width, which Mosaic takes as the array's own and pads to lane
+    tiles in VMEM alone)."""
+    return (use_pallas(override) and v.dtype == q.dtype
+            and chunk in _KERNEL_CHUNKS and fills_lane_tiles(q.shape[-1])
+            and fills_lane_tiles(v.shape[-1]))
 
 
 # ------------------------------ between chunks ------------------------------
@@ -658,9 +737,11 @@ def gated_delta_rule(q, k, v, g, beta, *, resets=None,
                      heads_a_pass: Optional[int] = None,
                      use_pallas_override: Optional[bool] = None):
     """o (B, n, S, d_v) of the gated delta rule over head-major q, k
-    (B, n, S, d_k), v (B, n, S, d_v), the log-decay g (B, n, S, d_k),
-    <= 0, and beta (B, n, S); every head starts from a zero state.  o
-    has v's dtype; g and beta are best handed over in float32.
+    (B, n, S, d_k), v (B, n, S, d_v), the log-decay g, <= 0, of a
+    channel (B, n, S, d_k) or of a head (B, n, S), and beta (B, n, S);
+    every head starts from a zero state.  o has v's dtype; g and beta
+    are best handed over in float32, and g's gradient comes back in
+    g's shape.
 
     `resets`: (B, S) bool, true at the tokens that start a document of
     a packed row: a head's state is zero before each, and g gets no
@@ -681,18 +762,26 @@ def gated_delta_rule(q, k, v, g, beta, *, resets=None,
     need not hold together (a tuned config's `heads`, if it has one;
     None is every head in one call).
 
-    On the chip the chunk-local stage of a call with 128-wide heads is
-    the Pallas pair `kda_locals_fwd` / `kda_locals_bwd`, elsewhere
-    compiled `jax.numpy` (`stats()["kernel_calls"]` counts the former);
+    On the chip the chunk-local stage of a call at a chunk of 32, 64
+    or 128 is the Pallas pair `kda_locals_fwd` / `kda_locals_bwd` where
+    the heads' widths fill their lane tiles (96, 192, a multiple of
+    128: `_kernels_take`), elsewhere compiled `jax.numpy`
+    (`stats()["kernel_calls"]` counts the former, "scalar_calls" and
+    "scalar_kernel_calls" the calls with a head's decay);
     `use_pallas_override` is for the tests: True runs the kernels in
     interpret mode off the chip, False keeps `jax.numpy` on it."""
     b, n, s, dk = q.shape
-    if k.shape != q.shape or g.shape != q.shape or v.shape[:3] != (b, n, s) \
-            or beta.shape != (b, n, s):
+    if k.shape != q.shape or g.shape not in (q.shape, (b, n, s)) \
+            or v.shape[:3] != (b, n, s) or beta.shape != (b, n, s):
         raise ValueError(
             f"shapes q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}, "
             f"beta {beta.shape} are not (B, n, S, d_k) x 2, (B, n, S, "
-            "d_v), (B, n, S, d_k), (B, n, S)")
+            "d_v), (B, n, S, d_k) or (B, n, S), (B, n, S)")
+    scalar = g.ndim == 3
+    if scalar:
+        # a head's decay is a channel's of one channel, which every
+        # product broadcasts over d_k; its gradient comes back (B, n, S)
+        g = g[..., None]
     if resets is not None:
         if resets.shape != (b, s):
             raise ValueError(f"resets {resets.shape} is not (B, S) = "
@@ -717,6 +806,11 @@ def gated_delta_rule(q, k, v, g, beta, *, resets=None,
     _calls["chunk"] = chunk
     _calls["saved_state_bytes"] += 4 * b * n * (s // chunk) * dk * v.shape[-1]
     _calls["kernel_calls"] += kernels
+    _calls["scalar_calls"] += scalar
+    _calls["scalar_kernel_calls"] += scalar and kernels
+    _calls["state_elems"] += dk * v.shape[-1]
+    _calls["state_lane_elems"] += round_up(dk, 8) * round_up(v.shape[-1],
+                                                             LANES)
     if heads_a_pass in (None, n):
         return _delta_rule(q, k, v, g, beta, chunk, kernels)
     if heads_a_pass < 1 or n % heads_a_pass:

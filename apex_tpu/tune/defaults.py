@@ -144,6 +144,22 @@ def _v5e_entries():
         "resets: chunk 64 33.77 ms in one call of 32 heads (34.98 in "
         "two of 16), chunk 32 37.39 (39.77), chunk 128 38.45 (39.89); "
         "chunk 64 without resets 32.97")
+    # one row of 8,192 packed tokens of the Olmo-Hybrid cell
+    # (models/olmo_hybrid.py): the delta rule with one decay a head at
+    # keys 96 and values 192, with its resets, and its full attention,
+    # 30 heads of 128, under the segment mask
+    e[make_key("delta_rule", tune.delta_rule_attrs(
+        1, 30, 8192, 96, 192, "bfloat16"))] = _mk(
+        {"chunk": 128, "heads": 10},
+        "v5e chip run, forward + backward a layer with a row's "
+        "resets: chunk 128 in three calls of 10 heads 28.81 ms, of 15 "
+        "30.37, one call of 30 29.78; chunk 64 31.25-31.62; chunk 32 "
+        "not swept: the step does not fit the chip at it")
+    e[_flash(1, 30, 8192, 8192, 128, "bfloat16", True, seg=True)] = _mk(
+        {"block_q": 2048, "block_k": 512, "fused_bwd": True},
+        "v5e chip run, forward + backward a layer under the "
+        "segment mask: 14.29 ms; fused (1024, 1024) 14.80, (1024, 512) "
+        "16.08, (512, 512) 19.87; two kernels 18.91-23.92")
     # grouped-query attention at 64-wide heads, 32 query heads on 8 kv
     # heads, one row of 8,192 (models/shortconv_moe.py at the
     # benchmark's seventh cell): the single pass again, at the blocks of

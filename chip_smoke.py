@@ -213,7 +213,8 @@ def gqa_flash_case(name, batch, heads, kv_heads, seq, head_dim,
 
 
 def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
-                    documents: bool = False) -> KernelCase:
+                    documents: bool = False, value_dim=None,
+                    scalar: bool = False) -> KernelCase:
     """The chunked gated delta rule (ops/delta_rule.py) in bf16 at the
     benchmark's shape, forward and all five gradients, against the
     recurrence a token at a time in float32.  Inputs as KDA makes them:
@@ -221,8 +222,11 @@ def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
     -A softplus(.) with A in (1, 16) and a time step in (1e-3, 1e-1),
     beta in (0, 2), or in (0, 1) with `documents`: then a row is packed
     documents, the op is handed their first tokens as `resets` and the
-    recurrence sets its state to 0 there, exactly.  The reference walks
-    the heads a few at a time: its backward keeps a state a token."""
+    recurrence sets its state to 0 there, exactly.  With `scalar` as
+    Gated DeltaNet makes them: one log-decay a head and token, beta in
+    (0, 2) with documents too, values `value_dim` wide.  The reference
+    walks the heads a few at a time: its backward keeps a state a
+    token."""
     import jax
     import jax.numpy as jnp
 
@@ -232,6 +236,8 @@ def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
     )
 
     shape = (batch, heads, seq, dim)
+    values = (batch, heads, seq, value_dim or dim)
+    decays = shape[:3] + ((1,) if scalar else (dim,))
 
     def make_args(key):
         ks = jax.random.split(key, 8)
@@ -242,15 +248,16 @@ def delta_rule_case(name, batch, heads, seq, dim, heads_a_pass=4,
                     ).astype(jnp.bfloat16)
         rate = jax.random.uniform(ks[3], (1, heads, 1, 1), jnp.float32, 1, 16)
         dt = jnp.exp(jax.random.uniform(
-            ks[4], (1, heads, 1, dim), jnp.float32, math.log(1e-3),
+            ks[4], (1, heads, 1, decays[-1]), jnp.float32, math.log(1e-3),
             math.log(1e-1)))
         g = -rate * jax.nn.softplus(
-            jnp.log(jnp.expm1(dt)) + 0.3 * jax.random.normal(ks[5], shape))
+            jnp.log(jnp.expm1(dt)) + 0.3 * jax.random.normal(ks[5], decays))
         beta = jax.nn.sigmoid(jax.random.normal(ks[6], shape[:3]))
         args = (unit(ks[0], dim ** -0.5), unit(ks[1]),
-                jax.random.normal(ks[2], shape, jnp.bfloat16), g,
-                beta if documents else 2 * beta,
-                jax.random.normal(ks[7], shape, jnp.bfloat16))
+                jax.random.normal(ks[2], values, jnp.bfloat16),
+                g[..., 0] if scalar else g,
+                beta if documents and not scalar else 2 * beta,
+                jax.random.normal(ks[7], values, jnp.bfloat16))
         if documents:
             args += (_seeded_documents(jax.random.fold_in(ks[5], 1), batch,
                                        seq)[1],)
@@ -293,7 +300,9 @@ def conv_stage_case(name, shapes) -> KernelCase:
     the three head-major outputs and the gradients of the streams and
     of the taps, at each of `shapes` = (batch, heads, seq, dim,
     documents), the two hybrid cells'; with `documents` a row is packed
-    documents and the taps stop at their first tokens."""
+    documents and the taps stop at their first tokens; `dim` a head's
+    width in all three streams, or a (q, k, v) triple of them, Gated
+    DeltaNet's."""
     import jax
     import jax.numpy as jnp
 
@@ -306,13 +315,16 @@ def conv_stage_case(name, shapes) -> KernelCase:
         args = []
         for i, (batch, heads, seq, dim, documents) in enumerate(shapes):
             ks = jax.random.split(jax.random.fold_in(key, i), 10)
-            wide = heads * dim
-            xs = tuple(jax.random.normal(k, (batch, seq, wide), jnp.bfloat16)
-                       for k in ks[:3])
-            ws = tuple((jax.random.uniform(k, (4, wide), minval=-1.0) / 2
-                        ).astype(jnp.bfloat16) for k in ks[3:6])
+            dims = _three(dim)
+            xs = tuple(jax.random.normal(k, (batch, seq, heads * d),
+                                         jnp.bfloat16)
+                       for k, d in zip(ks[:3], dims))
+            ws = tuple((jax.random.uniform(k, (4, heads * d), minval=-1.0)
+                        / 2).astype(jnp.bfloat16)
+                       for k, d in zip(ks[3:6], dims))
             cots = tuple(jax.random.normal(
-                k, (batch, heads, seq, dim), jnp.bfloat16) for k in ks[6:9])
+                k, (batch, heads, seq, d), jnp.bfloat16)
+                for k, d in zip(ks[6:9], dims))
             ids = _seeded_documents(ks[9], batch, seq)[0] if documents \
                 else None
             args.append((xs, ws, cots, ids))
@@ -321,7 +333,8 @@ def conv_stage_case(name, shapes) -> KernelCase:
     def fwd_bwd(stage, dtype, xs, ws, cots, ids, heads, dim):
         cast = lambda t: tuple(x.astype(dtype) for x in t)
         outs, pull = jax.vjp(
-            lambda xs, ws: stage(xs, ws, heads, (dim ** -0.5, 1.0, None),
+            lambda xs, ws: stage(xs, ws, heads,
+                                 (_three(dim)[0] ** -0.5, 1.0, None),
                                  ids=ids), cast(xs), cast(ws))
         dxs, dws = pull(cast(cots))
         return (*outs, *dxs, *dws)
@@ -337,6 +350,12 @@ def conv_stage_case(name, shapes) -> KernelCase:
         name, make_args, lambda *a: run(stage_conv_heads, jnp.bfloat16, *a),
         lambda *a: run(stage_conv_heads_reference, jnp.float32, *a),
         5e-2, 0.2, in_rms=True)
+
+
+def _three(dim):
+    """A head's width in q, k and v: one for all three, or each its
+    own."""
+    return tuple(dim) if isinstance(dim, tuple) else (dim,) * 3
 
 
 def short_conv_case(name, batch, seq, hidden, taps=3) -> KernelCase:
@@ -801,6 +820,15 @@ def kernel_cases(device) -> list:
         # unit scaling and the head-major order in one pass a direction
         conv_stage_case("conv_stage_grads", [(1, 64, 4096, 128, False),
                                              (1, 32, 8192, 128, True)]),
+        # Gated DeltaNet's at the Olmo-Hybrid cell's shapes
+        # (models/olmo_hybrid.py): one decay a head, keys 96 and values
+        # 192 wide on the Pallas pair over a packed row of 8,192, and
+        # the conv stage at its two widths in one pass
+        delta_rule_case("gdn_delta_rule_docs_grads", 1, 30, 8192, 96,
+                        heads_a_pass=3, documents=True, value_dim=192,
+                        scalar=True),
+        conv_stage_case("gdn_conv_stage_grads",
+                        [(1, 30, 8192, (96, 96, 192), True)]),
         # one chip's 16 of 256 experts over 8,192 tokens, 8 a token
         held_experts_case("moe_held_experts", 8192, 2048, 768, 256, 16, 8),
         # one chip's 8 of 320 experts of 1280 over 4,096 tokens, a

@@ -17,8 +17,9 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 Q, K, V = D ** -0.5, 1.0, None      # the scales of KDA's three streams
 
 # name -> (dtype, heads, tokens a block, the tokens that start a
-# document (None: no ids), the streams' scales).  A row is three blocks;
-# a block of the kernels is as long as the dtype's halo allows.
+# document (None: no ids), the streams' scales[, a head's width in each
+# stream, D where not given]).  A row is three blocks; a block of the
+# kernels is as long as the dtype's halo allows.
 CASES = {
     "f32-qkv": (F32, 2, 16, None, (Q, K, V)),
     "f32-qkv-docs": (F32, 2, 16, (5, 16, 33, 34, 35), (Q, K, V)),
@@ -34,19 +35,25 @@ CASES = {
     "bf16-qkv": (BF16, 2, 32, None, (Q, K, V)),
     "bf16-qkv-docs": (BF16, 4, 32, (31, 32, 65, 66), (Q, K, V)),
     "bf16-unscaled": (BF16, 2, 32, (33,), (V,)),
+    # Gated DeltaNet's two widths in one pass: 2 heads of 96 span no
+    # whole lane tiles but both together, which a block then holds
+    "f32-96-96-192-docs": (F32, 2, 16, (5, 16, 33, 34), (96 ** -0.5, K, V),
+                           (96, 96, 192)),
 }
 TOL = {F32: 1e-5, BF16: 2.0 ** -6}     # of an output's largest entry
 
 
-def _inputs(dtype, heads, rows, starts, scales, batch=2, seed=0):
+def _inputs(dtype, heads, rows, starts, scales, widths=None, batch=2,
+            seed=0):
     s, n = 3 * rows, len(scales)
+    widths = widths or (D,) * n
     ks = jax.random.split(jax.random.PRNGKey(seed), 3 * n)
-    xs = tuple(jax.random.normal(k, (batch, s, heads * D)).astype(dtype)
-               for k in ks[:n])
-    ws = tuple((jax.random.uniform(k, (4, heads * D), minval=-1.0) / 2
-                ).astype(dtype) for k in ks[n:2 * n])
-    cots = tuple(jax.random.normal(k, (batch, heads, s, D)).astype(dtype)
-                 for k in ks[2 * n:])
+    xs = tuple(jax.random.normal(k, (batch, s, heads * d)).astype(dtype)
+               for k, d in zip(ks[:n], widths))
+    ws = tuple((jax.random.uniform(k, (4, heads * d), minval=-1.0) / 2
+                ).astype(dtype) for k, d in zip(ks[n:2 * n], widths))
+    cots = tuple(jax.random.normal(k, (batch, heads, s, d)).astype(dtype)
+                 for k, d in zip(ks[2 * n:], widths))
     ids = None
     if starts is not None:
         first = jnp.zeros((batch, s), jnp.int32).at[:, list(starts)].set(1)
@@ -60,8 +67,9 @@ def _inputs(dtype, heads, rows, starts, scales, batch=2, seed=0):
 def _both(case):
     """{kernels: (outs, dxs, dws)} of a case, by the Pallas pair
     (interpret mode) and by `jax.numpy`."""
-    dtype, heads, rows, starts, scales = CASES[case]
-    xs, ws, cots, ids = _inputs(dtype, heads, rows, starts, scales)
+    dtype, heads, rows, starts, scales, *widths = CASES[case]
+    xs, ws, cots, ids = _inputs(dtype, heads, rows, starts, scales,
+                                *widths)
     was = CS.ROWS
     CS.ROWS = rows
     try:
@@ -204,3 +212,17 @@ def test_the_models_scan_inputs_are_the_same_by_either_path(packed):
     for g, w in zip(got, want, strict=True):
         assert g.shape == w.shape and g.dtype == w.dtype
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+def test_a_stages_blocks_span_whole_lane_tiles_of_every_stream():
+    """Heads a block: the most of (4, 2, 1) that fill whole lane tiles
+    in every stream, else all the row's heads, a block as wide as the
+    array; rows as many as keep the widest float32 block in
+    BLOCK_BYTES.  KDA's cells keep (256, 4)."""
+    assert CS._blocks(4096, 64, (128,) * 3, 4, BF16) == (256, 4)
+    assert CS._blocks(8192, 32, (128,) * 3, 4, BF16) == (256, 4)
+    assert CS._blocks(8192, 30, (96, 96, 192), 4, BF16) == (128, 30)
+    assert CS._blocks(8192, 8, (96, 96), 4, BF16) == (256, 4)
+    assert CS._blocks(8192, 6, (96, 192), 4, BF16) == (256, 6)
+    assert CS._blocks(8192, 8, (64, 128), 4, BF16) is None
+    assert CS._blocks(40, 2, (D,), 4, BF16) is None

@@ -120,11 +120,11 @@ def test_stats_count_calls_chunk_and_saved_states():
     assert DR.stats() == {
         "calls": 2, "chunk": 64,
         "saved_state_bytes": 4 * 2 * 3 * 16 * 8 * (128 // 16 + 128 // 64),
-        "kernel_calls": 0}
+        "kernel_calls": 0, "scalar_calls": 0, "scalar_kernel_calls": 0,
+        "state_elems": 2 * 16 * 8, "state_lane_elems": 2 * 16 * 128}
     assert list(DR.stats())[:2] == ["calls", "chunk"]
     DR.reset_stats()
-    assert DR.stats() == {"calls": 0, "chunk": 0, "saved_state_bytes": 0,
-                          "kernel_calls": 0}
+    assert set(DR.stats().values()) == {0}
 
 
 def test_the_chunk_comes_from_the_tuner_then_the_default(monkeypatch):
@@ -162,6 +162,21 @@ def test_the_committed_v5e_chunk_is_the_benchmark_shapes(heads, seq):
     assert DR._kernels_take(
         jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16),
         jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16),
+        config["chunk"], True)
+
+
+def test_the_committed_v5e_config_of_a_heads_decay_at_96_and_192():
+    """The Olmo-Hybrid cell's scan finds its measured entry: chunk 128,
+    ten heads a call, on the Pallas pair at its widths."""
+    from apex_tpu.tune import defaults
+
+    key = tune.make_key("delta_rule", tune.delta_rule_attrs(
+        1, 30, 8192, 96, 192, "bfloat16"))
+    config = defaults.DEFAULTS["v5e"][key]["config"]
+    assert config == {"chunk": 128, "heads": 10} and 30 % config["heads"] == 0
+    assert DR._kernels_take(
+        jax.ShapeDtypeStruct((1, 30, 8192, 96), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1, 30, 8192, 192), jnp.bfloat16),
         config["chunk"], True)
 
 
@@ -303,9 +318,12 @@ def test_a_channel_that_forgets_everything_through_the_kernels(chunk):
     ids=["128", "128-chunk32", "16x8", "8x8", "128x64", "128-chunk16"])
 def test_only_whole_lane_tiles_and_a_written_chunk_take_the_kernels(
         shape, chunk, kernels):
-    """Whatever the override says, a head narrower than a lane tile or
-    a chunk the kernels are not written for takes the compiled stage
-    and counts no kernel call."""
+    """Whatever the override says, a head that leaves more than a
+    quarter of its lane tiles empty (8, 16, 64 wide) or a chunk the
+    kernels are not written for takes the compiled stage and counts no
+    kernel call.  96 and 192 are the widths below a whole tile that
+    go (`test_the_committed_v5e_config_of_a_heads_decay_at_96_and_192`,
+    `test_the_counters_of_a_heads_decay`)."""
     args, _ = _inputs(2, b=1, n=2, s=128, **shape)
     DR.reset_stats()
     text = str(jax.make_jaxpr(_forced(chunk))(*args))
@@ -464,3 +482,112 @@ def test_resets_of_another_shape_are_refused():
     args, _ = _inputs(4, s=64)
     with pytest.raises(ValueError, match="resets"):
         DR.gated_delta_rule(*args, resets=jnp.zeros((2, 32), bool), chunk=32)
+
+
+# -------------------- one decay a head (Gated DeltaNet) --------------------
+# g of (B, n, S): the op as it takes a channel's decay of one channel,
+# against the same decay broadcast over d_k and the recurrence, keys
+# and values of different widths
+
+SCALAR = dict(b=1, n=2, s=128, dk=16, dv=32)
+
+
+def _scalar_inputs(seed, **shape):
+    """`_inputs` with one log-decay a head and token, beta in (0, 2)."""
+    args, do = _inputs(seed, **{**SCALAR, **shape})
+    g = -2.0 * jax.nn.softplus(jax.random.normal(
+        jax.random.PRNGKey(seed + 100), args[4].shape) - 2)
+    return args[:3] + (g,) + args[4:], do
+
+
+@pytest.fixture(scope="module")
+def scalar_runs():
+    """{resets: (a head's decay, the recurrence, the channel decay
+    broadcast inside the differentiated function)}, each (o, dq, dk,
+    dv, dg, dbeta), dg of (B, n, S); the compiled stage (the kernels are
+    held to it below, at 96 / 192)."""
+    out = {}
+    for resets in (False, True):
+        args, do = _scalar_inputs(5 + resets)
+        first = _resets(1, 128, (0, 17, 64, 100)) if resets else None
+        rule = lambda *a: DR.gated_delta_rule(*a, resets=first, chunk=32)
+        out[resets] = (
+            _fwd_bwd(rule, args, do),
+            _fwd_bwd(lambda *a: DR.gated_delta_rule_reference(
+                *a, resets=first), args, do),
+            _fwd_bwd(lambda q, k, v, g, beta: rule(
+                q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta),
+                args, do))
+    return out
+
+
+@pytest.mark.parametrize("what", range(6), ids=NAMES)
+@pytest.mark.parametrize("resets", [False, True], ids=["row", "documents"])
+def test_a_heads_decay_is_the_channels_broadcast_and_the_recurrence(
+        scalar_runs, resets, what):
+    """float32: the (C, C) decay matrix against the token recurrence
+    (with resets, its exact zero against the pinned decay) and against
+    the per-channel pair products over the same decay broadcast; the
+    bands of the per-channel tests above."""
+    got, want, chan = scalar_runs[resets]
+    assert got[what].shape == want[what].shape
+    scale = float(jnp.max(jnp.abs(want[what])))
+    for other in (want, chan):
+        np.testing.assert_allclose(got[what], other[what], rtol=1e-4,
+                                   atol=2e-5 * max(scale, 1.0))
+
+
+@pytest.fixture(scope="module")
+def narrow_stages():
+    """(kernels, jax.numpy): `_Locals` and the five gradients of a
+    head's decay with keys 96 and values 192 wide, bf16, chunk 32."""
+    args, _ = _scalar_inputs(21, n=1, s=64, dk=96, dv=192,
+                             dtype=jnp.bfloat16)
+    args = args[:3] + (args[3][..., None],) + args[4:]
+
+    def run(stage, args):
+        loc, pull = jax.vjp(lambda *a: stage(*a, 32), *args)
+        keys = jax.random.split(jax.random.PRNGKey(9), len(loc))
+        cot = DR._Locals(*(jax.random.normal(k, x.shape).astype(x.dtype)
+                           for k, x in zip(keys, loc)))
+        return tuple(loc), pull(cot)
+    return (jax.jit(functools.partial(run, DR._locals_kernels))(args),
+            jax.jit(functools.partial(run, DR._locals))(args))
+
+
+@pytest.mark.parametrize("what", range(11), ids=LOCALS + GRADS)
+def test_96_and_192_wide_heads_through_the_pallas_pair(narrow_stages, what):
+    """The pair interpreted at Gated DeltaNet's widths against the
+    compiled stage, the six of `_Locals` and the five gradients: the
+    bf16 band of the 128-wide tests above."""
+    (loc, grads), (want_loc, want_grads) = narrow_stages
+    got, want = (loc + grads)[what], (want_loc + want_grads)[what]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    _close(got, want, 8e-3)
+
+
+def test_the_counters_of_a_heads_decay():
+    """`scalar_calls` and `scalar_kernel_calls` count the calls with a
+    head's decay and those of them on the pair; the state's elements a
+    head against those of the (8, 128) tiles the float32 state lies
+    in: 192 values take two tiles of lanes, 75% of them carry."""
+    args, _ = _scalar_inputs(3, dk=96, dv=192)
+    DR.reset_stats()
+    jax.eval_shape(lambda *a: DR.gated_delta_rule(
+        *a, chunk=32, use_pallas_override=True), *args)
+    jax.eval_shape(lambda *a: DR.gated_delta_rule(*a, chunk=32), *args)
+    wide = args[:3] + (jnp.broadcast_to(args[3][..., None],
+                                        args[0].shape),) + args[4:]
+    jax.eval_shape(lambda *a: DR.gated_delta_rule(*a, chunk=32), *wide)
+    stats = DR.stats()
+    assert (stats["calls"], stats["kernel_calls"]) == (3, 1)
+    assert (stats["scalar_calls"], stats["scalar_kernel_calls"]) == (2, 1)
+    assert stats["state_elems"] == 3 * 96 * 192
+    assert stats["state_lane_elems"] == 3 * 96 * 256
+    DR.reset_stats()
+
+
+def test_a_decay_of_neither_shape_is_refused():
+    args, _ = _scalar_inputs(4)
+    with pytest.raises(ValueError, match="shapes"):
+        DR.gated_delta_rule(*args[:3], args[3][:, :1], args[4], chunk=32)

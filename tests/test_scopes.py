@@ -451,7 +451,10 @@ def test_step_owners_names_the_latent_attention_block():
         # the short-convolution block's, which its own step opens
         # (test_shortconv_moe.py::test_every_instruction_of_the_step_is_owned)
         "attn/in_proj", "attn/shortconv", "attn/out_proj",
-        "attn/qknorm_rope"}
+        "attn/qknorm_rope",
+        # the post-normed hybrid's full attention, which its own step
+        # opens (test_olmo_hybrid.py::test_a_step_through_the_step_builder)
+        "attn/qknorm"}
     assert added == {s.split("/", 1)[1] for s in new} - {
         "attn/flash", "attn/proj"} | {"mlp/gate_up", "mlp/down"}
 
